@@ -18,8 +18,8 @@ COPY src ./src
 
 RUN pip install --no-cache-dir ".[kafka,dashboard,geometry]" \
     # Compile the native ingest shim ahead of time so first ingest does
-    # not pay the build (it falls back to numpy if this fails).
-    && python -c "from esslivedata_tpu.native import flatten_events; print('native shim:', flatten_events is not None)"
+    # not pay the build; the image has g++, so a failed build fails here.
+    && python -c "from esslivedata_tpu import native; assert native.available(), native.unavailable_reason()"
 
 FROM python:3.12-slim
 

@@ -44,7 +44,7 @@ multichip:
 
 # Force-rebuild the native ingest shim (normally compile-on-demand).
 native:
-	rm -f src/esslivedata_tpu/native/_ingest.so
+	rm -f src/esslivedata_tpu/native/_ingest*.so
 	$(PY) -c "import sys; sys.path.insert(0, 'src'); \
 		from esslivedata_tpu import native; assert native.available()"
 

@@ -2,8 +2,10 @@
 """Multichip mesh benchmark driver: the dryrun, promoted (ADR 0115).
 
 `__graft_entry__.dryrun_multichip` proved the data×bank mesh compiles
-and executes one sharded step on 8 virtual CPU devices
-(MULTICHIP_r05.json). This driver runs the REAL serving path instead:
+and executes one sharded step on 8 virtual CPU devices. This driver
+runs the REAL serving path instead, still on 8 VIRTUAL CPU DEVICES — it
+is a structure check (per-slice dispatch counts, byte parity), never a
+measurement of a mesh of chips:
 ``bench.py --mesh`` in a FRESH subprocess — the
 ``--xla_force_host_platform_device_count`` flag must be staged before
 any backend init, which is exactly why this cannot run in an
@@ -58,7 +60,12 @@ def _parse_lines(stderr: str) -> dict[str, dict]:
 
 
 def main(argv: list[str] | None = None) -> int:
-    parser = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    parser = argparse.ArgumentParser(
+        description="Mesh serving-tier STRUCTURE check on 8 virtual CPU "
+        "devices (per-slice dispatch counts and da00 byte parity, run as "
+        "bench.py --mesh in a fresh subprocess). Not a measurement of a "
+        "mesh of chips: every 'device' shares this host's cores."
+    )
     parser.add_argument("--events", type=int, default=None)
     parser.add_argument("--batches", type=int, default=None)
     parser.add_argument(

@@ -63,6 +63,7 @@ REQUIRED_FAMILIES = (
     "livedata_stream_messages",
     "livedata_kafka_sink_events",
     "livedata_hbm_bytes",
+    "livedata_device_info",
     # SLO plane (ADR 0120): the e2e freshness histogram and the
     # state-loss counter are always-registered instruments.
     "livedata_e2e_latency_seconds",

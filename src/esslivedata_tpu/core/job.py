@@ -11,6 +11,7 @@ from __future__ import annotations
 import time
 import uuid
 from collections.abc import Mapping
+from enum import StrEnum
 from typing import Any
 
 import numpy as np
@@ -18,7 +19,6 @@ from pydantic import BaseModel, Field
 
 from ..config.workflow_spec import JobId, JobSchedule, ResultKey, WorkflowId
 from ..telemetry.health import HEALTH
-from ..utils.compat import StrEnum
 from ..utils.labeled import DataArray, Variable
 from ..workflows.workflow_factory import Workflow
 from .timestamp import Duration, Timestamp

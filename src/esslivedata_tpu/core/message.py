@@ -15,9 +15,9 @@ from __future__ import annotations
 
 from collections.abc import Sequence
 from dataclasses import dataclass, field
+from enum import StrEnum
 from typing import Generic, Protocol, TypeVar, runtime_checkable
 
-from ..utils.compat import StrEnum
 from .timestamp import Timestamp
 
 PayloadT = TypeVar("PayloadT")

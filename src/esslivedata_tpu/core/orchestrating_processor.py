@@ -1019,6 +1019,18 @@ class OrchestratingProcessor:
                     ],
                 )
             )
+        from ..utils.runtime import device_identity
+
+        identity = device_identity()
+        families.append(
+            family(
+                "livedata_device_info",
+                "gauge",
+                "The device this service computes on, as jax reports it "
+                "(platform / device_kind / count as labels; value 1)",
+                [(tuple((k, str(v)) for k, v in identity.items()), 1)],
+            )
+        )
         hbm = MetricFamily(
             "livedata_hbm_bytes",
             "gauge",

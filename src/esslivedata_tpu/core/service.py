@@ -152,9 +152,9 @@ def setup_arg_parser(description: str = "") -> argparse.ArgumentParser:
     """Common CLI surface shared by all services (reference service.py:194).
 
     ``LIVEDATA_FORCE_CPU`` (1/true/yes) or ``--cpu`` pins JAX to the CPU
-    backend before anything initializes one — the dev/demo escape hatch
-    for machines where the ambient accelerator platform is configured but
-    unreachable (backend init would otherwise hang or fail every job).
+    backend before anything initializes one — how a dev/demo run CHOOSES
+    the CPU. Without it the service runs on whatever jax finds and names
+    it at start (utils/runtime.log_device_identity).
     """
     parser = _ServiceArgumentParser(description=description)
     parser.add_argument("--instrument", required=False, default="dummy")
@@ -214,9 +214,8 @@ def setup_arg_parser(description: str = "") -> argparse.ArgumentParser:
         default=False,
         help="AOT warm-up (ADR 0118): compile tick programs on a "
         "background thread at job-commit/policy-flip time so the hot "
-        "path never pays a jit compile at commit; with "
-        "--checkpoint-dir also enables JAX's persistent compilation "
-        "cache so restarts skip XLA (LIVEDATA_WARMUP equivalently)",
+        "path never pays a jit compile at commit "
+        "(LIVEDATA_WARMUP equivalently)",
     )
     parser.add_argument(
         "--batch-decode",

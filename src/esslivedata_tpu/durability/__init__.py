@@ -7,8 +7,9 @@ Three pieces, composable and individually optional:
   time, seeding the :class:`~..ops.tick.TickCombiner` program LRU so
   the first post-commit tick is a cache hit — commit-time compile count
   on the hot path is 0 (measured by the ADR 0116 instrument) and
-  first-tick latency equals steady state. Also enables JAX's persistent
-  compilation cache so process restarts skip XLA entirely.
+  first-tick latency equals steady state. (The persistent compilation
+  cache that lets restarts skip XLA is placed by every runner at start,
+  utils/runtime.py, whether or not warm-up is on.)
 - :mod:`.checkpoint` — ``CheckpointPlane``: periodic, epoch-tagged
   device→host snapshots of rolling-histogram state plus per-stream
   Kafka offset bookmarks, written atomically under a manifest
@@ -24,17 +25,12 @@ Three pieces, composable and individually optional:
 
 from .checkpoint import CheckpointPlane
 from .replay import load_latest_manifest, start_offsets
-from .warmup import (
-    CompileWarmupService,
-    WarmupRequest,
-    enable_persistent_compilation_cache,
-)
+from .warmup import CompileWarmupService, WarmupRequest
 
 __all__ = [
     "CheckpointPlane",
     "CompileWarmupService",
     "WarmupRequest",
-    "enable_persistent_compilation_cache",
     "load_latest_manifest",
     "start_offsets",
 ]

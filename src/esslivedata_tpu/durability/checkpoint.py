@@ -25,7 +25,7 @@ gone too. This plane generalizes it into the periodic channel
   (×4) while the attached :class:`~..core.link_monitor.LinkMonitor`
   reports a degraded link or a widened publish tick — a checkpoint's
   device→host fetches must never compete with a congested publish
-  path for relay bandwidth.
+  path.
 - **Staleness.** Run-boundary resets bump a persistent ``reset_seq``
   marker (``note_reset``, written atomically). A manifest written
   BEFORE the most recent reset is rejected by :func:`.replay.
@@ -141,8 +141,8 @@ class CheckpointPlane:
     def due(self, now: float | None = None) -> bool:
         """True when the next checkpoint should be taken. The interval
         stretches ×4 while the link monitor reports a degraded link or
-        a widened publish tick: snapshot fetches are relay traffic, and
-        a congested publish path must win that contention."""
+        a widened publish tick: snapshot fetches share the device→host
+        path, and a congested publish path must win that contention."""
         now = time.monotonic() if now is None else now
         with self._lock:
             last = self._last_wall

@@ -22,10 +22,9 @@ the loop (ROADMAP item 1, SNIPPETS.md [1] ``Lowered`` AOT path):
   AOT-lowers, compiles, and seeds the program LRU with the ready
   executable. The next live tick is a cache hit: no compile event, no
   ``last_compiled`` RTT exclusion, first-tick latency == steady state.
-- :func:`enable_persistent_compilation_cache` turns on JAX's on-disk
-  compilation cache (every entry, no minimum size/time), so a process
-  restart re-lowers but skips XLA entirely — warm-up after restart is
-  milliseconds, not seconds.
+- The runners' persistent compilation cache (utils/runtime.py, every
+  entry, no minimum size/time) is written by ``Lowered.compile`` too,
+  so a process restart re-lowers but skips XLA entirely.
 
 Warm-up is strictly best-effort: a failed request is counted
 (``livedata_durability_warmup_failures_total``) and the live path
@@ -45,11 +44,7 @@ from typing import Any
 
 from ..telemetry.registry import REGISTRY
 
-__all__ = [
-    "CompileWarmupService",
-    "WarmupRequest",
-    "enable_persistent_compilation_cache",
-]
+__all__ = ["CompileWarmupService", "WarmupRequest"]
 
 logger = logging.getLogger(__name__)
 
@@ -69,30 +64,6 @@ _WARMUP_SECONDS = REGISTRY.histogram(
     "livedata_durability_warmup_seconds",
     "Wall time of one warm-up request (staging + AOT lower + compile)",
 )
-
-
-def enable_persistent_compilation_cache(directory) -> bool:
-    """Point JAX's persistent compilation cache at ``directory`` so a
-    restarted process skips XLA for every program it compiled before
-    (warm-up included — the AOT ``Lowered.compile`` path writes the
-    same cache). Every entry is cached regardless of size or compile
-    time: the tick programs this plane exists for are small and fast on
-    CPU but seconds-scale on a real mesh, and the restart-latency win
-    is the point either way. Returns False (logged) when this jax build
-    lacks the config surface."""
-    import jax
-
-    try:
-        jax.config.update("jax_compilation_cache_dir", str(directory))
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    except Exception:
-        logger.exception(
-            "persistent compilation cache unavailable on this jax build"
-        )
-        return False
-    logger.info("persistent compilation cache at %s", directory)
-    return True
 
 
 @dataclass(slots=True)

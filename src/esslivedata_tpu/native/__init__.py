@@ -1,9 +1,13 @@
 """ctypes binding for the native ingest shim (ingest.cpp).
 
-The library is compiled on demand with g++ into this package directory and
-cached; if no compiler is available the binding reports unavailable and
-callers fall back to the pure-Python path (kafka/wire.py decode +
-ops/event_batch.StagingBuffer) — identical semantics, same tests.
+The library is compiled on demand with g++ into this package directory
+from the two tracked sources and cached under a name that carries a
+digest of their bytes, so a binary is only ever loaded for the exact
+sources it was built from (a checkout, an edit or a stale untracked
+``.so`` can never be trusted by accident). If no compiler is available
+the binding reports unavailable and callers fall back to the pure-Python
+path (kafka/wire.py decode + ops/event_batch.StagingBuffer) — identical
+semantics, same tests; :func:`unavailable_reason` says why.
 
 Reference parity: this is our equivalent of the native machinery the
 reference's ingest path rests on (generated FlatBuffers decode in
@@ -14,6 +18,9 @@ reference kafka/message_adapter.py:360 for the partial-decode fast path).
 from __future__ import annotations
 
 import ctypes
+import hashlib
+import logging
+import os
 import subprocess
 import threading
 from pathlib import Path
@@ -24,16 +31,19 @@ __all__ = [
     "NativeStagingBuffer",
     "available",
     "ev44_info",
+    "library_path",
     "load_library",
+    "unavailable_reason",
 ]
+
+logger = logging.getLogger(__name__)
 
 _HERE = Path(__file__).resolve().parent
 _SOURCES = [_HERE / "ingest.cpp", _HERE / "da00_encode.cpp"]
-_LIB = _HERE / "_ingest.so"
 
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
-_load_failed = False
+_load_error: str | None = None
 
 _ERRORS = {
     -1: "short or corrupt flatbuffer",
@@ -46,7 +56,20 @@ _ERRORS = {
 }
 
 
-def _compile() -> bool:
+def library_path() -> Path:
+    """The cached binary for the sources as they are on disk now:
+    ``_ingest-<sha256 of both sources, 16 hex>.so`` beside them."""
+    digest = hashlib.sha256()
+    for source in _SOURCES:
+        digest.update(source.read_bytes())
+    return _HERE / f"_ingest-{digest.hexdigest()[:16]}.so"
+
+
+def _compile(target: Path) -> str | None:
+    """Build ``target`` from the tracked sources; None on success, else
+    the reason. Links to a private temp name and renames into place, so
+    a concurrent process never loads a half-written binary."""
+    tmp = target.with_name(f".{target.name}.{os.getpid()}.tmp")
     cmd = [
         "g++",
         "-O3",
@@ -56,13 +79,26 @@ def _compile() -> bool:
         "-std=c++17",
         *[str(s) for s in _SOURCES],
         "-o",
-        str(_LIB),
+        str(tmp),
     ]
     try:
         proc = subprocess.run(cmd, capture_output=True, timeout=120)
-    except (OSError, subprocess.TimeoutExpired):
-        return False
-    return proc.returncode == 0 and _LIB.exists()
+    except (OSError, subprocess.TimeoutExpired) as err:
+        return f"g++ did not run: {err!r}"
+    try:
+        if proc.returncode != 0 or not tmp.exists():
+            return (
+                f"g++ exited {proc.returncode}: "
+                f"{proc.stderr.decode(errors='replace')[-2000:]}"
+            )
+        os.replace(tmp, target)
+    finally:
+        tmp.unlink(missing_ok=True)
+    # Binaries of other source digests are dead weight, never loaded.
+    for stale in _HERE.glob("_ingest*.so"):
+        if stale != target:
+            stale.unlink(missing_ok=True)
+    return None
 
 
 def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
@@ -159,35 +195,43 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
 
 def load_library() -> ctypes.CDLL | None:
     """Load (compiling if needed) the native library; None if unavailable."""
-    global _lib, _load_failed
+    global _lib, _load_error
     with _lock:
         if _lib is not None:
             return _lib
-        if _load_failed:
-            return None
-        # A cached .so older than the source misses newly added symbols
-        # (binding would raise AttributeError): rebuild it.
-        stale = _LIB.exists() and any(
-            s.exists() and _LIB.stat().st_mtime < s.stat().st_mtime
-            for s in _SOURCES
-        )
-        if (not _LIB.exists() or stale) and not _compile():
-            _load_failed = True
+        if _load_error is not None:
             return None
         try:
-            _lib = _bind(ctypes.CDLL(str(_LIB)))
-        except (OSError, AttributeError):
-            # AttributeError: stale cached binary missing a symbol despite
-            # the mtime check (e.g. clock skew on a shared filesystem) —
-            # fall back to the pure-Python paths rather than crashing
-            # every native entry point.
-            _load_failed = True
-            return None
+            target = library_path()
+        except OSError as err:
+            _load_error = f"native sources unreadable: {err!r}"
+        else:
+            if not target.exists():
+                # One-time initialization: every caller needs the
+                # library and has to wait for this build anyway.
+                _load_error = _compile(target)  # graftlint: disable=JGL023
+            if _load_error is None:
+                try:
+                    _lib = _bind(ctypes.CDLL(str(target)))
+                except (OSError, AttributeError) as err:
+                    _load_error = f"{target.name} did not load: {err!r}"
+        if _load_error is not None:
+            logger.warning(
+                "native ingest shim unavailable, using the numpy paths: %s",
+                _load_error,
+            )
         return _lib
 
 
 def available() -> bool:
     return load_library() is not None
+
+
+def unavailable_reason() -> str | None:
+    """Why :func:`available` is False (compiler output included), or
+    None when the library loaded."""
+    load_library()
+    return _load_error
 
 
 def da00_encode_raw(

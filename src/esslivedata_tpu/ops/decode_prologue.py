@@ -89,7 +89,8 @@ def decode_prologue(pixel_id, toa, *, interpret: bool | None = None):
     is semantically identical. Off-TPU the jnp kernel is also the
     DEFAULT (interpret-mode pallas is a test vehicle, not a fast path);
     pass ``interpret=True`` explicitly to exercise the pallas kernel
-    without hardware.
+    without hardware. On TPU a kernel that fails to lower RAISES: a
+    Mosaic refusal must surface, not hide behind the jnp kernel.
     """
     n = int(pixel_id.shape[0])
     if n == 0 or n % _BLOCK:
@@ -98,7 +99,4 @@ def decode_prologue(pixel_id, toa, *, interpret: bool | None = None):
         if jax.default_backend() != "tpu":
             return _prologue_jnp(pixel_id, toa, False)
         interpret = False
-    try:
-        return _prologue_pallas(pixel_id, toa, bool(interpret))
-    except Exception:  # pragma: no cover - pallas unavailable/lowering gap
-        return _prologue_jnp(pixel_id, toa, False)
+    return _prologue_pallas(pixel_id, toa, bool(interpret))

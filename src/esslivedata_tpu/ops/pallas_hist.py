@@ -84,17 +84,8 @@ def _bincount_call(flat, n_bins_padded: int, block: int, interpret: bool):
 
     # vma propagation: inside shard_map (the sharded Q kernels) the
     # per-shard delta varies over the mesh axes the events vary over;
-    # check_vma requires the out_shape to say so. Older jax (0.4.x,
-    # check_rep era) has neither jax.typeof nor the vma field — there
-    # the sharded callers disable the replication check instead
-    # (parallel/mesh.py shard_map shim), so the plain ShapeDtypeStruct
-    # is exactly right.
-    sds_kwargs = {}
-    typeof = getattr(jax, "typeof", None)
-    if typeof is not None:
-        vma = getattr(typeof(flat), "vma", None)
-        if vma is not None:
-            sds_kwargs["vma"] = vma
+    # check_vma requires the out_shape to say so.
+    vma = jax.typeof(flat).vma
     return pl.pallas_call(
         kernel,
         grid=(grid,),
@@ -103,7 +94,7 @@ def _bincount_call(flat, n_bins_padded: int, block: int, interpret: bool):
         ],
         out_specs=pl.BlockSpec((1, n_bins_padded), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct(
-            (1, n_bins_padded), jnp.float32, **sds_kwargs
+            (1, n_bins_padded), jnp.float32, vma=vma
         ),
         interpret=interpret,
     )(rows)[0]

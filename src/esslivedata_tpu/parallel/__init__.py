@@ -21,11 +21,9 @@ spread round-robin across chips, bank-sharded LOKI-scale jobs take the
 whole mesh. Service surface: ``--mesh data,bank`` / ``LIVEDATA_MESH``
 (services/service_factory.py); per-slice dispatch counts and publish
 RTTs report through ``ops/publish.METRICS`` and the link monitor.
-:mod:`.mesh` also carries the jax-version ``shard_map`` shim (modern
-``jax.shard_map`` vs the 0.4.x experimental entry point).
 """
 
-from .mesh import make_mesh, mesh_from_spec, shard_map, shard_map_available
+from .mesh import make_mesh, mesh_from_spec
 from .mesh_tick import DevicePlacement, MeshTickCombiner, TickSlice
 from .sharded_hist import ShardedHistogrammer
 from .sharded_qhist import ShardedQHistogrammer
@@ -38,6 +36,4 @@ __all__ = [
     "TickSlice",
     "make_mesh",
     "mesh_from_spec",
-    "shard_map",
-    "shard_map_available",
 ]

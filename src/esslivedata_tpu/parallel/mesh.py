@@ -1,79 +1,13 @@
-"""Mesh construction helpers + the jax-version shard_map shim.
-
-``shard_map`` is the mesh serving tier's one hard jax dependency and its
-import path moved across jax releases: modern jax exposes ``jax.shard_map``
-(with a ``check_vma`` kwarg), while the 0.4.x line ships it as
-``jax.experimental.shard_map.shard_map`` (kwarg named ``check_rep``).
-The shim below resolves whichever this jax provides so the sharded
-kernels — and the mesh tick program built on them (parallel/mesh_tick.py,
-ADR 0115) — compile on both, instead of the whole parallel layer dying
-with an AttributeError on the older line (SNIPPETS.md [2]'s
-prefer-explicit-shardings-else-shard_map fallback shape).
-"""
+"""Mesh construction helpers for the mesh serving tier (ADR 0115)."""
 
 from __future__ import annotations
-
-from typing import Callable
 
 import numpy as np
 
 import jax
 from jax.sharding import Mesh
 
-__all__ = ["make_mesh", "mesh_from_spec", "shard_map", "shard_map_available"]
-
-
-def _resolve_shard_map() -> tuple[Callable | None, bool]:
-    """(shard_map callable, native) for this jax, else (None, False).
-
-    ``native`` = the modern ``jax.shard_map`` entry point (accepts
-    ``check_vma``); the experimental fallback takes ``check_rep``.
-    """
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None:
-        return fn, True
-    try:  # jax 0.4.x line
-        from jax.experimental.shard_map import shard_map as legacy
-    except ImportError:
-        return None, False
-    return legacy, False
-
-
-_SHARD_MAP, _SHARD_MAP_NATIVE = _resolve_shard_map()
-
-
-def shard_map_available() -> bool:
-    """True when some shard_map entry point exists on this jax. When
-    False, the collective mesh kernels cannot compile at all — callers
-    (and the version-guarded tests) degrade to single-device serving
-    with a message naming the gap instead of an AttributeError."""
-    return _SHARD_MAP is not None
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """Version-portable ``shard_map``.
-
-    Maps ``check_vma`` onto the older line's ``check_rep`` (same
-    semantics: disable the static varying-mesh-axes/replication check
-    where a kernel's replication invariant holds by construction but
-    cannot be inferred — the event_gather exchange, interpret-mode
-    pallas)."""
-    if _SHARD_MAP is None:
-        raise RuntimeError(
-            "This jax provides neither jax.shard_map nor "
-            "jax.experimental.shard_map: the mesh-sharded kernels "
-            "(parallel/) cannot compile. Upgrade jax or use the "
-            "single-device serving path."
-        )
-    if _SHARD_MAP_NATIVE:
-        return _SHARD_MAP(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    return _SHARD_MAP(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma,
-    )
+__all__ = ["make_mesh", "mesh_from_spec"]
 
 
 def mesh_from_spec(spec: str, *, devices=None) -> Mesh:

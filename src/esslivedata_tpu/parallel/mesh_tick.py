@@ -3,7 +3,7 @@ data×bank mesh, and place every tick group on a mesh slice.
 
 The single-device hot path runs a steady-state tick as ONE jitted
 dispatch + ONE fetch (ops/tick.py). This module is the scale-out tier
-that turns the standalone mesh demo (`MULTICHIP_r05.json`'s dryrun) into
+that turns the standalone mesh demo (`__graft_entry__.py`'s dryrun) into
 the real serving topology (ADR 0115, ROADMAP item 1):
 
 - :class:`MeshTickCombiner` compiles the SAME tick program under a
@@ -15,8 +15,7 @@ the real serving topology (ADR 0115, ROADMAP item 1):
   output vector is replicated and ONE ``device_get`` serves the whole
   mesh. Donation is preserved straight through the outer jit
   (SNIPPETS.md [1]–[2]: donation composes with pjit-style explicit
-  shardings; the shard_map fallback shim in :mod:`.mesh` covers jax
-  lines without the modern entry point).
+  shardings).
 
 - :class:`DevicePlacement` makes the JobManager placement-aware: each
   (stream, fuse-key) tick group is assigned a mesh *slice* — a single

@@ -45,7 +45,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops.histogram import EventProjection, HistogramState
-from .mesh import shard_map
 
 __all__ = ["ShardedHistogrammer"]
 
@@ -112,6 +111,10 @@ class ShardedHistogrammer:
         # an argument (ADR 0105).
         self._proj.place_constants(self._replicate)
         self._lut_rep = self._proj.lut if self._has_lut else None
+        # graft: key-derived=_rows_per_bank,_n_toa pure functions of
+        # keyed configuration: fuse_key carries n_bank and the layout
+        # digest, which hashes the screen size and the edges these
+        # unpack from.
         self._rows_per_bank = n_screen // self._n_bank
         self._n_screen = n_screen
         self._n_toa = self._proj.n_toa
@@ -143,7 +146,7 @@ class ShardedHistogrammer:
 
         lut_specs = (P(),) if self._has_lut else ()  # replicated LUT arg
         shard = partial(
-            shard_map,
+            jax.shard_map,
             mesh=mesh,
             in_specs=(
                 P("bank", None),  # window
@@ -207,7 +210,7 @@ class ShardedHistogrammer:
         self._fused = jax.jit(self._tick_step_impl, donate_argnums=(0,))
 
         norm = partial(
-            shard_map,
+            jax.shard_map,
             mesh=mesh,
             in_specs=(P("bank", None), P("data")),
             out_specs=P("bank", None),

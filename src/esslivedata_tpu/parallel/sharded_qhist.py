@@ -33,7 +33,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops.event_batch import sanitize_pixel_id, stage_for
 from ..ops.qhistogram import PixelBinMap, QState, table_scatter_delta
-from .mesh import shard_map
 
 __all__ = ["ShardedQHistogrammer"]
 
@@ -141,7 +140,7 @@ class ShardedQHistogrammer:
             monitor_window=P(),
         )
         self._step = jax.jit(
-            shard_map(
+            jax.shard_map(
                 _step,
                 mesh=mesh,
                 in_specs=(state_specs, P(axis, None), P(), P(), P()),

@@ -11,11 +11,10 @@ plain numbers converted by the consuming workflow).
 from __future__ import annotations
 
 import json
+from enum import StrEnum
 
 import numpy as np
 from pydantic import BaseModel, Field, field_validator, model_validator
-
-from .utils.compat import StrEnum
 
 __all__ = [
     "Angle",

@@ -240,17 +240,9 @@ class DataServiceBuilder:
             # A bad mesh spec is a deployment configuration error: fail
             # the build loudly rather than silently serving single-
             # placement (the operator asked for a topology).
-            from ..parallel.mesh import mesh_from_spec, shard_map_available
+            from ..parallel.mesh import mesh_from_spec
             from ..parallel.mesh_tick import DevicePlacement
 
-            if not shard_map_available():
-                raise RuntimeError(
-                    "--mesh/LIVEDATA_MESH requested but this jax "
-                    "provides no shard_map entry point (neither "
-                    "jax.shard_map nor jax.experimental.shard_map): "
-                    "mesh-sharded kernels cannot compile. Upgrade jax "
-                    "or drop the mesh spec."
-                )
             mesh = mesh_from_spec(self.mesh_spec)
             placement = DevicePlacement(mesh)
             logger.info(
@@ -294,20 +286,9 @@ class DataServiceBuilder:
                 replica_ids,
             )
         if self.warmup:
-            from ..durability import (
-                CompileWarmupService,
-                enable_persistent_compilation_cache,
-            )
+            from ..durability import CompileWarmupService
 
             job_manager.set_warmup(CompileWarmupService())
-            if self.checkpoint_dir:
-                # Restarts skip XLA entirely: the AOT warm-up path and
-                # the live jits share one on-disk compilation cache.
-                import os as _os
-
-                enable_persistent_compilation_cache(
-                    _os.path.join(self.checkpoint_dir, "xla-cache")
-                )
             logger.info("AOT tick-program warm-up enabled")
         # Contract derived from this instrument's registered specs: outputs
         # listed in ``device_outputs`` ride the stable NICOS device stream.
@@ -528,7 +509,15 @@ class DataServiceRunner:
             )
             return 0
         from ..kafka.consumer import assign_all_partitions
+        from ..utils.runtime import (
+            enable_persistent_compilation_cache,
+            log_device_identity,
+        )
 
+        # Before anything compiles: the AOT warm-up path and the live
+        # jits share one on-disk compilation cache, so restarts skip XLA.
+        enable_persistent_compilation_cache()
+        log_device_identity()
         if args.broker_dir:
             from ..kafka.file_broker import (
                 FileBrokerConsumer,

@@ -88,7 +88,7 @@ E2E_STAGES = (
 
 #: Freshness buckets: resolve the <100 ms SLO region finely (the
 #: ROADMAP headline), keep coverage out to the multi-second stalls a
-#: congested relay or a wedged consumer produces.
+#: congested publish path or a wedged consumer produces.
 E2E_BUCKETS = (
     0.001,
     0.0025,

@@ -26,7 +26,7 @@ __all__ = [
 
 #: Publish/tick device round-trip wall times as a labeled histogram
 #: (ADR 0116): the EWMA drives the link policy, but a scrape needs the
-#: DISTRIBUTION — a bimodal RTT (healthy ticks + relay stalls) averages
+#: DISTRIBUTION — a bimodal RTT (healthy ticks + stalls) averages
 #: into a lie. ``slice`` carries the mesh slice (ADR 0115) or "all".
 PUBLISH_RTT_SECONDS = REGISTRY.histogram(
     "livedata_publish_rtt_seconds",

@@ -55,7 +55,7 @@ __all__ = ["TRACER", "Span", "TickTracer"]
 logger = logging.getLogger(__name__)
 
 #: Aggregate span-duration decomposition on the scrape; buckets from
-#: sub-ms host phases up through relay-RTT-dominated device ticks.
+#: sub-ms host phases up through multi-second device ticks.
 _SPAN_SECONDS = REGISTRY.histogram(
     "livedata_tick_span_seconds",
     "Duration of per-tick phases (decode/prestage/tick_execute/fetch/"

@@ -30,7 +30,7 @@ class TestPolicySwitching:
         injected timings, must flip the batch-size target AND the wire
         format (and back)."""
         monitor = LinkMonitor()
-        # Healthy relay: ~800 MB/s (round-3 measured regime).
+        # Healthy link: ~800 MB/s.
         feed(monitor, 8.0e8)
         healthy = monitor.policy()
         assert healthy.window_scale == 1.0
@@ -84,8 +84,8 @@ class TestPolicySwitching:
         assert monitor.policy().window_scale == 8.0
 
     def test_rtt_alone_deepens_pipeline(self):
-        """A healthy-bandwidth but high-RTT link (the 78 ms relay round
-        trip) still wants more windows in flight."""
+        """A healthy-bandwidth but high-RTT link (a 78 ms round trip)
+        still wants more windows in flight."""
         monitor = LinkMonitor()
         feed(monitor, 8.0e8)
         for _ in range(20):

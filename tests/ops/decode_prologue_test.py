@@ -79,6 +79,21 @@ class TestKernelParity:
         assert np.asarray(out_pid).shape == (_BLOCK + 1,)
 
 
+class TestNoSilentFallback:
+    def test_raising_pallas_call_is_not_swallowed(self, monkeypatch):
+        """A kernel that fails to lower must surface (on TPU: a Mosaic
+        refusal), never quietly become the jnp kernel."""
+        from esslivedata_tpu.ops import decode_prologue as module
+
+        def refuse(*_args):
+            raise RuntimeError("Mosaic refused the kernel")
+
+        monkeypatch.setattr(module, "_prologue_pallas", refuse)
+        pid, toa = _wire_pair(_BLOCK)
+        with pytest.raises(RuntimeError, match="Mosaic refused"):
+            decode_prologue(pid, toa, interpret=True)
+
+
 class TestStageRawFusion:
     def _batch(self, prologue):
         pid = np.full(4096, -1, dtype=np.int32)

@@ -24,7 +24,6 @@ from esslivedata_tpu.kafka.wire import encode_da00
 from esslivedata_tpu.ops import EventBatch
 from esslivedata_tpu.ops.publish import METRICS
 from esslivedata_tpu.parallel import ShardedHistogrammer, make_mesh
-from esslivedata_tpu.parallel.mesh import shard_map_available
 from esslivedata_tpu.parallel.mesh_tick import (
     DevicePlacement,
     MeshTickCombiner,
@@ -34,20 +33,6 @@ from esslivedata_tpu.workflows import WorkflowFactory
 from esslivedata_tpu.workflows.multibank import (
     MultiBankParams,
     MultiBankViewWorkflow,
-)
-
-# Version guard, not an error: the jax-0.4.37 line ships shard_map only
-# as jax.experimental.shard_map (check_rep era) — parallel/mesh.py shims
-# it — but a jax with NEITHER entry point cannot compile the collective
-# mesh step at all, and these tests must say so instead of erroring.
-pytestmark = pytest.mark.skipif(
-    not shard_map_available(),
-    reason=(
-        "this jax provides neither jax.shard_map nor "
-        "jax.experimental.shard_map.shard_map (the jax-0.4.37-era API "
-        "the mesh shim falls back to): the mesh tick program's "
-        "collective step cannot compile"
-    ),
 )
 
 T = Timestamp.from_ns

@@ -127,6 +127,7 @@ class TestScrapeExposesTheStack:
             "livedata_stream_messages",
             "livedata_kafka_sink_events",
             "livedata_hbm_bytes",
+            "livedata_device_info",
             "livedata_jit_compiles_total",
             "livedata_jit_compile_seconds",
             "livedata_tick_span_seconds",
@@ -138,6 +139,12 @@ class TestScrapeExposesTheStack:
         # tick program, spans decomposed the windows, the pipeline
         # reported its stages.
         assert parse_one_total(parsed, "livedata_jit_compiles_total") >= 1
+        # Every live processor names its device (tests pin the CPU).
+        infos = parsed["livedata_device_info"].samples
+        assert infos
+        for _name, device, value in infos:
+            assert value == 1 and device["platform"] == "cpu"
+            assert device["device_kind"] and int(device["count"]) >= 1
         span_names = {
             labels.get("span")
             for _n, labels, _v in parsed["livedata_tick_span_seconds"].samples

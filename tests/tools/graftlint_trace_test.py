@@ -198,7 +198,8 @@ def test_jgl104_debug_callback_fires():
     report = run_trace(specs=[_spec(build)])
     assert _rules(report) == ["JGL104"]
     [f] = report.findings
-    assert "debug_callback" in f.message
+    # jax 0.9 traces jax.debug.print to the primitive ``debug_print``.
+    assert "debug_print" in f.message
 
 
 def test_jgl104_pure_callback_fires():

@@ -428,7 +428,7 @@ class TestLinkMonitorCoalesceAxis:
         # Back in the dead zone from BELOW: stays released.
         mon.observe_publish(0.03)
         assert mon.policy().publish_coalesce == 1
-        # A catastrophic relay caps at the bound.
+        # A catastrophic RTT caps at the bound.
         mon.observe_publish(0.5)
         assert mon.policy().publish_coalesce == 8
 

@@ -45,6 +45,7 @@ _HOST_PRIMS = frozenset(
         "pure_callback",
         "io_callback",
         "debug_callback",
+        "debug_print",  # what jax.debug.print traces to since jax 0.9
         "callback",
         "infeed",
         "outfeed",
@@ -193,8 +194,8 @@ def _check_program(jax, spec, program, path: str, line: int):
                 "JGL104",
                 f"{spec.family}: host callback primitive(s) "
                 f"{sorted(hits)} inside the traced {program.label} "
-                "program — each one is a per-tick host round trip on "
-                "the relay; move the host work off the tick (publish "
+                "program — each one is a per-tick host round trip; "
+                "move the host work off the tick (publish "
                 "channel, telemetry thread)",
             )
         )
@@ -293,7 +294,7 @@ def check_spec(jax, spec, encodable) -> tuple[list["Finding"], dict | None]:
                 f"{spec.family}: tick comprises {len(base.programs)} "
                 "executables "
                 f"({[p.label for p in base.programs]}) — every extra "
-                "program is a hidden relay round trip per tick; fuse "
+                "program is one more dispatch per tick; fuse "
                 "into the one tick program (ADR 0114)",
             )
         )
