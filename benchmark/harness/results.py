@@ -1,0 +1,217 @@
+"""The client: follows the data topic, stamps every publish with the
+time it was read, and after the window turns the record into the
+end-to-end metrics and the comparison with the reference.
+"""
+
+from __future__ import annotations
+
+from dataclasses import dataclass, field
+
+import numpy as np
+
+from .wire import decode_da00
+
+@dataclass(frozen=True)
+class Outputs:
+    """The outputs of one publish that are compared, as the
+    configuration file lists them under ``outputs``. A name ends in
+    ``_current`` (the pulses since the previous publish) or
+    ``_cumulative`` (all pulses). ``prefix_total`` is the cumulative
+    scalar that tells which pulses a publish holds; it comes last of
+    them on the wire and marks the publish as received."""
+
+    scalars: tuple[str, ...]
+    spectra: tuple[str, ...]
+    images: tuple[str, ...]
+    prefix_total: str
+
+    @classmethod
+    def from_config(cls, config: dict) -> "Outputs":
+        doc = config["outputs"]
+        return cls(tuple(doc["scalars"]), tuple(doc["spectra"]), tuple(doc["images"]),
+                   doc["prefix_total"])
+
+    @property
+    def compared(self) -> tuple[str, ...]:
+        return self.scalars + self.spectra + self.images
+
+
+@dataclass
+class Publish:
+    """One job's outputs of one closed window, as the client read them."""
+
+    job: str
+    ordinal: int  # 0, 1, ... per job
+    received_ns: int = 0  # CLOCK_MONOTONIC, at the prefix total
+    scalars: dict[str, float] = field(default_factory=dict)
+    spectra: dict[str, np.ndarray] = field(default_factory=dict)
+    images: dict[str, np.ndarray] = field(default_factory=dict)  # kept for the sample only
+    prefix: int = -1  # pulses [0, prefix) are in the prefix total
+    off_by: float = 0.0
+
+
+class ResultReader:
+    """Keeps every publish's scalars and spectra, and the images of the
+    publishes that ``sampled`` names plus each job's newest (at NMX size
+    all of them would not fit). The outputs of one publish share their
+    da00 timestamp, which is used as a grouping key and nothing else."""
+
+    def __init__(self, child, jobs_by_number: dict[str, str], clock, outputs: Outputs,
+                 sampled=lambda job, ordinal: False) -> None:
+        self._child = child
+        self._jobs = jobs_by_number
+        self._clock = clock
+        self.outputs = outputs
+        self._sampled = sampled
+        self._open: dict[tuple[str, int], tuple[Publish, list[int]]] = {}
+        self.publishes: dict[str, list[Publish]] = {j: [] for j in jobs_by_number.values()}
+        self.bytes_read = 0
+
+    def drain(self) -> int:
+        """Read what has arrived; returns how many publishes completed."""
+        done = 0
+        while raws := self._child.poll("data", 32):
+            now = self._clock()
+            for raw in raws:
+                self.bytes_read += len(raw)
+                source, stamp, variables = decode_da00(raw)
+                _wid, _src, number, output = source.split("|")
+                job = self._jobs.get(number)
+                if job is None or output not in self.outputs.compared:
+                    continue
+                publish, seen = self._open.setdefault(
+                    (job, stamp), (Publish(job, -1), [0])
+                )
+                seen[0] += 1
+                signal = variables["signal"]
+                if output in self.outputs.scalars:
+                    publish.scalars[output] = float(signal)
+                elif output in self.outputs.spectra:
+                    publish.spectra[output] = np.array(signal, np.float64)
+                else:
+                    publish.images[output] = np.array(signal)
+                if output == self.outputs.prefix_total:
+                    publish.received_ns = now
+                if seen[0] == len(self.outputs.compared):
+                    del self._open[(job, stamp)]
+                    items = self.publishes[job]
+                    publish.ordinal = len(items)
+                    if items and not self._sampled(job, items[-1].ordinal):
+                        items[-1].images.clear()
+                    items.append(publish)
+                    done += 1
+        return done
+
+
+def percentile(values, q: float) -> float:
+    return float(np.percentile(np.asarray(values, np.float64), q))
+
+
+def assign_prefixes(publishes: dict[str, list[Publish]], refs, pulses_sent: int,
+                    prefix_total: str) -> None:
+    for job, items in publishes.items():
+        for publish in items:
+            publish.prefix, publish.off_by = refs[job].prefix_of(
+                publish.scalars[prefix_total], pulses_sent
+            )
+
+
+def freshness(publishes, due_ns: np.ndarray, t0: int, t1: int, offered: int,
+              window_pulses: int, drained_ns: int) -> list[tuple[float, float]]:
+    """(due time after t0 in s, freshness in ms) of every (job, publish)
+    pair whose last pulse was due in [t0, t1), whenever it arrived:
+    receive time minus that due time. Pulses offered that no publish of
+    a job holds by ``drained_ns``, the drain's end, count window by
+    window with their age then: a stall or a backlog at the window's
+    end moves the tail, it does not fall out of it."""
+    out = []
+
+    def pair(last_pulse: int, seen_ns: int) -> None:
+        due = int(due_ns[last_pulse - 1])
+        if t0 <= due < t1:
+            out.append(((due - t0) / 1e9, (seen_ns - due) / 1e6))
+
+    for items in publishes.values():
+        held = 0
+        for publish in items:
+            if 0 < publish.prefix <= len(due_ns):
+                pair(publish.prefix, publish.received_ns)
+                held = max(held, publish.prefix)
+        while held < offered:
+            held = min(held + window_pulses, offered)
+            pair(held, drained_ns)
+    return out
+
+
+def pulses_covered(items: list[Publish], t0: int, t1: int) -> tuple[int, int]:
+    """(prefix of the last publish before t0, of the last inside [t0, t1))."""
+    before = [p.prefix for p in items if p.received_ns < t0]
+    inside = [p.prefix for p in items if t0 <= p.received_ns < t1]
+    lo = max(before, default=0)
+    return lo, max(inside, default=lo)
+
+
+#: float32 holds every integer below this; the outputs are float32.
+EXACT_BELOW = 2**24
+#: The share of an expected bin value of 2**24 or more by which a
+#: float32 output may miss it (16 of its last places); see PERF.md.
+ROUNDING = 2.0**-20
+
+
+def bins_off(got: np.ndarray, want: np.ndarray, rounding: float = ROUNDING) -> int:
+    """How many bins of ``got`` differ from the integers ``want``: at
+    all where the expected value is below 2**24, which float32 holds
+    exactly; by more than ``rounding`` of the expected value where it is
+    not (a bin gets there after 6 826 pulses of one hot TOA bin; no run does today)."""
+    slack = np.where(want < EXACT_BELOW, 0.0, want * rounding)
+    return int(np.count_nonzero(np.abs(got.astype(np.float64) - want) > slack))
+
+
+def compare(publishes, refs, limits: dict[str, float], outputs: Outputs,
+            since_ns: int = 0) -> tuple[dict, int]:
+    """The numbers compared over every publish of the run, each beside
+    its limit, and how many publishes received from ``since_ns`` on
+    arrived wrong. Bins are compared exactly as long as float32 can
+    hold them (``bins_off``). The float32 totals pass 2**24 within
+    seconds; the cumulative one is used only to find the publish's pulse
+    prefix, to the nearest pulse.
+    """
+    wrong = {"spectrum_bins_wrong": 0, "image_bins_wrong": 0, "prefix_off_pulses": 0.0}
+    compared = {"spectra": 0, "images": 0}
+    bad_publishes = 0
+    for job, items in publishes.items():
+        ref = refs[job]
+        previous = 0
+        for publish in items:
+            bad = 0
+            wrong["prefix_off_pulses"] = max(wrong["prefix_off_pulses"], publish.off_by)
+            bad += publish.off_by > limits["prefix_off_pulses"] or publish.prefix <= previous
+            spans = {"current": (previous, publish.prefix), "cumulative": (0, publish.prefix)}
+            for output, got in publish.spectra.items():
+                lo, hi = spans[output.rsplit("_", 1)[1]]
+                miss = bins_off(got, ref.spectrum(lo, max(hi, lo)))
+                wrong["spectrum_bins_wrong"] += miss
+                compared["spectra"] += 1
+                bad += miss > 0
+            for output, got in publish.images.items():
+                lo, hi = spans[output.rsplit("_", 1)[1]]
+                want = ref.image(lo, max(hi, lo))
+                if got.shape != want.shape:
+                    miss = want.size
+                else:
+                    miss = bins_off(got, want)
+                wrong["image_bins_wrong"] += miss
+                compared["images"] += 1
+                bad += miss > 0
+            if len(publish.spectra) != len(outputs.spectra):
+                bad += 1
+            bad_publishes += bad > 0 and publish.received_ns >= since_ns
+            previous = max(previous, publish.prefix)
+    numbers = {
+        name: {"value": value, "limit": limits[name]} for name, value in wrong.items()
+    }
+    numbers["largest_bin"] = {"value": max(
+        (float(s.max()) for items in publishes.values() for p in items
+         for s in p.spectra.values() if s.size), default=0.0), "exact_below": EXACT_BELOW}
+    numbers["compared"] = compared
+    return numbers, bad_publishes

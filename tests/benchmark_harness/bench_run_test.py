@@ -1,0 +1,169 @@
+"""The harness end to end on the package's dummy instrument, through the
+fixture's configuration and cells (hotspot pixels and four messages a
+pulse in the paced one), on the CPU: what a run measures and compares
+is driven as on the chip, only the look for a chip is skipped. The
+command itself keeps that look, and a test pins it."""
+
+from __future__ import annotations
+
+import dataclasses
+import json
+import os
+import shutil
+import subprocess
+import sys
+import time
+
+import pytest
+from bench_support import FIXTURE, REPO, overlay_fixture
+from harness import bench, manifest, reference
+
+SECONDS = 3.0
+
+
+def run(toy_root, cell_name, seed, *, trace=False, cell_edit=None, **how):
+    cell = manifest.load_cell(toy_root, cell_name)
+    if cell_edit:
+        cell = cell_edit(cell)
+    return bench.run_cell(
+        cell, seed, SECONDS, trace, REPO, time.monotonic(), allow_cpu=True, **how
+    )
+
+
+def shape_of_a_result(line, cell, trace):
+    assert list(line)[:5] == ["correct", "attempted", "failed", "metrics", "device"]
+    assert list(line)[-1] == "checks"  # the numbers compared come last
+    assert line["device"]["platform"] == "cpu" and line["device"]["count"] == 1
+    assert "memory_peak_bytes" in line["device"]
+    wanted = cell.per_layer if trace else cell.end_to_end
+    assert set(line["metrics"]) <= {m["name"] for m in wanted}
+    for name, entry in line["metrics"].items():
+        unit = next(m["unit"] for m in wanted if m["name"] == name)
+        assert entry["unit"] == unit and isinstance(entry["value"], float)
+    for name in ("spectrum_bins_wrong", "image_bins_wrong", "prefix_off_pulses"):
+        assert set(line["checks"][name]) == {"value", "limit"}
+
+
+def test_paced_cell_reports_freshness_of_every_pair_and_is_correct(toy_root):
+    line, report = run(toy_root, "toy_panel.toy_paced", 2**31 + 5)
+    cell = manifest.load_cell(toy_root, "toy_panel.toy_paced")
+    shape_of_a_result(line, cell, trace=False)
+    assert line["correct"] is True and line["failed"] == 0
+    assert line["attempted"] == int(SECONDS * 14) // 14  # one job, one publish a window
+    assert set(line["metrics"]) == {"freshness_p50_ms", "freshness_p95_ms", "setup_s"}
+    p50, p95 = (line["metrics"][k]["value"] for k in ("freshness_p50_ms", "freshness_p95_ms"))
+    # a window closes on the next pulse (71 ms) and the poll; never before
+    assert 71.0 < p50 <= p95 < 2000.0
+    assert line["checks"]["compared"]["spectra"] >= 2 * line["attempted"]
+    assert line["checks"]["compared"]["images"] >= 2
+    assert any(text.startswith("check failed_publishes: 0 of") for text in report)
+    assert report[0].startswith("check spectrum_bins_wrong: ")
+
+
+def test_traced_run_prints_the_per_layer_metrics_that_found_something(toy_root):
+    line, _ = run(toy_root, "toy_panel.toy_paced", 11, trace=True)
+    cell = manifest.load_cell(toy_root, "toy_panel.toy_paced")
+    shape_of_a_result(line, cell, trace=True)
+    assert line["correct"] is True
+    # prometheus and generator readers found their counters; the CPU has
+    # no device plane in its trace, so the trace readers return nothing
+    # and their metrics are left out (never 0 for a share of a peak).
+    assert {"generator_late_p95_ms", "decode_ms.paced", "compiles_in_window.paced",
+            "toy_messages"} <= set(line["metrics"])
+    assert line["metrics"]["compiles_in_window.paced"]["value"] == 0.0
+    assert line["metrics"]["toy_messages"]["value"] >= 4 * 14 * (SECONDS - 1)
+    assert "freshness_p50_ms" not in line["metrics"]
+    assert "breakdown" not in line and "busy_s" not in line["device"]
+
+
+def test_every_pair_due_in_the_window_is_in_the_line_with_its_due_time(toy_root):
+    line, _ = run(toy_root, "toy_panel.toy_blob", 12)
+    assert line["correct"] is True and line["failed"] == 0
+    pairs = line["pulses"]["pairs"]
+    # one job: one pair for each base window whose last pulse was due in the window
+    assert len(pairs) in (int(SECONDS) - 1, int(SECONDS), int(SECONDS) + 1)
+    assert all(0.0 <= at < line["pulses"]["window_s"] for at, _ in pairs)
+    assert [at for at, _ in pairs] == sorted(at for at, _ in pairs)
+    assert max(ms for _, ms in pairs) == pytest.approx(line["pulses"]["freshness_max_ms"], abs=1e-3)
+    assert line["metrics"]["freshness_p95_ms"]["value"] <= line["pulses"]["freshness_max_ms"]
+
+
+def test_controls_read_beside_a_sound_run_and_each_comes_out_not_correct(toy_root):
+    """The reference with one guarantee broken, put in the program's
+    place: the sound run stays correct, every control does not."""
+    line, _ = run(toy_root, "toy_panel.toy_paced", 14, controls=reference.FAULTS)
+    assert line["correct"] is True
+    assert list(line)[-2:] == ["controls", "checks"]
+    assert set(line["controls"]) == set(reference.FAULTS)
+    for reading in line["controls"].values():
+        assert reading["correct"] is False and reading["failed"] > 0
+        assert reading["spectrum_bins_wrong"] >= 1
+    assert line["controls"]["half_pulse"]["prefix_off_pulses"] > 0.3
+
+
+@pytest.mark.parametrize(
+    "fault, cell_name",
+    [
+        ("altered_answer", "toy_panel.toy_paced"),
+        ("half_batch", "toy_panel.toy_paced"),
+        ("state_unchanged", "toy_panel.toy_paced"),
+        ("half_batch", "toy_panel.toy_blob"),
+    ],
+)
+def test_a_fault_under_the_timed_path_makes_correct_false(toy_root, monkeypatch, fault, cell_name):
+    """The rest of a run as it is, the service broken underneath: an
+    answer altered where it is produced, half of every batch left out,
+    a step that returns its state unchanged."""
+    monkeypatch.setenv("BENCH_TEST_FAULT", fault)
+    monkeypatch.setenv("PYTHONPATH", str(FIXTURE.parent))
+
+    def faulty(cell):
+        return dataclasses.replace(
+            cell, config={**cell.config, "service": "fixture.faulty_service"}
+        )
+
+    line, report = run(toy_root, cell_name, 15, cell_edit=faulty)
+    assert line["correct"] is False
+    assert line["failed"] > 0
+    assert any(text.startswith("check failed_publishes: ") for text in report)
+
+
+@pytest.fixture(scope="module")
+def checkout(tmp_path_factory):
+    """What the driver's checkout holds of the benchmark, with the
+    fixture's files laid over it and the program beside it."""
+    root = tmp_path_factory.mktemp("checkout")
+    overlay_fixture(root)
+    shutil.copytree(REPO / "benchmark" / "harness", root / "benchmark" / "harness",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    shutil.copy(REPO / "benchmark" / "run.py", root / "benchmark" / "run.py")
+    return root
+
+
+def command(root, *args):
+    env = {k: v for k, v in os.environ.items() if k != "BENCH_RUN"}
+    return subprocess.run(
+        [sys.executable, str(root / "benchmark" / "run.py"), *args],
+        capture_output=True, text=True, timeout=120, cwd=root, env={**env, "BENCH_RUN": "7"},
+    )
+
+
+def test_the_command_gives_no_result_without_the_program_beside_it(checkout):
+    done = command(checkout, "--workload", "toy_panel.toy_paced", "--seed", "1",
+                   "--seconds", "1", "--trace", "0")
+    assert done.returncode != 0 and done.stdout == ""
+    assert "not beside" in done.stderr
+
+
+def test_the_command_accepts_cells_added_as_files_and_refuses_a_cpu(checkout):
+    (checkout / "src").symlink_to(REPO / "src")
+    done = command(checkout, "--workload", "toy_panel.toy_paced", "--seed", "3000000019",
+                   "--seconds", "1", "--trace", "0")
+    assert done.returncode != 0
+    assert done.stdout == "", "no result line without a TPU"
+    assert "not a TPU" in done.stderr
+    unknown = command(checkout, "--workload", "toy_panel.nowhere", "--seed", "1",
+                      "--seconds", "1", "--trace", "0")
+    assert unknown.returncode != 0 and unknown.stdout == ""
+    assert "no workload" in unknown.stderr
+    assert not list(checkout.glob("benchmark/**/broker")), "the broker lives outside the checkout"
