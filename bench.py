@@ -3049,9 +3049,9 @@ def run_benchmark(args) -> dict:
     from contextlib import nullcontext
 
     if args.profile:
-        from esslivedata_tpu.utils.profiling import device_trace
+        import jax
 
-        trace = device_trace(args.profile)
+        trace = jax.profiler.trace(args.profile)
     else:
         trace = nullcontext()
     # Three timed windows, best one graded (all three are printed to

@@ -257,7 +257,7 @@ class IngestPipeline:
         self._reraise_failure()
         window = PipelineWindow(
             seq=-1, payload=payload, start=start, end=end,
-            t_submit=time.monotonic(),
+            t_submit=time.perf_counter(),
             source_ts_ns=(
                 int(end.ns) if hasattr(end, "ns") else None
             ),
@@ -566,7 +566,7 @@ class IngestPipeline:
                 # time against the latched threshold; a breach logs this
                 # window's full span breakdown.
                 TRACER.finish_tick(
-                    window.trace, time.monotonic() - window.t_submit
+                    window.trace, time.perf_counter() - window.t_submit
                 )
             if self._on_complete is not None:
                 try:

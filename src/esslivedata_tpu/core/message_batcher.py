@@ -29,11 +29,13 @@ import time
 from dataclasses import dataclass, field
 from typing import Protocol, runtime_checkable
 
+from ..telemetry.instruments import BATCHER_SCALE_CHANGES
 from .message import Message
 from .timestamp import Duration, Timestamp
 
 __all__ = [
     "AdaptiveMessageBatcher",
+    "BatchHold",
     "LoadGovernor",
     "MessageBatch",
     "MessageBatcher",
@@ -70,6 +72,44 @@ class MessageBatcher(Protocol):
     def batch(self, messages: list[Message]) -> MessageBatch | None: ...
 
     def report_processing_time(self, duration: Duration) -> None: ...
+
+
+class BatchHold:
+    """When the newest message of each emitted batch was delivered.
+
+    The processor feeds every poll (its return time on ``perf_counter``,
+    the data messages it brought, what the batcher made of them) and
+    gets back, for an emitted batch, the return time of the poll that
+    delivered the batch's last message; the batch's hold is the start of
+    its processing minus that. A window closes on the first message of a
+    LATER window, so the closing poll often brings nothing the batch
+    holds: its last message then came with an earlier poll, which is the
+    one remembered here.
+    """
+
+    def __init__(self) -> None:
+        #: Return time of the newest poll whose data is still buffered.
+        self._buffered_at: float | None = None
+
+    def arrival(
+        self,
+        polled_at: float,
+        data: list[Message],
+        batch: MessageBatch | None,
+    ) -> float | None:
+        """None while nothing is emitted; else the delivery time of the
+        batch's newest message."""
+        if batch is None:
+            if data:
+                self._buffered_at = polled_at
+            return None
+        end = batch.end
+        arrived = self._buffered_at
+        if arrived is None or any(m.timestamp < end for m in data):
+            arrived = polled_at
+        if any(m.timestamp >= end for m in data):
+            self._buffered_at = polled_at
+        return arrived
 
 
 class NaiveMessageBatcher:
@@ -231,14 +271,18 @@ class LoadGovernor:
             new = min(self._max_scale, self.scale * 2.0)
             changed = new != self.scale
             self.scale = new
-            return changed
+        if changed:
+            BATCHER_SCALE_CHANGES.inc(direction="up")
+        return changed
 
     def relax(self) -> bool:
         with self._lock:
             new = max(1.0, self.scale / math.sqrt(2.0))
             changed = new != self.scale
             self.scale = new
-            return changed
+        if changed:
+            BATCHER_SCALE_CHANGES.inc(direction="down")
+        return changed
 
 
 class AdaptiveMessageBatcher(SimpleMessageBatcher):

@@ -21,6 +21,7 @@ from typing import Any
 from ..config.acknowledgement import CommandAcknowledgement
 from ..core.preprocessor import PreprocessorFactory
 from ..telemetry.e2e import observe_stage
+from ..telemetry.instruments import BATCH_HOLD_SECONDS
 from ..telemetry.trace import TRACER
 from .command_dispatcher import CommandDispatcher
 from .job_manager import JobManager
@@ -36,7 +37,7 @@ from .message import (
     StreamId,
     StreamKind,
 )
-from .message_batcher import MessageBatcher
+from .message_batcher import BatchHold, MessageBatcher
 from .timestamp import Duration, Timestamp
 
 __all__ = ["MessagePreprocessor", "OrchestratingProcessor"]
@@ -218,6 +219,7 @@ class OrchestratingProcessor:
         self._preprocessor = MessagePreprocessor(preprocessor_factory)
         self._job_manager = job_manager
         self._batcher = batcher
+        self._hold = BatchHold()
         self._dispatcher = CommandDispatcher(
             job_manager=job_manager,
             instrument=instrument,
@@ -340,6 +342,7 @@ class OrchestratingProcessor:
     # -- cycle ------------------------------------------------------------
     def process(self) -> None:
         messages = list(self._source.get_messages())
+        polled_at = time.perf_counter()
 
         commands = [m for m in messages if m.stream.kind.is_command]
         run_control = [m for m in messages if m.stream.kind.is_run_control]
@@ -353,12 +356,15 @@ class OrchestratingProcessor:
                 self._job_manager.handle_run_transition(msg.value)
 
         batch = self._batcher.batch(data)
+        arrived_at = self._hold.arrival(polled_at, data, batch)
         if batch is not None:
             t0 = self._clock()
+            hold_s = time.perf_counter() - arrived_at
+            BATCH_HOLD_SECONDS.observe(hold_s)
             if self._pipeline is not None:
                 self._submit_batch(batch)
             else:
-                self._process_batch(batch)
+                self._process_batch(batch, hold_s)
             # Pipelined: the duration is decode+submit, where submit
             # blocks while the pipeline is at depth — backpressure from
             # a slow stage reaches the adaptive batcher as load through
@@ -580,7 +586,7 @@ class OrchestratingProcessor:
             coalesce,
         )
 
-    def _process_batch(self, batch) -> None:
+    def _process_batch(self, batch, hold_s: float = 0.0) -> None:
         self._last_batch_len = len(batch.messages)
         # Serial-path tracing (ADR 0116): the trace id is born at
         # decode, exactly like the pipelined decode worker's, so the
@@ -601,9 +607,11 @@ class OrchestratingProcessor:
         decode_ts_ns = (
             oldest_ts_ns if oldest_ts_ns is not None else source_ts_ns
         )
-        t_start = time.monotonic()
+        t_start = time.perf_counter()
+        # The hold rides the decode span so that a dump shows it tick
+        # by tick beside the aggregate livedata_batch_hold_seconds.
         with self.stage_timer.stage("preprocess"), TRACER.span(
-            "decode", trace_id
+            "decode", trace_id, {"hold_us": round(hold_s * 1e6)}
         ):
             self._preprocessor.preprocess(batch.messages)
             window = self._preprocessor.collect_window()
@@ -630,7 +638,11 @@ class OrchestratingProcessor:
                 observe_stage("published", source_ts_ns)
         finally:
             self._preprocessor.release()
-            TRACER.finish_tick(trace_id, time.monotonic() - t_start)
+            # The serial loop's spans tile the tick: what none covers is
+            # reported as ``unspanned`` (telemetry/trace.py).
+            TRACER.finish_tick(
+                trace_id, time.perf_counter() - t_start, tiled=True
+            )
 
     def _record_lag(self, batch) -> None:
         now_ns = time.time_ns()
@@ -806,6 +818,7 @@ class OrchestratingProcessor:
             ]
             return fam
 
+        scale = getattr(self._batcher, "scale", None)
         families = [
             family(
                 "livedata_stream_messages",
@@ -836,6 +849,13 @@ class OrchestratingProcessor:
                 "gauge",
                 "Jobs this service hosts",
                 [((), self._job_manager.n_jobs)],
+            ),
+            family(
+                "livedata_batcher_window_scale",
+                "gauge",
+                "Current window scale of the adaptive batcher (1 = the "
+                "base window; each doubling recompiles the tick programs)",
+                [] if scale is None else [((), scale)],
             ),
             family(
                 "livedata_processor_stage_seconds",

@@ -243,6 +243,11 @@ class RateAwareMessageBatcher:
         return self._window
 
     @property
+    def scale(self) -> float:
+        """The governor's window scale (``livedata_batcher_window_scale``)."""
+        return self._governor.scale
+
+    @property
     def pending_messages(self) -> int:
         """Messages buffered toward not-yet-closed windows across every
         internal hold (non-gated flow, overflow, near-future, per-stream

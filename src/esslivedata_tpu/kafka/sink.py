@@ -15,6 +15,7 @@ import logging
 import os
 import socket
 import threading
+import time
 from collections.abc import Sequence
 from dataclasses import dataclass
 from typing import Protocol, runtime_checkable
@@ -24,6 +25,7 @@ from pydantic import BaseModel
 
 from ..core.message import Message, StreamKind
 from ..preprocessors.to_nxlog import LogData
+from ..telemetry.instruments import SINK_BYTES, SINK_SECONDS
 from ..utils.labeled import DataArray
 from . import wire
 from .da00_compat import dataarray_to_da00
@@ -40,6 +42,10 @@ __all__ = [
 ]
 
 logger = logging.getLogger(__name__)
+
+_SERIALIZE_S = SINK_SECONDS.labels(phase="serialize")
+_PRODUCE_S = SINK_SECONDS.labels(phase="produce")
+_FLUSH_S = SINK_SECONDS.labels(phase="flush")
 
 
 @dataclass(frozen=True, slots=True)
@@ -250,37 +256,37 @@ class KafkaSink:
         logger.warning("%s failed (%d consecutive)", what, consecutive)
 
     def publish_messages(self, messages: Sequence[Message]) -> None:
-        for msg in messages:
-            try:
-                sm = self._serializer.serialize(msg)
-            except Exception:
-                with self._lock:
-                    self.serialize_errors += 1
-                logger.exception("Failed to serialize %s", msg.stream)
-                continue
-            try:
-                self._producer.produce(sm.topic, sm.value, sm.key)
-            except BufferError as err:
-                # Producer queue full: drop rather than stall the hot
-                # loop (reference sink.py:110-118) — but during an
-                # extended broker outage an async producer fails
-                # EXACTLY this way (the local queue never drains), so
-                # sustained drops must trip the breaker too instead of
-                # black-holing every message behind per-drop warnings.
-                with self._lock:
-                    self.dropped += 1
-                    self._consecutive_produce += 1
-                    consecutive = self._consecutive_produce
-                self._trip_or_warn(consecutive, "produce (queue full)", err)
-            except Exception as err:
-                with self._lock:
-                    self.produce_errors += 1
-                    self._consecutive_produce += 1
-                    consecutive = self._consecutive_produce
-                self._trip_or_warn(consecutive, "produce", err)
-            else:
-                with self._lock:
-                    self._consecutive_produce = 0
+        # Phase seconds (ADR 0116): encode and broker write interleave
+        # per message, so they are summed over the loop from two clock
+        # reads a message (each read ends one phase and starts the
+        # next), not recorded as a span each: the ``sink`` span around
+        # this call stays one ring entry.
+        serialize_s = produce_s = 0.0
+        nbytes = 0
+        mark = time.perf_counter()
+        try:
+            for msg in messages:
+                try:
+                    sm = self._serializer.serialize(msg)
+                except Exception:
+                    with self._lock:
+                        self.serialize_errors += 1
+                    logger.exception("Failed to serialize %s", msg.stream)
+                    continue
+                now = time.perf_counter()
+                serialize_s += now - mark
+                mark = now
+                nbytes += len(sm.value)
+                try:
+                    self._produce(sm)
+                finally:
+                    now = time.perf_counter()
+                    produce_s += now - mark
+                    mark = now
+        finally:
+            _SERIALIZE_S.inc(serialize_s)
+            _PRODUCE_S.inc(produce_s)
+            SINK_BYTES.inc(nbytes)
         try:
             self._producer.flush(0)
         except Exception as err:
@@ -292,6 +298,33 @@ class KafkaSink:
         else:
             with self._lock:
                 self._consecutive_flush = 0
+        finally:
+            _FLUSH_S.inc(time.perf_counter() - mark)
+
+    def _produce(self, sm: SerializedMessage) -> None:
+        try:
+            self._producer.produce(sm.topic, sm.value, sm.key)
+        except BufferError as err:
+            # Producer queue full: drop rather than stall the hot
+            # loop (reference sink.py:110-118) — but during an
+            # extended broker outage an async producer fails
+            # EXACTLY this way (the local queue never drains), so
+            # sustained drops must trip the breaker too instead of
+            # black-holing every message behind per-drop warnings.
+            with self._lock:
+                self.dropped += 1
+                self._consecutive_produce += 1
+                consecutive = self._consecutive_produce
+            self._trip_or_warn(consecutive, "produce (queue full)", err)
+        except Exception as err:
+            with self._lock:
+                self.produce_errors += 1
+                self._consecutive_produce += 1
+                consecutive = self._consecutive_produce
+            self._trip_or_warn(consecutive, "produce", err)
+        else:
+            with self._lock:
+                self._consecutive_produce = 0
 
 
 class UnrollingSinkAdapter:

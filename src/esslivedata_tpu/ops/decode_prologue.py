@@ -74,6 +74,7 @@ def _prologue_pallas(pixel_id, toa, interpret: bool):
             jax.ShapeDtypeStruct((grid, 8, w), jnp.float32),
         ],
         interpret=interpret,
+        name="decode_prologue",
     )(pid_rows, toa_rows)
     return pid_o.reshape(n), toa_o.reshape(n)
 

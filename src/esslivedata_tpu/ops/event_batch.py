@@ -17,6 +17,9 @@ from dataclasses import dataclass
 
 import numpy as np
 
+from ..telemetry.instruments import STAGED_EVENTS
+from ..telemetry.trace import TRACER
+
 __all__ = [
     "EventBatch",
     "StagingBuffer",
@@ -25,6 +28,7 @@ __all__ = [
     "leaf_device_set",
     "make_staging_buffer",
     "sanitize_pixel_id",
+    "ship",
     "stage_raw",
 ]
 
@@ -234,6 +238,27 @@ def dispatch_safe(x):
     return x
 
 
+_STAGED_SLOTS = STAGED_EVENTS.labels(kind="staged")
+_PAD_SLOTS = STAGED_EVENTS.labels(kind="pad")
+
+
+def ship(batch: EventBatch, arrays: tuple, device=None) -> tuple:
+    """The ``h2d`` leaf span of a stage-cache miss: ``arrays`` (the
+    batch's wire, raw or flattened) through ``dispatch_safe`` or, placed,
+    ``stage_for``. It times the host copy and the ENQUEUE of the
+    asynchronous ``device_put``; the transfer itself completes under the
+    tick's ``fetch``. With it the count of what was shipped: the
+    bucket's slots, and those of them that are padding."""
+    with TRACER.span("h2d", args={"bytes": sum(a.nbytes for a in arrays)}):
+        if device is None:
+            shipped = tuple(dispatch_safe(a) for a in arrays)
+        else:
+            shipped = tuple(stage_for(a, device) for a in arrays)
+    _STAGED_SLOTS.inc(batch.padded_size)
+    _PAD_SLOTS.inc(batch.padded_size - batch.n_valid)
+    return shipped
+
+
 def stage_raw(batch: EventBatch, cache=None, tag: str = "", device=None):
     """Stage a batch's raw ``(pixel_id, toa)`` pair for the device path.
 
@@ -260,12 +285,7 @@ def stage_raw(batch: EventBatch, cache=None, tag: str = "", device=None):
     """
 
     def stage():
-        if device is None:
-            pid = dispatch_safe(batch.pixel_id)
-            toa = dispatch_safe(batch.toa)
-        else:
-            pid = stage_for(batch.pixel_id, device)
-            toa = stage_for(batch.toa, device)
+        pid, toa = ship(batch, (batch.pixel_id, batch.toa), device)
         if getattr(batch, "prologue", False):
             from .decode_prologue import decode_prologue
 

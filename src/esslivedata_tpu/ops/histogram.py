@@ -54,19 +54,26 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..telemetry.trace import TRACER
 from .event_batch import (
     EventBatch,
     device_token,
     dispatch_safe,
     leaf_device_set,
     sanitize_pixel_id,
-    stage_for,
+    ship,
     stage_raw,
 )
 
 __all__ = ["EventHistogrammer", "EventProjection", "HistogramState"]
 
 logger = logging.getLogger(__name__)
+
+
+def _flatten_args(batch: EventBatch) -> dict[str, int]:
+    """The ``flatten`` span's counts: decoded events, and the bucket
+    they were padded to (what flatten, H2D and the scatter cost)."""
+    return {"events": int(batch.n_valid), "padded": batch.padded_size}
 
 
 class EventProjection:
@@ -694,7 +701,8 @@ class EventHistogrammer:
         zeroes. Workflows compose this into their fused publish programs
         (ops/publish.py) so summaries and the fold ride one execute call;
         ``clear_window`` is the standalone jitted equivalent."""
-        return self._clear_window_impl(state)
+        with jax.named_scope("fold"):
+            return self._clear_window_impl(state)
 
     # -- state snapshot codec (core/state_snapshot.py, ADR 0107) -----------
     # The ONE place that knows how a HistogramState serializes; workflow
@@ -844,7 +852,7 @@ class EventHistogrammer:
         return base
 
     def _staged_flat(
-        self, pixel_id, toa, cache, tag: str, pool=None, device=None
+        self, batch: EventBatch, cache, tag: str, pool=None, device=None
     ):
         """Host-flattened indices staged for dispatch — once per window
         per (stream, tag, layout, slice) when a cache slot is provided.
@@ -852,17 +860,18 @@ class EventHistogrammer:
         thread pool; the result is bit-identical either way. ``device``
         (mesh-slice placement, parallel/mesh_tick.py) commits the wire
         to that slice and keys the cache by it, so each batch stages
-        once per slice."""
-        def flatten():
-            if pool is not None:
-                return self.flatten_host_chunked(pixel_id, toa, pool)
-            return self.flatten_host(pixel_id, toa)
+        once per slice. A miss records the ``flatten`` and ``h2d`` leaf
+        spans (ADR 0116); a hit records nothing."""
 
         def stage():
-            flat = flatten()
-            if device is None:
-                return dispatch_safe(flat)
-            return stage_for(flat, device)
+            with TRACER.span("flatten", args=_flatten_args(batch)):
+                if pool is not None:
+                    flat = self.flatten_host_chunked(
+                        batch.pixel_id, batch.toa, pool
+                    )
+                else:
+                    flat = self.flatten_host(batch.pixel_id, batch.toa)
+            return ship(batch, (flat,), device)[0]
 
         if cache is None:
             return stage()
@@ -870,10 +879,12 @@ class EventHistogrammer:
             (tag,) + self.stage_key + (device_token(device),), stage
         )
 
-    def _staged_partition(self, pixel_id, toa, cache, tag: str, device=None):
+    def _staged_partition(
+        self, batch: EventBatch, cache, tag: str, device=None
+    ):
         """Block-partitioned (events, chunk_map) staged for the pallas2d
         kernel — once per window per (stream, tag, partition layout,
-        slice).
+        slice); the same two leaf spans on a miss.
 
         The compaction flag is read ONCE and threaded through both the
         key and the partition pass: a link-policy wire flip arriving
@@ -882,12 +893,11 @@ class EventHistogrammer:
         compact = self._p2_compact
 
         def stage():
-            events, chunk_map = self.flatten_partition_host(
-                pixel_id, toa, compact=compact
-            )
-            if device is None:
-                return dispatch_safe(events), dispatch_safe(chunk_map)
-            return stage_for(events, device), stage_for(chunk_map, device)
+            with TRACER.span("flatten", args=_flatten_args(batch)):
+                wire = self.flatten_partition_host(
+                    batch.pixel_id, batch.toa, compact=compact
+                )
+            return ship(batch, wire, device)
 
         if cache is None:
             return stage()
@@ -924,13 +934,10 @@ class EventHistogrammer:
         if cache is None:
             return
         if self._method == "pallas2d":
-            self._staged_partition(
-                batch.pixel_id, batch.toa, cache, batch_tag, device=device
-            )
+            self._staged_partition(batch, cache, batch_tag, device=device)
         elif self.supports_host_flatten:
             self._staged_flat(
-                batch.pixel_id, batch.toa, cache, batch_tag, pool=pool,
-                device=device,
+                batch, cache, batch_tag, pool=pool, device=device
             )
         else:
             stage_raw(batch, cache, batch_tag, device=device)
@@ -1081,16 +1088,13 @@ class EventHistogrammer:
             device = self._state_slice_device(state)
         if self._method == "pallas2d":
             events, chunk_map = self._staged_partition(
-                batch.pixel_id, batch.toa, cache, batch_tag, device=device
+                batch, cache, batch_tag, device=device
             )
             return self._step_part(state, events, chunk_map)
         if self.supports_host_flatten:
             return self._step_flat(
                 state,
-                self._staged_flat(
-                    batch.pixel_id, batch.toa, cache, batch_tag,
-                    device=device,
-                ),
+                self._staged_flat(batch, cache, batch_tag, device=device),
             )
         pid, toa = stage_raw(batch, cache, batch_tag, device=device)
         return self._step(state, self._proj.lut, pid, toa)
@@ -1121,7 +1125,7 @@ class EventHistogrammer:
             device = self._state_slice_device(states[0])
         if self._method == "pallas2d":
             events, chunk_map = self._staged_partition(
-                batch.pixel_id, batch.toa, cache, batch_tag, device=device
+                batch, cache, batch_tag, device=device
             )
             return self._dispatch_fused(
                 self._step_part_fused, states, events, chunk_map
@@ -1130,10 +1134,7 @@ class EventHistogrammer:
             return self._dispatch_fused(
                 self._step_flat_fused,
                 states,
-                self._staged_flat(
-                    batch.pixel_id, batch.toa, cache, batch_tag,
-                    device=device,
-                ),
+                self._staged_flat(batch, cache, batch_tag, device=device),
             )
         pid, toa = stage_raw(batch, cache, batch_tag, device=device)
         return self._dispatch_fused(
@@ -1201,13 +1202,12 @@ class EventHistogrammer:
         step body itself."""
         if self._method == "pallas2d":
             return self._staged_partition(
-                batch.pixel_id, batch.toa, cache, batch_tag, device=device
+                batch, cache, batch_tag, device=device
             )
         if self.supports_host_flatten:
             return (
                 self._staged_flat(
-                    batch.pixel_id, batch.toa, cache, batch_tag, pool=pool,
-                    device=device,
+                    batch, cache, batch_tag, pool=pool, device=device
                 ),
             )
         pid, toa = stage_raw(batch, cache, batch_tag, device=device)

@@ -97,6 +97,7 @@ def _bincount_call(flat, n_bins_padded: int, block: int, interpret: bool):
             (1, n_bins_padded), jnp.float32, vma=vma
         ),
         interpret=interpret,
+        name="bincount_onehot",
     )(rows)[0]
 
 

@@ -331,6 +331,7 @@ def _pallas2d_call(
         out_shape=jax.ShapeDtypeStruct(win3.shape, jnp.float32),
         input_output_aliases={2: 0},  # window (after the 2 scalar args)
         interpret=interpret,
+        name="scatter_add_pallas2d",
     )(chunk_map, upd_arr, win3, rows)
     return out.reshape(n_blocks * bpb)
 

@@ -83,6 +83,39 @@ __all__ = [
 logger = logging.getLogger(__name__)
 
 
+def program_name(kind: str, publishers) -> str:
+    """``<kind>_<family>[_<family>...]``: the name a jitted publish or
+    tick program carries into the device trace and the compile log, from
+    the families (``PackedPublisher.name``) of its members."""
+    return "_".join([kind, *sorted({pub.name for pub in publishers})])
+
+
+def fetch_outputs(outputs):
+    """The ``fetch`` span of a steady-state round (ADR 0116): the wait
+    for the chip, then the copy back, on the caller's bound trace.
+
+    The dispatch before it is asynchronous, so this is where the host
+    meets the program's run time: ``block_until_ready`` first, then
+    ``device_get``. The second part alone is observed as the aggregate
+    ``d2h`` (no ring entry: it lies inside ``fetch``, and the ring
+    stays flat), which tells the wait from the copy.
+
+    The copies are ENQUEUED before the wait, as ``device_get`` alone
+    would: they then start on the device the moment the program ends.
+    Enqueued only after the wait, each costs a host wake-up and a
+    submit with the chip idle: 20 ms of every picture's age at seven
+    fetches a tick (PERF.md section 6). ``d2h`` is therefore what of the
+    copy is left to wait for once the program has run."""
+    with TRACER.span("fetch"):
+        for leaf in jax.tree_util.tree_leaves(outputs):
+            leaf.copy_to_host_async()
+        jax.block_until_ready(outputs)
+        t0 = time.perf_counter()
+        fetched = jax.device_get(outputs)
+        TRACER.observe("d2h", time.perf_counter() - t0)
+    return fetched
+
+
 class PublishMetrics:
     """Process-wide publish round-trip counters.
 
@@ -318,6 +351,11 @@ class PackedPublisher:
     only when the per-call ``static_token`` misses the host-side cache,
     and served from that cache on every later publish until the token
     changes. A call without a token treats every output as dynamic.
+
+    ``name`` is the workflow family's, for the device trace: the jitted
+    programs this publisher is part of are called ``publish_<name>`` and
+    ``tick_<name>`` (``program_name``), stable across refactors, where
+    the wrapped closure's own name says nothing.
     """
 
     #: Static cache entries kept per publisher; tokens are layout
@@ -330,8 +368,10 @@ class PackedPublisher:
         *,
         donate: tuple[int, ...] = (0,),
         static_keys: Sequence[str] = (),
+        name: str = "publish",
     ) -> None:
         self._program = program
+        self.name = name
         self._donate = tuple(donate)
         self._static_keys = frozenset(static_keys)
         # (signature, static-key split) -> (dynamic spec, static names).
@@ -436,16 +476,21 @@ class PackedPublisher:
         """The traceable publish body: ``(packed_dynamic, static_leaves,
         *carry)``. The combiner inlines this per member, so private and
         combined publishes run the exact same per-job ops."""
-        outputs, *carry = self._program(*args)
+        # Scopes name the phases in the device trace (op metadata
+        # only): the member's reductions (the fold inside them is
+        # ``fold``, ops/histogram.py) and the pack into one vector.
+        with jax.named_scope("publish_reduce"):
+            outputs, *carry = self._program(*args)
         dynamic = sorted(
             (k, v) for k, v in outputs.items() if k not in skeys
         )
-        if dynamic:
-            packed = jnp.concatenate(
-                [jnp.ravel(v).astype(jnp.float32) for _, v in dynamic]
-            )
-        else:
-            packed = jnp.zeros((0,), jnp.float32)
+        with jax.named_scope("pack"):
+            if dynamic:
+                packed = jnp.concatenate(
+                    [jnp.ravel(v).astype(jnp.float32) for _, v in dynamic]
+                )
+            else:
+                packed = jnp.zeros((0,), jnp.float32)
         statics = (
             tuple(
                 outputs[k] for k in sorted(k for k in outputs if k in skeys)
@@ -463,6 +508,7 @@ class PackedPublisher:
             def run(*args, _sk=skeys, _inc=include_static):
                 return self._packed_impl(_sk, _inc, *args)
 
+            run.__name__ = program_name("publish", [self])
             fn = self._jits[key] = jax.jit(
                 run, donate_argnums=self._donate
             )
@@ -787,8 +833,7 @@ class PublishCombiner:
             else:
                 with TRACER.span("publish_execute"):
                     packed, statics, carries = fn(*flat_args)
-                with TRACER.span("fetch"):
-                    flat, static_fetched = jax.device_get((packed, statics))
+                flat, static_fetched = fetch_outputs((packed, statics))
         except Exception as err:
             # Dispatch-level failure: per-member containment happens at
             # the caller, which needs to know whose donated state the
@@ -847,13 +892,15 @@ class PublishCombiner:
                 parts.append(packed)
                 statics.append(stat)
                 carries.append(tuple(carry))
-            packed_all = (
-                jnp.concatenate(parts)
-                if parts
-                else jnp.zeros((0,), jnp.float32)
-            )
+            with jax.named_scope("pack"):
+                packed_all = (
+                    jnp.concatenate(parts)
+                    if parts
+                    else jnp.zeros((0,), jnp.float32)
+                )
             return packed_all, tuple(statics), tuple(carries)
 
+        mega.__name__ = program_name("publish", [m[0] for m in members])
         donate: list[int] = []
         offset = 0
         for pub, n_args, _skeys, _inc in members:

@@ -761,7 +761,8 @@ class QHistogrammer:
     def fold_window(self, state: QState) -> QState:
         """Traceable window fold, for composition into fused publish
         programs (ops/publish.py); ``clear_window`` is the jitted one."""
-        return self._clear_window_impl(state)
+        with jax.named_scope("fold"):
+            return self._clear_window_impl(state)
 
     def clear_window(self, state: QState) -> QState:
         return self._clear_window(state)

@@ -68,7 +68,9 @@ from .publish import (
     CombinedPublish,
     PackedPublisher,
     PublishRequest,
+    fetch_outputs,
     member_signature,
+    program_name,
     plan_members,
     publish_args_consumed,
     signature_fingerprint,
@@ -193,13 +195,14 @@ class TickCombiner:
             else:
                 # Per-tick tracer spans (ADR 0116), against the step
                 # worker's thread-bound trace id: the dispatch (host
-                # Python + async submit) and the fetch (the device
-                # round trip a steady-state tick actually waits on)
-                # decompose separately in the slow-tick breakdown.
+                # Python + ASYNC submit: it returns before the program
+                # has run) and the fetch (the wait for the chip, then
+                # the copy back: what a steady-state tick actually
+                # waits on) decompose separately in the slow-tick
+                # breakdown.
                 with TRACER.span("tick_execute"):
                     packed, statics, carries = fn(*flat_args)
-                with TRACER.span("fetch"):
-                    flat, static_fetched = jax.device_get((packed, statics))
+                flat, static_fetched = fetch_outputs((packed, statics))
         except Exception as err:
             # Dispatch-level failure: per-member containment happens at
             # the caller, which needs to know whose donated state the
@@ -385,7 +388,8 @@ class TickCombiner:
             staged = args[:n_staged]
             flat = args[n_staged:]
             states = tuple(flat[o] for o in state_offsets)
-            new_states = hist.tick_step(states, *staged)
+            with jax.named_scope("scatter"):
+                new_states = hist.tick_step(states, *staged)
             parts, statics, carries = [], [], []
             for j, (pub, n_args, skeys, include_static) in enumerate(
                 members
@@ -400,15 +404,20 @@ class TickCombiner:
                 parts.append(packed)
                 statics.append(stat)
                 carries.append(tuple(carry))
-            packed_all = (
-                jnp.concatenate(parts)
-                if parts
-                else jnp.zeros((0,), jnp.float32)
-            )
+            with jax.named_scope("pack"):
+                packed_all = (
+                    jnp.concatenate(parts)
+                    if parts
+                    else jnp.zeros((0,), jnp.float32)
+                )
             packed_all, statics = self._finish_outputs(
                 packed_all, statics
             )
             return packed_all, tuple(statics), tuple(carries)
+
+        # A stable name per workflow family (``tick_detector_view``):
+        # what the device trace and the compile log call the program.
+        tick.__name__ = program_name("tick", [m[0] for m in members])
 
         # Shifted donation: member states (and any further publisher
         # donations) keep their donated positions behind the staged

@@ -562,13 +562,16 @@ class ShardedHistogrammer:
         counterpart of ``clear_window``): the cumulative absorbs the
         physical window in place — both leaves keep their P('bank')
         sharding, so the fold is collective-free."""
-        return HistogramState(
-            folded=state.folded + self.physical_window(state),
-            window=jnp.zeros_like(state.window),
-            scale=(
-                None if state.scale is None else jnp.ones_like(state.scale)
-            ),
-        )
+        with jax.named_scope("fold"):
+            return HistogramState(
+                folded=state.folded + self.physical_window(state),
+                window=jnp.zeros_like(state.window),
+                scale=(
+                    None
+                    if state.scale is None
+                    else jnp.ones_like(state.scale)
+                ),
+            )
 
     def clear(self, state: HistogramState) -> HistogramState:
         """Zero the full accumulation (run-transition reset), keeping

@@ -16,12 +16,17 @@ from __future__ import annotations
 from .registry import REGISTRY
 
 __all__ = [
+    "BATCHER_SCALE_CHANGES",
+    "BATCH_HOLD_SECONDS",
     "CALIBRATION_SWAPS",
     "DECODE_BATCH_SIZE",
     "DECODE_BYTES",
     "DECODE_ERRORS",
     "EVENTS_FILTERED",
     "PUBLISH_RTT_SECONDS",
+    "SINK_BYTES",
+    "SINK_SECONDS",
+    "STAGED_EVENTS",
 ]
 
 #: Publish/tick device round-trip wall times as a labeled histogram
@@ -84,4 +89,55 @@ DECODE_ERRORS = REGISTRY.counter(
     "livedata_decode_errors_total",
     "Messages dropped by the decode plane as malformed wire",
     labelnames=("schema",),
+)
+
+#: How long an emitted batch's newest message sat in the batcher: from
+#: the return of the poll that delivered it to the start of the batch's
+#: processing (``core/message_batcher.BatchHold``). A window closes only
+#: when a message of a LATER window arrives, so at 14 Hz this is one
+#: pulse period (71 ms) plus the phase of the consumer's polls: the
+#: part of a picture's age that no span of the tick covers.
+BATCH_HOLD_SECONDS = REGISTRY.histogram(
+    "livedata_batch_hold_seconds",
+    "Poll that delivered a batch's last message -> start of its "
+    "processing (one observation per emitted batch)",
+)
+
+#: The adaptive batchers' window scale moving (``LoadGovernor``): an
+#: escalation re-shapes every staged batch, so each one is followed by
+#: a recompile of every tick program (``up`` is the benchmark's
+#: ``escalations_in_window``). The scale itself is the gauge
+#: ``livedata_batcher_window_scale`` in the processor's collector,
+#: which ``scripts/slo_rules/default.json`` holds to 1.
+BATCHER_SCALE_CHANGES = REGISTRY.counter(
+    "livedata_batcher_scale_changes_total",
+    "Window-scale changes of the load governor (up = escalation)",
+    labelnames=("direction",),
+)
+
+#: Event slots shipped to the device per stage-cache miss, beside the
+#: ``flatten`` / ``h2d`` spans: ``staged`` = slots of the bucket,
+#: ``pad`` = those of them that hold no decoded event (the bucket's
+#: padding, which the scatter pays for all the same).
+STAGED_EVENTS = REGISTRY.counter(
+    "livedata_staged_events_total",
+    "Event slots staged for the device; kind=pad is the share of them "
+    "that is bucket padding",
+    labelnames=("kind",),
+)
+
+#: Where the ``sink`` span's time goes (``kafka/sink.py``): summed over
+#: a publish's messages, two clock reads a message, not a span each.
+SINK_SECONDS = REGISTRY.counter(
+    "livedata_sink_seconds_total",
+    "Cumulative seconds of publish_messages by phase "
+    "(serialize = da00/f144/... encode, produce, flush)",
+    labelnames=("phase",),
+)
+
+#: What that time is paid for: the benchmark's ``sink_mb`` per tick, an
+#: operator's output bandwidth (broker and retention sizing) as a rate.
+SINK_BYTES = REGISTRY.counter(
+    "livedata_sink_bytes_total",
+    "Serialized payload bytes handed to the producer",
 )

@@ -3,12 +3,44 @@
 The 30 s metrics line says WHAT is slow on average; it cannot say what
 happened inside the one tick that blew the p99. This module closes that
 gap (ADR 0116): every ingest window gets a **trace id** when it is
-decoded, and each phase of its life — decode | prestage | tick-execute |
-fetch | finalize | sink — records a span ``(trace_id, name, start,
-duration, thread)`` into a bounded ring buffer. Correlation is the whole
-point: the spans of one window share its id across the three pipeline
-workers and the job threads, so a slow tick decomposes into which phase
-ate the time.
+decoded, and each phase of its life records a span ``(trace_id, name,
+start, duration, thread, args)`` into a bounded ring buffer.
+Correlation is the whole point: the spans of one window share its id
+across the three pipeline workers and the job threads, so a slow tick
+decomposes into which phase ate the time.
+
+The spans of the serial loop, in the order a tick runs them:
+
+- ``decode``: preprocess + collect of the window's messages (``args``:
+  ``hold_us``, how long the window's last message sat in the batcher);
+- ``flatten``: host flatten / partition of one stream's events, once
+  per stage-cache miss (``events``, ``padded``);
+- ``h2d``: the host copy and the ENQUEUE of the asynchronous
+  ``device_put`` (``bytes``); the transfer itself completes under the
+  next ``fetch``;
+- ``tick_execute`` (``publish_execute`` on the combined-publish path):
+  the dispatch. It is asynchronous: host Python plus the submit, not
+  the program's run time;
+- ``fetch``: the wait for the chip (``block_until_ready`` on the
+  program's outputs) followed by the copy back, whose transfers are
+  enqueued before the wait; what is left of the copy after the wait
+  is the aggregate ``d2h``;
+- ``finalize``, ``sink``.
+
+The pipelined path adds ``prestage`` (its stage worker's flatten +
+H2D as one span). **The ring stays flat**: the spans one thread
+records never overlap, a span's parent is its tick (the trace id), and
+an enclosing or contained phase (``tick``, ``unspanned``, ``d2h``) is
+an aggregate on ``/metrics`` (:meth:`TickTracer.observe`), never a
+second ring entry. ``benchmark/harness/trace_reduce.py:name_gap`` adds
+the ring's spans up and relies on it.
+
+One clock: spans and tick totals read ``time.perf_counter()``; a dump
+names it and carries the offset to the epoch sampled in this process.
+While a ``jax.profiler`` session runs (``--profile``, ``POST
+/profile``) every :meth:`TickTracer.span` is mirrored into the
+profiler's own trace as a ``TraceAnnotation``, on the profiler's
+clock, next to the device ops.
 
 Three consumers:
 
@@ -26,7 +58,8 @@ Three consumers:
   in aggregate.
 
 Hot-path cost: an enabled span is two ``perf_counter`` calls, one
-histogram observe and one deque append under the ring lock; a disabled
+histogram observe, one deque append under the ring lock and, where jax
+is loaded, the profiler's is-a-session-running flag test; a disabled
 tracer (``LIVEDATA_TRACE=0``) costs one attribute read. Span recording
 must NEVER run inside jit-traced code — it would measure trace time,
 not execution (graftlint JGL018 polices this).
@@ -42,6 +75,7 @@ from __future__ import annotations
 import json
 import logging
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -58,8 +92,9 @@ logger = logging.getLogger(__name__)
 #: sub-ms host phases up through multi-second device ticks.
 _SPAN_SECONDS = REGISTRY.histogram(
     "livedata_tick_span_seconds",
-    "Duration of per-tick phases (decode/prestage/tick_execute/fetch/"
-    "finalize/sink), labeled by span name",
+    "Duration of per-tick phases (decode/flatten/h2d/prestage/"
+    "tick_execute/fetch/finalize/sink; aggregate only: tick/unspanned/"
+    "d2h), labeled by span name",
     labelnames=("span",),
 )
 
@@ -73,15 +108,32 @@ class Span:
     start_s: float  # perf_counter timebase
     duration_s: float
     thread: str
+    #: Small counts taken at the span's boundary (events, bytes, ...):
+    #: exported in the Chrome event's ``args`` and as the profiler
+    #: annotation's keywords.
+    args: dict[str, int] | None = None
+
+
+def _session_annotation():
+    """``jax.profiler.TraceAnnotation`` while a profiler session runs,
+    else None: what gives a span its twin in the profiler's own trace.
+
+    One flag test when no session runs, and only where jax is already
+    loaded: the fakes and the relay never import it, and this module
+    must not be what does."""
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    if profiler is None or not profiler.TraceAnnotation.is_enabled():
+        return None
+    return profiler.TraceAnnotation
 
 
 class TickTracer:
     """Bounded ring of spans + trace-id allocation + slow-tick watchdog.
 
-    ``capacity`` bounds memory for long-running services: at the 14 Hz
-    pulse cadence and ~6 spans per window the default 8192 spans hold
-    the last ~90 s — enough to dump the context around any slow tick
-    the watchdog just logged.
+    ``capacity`` bounds memory for long-running services: at 15 (three
+    tick groups) to 31 (seven) spans per 1 s window the default 8192
+    spans hold the last four to nine minutes — enough to dump the
+    context around any slow tick the watchdog just logged.
     """
 
     def __init__(
@@ -147,7 +199,7 @@ class TickTracer:
     # -- spans -------------------------------------------------------------
     def record(
         self, name: str, start_s: float, duration_s: float,
-        trace_id: int | None = None,
+        trace_id: int | None = None, args: dict[str, int] | None = None,
     ) -> None:
         """Fold one externally timed span in (hot path; see module
         docstring for cost). ``trace_id=None`` uses the thread's bound
@@ -167,33 +219,84 @@ class TickTracer:
             start_s=start_s,
             duration_s=duration_s,
             thread=threading.current_thread().name,
+            args=args,
         )
+        # What this thread's spans cover of the trace, for
+        # finish_tick's ``unspanned``: one running sum per thread (a
+        # thread works on one tick at a time), never a ring scan.
+        local = self._local
+        if getattr(local, "covered_id", None) == trace_id:
+            local.covered_s += duration_s
+        else:
+            local.covered_id = trace_id
+            local.covered_s = duration_s
         with self._lock:
             self._spans.append(span)
 
+    def observe(self, name: str, seconds: float) -> None:
+        """An aggregate-only phase: into the span histogram, never the
+        ring. For a phase that encloses ring spans (``tick``) or lies
+        inside one (``d2h`` inside ``fetch``): a ring entry for it
+        would overlap them, and the ring stays flat."""
+        if self.enabled:
+            _SPAN_SECONDS.observe(seconds, span=name)
+
     @contextmanager
-    def span(self, name: str, trace_id: int | None = None):
-        """Record the wrapped region as one span. Never place this
-        inside jit-traced code (JGL018): it times Python trace/dispatch,
-        not device execution."""
+    def span(
+        self, name: str, trace_id: int | None = None,
+        args: dict[str, int] | None = None,
+    ):
+        """Record the wrapped region as one span, mirrored into the
+        profiler's trace while a session runs. Never place this inside
+        jit-traced code (JGL018): it times Python trace/dispatch, not
+        device execution. ``record()`` (externally timed) has no such
+        twin: the profiler cannot be told of a region after the fact."""
         if not self.enabled:
             yield
             return
+        annotation = _session_annotation()
         start = time.perf_counter()
         try:
-            yield
+            if annotation is None:
+                yield
+            else:
+                tick = self.current() if trace_id is None else trace_id
+                with annotation(name, trace_id=tick or 0, **(args or {})):
+                    yield
         finally:
             self.record(
-                name, start, time.perf_counter() - start, trace_id
+                name, start, time.perf_counter() - start, trace_id, args
             )
 
     # -- watchdog ----------------------------------------------------------
-    def finish_tick(self, trace_id: int, total_s: float) -> None:
-        """Window completion hook: log the span breakdown of a tick
-        whose wall time exceeds the latched threshold (see class
-        docstring for the latch/decay shape)."""
+    def finish_tick(
+        self, trace_id: int, total_s: float, *, tiled: bool = False
+    ) -> None:
+        """Window completion hook. Observes the tick's wall time
+        (``perf_counter``, like the spans) as the aggregate ``tick``
+        and, where the calling thread's spans tile the tick (``tiled``:
+        the serial loop; the pipelined stages overlap across threads),
+        what none of them covered as ``unspanned`` (negative where
+        they overlap: the ring is then no longer flat). Then the
+        watchdog:
+        log the span breakdown of a tick whose wall time exceeds the
+        latched threshold (see class docstring for the latch/decay
+        shape)."""
         if not self.enabled:
             return
+        _SPAN_SECONDS.observe(total_s, span="tick")
+        if tiled:
+            local = self._local
+            covered = (
+                local.covered_s
+                if getattr(local, "covered_id", None) == trace_id
+                else 0.0
+            )
+            # Signed: spans that nest or are recorded twice cover more
+            # than the tick, and the remainder then reads NEGATIVE on
+            # the scrape (``tick_unspanned_ms``). A clamp at zero would
+            # report that fault as perfect tiling.
+            _SPAN_SECONDS.observe(total_s - covered, span="unspanned")
         with self._lock:
             threshold = self._slow_latch_s
             if total_s > threshold:
@@ -279,13 +382,19 @@ class TickTracer:
 
         Complete ('X') events in microseconds; the trace id rides
         ``pid`` so chrome://tracing groups one window's spans into one
-        row-set, with the worker thread preserved in ``tid``/args.
+        row-set, with the worker thread preserved in ``tid`` and the
+        span's counts in ``args``.
         ``spans`` lets a caller render an :meth:`export` snapshot it
         already holds (dump does — payload and count must describe the
         SAME snapshot)."""
         if spans is None:
             spans = self.export()
         return {
+            # Which clock ``ts`` is on, and what to add to put it on the
+            # epoch: sampled HERE, in the process that recorded the
+            # spans (a reader in another process can only guess).
+            "clock": "perf_counter",
+            "epoch_minus_clock_ns": time.time_ns() - time.perf_counter_ns(),
             "traceEvents": [
                 {
                     "name": span.name,
@@ -295,7 +404,7 @@ class TickTracer:
                     "dur": span.duration_s * 1e6,
                     "pid": span.trace_id,
                     "tid": span.thread,
-                    "args": {"trace_id": span.trace_id},
+                    "args": {"trace_id": span.trace_id, **(span.args or {})},
                 }
                 for span in spans
             ],

@@ -4,8 +4,10 @@ SURVEY.md §5 build note: the reference has no dedicated tracer (timings
 come from per-batch processing_time_s + 30 s metrics); here device-level
 profiling is first-class. Two tools:
 
-- :func:`device_trace`: context manager around ``jax.profiler`` writing a
-  TensorBoard-loadable trace of XLA execution for the wrapped region.
+- :func:`bounded_device_trace`: a wall-clock-bounded ``jax.profiler``
+  session (``--profile`` at launch, ``POST /profile`` on command)
+  writing a TensorBoard/Perfetto-loadable trace of XLA execution with
+  the tick spans beside it (telemetry/trace.py mirrors them in).
 - :class:`StageTimer`: cheap wall-clock stage accounting for the service
   hot loop (decode / stage / device step / publish), drained into the 30 s
   metrics report the same way consumer metrics are.
@@ -19,27 +21,12 @@ import logging
 from collections import defaultdict
 from contextlib import contextmanager
 
-__all__ = ["StageTimer", "bounded_device_trace", "device_memory_stats", "device_trace"]
-
-
-@contextmanager
-def device_trace(log_dir: str):
-    """Profile XLA device execution of the wrapped region.
-
-    Writes a trace under ``log_dir`` (TensorBoard 'profile' plugin /
-    Perfetto readable). Usage::
-
-        with device_trace("/tmp/prof"):
-            state = hist.step(state, batch)
-            state.window.block_until_ready()
-    """
-    import jax
-
-    jax.profiler.start_trace(log_dir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
+__all__ = [
+    "StageTimer",
+    "bounded_device_trace",
+    "device_memory_stats",
+    "session_active",
+]
 
 
 class StageTimer:
@@ -113,33 +100,55 @@ class StageTimer:
             return out
 
 
-def bounded_device_trace(log_dir: str, seconds: float) -> None:
+#: Held while a profiler session runs: ``jax.profiler`` allows one per
+#: process, whoever started it (--profile at launch, POST /profile).
+_SESSION = threading.Lock()
+
+
+def session_active() -> bool:
+    """Whether a profiler session started here is still running."""
+    return _SESSION.locked()
+
+
+def bounded_device_trace(log_dir: str, seconds: float) -> bool:
     """Capture a wall-clock-bounded device trace without blocking the
     caller: starts the JAX profiler now and schedules the stop on a timer
-    thread. For long-running services (--profile): an unbounded trace
-    would grow without limit, so the capture window is explicit. The stop
-    also runs at interpreter exit — a service stopped before the window
-    elapses must still flush the trace, not lose it."""
+    thread. For long-running services (``--profile``, ``POST /profile``):
+    an unbounded trace would grow without limit, so the capture window is
+    explicit. The stop also runs at interpreter exit — a service stopped
+    before the window elapses must still flush the trace, not lose it.
+
+    Returns False, and starts nothing, while another session runs."""
     import atexit
 
     import jax
 
-    jax.profiler.start_trace(log_dir)
-    stopped = threading.Event()
+    if not _SESSION.acquire(blocking=False):
+        return False
+    try:
+        jax.profiler.start_trace(log_dir)
+    except BaseException:
+        _SESSION.release()
+        raise
+    once = threading.Lock()
 
     def _stop() -> None:
-        if stopped.is_set():
+        # Timer thread and atexit may both come: the first one stops.
+        if not once.acquire(blocking=False):
             return
-        stopped.set()
         try:
             jax.profiler.stop_trace()
         except Exception:  # pragma: no cover - profiler teardown races
             logging.getLogger(__name__).exception("stop_trace failed")
+        finally:
+            _SESSION.release()
+            atexit.unregister(_stop)
 
     atexit.register(_stop)
     timer = threading.Timer(seconds, _stop)
     timer.daemon = True
     timer.start()
+    return True
 
 
 def device_memory_stats() -> dict[str, int]:

@@ -177,7 +177,9 @@ class DetectorViewWorkflow:
         # the host cache after (ADR 0113). ``set_rois`` flips them
         # dynamic the moment masks make them carry data.
         self._publish = PackedPublisher(
-            publish_program, static_keys=self._STATIC_ROI_KEYS
+            publish_program,
+            static_keys=self._STATIC_ROI_KEYS,
+            name="detector_view",
         )
         #: Combined-publish hand-off (ops/publish.py PublishOffer): the
         #: JobManager prefetches this job's outputs through one fused

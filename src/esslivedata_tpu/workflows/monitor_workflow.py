@@ -138,7 +138,7 @@ class MonitorWorkflow:
         from ..ops.publish import PackedPublisher
 
         # One execute + one fetch per publish (see ops/publish.py).
-        self._publish = PackedPublisher(publish_program)
+        self._publish = PackedPublisher(publish_program, name="monitor")
         #: Combined-publish hand-off (ADR 0113): outputs prefetched by
         #: the JobManager's fused tick round trip, consumed in finalize.
         self._prefetched_publish: dict | None = None

@@ -174,7 +174,7 @@ class MultiBankViewWorkflow:
                 }
                 return outputs, self._hist.fold_window(state)
 
-            self._publish = PackedPublisher(program)
+            self._publish = PackedPublisher(program, name="multibank")
         return self._publish
 
     def publish_offer(self):

@@ -156,7 +156,7 @@ class QStreamingMixin:
                 }
                 return outputs, self._hist.fold_window(state)
 
-            self._publish = PackedPublisher(program)
+            self._publish = PackedPublisher(program, name="qshared")
         return self._publish
 
     def event_ingest(self, stream: str, staged: StagedEvents):

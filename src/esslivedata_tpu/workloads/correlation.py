@@ -98,7 +98,7 @@ class TimeseriesCorrelationWorkflow:
 
         from ..ops.publish import PackedPublisher
 
-        self._publish = PackedPublisher(publish_program)
+        self._publish = PackedPublisher(publish_program, name="correlation")
         self._prefetched_publish: dict | None = None
 
     def _init_state(self) -> CorrelationState:

@@ -113,7 +113,7 @@ class ImagingViewWorkflow:
         from ..ops.publish import PackedPublisher
 
         self._publish = PackedPublisher(
-            publish_program, static_keys=("flatfield",)
+            publish_program, static_keys=("flatfield",), name="imaging"
         )
         self._prefetched_publish: dict | None = None
         assert n_frames == edges.size - 1

@@ -108,7 +108,8 @@ class PowderFocusWorkflow:
         from ..ops.publish import PackedPublisher
 
         self._publish = PackedPublisher(
-            publish_program, static_keys=("acceptance",)
+            publish_program, static_keys=("acceptance",),
+            name="powder_focus",
         )
         self._prefetched_publish: dict | None = None
         assert self._acceptance_host.shape == (n_banks, n_d)

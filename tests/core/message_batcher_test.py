@@ -4,9 +4,12 @@ from esslivedata_tpu.core import Duration, Message, StreamId, StreamKind, Timest
 from esslivedata_tpu.core.constants import PULSE_PERIOD_NS_DEN, PULSE_PERIOD_NS_NUM
 from esslivedata_tpu.core.message_batcher import (
     AdaptiveMessageBatcher,
+    BatchHold,
+    LoadGovernor,
     NaiveMessageBatcher,
     SimpleMessageBatcher,
 )
+from esslivedata_tpu.telemetry import REGISTRY
 
 STREAM = StreamId(kind=StreamKind.DETECTOR_EVENTS, name="bank0")
 
@@ -247,3 +250,90 @@ class TestMessagePreservationAcrossResize:
         for (s0, e0), (s1, e1) in zip(bounds, bounds[1:], strict=False):
             assert e0 <= s1, f"windows overlap: {(s0, e0)} then {(s1, e1)}"
             assert s0 < e0 and s1 < e1
+
+
+class TestBatchHold:
+    """When a batch's newest message was delivered, from the polls the
+    processor feeds: the fake clock is the poll's return time."""
+
+    def feed(self, hold, batcher, polled_at, data):
+        batch = batcher.batch(data)
+        return batch, hold.arrival(polled_at, data, batch)
+
+    def test_closing_poll_that_brings_only_the_next_window(self):
+        """Two polls: the window's pulses, then the pulse that closes
+        it. The batch's last message came with the FIRST poll."""
+        hold, batcher = BatchHold(), SimpleMessageBatcher(Duration.from_s(1.0))
+        batch, arrived = self.feed(
+            hold, batcher, 10.000, [msg(p) for p in range(14)]
+        )
+        assert batch is None and arrived is None
+        batch, arrived = self.feed(hold, batcher, 10.071, [msg(14)])
+        assert len(batch) == 14
+        assert arrived == 10.000
+
+    def test_closing_poll_that_also_brings_the_windows_last_pulse(self):
+        """A poll that falls between the sources of one pulse delivers
+        the window's tail together with its closing message: the hold
+        starts at that poll."""
+        hold, batcher = BatchHold(), SimpleMessageBatcher(Duration.from_s(1.0))
+        self.feed(hold, batcher, 10.000, [msg(p) for p in range(13)])
+        batch, arrived = self.feed(hold, batcher, 10.050, [msg(13), msg(14)])
+        assert len(batch) == 14
+        assert arrived == 10.050
+        # ... and the message it left buffered opens the next window
+        # with this poll's time.
+        self.feed(hold, batcher, 10.900, [])
+        batch, arrived = self.feed(hold, batcher, 11.071, [msg(28)])
+        assert [m.value for m in batch.messages] == [14]
+        assert arrived == 10.050
+
+    def test_backlog_drained_without_new_data(self):
+        """Catch-up: one poll brought several windows; the later ones
+        are emitted by polls that bring nothing."""
+        hold, batcher = BatchHold(), SimpleMessageBatcher(Duration.from_s(1.0))
+        batch, arrived = self.feed(
+            hold, batcher, 5.0, [msg(0), msg(14), msg(28)]
+        )
+        assert [m.value for m in batch.messages] == [0] and arrived == 5.0
+        batch, arrived = self.feed(hold, batcher, 6.0, [])
+        assert [m.value for m in batch.messages] == [14] and arrived == 5.0
+
+    def test_naive_batcher_holds_nothing(self):
+        hold, batcher = BatchHold(), NaiveMessageBatcher()
+        _, arrived = self.feed(hold, batcher, 1.0, [msg(0)])
+        assert arrived == 1.0
+        _, arrived = self.feed(hold, batcher, 2.0, [msg(1)])
+        assert arrived == 2.0
+
+
+class TestScaleChangeCounter:
+    """``livedata_batcher_scale_changes_total`` follows the governor:
+    one count per change of scale, none at the cap or the floor."""
+
+    @staticmethod
+    def changes() -> tuple[float, float]:
+        family = REGISTRY.get("livedata_batcher_scale_changes_total")
+        return family.value(direction="up"), family.value(direction="down")
+
+    def test_counts_each_escalation_and_relaxation(self):
+        governor = LoadGovernor(max_scale=4.0)
+        up0, down0 = self.changes()
+        assert governor.escalate() and governor.escalate()
+        assert not governor.escalate()  # at the cap: no change, no count
+        assert self.changes() == (up0 + 2, down0)
+        while governor.relax():
+            pass
+        assert governor.scale == 1.0
+        up, down = self.changes()
+        assert up == up0 + 2 and down == down0 + 4  # 4 / sqrt(2)^4 = 1
+        assert not governor.relax()  # at the floor
+        assert self.changes() == (up, down)
+
+    def test_adaptive_batcher_escalation_is_counted(self):
+        batcher = AdaptiveMessageBatcher(Duration.from_s(1.0))
+        up0, _ = self.changes()
+        for _ in range(2):
+            batcher.report_processing_time(Duration.from_s(0.9))
+        assert batcher.scale == 2.0
+        assert self.changes()[0] == up0 + 1

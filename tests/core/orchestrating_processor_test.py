@@ -14,7 +14,11 @@ from esslivedata_tpu.core.message import (
     StreamId,
     StreamKind,
 )
-from esslivedata_tpu.core.message_batcher import NaiveMessageBatcher
+from esslivedata_tpu.core.message_batcher import (
+    AdaptiveMessageBatcher,
+    NaiveMessageBatcher,
+    SimpleMessageBatcher,
+)
 from esslivedata_tpu.core.orchestrating_processor import (
     MessagePreprocessor,
     OrchestratingProcessor,
@@ -148,6 +152,7 @@ def make_processor(
     factory=None,
     clock=None,
     heartbeat_interval_s: float = 2.0,
+    batcher=None,
 ):
     sink = FakeMessageSink()
     processor = OrchestratingProcessor(
@@ -155,7 +160,7 @@ def make_processor(
         sink=sink,
         preprocessor_factory=factory or StubFactory({}),
         job_manager=JobManager(job_threads=1),
-        batcher=NaiveMessageBatcher(),
+        batcher=batcher or NaiveMessageBatcher(),
         instrument="dummy",
         service_name="detector_data",
         clock=clock or (lambda: 0.0),
@@ -250,3 +255,96 @@ class TestProcessorCycle:
         ]
         assert job_beats, "per-job heartbeat expected on finalize"
         assert all(j.state == "stopped" for j in job_beats)
+
+
+class ClockedSource:
+    """A source whose polls return at stated times of a fake
+    ``perf_counter``: ``polls`` is [(return time, messages)]."""
+
+    def __init__(self, polls, now: dict) -> None:
+        self._polls = list(polls)
+        self._now = now
+
+    def get_messages(self):
+        if not self._polls:
+            return []
+        self._now["t"], messages = self._polls.pop(0)
+        return messages
+
+
+class TestBatchHoldAndScale:
+    def pulse(self, index: int) -> Message:
+        return Message(
+            timestamp=Timestamp.from_pulse_index(index),
+            stream=data_stream("a"),
+            value=float(index),
+        )
+
+    def test_hold_from_the_poll_that_delivered_the_last_message(
+        self, monkeypatch
+    ):
+        """A batcher fed two polls under a fake clock: the window's
+        pulses at 10.000, the pulse that closes it at 10.071. The hold
+        is observed once, as the 71 ms between them, and rides the
+        ``decode`` span of that tick."""
+        import time
+        from types import SimpleNamespace
+
+        from esslivedata_tpu.core import orchestrating_processor as module
+        from esslivedata_tpu.telemetry import REGISTRY, TRACER
+
+        now = {"t": 0.0}
+        monkeypatch.setattr(
+            module,
+            "time",
+            SimpleNamespace(
+                perf_counter=lambda: now["t"],
+                monotonic=time.monotonic,
+                time_ns=time.time_ns,
+            ),
+        )
+        source = ClockedSource(
+            [
+                (10.000, [self.pulse(p) for p in range(14)]),
+                (10.071, [self.pulse(14)]),
+            ],
+            now,
+        )
+        processor, _ = make_processor(
+            source=source,
+            factory=StubFactory({"a": RecordingAccumulator()}),
+            batcher=SimpleMessageBatcher(),
+        )
+        hold = REGISTRY.get("livedata_batch_hold_seconds")
+        count0, sum0 = hold.total_count(), hold.sum()
+        TRACER.enabled = True
+        TRACER.clear()
+        processor.process()
+        assert hold.total_count() == count0  # the window is still open
+        processor.process()
+        assert hold.total_count() == count0 + 1
+        assert abs(hold.sum() - sum0 - 0.071) < 1e-9
+        (decode,) = [s for s in TRACER.spans() if s.name == "decode"]
+        assert decode.args == {"hold_us": 71000}
+
+    def gauge(self, processor) -> list[float]:
+        return [
+            sample.value
+            for family in processor._telemetry_families()
+            if family.name == "livedata_batcher_window_scale"
+            for sample in family.samples
+        ]
+
+    def test_scale_gauge_follows_the_adaptive_batcher(self):
+        from esslivedata_tpu.core.timestamp import Duration
+
+        batcher = AdaptiveMessageBatcher()
+        processor, _ = make_processor(batcher=batcher)
+        assert self.gauge(processor) == [1.0]
+        for _ in range(2):
+            batcher.report_processing_time(Duration.from_s(0.9))
+        assert self.gauge(processor) == [2.0]
+
+    def test_a_batcher_without_a_scale_exposes_no_sample(self):
+        processor, _ = make_processor()
+        assert self.gauge(processor) == []

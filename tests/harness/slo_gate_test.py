@@ -115,6 +115,27 @@ class TestEvaluation:
         rule["allow_missing"] = True
         assert slo_gate.evaluate_rule(rule, fams)[0]
 
+    @pytest.mark.parametrize(("scale", "green"), [(1, True), (2, False)])
+    def test_default_rules_hold_the_batcher_to_its_base_window(
+        self, slo_gate, scale, green
+    ):
+        """``livedata_batcher_window_scale`` above 1 is a service past
+        the rate it sustains: the shipped default gate goes red on it,
+        and passes a scrape of a batcher that has no governor."""
+        import json
+
+        rules = json.loads(
+            (REPO / "scripts" / "slo_rules" / "default.json").read_text()
+        )["rules"]
+        (rule,) = [r for r in rules if r["name"] == "batcher_at_base_window"]
+        fams = parse_prometheus_text(
+            "# HELP livedata_batcher_window_scale scale\n"
+            "# TYPE livedata_batcher_window_scale gauge\n"
+            f'livedata_batcher_window_scale{{service="detector_data"}} {scale}\n'
+        )
+        assert slo_gate.evaluate_rule(rule, fams)[0] is green
+        assert slo_gate.evaluate_rule(rule, parse_prometheus_text(COUNTERS))[0]
+
     def test_subtract_deltas_counters_keeps_gauges(self, slo_gate):
         before = parse_prometheus_text(COUNTERS)
         after_text = COUNTERS.replace(

@@ -122,6 +122,55 @@ class TestKafkaSink:
         assert sink.dropped == 1
         assert len(producer.messages) == 1
 
+    def test_phase_seconds_and_bytes_are_counted(self):
+        """``livedata_sink_seconds_total{phase}`` and
+        ``livedata_sink_bytes_total``: summed over a publish's
+        messages, the serialize and produce times each land in their
+        own phase and the bytes are the payloads handed over."""
+        import time
+
+        from esslivedata_tpu.telemetry import REGISTRY
+
+        seconds = REGISTRY.get("livedata_sink_seconds_total")
+        nbytes = REGISTRY.get("livedata_sink_bytes_total")
+
+        class SlowProducer(FakeProducer):
+            def produce(self, topic, value, key=None):
+                time.sleep(0.02)
+                super().produce(topic, value, key)
+
+            def flush(self, timeout=0.0):
+                time.sleep(0.01)
+
+        class SlowSerializer:
+            def __init__(self, inner):
+                self._inner = inner
+
+            def serialize(self, message):
+                time.sleep(0.005)
+                return self._inner.serialize(message)
+
+        producer = SlowProducer()
+        topics = LivedataTopics.for_instrument("dummy")
+        sink = KafkaSink(
+            producer, SlowSerializer(make_default_serializer(topics))
+        )
+        before = {
+            phase: seconds.value(phase=phase)
+            for phase in ("serialize", "produce", "flush")
+        }
+        bytes0 = nbytes.total()
+        sink.publish_messages([hist_message(), hist_message("bank0/b")])
+        added = {
+            phase: seconds.value(phase=phase) - before[phase]
+            for phase in before
+        }
+        assert 0.010 <= added["serialize"] < 0.040 <= added["produce"]
+        assert 0.010 <= added["flush"] < 0.040
+        assert nbytes.total() - bytes0 == sum(
+            len(m.value) for m in producer.messages
+        )
+
     def test_serialize_error_contained(self):
         producer = FakeProducer()
         topics = LivedataTopics.for_instrument("dummy")

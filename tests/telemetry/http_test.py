@@ -82,6 +82,161 @@ class TestMetricsPlane:
             parse_prometheus_text(body)
 
 
+def post(server: MetricsServer, path: str):
+    request = urllib.request.Request(
+        f"http://127.0.0.1:{server.port}{path}", method="POST"
+    )
+    return urllib.request.urlopen(request, timeout=30)
+
+
+class TestProfilerOnCommand:
+    """``POST /profile?seconds=N``: a bounded ``jax.profiler`` session
+    into a directory of the server's choosing."""
+
+    @pytest.fixture(autouse=True)
+    def no_cooldown_left_over(self, monkeypatch):
+        """Each test starts with the endpoint's cool-down spent, and
+        (but for the test of it) asks for none."""
+        from esslivedata_tpu.telemetry import http
+
+        import jax  # noqa: F401  (the endpoint refuses without it)
+
+        monkeypatch.setattr(http._PROFILE, "_not_before", 0.0)
+        monkeypatch.setattr(http, "PROFILE_COOLDOWN_S", 0.0)
+
+    @staticmethod
+    def wait_for_the_session_to_end():
+        import time
+
+        from esslivedata_tpu.utils import profiling
+
+        deadline = time.monotonic() + 30
+        while profiling._SESSION.locked() and time.monotonic() < deadline:
+            time.sleep(0.05)
+        assert not profiling._SESSION.locked()
+
+    def test_starts_a_session_in_a_fresh_directory_under_tmpdir(
+        self, server, tmp_path, monkeypatch
+    ):
+        import tempfile
+        from pathlib import Path
+
+        monkeypatch.setenv("TMPDIR", str(tmp_path))
+        monkeypatch.setattr(tempfile, "tempdir", None)  # re-read TMPDIR
+        try:
+            response = post(server, "/profile?seconds=0.2")
+            assert response.status == 202
+            body = json.loads(response.read())
+            assert body["seconds"] == 0.2
+            log_dir = Path(body["dir"])
+            assert log_dir.parent == tmp_path and log_dir.is_dir()
+            # One session per process: a second ask while it runs is
+            # refused, whoever started the first (--profile included).
+            with pytest.raises(urllib.error.HTTPError) as err:
+                post(server, "/profile?seconds=0.2")
+            assert err.value.code == 409
+        finally:
+            self.wait_for_the_session_to_end()
+            monkeypatch.setattr(tempfile, "tempdir", None)
+        assert list(log_dir.glob("plugins/profile/*/*.xplane.pb"))
+        # ... and once it is over the next one starts.
+        assert post(server, "/profile?seconds=0.1").status == 202
+        self.wait_for_the_session_to_end()
+
+    @pytest.mark.parametrize(
+        "query", ["seconds=0", "seconds=-1", "seconds=61", "seconds=nan",
+                  "seconds=abc"]
+    )
+    def test_bad_or_uncapped_seconds_are_refused(self, server, query):
+        from esslivedata_tpu.utils import profiling
+
+        with pytest.raises(urllib.error.HTTPError) as err:
+            post(server, f"/profile?{query}")
+        assert err.value.code == 400
+        assert not profiling._SESSION.locked()
+
+    def test_no_path_is_taken_from_the_client(self, server):
+        with pytest.raises(urllib.error.HTTPError) as err:
+            post(server, "/profile/etc/cron.d?seconds=1")
+        assert err.value.code == 404
+        with pytest.raises(urllib.error.HTTPError) as err:
+            post(server, "/metrics")
+        assert err.value.code == 404
+
+    def test_a_session_is_followed_by_a_cool_down(self, server, monkeypatch):
+        from esslivedata_tpu.telemetry import http
+
+        monkeypatch.setattr(http, "PROFILE_COOLDOWN_S", 120.0)
+        try:
+            assert post(server, "/profile?seconds=0.1").status == 202
+        finally:
+            self.wait_for_the_session_to_end()
+        with pytest.raises(urllib.error.HTTPError) as err:
+            post(server, "/profile?seconds=0.1")
+        assert err.value.code == 429
+        assert 0 < int(err.value.headers["Retry-After"]) <= 121
+        # Once it has passed the next session starts.
+        monkeypatch.setattr(http._PROFILE, "_not_before", 0.0)
+        assert post(server, "/profile?seconds=0.1").status == 202
+        self.wait_for_the_session_to_end()
+
+    def test_only_the_newest_trace_directories_are_kept(
+        self, server, tmp_path, monkeypatch
+    ):
+        import os
+        import tempfile
+
+        from esslivedata_tpu.telemetry import http
+
+        monkeypatch.setenv("TMPDIR", str(tmp_path))
+        monkeypatch.setattr(tempfile, "tempdir", None)  # re-read TMPDIR
+        try:
+            for age in range(6):  # what earlier sessions and runs left
+                old = tmp_path / f"livedata-profile-old{age}"
+                old.mkdir()
+                (old / "trace.xplane.pb").write_bytes(b"x")
+                os.utime(old, (1000 + age, 1000 + age))
+            other = tmp_path / "someone-elses"
+            other.mkdir()
+            body = json.loads(post(server, "/profile?seconds=0.1").read())
+        finally:
+            self.wait_for_the_session_to_end()
+            monkeypatch.setattr(tempfile, "tempdir", None)
+        kept = sorted(p.name for p in tmp_path.glob("livedata-profile-*"))
+        assert len(kept) == http.PROFILE_KEEP_DIRS
+        assert os.path.basename(body["dir"]) in kept
+        assert {"livedata-profile-old3", "livedata-profile-old4",
+                "livedata-profile-old5"} < set(kept)
+        assert other.is_dir()
+
+    def test_a_process_without_jax_is_refused(self, server, monkeypatch):
+        """The relay and the fakes never import jax, and this endpoint
+        must not be what does."""
+        import sys
+
+        from esslivedata_tpu.utils import profiling
+
+        monkeypatch.delitem(sys.modules, "jax")
+        with pytest.raises(urllib.error.HTTPError) as err:
+            post(server, "/profile?seconds=1")
+        assert err.value.code == 503
+        assert "jax" not in sys.modules
+        assert not profiling._SESSION.locked()
+
+    def test_a_launch_time_session_blocks_the_endpoint(self, server, tmp_path):
+        from esslivedata_tpu.utils.profiling import bounded_device_trace
+
+        assert bounded_device_trace(str(tmp_path / "launch"), 0.3)
+        try:
+            assert not bounded_device_trace(str(tmp_path / "second"), 0.3)
+            with pytest.raises(urllib.error.HTTPError) as err:
+                post(server, "/profile?seconds=1")
+            assert err.value.code == 409
+        finally:
+            self.wait_for_the_session_to_end()
+        assert not (tmp_path / "second").exists()
+
+
 class TestServiceRunnerFlag:
     def test_setup_arg_parser_starts_endpoint_on_metrics_port(self):
         """--metrics-port 0 on the shared parser (every service runner's

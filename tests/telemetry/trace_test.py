@@ -7,7 +7,7 @@ from __future__ import annotations
 import json
 import threading
 
-from esslivedata_tpu.telemetry import CompileEventRecorder, TickTracer
+from esslivedata_tpu.telemetry import REGISTRY, CompileEventRecorder, TickTracer
 
 
 def make_tracer(**kwargs) -> TickTracer:
@@ -75,6 +75,99 @@ class TestSpans:
         assert tracer.spans() == []
 
 
+def span_totals(name: str) -> tuple[float, int]:
+    """(sum, count) of ``livedata_tick_span_seconds{span=name}``: the
+    histogram is the process's, so tests read deltas."""
+    family = REGISTRY.get("livedata_tick_span_seconds")
+    return family.sum(span=name), family.count(span=name)
+
+
+class TestAggregatesAndArgs:
+    def test_span_args_ride_the_ring(self):
+        tracer = make_tracer()
+        trace_id = tracer.new_trace()
+        with tracer.span("flatten", trace_id, {"events": 7, "padded": 4096}):
+            pass
+        with tracer.span("sink", trace_id):
+            pass
+        flatten, sink = tracer.spans(trace_id)
+        assert flatten.args == {"events": 7, "padded": 4096}
+        assert sink.args is None
+
+    def test_observe_is_aggregate_only(self):
+        """An enclosing or contained phase goes to the histogram and
+        never to the ring: the ring stays flat."""
+        tracer = make_tracer()
+        tracer.set_current(tracer.new_trace())
+        before = span_totals("d2h")
+        tracer.observe("d2h", 0.25)
+        after = span_totals("d2h")
+        assert after[1] == before[1] + 1
+        assert after[0] - before[0] == 0.25
+        assert tracer.spans() == []
+        TickTracer(enabled=False).observe("d2h", 1.0)
+        assert span_totals("d2h") == after
+
+    def test_finish_tick_observes_tick_and_what_no_span_covered(self):
+        tracer = make_tracer(slow_tick_s=10.0)
+        trace_id = tracer.new_trace()
+        tracer.record("decode", 0.0, 0.010, trace_id)
+        with tracer.bind(trace_id):
+            tracer.record("fetch", 0.010, 0.030)
+        # Another thread's span of the same trace does not tile this
+        # thread's tick (pool threads, pipeline workers).
+        worker = threading.Thread(
+            target=tracer.record, args=("prestage", 0.0, 0.5, trace_id)
+        )
+        worker.start()
+        worker.join()
+        tick0, unspanned0 = span_totals("tick"), span_totals("unspanned")
+        tracer.finish_tick(trace_id, 0.050, tiled=True)
+        tick1, unspanned1 = span_totals("tick"), span_totals("unspanned")
+        assert (tick1[1], unspanned1[1]) == (tick0[1] + 1, unspanned0[1] + 1)
+        assert abs(tick1[0] - tick0[0] - 0.050) < 1e-12
+        assert abs(unspanned1[0] - unspanned0[0] - 0.010) < 1e-12
+        # Aggregate only: no ring entry for either.
+        assert {s.name for s in tracer.spans()} == {
+            "decode", "fetch", "prestage"
+        }
+
+    def test_overlapping_stages_report_tick_alone(self):
+        """The pipelined path (stages overlap across threads) does not
+        ask for ``unspanned``."""
+        tracer = make_tracer(slow_tick_s=10.0)
+        trace_id = tracer.new_trace()
+        tracer.record("decode", 0.0, 0.010, trace_id)
+        tick0, unspanned0 = span_totals("tick"), span_totals("unspanned")
+        tracer.finish_tick(trace_id, 0.050)
+        assert span_totals("tick")[1] == tick0[1] + 1
+        assert span_totals("unspanned") == unspanned0
+
+    def test_overlapping_spans_read_as_a_negative_remainder(self):
+        """A nested (or double-recorded) span on the loop thread covers
+        more than the tick: ``unspanned`` then goes NEGATIVE on the
+        scrape instead of reading as perfect tiling."""
+        tracer = make_tracer(slow_tick_s=10.0)
+        trace_id = tracer.new_trace()
+        tracer.record("fetch", 0.0, 0.030, trace_id)
+        tracer.record("d2h", 0.020, 0.010, trace_id)  # inside fetch
+        before = span_totals("unspanned")
+        tracer.finish_tick(trace_id, 0.035, tiled=True)
+        after = span_totals("unspanned")
+        assert after[1] == before[1] + 1
+        assert abs(after[0] - before[0] - (0.035 - 0.040)) < 1e-12
+
+    def test_covered_sum_does_not_leak_into_the_next_tick(self):
+        tracer = make_tracer(slow_tick_s=10.0)
+        first, second = tracer.new_trace(), tracer.new_trace()
+        tracer.record("decode", 0.0, 0.040, first)
+        tracer.finish_tick(first, 0.040, tiled=True)
+        tracer.record("decode", 0.0, 0.010, second)
+        before = span_totals("unspanned")
+        tracer.finish_tick(second, 0.030, tiled=True)
+        assert abs(span_totals("unspanned")[0] - before[0] - 0.020) < 1e-12
+
+
 class TestChromeExport:
     def test_chrome_trace_loads_and_groups_by_trace_id(self, tmp_path):
         tracer = make_tracer()
@@ -95,6 +188,28 @@ class TestChromeExport:
             assert event["pid"] in (t1, t2)
         names_t1 = [e["name"] for e in events if e["pid"] == t1]
         assert names_t1 == ["decode", "prestage", "tick_execute", "fetch"]
+
+    def test_dump_names_its_clock_and_carries_span_args(self, tmp_path):
+        """``ts`` is on ``perf_counter``; the offset to the epoch is
+        sampled in the process that recorded the spans, at dump time."""
+        import time
+
+        tracer = make_tracer()
+        trace_id = tracer.new_trace()
+        with tracer.span("h2d", trace_id, {"bytes": 16384}):
+            pass
+        path = tmp_path / "trace.json"
+        lo = time.time_ns() - time.perf_counter_ns()
+        tracer.dump(str(path))
+        hi = time.time_ns() - time.perf_counter_ns()
+        doc = json.loads(path.read_text())
+        assert doc["clock"] == "perf_counter"
+        # The two clocks tick together to well under a millisecond.
+        assert lo - 1_000_000 <= doc["epoch_minus_clock_ns"] <= hi + 1_000_000
+        (event,) = doc["traceEvents"]
+        assert event["args"] == {"trace_id": trace_id, "bytes": 16384}
+        at_ns = event["ts"] * 1e3 + doc["epoch_minus_clock_ns"]
+        assert abs(at_ns - time.time_ns()) < 60e9
 
 
 class TestWatchdog:

@@ -577,3 +577,74 @@ class TestLinkObserver:
         assert mon.policy().publish_coalesce == 1
         mon.observe_publish(0.0877)
         assert mon.policy().publish_coalesce == 4
+
+
+class TestProgramNamesAndScopes:
+    """What the device trace calls the tick program and its phases
+    (ADR 0116): a stable name per workflow family in place of
+    ``jit_tick``, and ``jax.named_scope`` on the step, the fold, each
+    member's reductions and the pack. Op metadata only: the parity
+    tests above hold with them in."""
+
+    def lowered_programs(self, makes, monkeypatch) -> dict[str, str]:
+        from esslivedata_tpu.ops.tick import TickCombiner
+
+        texts: dict[str, str] = {}
+        build = TickCombiner._build
+
+        def recording_build(self, hist, n_staged, members):
+            fn = build(self, hist, n_staged, members)
+
+            def run(*args):
+                texts[fn.__name__] = fn.lower(*args).as_text(debug_info=True)
+                return fn(*args)
+
+            return run
+
+        monkeypatch.setattr(TickCombiner, "_build", recording_build)
+        mgr, _ = _make_manager(makes)
+        rng = np.random.default_rng(53)
+        (pid, toa), = _windows(rng, 1, 500, -5, 150)
+        mgr.process_jobs({"det0": _staged(pid, toa)}, start=T(0), end=T(1))
+        mgr.shutdown()
+        return texts
+
+    def test_tick_program_is_named_for_its_family(self, monkeypatch):
+        det = _det()
+        texts = self.lowered_programs(
+            [
+                lambda: DetectorViewWorkflow(projection=project_logical(det)),
+                lambda: DetectorViewWorkflow(projection=project_logical(det)),
+                lambda: MonitorWorkflow(),
+            ],
+            monkeypatch,
+        )
+        assert set(texts) == {"tick_detector_view", "tick_monitor"}
+        for name, text in texts.items():
+            assert f"jit({name})" in text
+
+    def test_phases_are_scoped_in_the_lowered_program(self, monkeypatch):
+        det = _det()
+        texts = self.lowered_programs(
+            [lambda: DetectorViewWorkflow(projection=project_logical(det))],
+            monkeypatch,
+        )
+        text = texts["tick_detector_view"]
+        for scope in ("scatter", "publish_reduce", "fold", "pack"):
+            assert f"/{scope}/" in text, f"no op carries the scope {scope!r}"
+        # The fold runs inside the member's publish program.
+        assert "publish_reduce/fold/" in text
+
+    def test_program_name_joins_the_families_of_a_mixed_group(self):
+        from esslivedata_tpu.ops.publish import PackedPublisher, program_name
+
+        pubs = [
+            PackedPublisher(lambda s: ({}, s), name="monitor"),
+            PackedPublisher(lambda s: ({}, s), name="detector_view"),
+            PackedPublisher(lambda s: ({}, s), name="monitor"),
+            PackedPublisher(lambda s: ({}, s)),
+        ]
+        assert (
+            program_name("tick", pubs) == "tick_detector_view_monitor_publish"
+        )
+        assert program_name("publish", pubs[:1]) == "publish_monitor"
