@@ -30,6 +30,7 @@ from pydantic import BaseModel, model_validator
 
 from ..config.workflow_spec import JobId, WorkflowConfig
 from ..preprocessors.event_data import StagedEvents
+from ..telemetry.instruments import TICK_GROUPS
 from ..telemetry.trace import TRACER
 from ..workflows.workflow_factory import WorkflowFactory, workflow_registry
 from .device_event_cache import DeviceEventCache
@@ -1101,7 +1102,27 @@ class JobManager:
         self, tick_groups: list[tuple[tuple, Any, list]]
     ) -> tuple[set[int], dict[JobId, set[str]]]:
         """Execute every ((stream, key), slice, members) tick group as
-        ONE device dispatch + ONE fetch.
+        ONE device dispatch + ONE fetch, the groups of a tick
+        software-pipelined: for each group stage, then dispatch; then,
+        in the same order, collect each and do its members' bookkeeping.
+        The dispatch is asynchronous, so the host flattens and ships
+        group i+1 while the chip runs group i, and a group's copy back
+        runs under the next group's program. The per-group contract
+        (ADR 0114) and every result are unchanged; only the host's
+        position in time moves.
+
+        Three cases stay serial, by what can be observed here and by
+        no switch. A group whose program misses the program LRU ran
+        its compile round to the end inside ``dispatch``: it, and
+        whatever is pending, is collected on the spot, so nothing is
+        dispatched ahead across a compile. A tick with one group has
+        nothing to run ahead of. A record that appears in a second
+        group of the tick (none today: ``_split_tick_groups`` admits
+        only single-stream members) waits for the first's collect,
+        which adopts the state its next dispatch donates.
+        ``livedata_tick_groups_total{dispatched}`` counts each group at
+        its dispatch: ``ahead`` when an earlier group of the tick was
+        still uncollected, ``alone`` otherwise.
 
         Returns (served record ids, job_id -> streams accumulated
         out-of-band). Served records' publishes are complete — the
@@ -1110,29 +1131,59 @@ class JobManager:
         ``skip_accumulate`` exactly like the fused-step map.
 
         Containment (mirrors ``_run_combined_publish`` +
-        ``_run_fused_steps``): a staging failure drops the whole group
-        to the separate-dispatch path (nothing was touched); a plan
-        failure drops only that member; an unpack failure adopts the
-        member's folded carry — the fold already ran on device, so the
-        stream is still marked accumulated and finalize republishes
-        privately; a dispatch failure after donation resets exactly the
-        members whose buffers were consumed (``state_lost``), with a
-        visible warning, and the private path re-adds THIS window's
-        batch into the fresh state.
+        ``_run_fused_steps``), per group, every other group's pending
+        handle staying collectable: a staging failure drops the whole
+        group to the separate-dispatch path (nothing was touched); a
+        plan failure drops only that member; an unpack failure adopts
+        the member's folded carry — the fold already ran on device, so
+        the stream is still marked accumulated and finalize republishes
+        privately; a dispatch failure after donation, synchronous or
+        surfacing at the collect, resets exactly the members whose
+        buffers were consumed (``state_lost``), with a visible warning,
+        and the private path re-adds THIS window's batch into the fresh
+        state.
 
-        Each group's execute+fetch wall time — the whole tick's device
-        round trip — feeds the link monitor, with compile rounds
-        excluded via ``TickCombiner.last_compiled`` (ADR 0113's
-        mechanism, threaded through this path too so a first-tick
-        compile cannot latch ``publish_coalesce`` spuriously).
+        The link monitor is fed, per group, the host's time in the
+        group's two halves: its dispatch plus its wait at the collect.
+        Dispatched alone that is the execute+fetch round trip as
+        before. Dispatched ahead, what of the round trip ran under the
+        staging of later groups is not in it (the tick did not wait for
+        it), and the programs queued before the group are, as they are
+        behind any busy chip; with prestaged windows, where the
+        dispatches follow each other at once, each group reads its own
+        program as before. The wall time from dispatch to collected
+        would add the whole queue to every group, and a three-group
+        NMX service then read one 200 ms round trip where it has three
+        of 60 and latched ``publish_coalesce`` at its maximum (PERF.md
+        section 6, PR 25). Compile rounds are excluded via the handle's
+        ``compiled`` (ADR 0113's mechanism, threaded through this path
+        too so a first-tick compile cannot latch ``publish_coalesce``
+        spuriously).
         """
         served: set[int] = set()
         streams_done: dict[JobId, set[str]] = {}
         if not tick_groups:
             return served, streams_done
-        from ..ops.publish import PublishRequest, publish_args_consumed
+        from ..ops.publish import PublishRequest
+
+        # (members, combiner, pending handle, slice label, seconds its
+        # dispatch took) of every group dispatched and not yet
+        # collected, and the records among their members.
+        in_flight: list[tuple] = []
+        flying: set[int] = set()
+
+        def drain() -> None:
+            for members, combiner, pending, slice_key, took in in_flight:
+                self._collect_tick_group(
+                    members, combiner, pending, slice_key, took,
+                    served, streams_done,
+                )
+            in_flight.clear()
+            flying.clear()
 
         for (stream, key), plc, members in tick_groups:
+            if any(id(rec) in flying for rec, *_ in members):
+                drain()
             _rec0, _stream0, value0, ingest0, _offer0 = members[0]
             try:
                 staged = ingest0.stage(
@@ -1163,104 +1214,144 @@ class JobManager:
                     combiner = plc.combiner
             t0 = time.perf_counter()
             try:
-                results = combiner.publish(
+                pending = combiner.dispatch(
                     ingest0.hist, key, staged, requests,
                     slice_key=slice_key,
                 )
+                took = time.perf_counter() - t0
+                TICK_GROUPS.labels(
+                    dispatched=(
+                        "ahead"
+                        if in_flight and not pending.compiled
+                        else "alone"
+                    )
+                ).inc()
                 if self._chaos is not None:
                     # Chaos site (ADR 0120): the dispatch RAN — donated
                     # member buffers are consumed — and then "fails".
-                    # The containment below sees exactly what a real
+                    # The containment sees exactly what a real
                     # post-donation XLA failure produces: consumed args,
                     # no adoptable results, note_state_lost + re-seed.
                     self._chaos.check("tick_dispatch")
             except Exception:
-                # The combiner contains plan/dispatch/unpack failures
-                # per member; anything escaping is a combiner bug — it
-                # must degrade this group to the separate path, never
-                # take the window down. States a partial dispatch
-                # already consumed are rebuilt with a visible warning.
-                logger.exception(
-                    "tick program failed (%d jobs); falling back to "
-                    "separate dispatches",
-                    len(members),
-                )
-                for rec, _strm, _value, _ingest, offer in members:
-                    if publish_args_consumed(offer.args):
-                        if offer.reset is not None:
-                            offer.reset()
-                        rec.job.note_state_lost()
-                        rec.warning = (
-                            "tick program failed after buffer donation; "
-                            "accumulation reset (see service log)"
-                        )
-                        self._after_state_loss(rec)
+                self._tick_group_failed(members)
                 continue
-            observer = self._link_observer
-            # Compile rounds are one-off XLA work, not round trips —
-            # feeding them would latch coalescing on every startup,
-            # layout swap or wire flip (the combiner-path rule, threaded
-            # through the tick path too). Slice-placed groups report
-            # under their slice label so the policy reacts to the WORST
-            # slice (ADR 0115).
-            if (
-                observer is not None
-                and not combiner.last_compiled
-                and any(res.error is None for res in results)
-            ):
-                self._observe_publish(
-                    observer, time.perf_counter() - t0, slice_key
-                )
-            for (rec, strm, _value, _ingest, offer), res in zip(
-                members, results, strict=True
-            ):
-                if res.error is not None:
-                    if res.state_lost:
-                        # Donation already invalidated the buffers: the
-                        # pre-tick accumulation is unrecoverable in
-                        # place. Rebuild a fresh state (the private
-                        # fallback re-adds THIS window's batch) and
-                        # surface the loss instead of stepping a
-                        # deleted array forever.
-                        if offer.reset is not None:
-                            offer.reset()
-                        rec.job.note_state_lost()
-                        rec.warning = (
-                            "tick program failed after buffer donation; "
-                            "accumulation reset (see service log)"
-                        )
-                        self._after_state_loss(rec)
-                    elif res.carry:
-                        # The step+fold already ran on device: adopt the
-                        # new state, mark the stream accumulated (a
-                        # private re-add would double-count), and let
-                        # finalize republish privately — this tick's
-                        # window summaries read zero; the cumulative is
-                        # intact.
-                        try:
-                            offer.consume(None, res.carry)
-                            streams_done.setdefault(
-                                rec.job.job_id, set()
-                            ).add(strm)
-                        except Exception:
-                            logger.exception(
-                                "tick carry adoption failed for %s",
-                                rec.job.job_id,
-                            )
-                    # Plan-time error (no carry): state untouched — the
-                    # member takes the full private accumulate + publish
-                    # path this window.
-                    continue
-                try:
-                    offer.consume(res.outputs, res.carry)
-                except Exception:
-                    logger.exception(
-                        "tick consume failed for %s", rec.job.job_id
-                    )
-                    continue
-                served.add(id(rec))
-                streams_done.setdefault(rec.job.job_id, set()).add(strm)
+            in_flight.append((members, combiner, pending, slice_key, took))
+            flying.update(id(rec) for rec, *_ in members)
+            if pending.compiled:
+                drain()
+        drain()
         return served, streams_done
+
+    def _tick_group_failed(self, members: list) -> None:
+        """The combiner contains plan/dispatch/unpack failures per
+        member; anything escaping is a combiner bug (or the chaos
+        site) — it must degrade this group to the separate path, never
+        take the window or another group down. States a partial
+        dispatch already consumed are rebuilt with a visible warning."""
+        from ..ops.publish import publish_args_consumed
+
+        logger.exception(
+            "tick program failed (%d jobs); falling back to "
+            "separate dispatches",
+            len(members),
+        )
+        for rec, _strm, _value, _ingest, offer in members:
+            if publish_args_consumed(offer.args):
+                if offer.reset is not None:
+                    offer.reset()
+                rec.job.note_state_lost()
+                rec.warning = (
+                    "tick program failed after buffer donation; "
+                    "accumulation reset (see service log)"
+                )
+                self._after_state_loss(rec)
+
+    def _collect_tick_group(
+        self,
+        members: list,
+        combiner,
+        pending,
+        slice_key,
+        dispatch_s: float,
+        served: set[int],
+        streams_done: dict[JobId, set[str]],
+    ) -> None:
+        """The second half of one tick group: wait for its program,
+        then the per-member bookkeeping into ``served`` and
+        ``streams_done`` (``_run_tick_programs``)."""
+        t0 = time.perf_counter()
+        try:
+            results = combiner.collect(pending)
+        except Exception:
+            self._tick_group_failed(members)
+            return
+        observer = self._link_observer
+        # Compile rounds are one-off XLA work, not round trips —
+        # feeding them would latch coalescing on every startup,
+        # layout swap or wire flip (the combiner-path rule, threaded
+        # through the tick path too). Slice-placed groups report
+        # under their slice label so the policy reacts to the WORST
+        # slice (ADR 0115).
+        if (
+            observer is not None
+            and not pending.compiled
+            and any(res.error is None for res in results)
+        ):
+            self._observe_publish(
+                observer,
+                dispatch_s + time.perf_counter() - t0,
+                slice_key,
+            )
+        for (rec, strm, _value, _ingest, offer), res in zip(
+            members, results, strict=True
+        ):
+            if res.error is not None:
+                if res.state_lost:
+                    # Donation already invalidated the buffers: the
+                    # pre-tick accumulation is unrecoverable in
+                    # place. Rebuild a fresh state (the private
+                    # fallback re-adds THIS window's batch) and
+                    # surface the loss instead of stepping a
+                    # deleted array forever.
+                    if offer.reset is not None:
+                        offer.reset()
+                    rec.job.note_state_lost()
+                    rec.warning = (
+                        "tick program failed after buffer donation; "
+                        "accumulation reset (see service log)"
+                    )
+                    self._after_state_loss(rec)
+                elif res.carry:
+                    # The step+fold already ran on device: adopt the
+                    # new state, mark the stream accumulated (a
+                    # private re-add would double-count), and let
+                    # finalize republish privately — this tick's
+                    # window summaries read zero; the cumulative is
+                    # intact.
+                    try:
+                        offer.consume(None, res.carry)
+                        streams_done.setdefault(
+                            rec.job.job_id, set()
+                        ).add(strm)
+                    except Exception:
+                        logger.exception(
+                            "tick carry adoption failed for %s",
+                            rec.job.job_id,
+                        )
+                # Plan-time error (no carry): state untouched — the
+                # member takes the full private accumulate + publish
+                # path this window.
+                continue
+            try:
+                offer.consume(res.outputs, res.carry)
+            except Exception:
+                logger.exception(
+                    "tick consume failed for %s", rec.job.job_id
+                )
+                continue
+            served.add(id(rec))
+            streams_done.setdefault(rec.job.job_id, set()).add(strm)
 
     @staticmethod
     def _observe_publish(observer, seconds: float, slice_key) -> None:

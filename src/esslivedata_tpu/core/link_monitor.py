@@ -212,13 +212,19 @@ class LinkMonitor:
         — a combined publish (ADR 0113) or a whole tick program
         (ops/tick.py, ADR 0114: step AND publish in the one dispatch, so
         the sample is the full device round trip a steady-state tick
-        pays). Compile rounds (``PublishCombiner.last_compiled`` /
-        ``TickCombiner.last_compiled``) are one-off XLA work worth
+        pays). The groups of a tick are dispatched before the first is
+        collected, and a group's sample is the host's time in its two
+        halves, the dispatch plus the wait at the collect: the round
+        trip as before for a group dispatched alone, and for one
+        dispatched ahead what of it the tick still waited for, the
+        programs queued before it included
+        (``JobManager._run_tick_programs``). Compile rounds (``PublishCombiner.last_compiled`` / the tick
+        handle's ``compiled``) are one-off XLA work worth
         hundreds of ms and must never reach the EWMA — a first-tick
         compile or a layout-swap/wire-flip recompile would otherwise
         latch the publish-coalescing policy on a healthy link. Two ways
         to exclude them, by caller kind: the JobManager SKIPS the call
-        when ``last_compiled`` is set (the observer slot is duck-typed —
+        when the round compiled (the observer slot is duck-typed —
         a stub observer need not accept this kwarg), while direct
         LinkMonitor users pass ``compiled=True`` and this method drops
         the sample. Both are load-bearing; a timing that might include

@@ -105,7 +105,15 @@ def fetch_outputs(outputs):
     Enqueued only after the wait, each costs a host wake-up and a
     submit with the chip idle: 20 ms of every picture's age at seven
     fetches a tick (PERF.md section 6). ``d2h`` is therefore what of the
-    copy is left to wait for once the program has run."""
+    copy is left to wait for once the program has run.
+
+    The tick path enqueues them earlier still, at the dispatch
+    (``ops/tick.TickCombiner.dispatch``; asking again here costs
+    nothing), and dispatches every group of a tick before the first
+    fetch. A group's program and copy have then mostly run under the
+    staging of the groups after it: the ``fetch`` spans of a tick add
+    up to what of the chip's work is left to wait for after the last
+    dispatch, and ``d2h`` to little more than the last group's copy."""
     with TRACER.span("fetch"):
         for leaf in jax.tree_util.tree_leaves(outputs):
             leaf.copy_to_host_async()

@@ -23,7 +23,12 @@ jitted **tick program** that
   packed vectors into one fetch with the ADR 0113 static/dynamic output
   split carried through verbatim.
 
-A steady-state tick is then ONE execute + ONE ``device_get``. Donation
+A steady-state tick is then ONE execute + ONE ``device_get`` per group.
+The two are separate calls, :meth:`TickCombiner.dispatch` (asynchronous)
+and :meth:`TickCombiner.collect`, so that the JobManager can dispatch
+every group of a tick before it collects the first: the host stages
+group i+1 while the chip runs group i. Nothing in the contract of one
+group changes by that. Donation
 is shifted like the combiner's: each member's pre-step state enters at
 its flat position and is donated there (the step consumes it; the
 publish fold reuses the buffers), plus any further donated argnums the
@@ -58,6 +63,7 @@ import threading
 import time
 from collections import OrderedDict
 from collections.abc import Callable, Sequence
+from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
@@ -77,9 +83,33 @@ from .publish import (
     unpack_members,
 )
 
-__all__ = ["TickCombiner"]
+__all__ = ["PendingTick", "TickCombiner"]
 
 logger = logging.getLogger(__name__)
+
+
+@dataclass
+class PendingTick:
+    """One group's tick program between :meth:`TickCombiner.dispatch`
+    and :meth:`TickCombiner.collect`: the plan, the program's LRU key
+    and what it returned, not yet fetched. Opaque to the caller but for
+    ``compiled``."""
+
+    n_requests: int
+    plan: list[tuple]
+    #: Results so far: plan-time errors, then whatever ``collect``
+    #: unpacks or a failure fills in.
+    by_index: dict[int, CombinedPublish]
+    slice_key: str | None = None
+    key: tuple | None = None
+    #: The dispatch missed the program LRU and ran the compile round
+    #: to its end (``TickCombiner.last_compiled`` of this group).
+    compiled: bool = False
+    #: ``(packed, statics)`` on the device, their copies enqueued.
+    outputs: tuple | None = None
+    #: The same on the host: a compile round's, fetched at dispatch.
+    fetched: tuple | None = None
+    carries: tuple = ()
 
 
 class TickCombiner:
@@ -99,14 +129,16 @@ class TickCombiner:
         self._programs: OrderedDict[tuple, Callable] = OrderedDict()
         self._max_programs = int(max_programs)
         # The LRU is touched from TWO threads since ADR 0118: the step
-        # worker's publish() and the warm-up thread's warm() (insert +
+        # worker's dispatch() and the warm-up thread's warm() (insert +
         # eviction). An unlocked move_to_end racing a concurrent
         # eviction is a KeyError in the middle of a live tick; the
         # lock covers only dict operations (never a build/compile), so
         # it costs nanoseconds against a millisecond tick.
         self._programs_lock = threading.Lock()
-        #: True when the last ``publish`` compiled its program (cache
-        #: miss). RTT observers must skip those rounds — same contract
+        #: True when the last ``dispatch`` compiled its program (cache
+        #: miss; the handle's ``compiled`` says it per group, which is
+        #: what a caller with several groups in flight reads). RTT
+        #: observers must skip those rounds — same contract
         #: as ``PublishCombiner.last_compiled`` (ADR 0113): a tick
         #: compile is one-off XLA work, and folding it into the EWMA
         #: publish RTT would latch the coalescing policy on every
@@ -122,10 +154,32 @@ class TickCombiner:
         *,
         slice_key: str | None = None,
     ) -> list[CombinedPublish]:
-        """Run one tick program: step every member's state (``args[0]``
-        of its request, the ``make_publish_offer`` contract) from the
-        shared ``staged`` arrays, then serve every member's publish from
-        the one packed fetch.
+        """Run one tick program to its end: ``collect(dispatch(...))``,
+        for the callers that have one group (tests, parity paths). The
+        JobManager calls the two halves itself, every group's dispatch
+        before the first collect."""
+        return self.collect(
+            self.dispatch(
+                hist, group_key, staged, requests, slice_key=slice_key
+            )
+        )
+
+    def dispatch(
+        self,
+        hist,
+        group_key,
+        staged: tuple,
+        requests: Sequence[PublishRequest],
+        *,
+        slice_key: str | None = None,
+    ) -> PendingTick:
+        """Submit one tick program and return without waiting for it:
+        step every member's state (``args[0]`` of its request, the
+        ``make_publish_offer`` contract) from the shared ``staged``
+        arrays, then pack every member's publish into one vector whose
+        copy to the host is enqueued here, so that it starts the moment
+        the program ends. :meth:`collect` waits for it and serves the
+        members.
 
         ``hist`` is the group's (shared-configuration) histogrammer —
         its ``tick_step`` is the traceable fused step; ``group_key`` is
@@ -133,17 +187,29 @@ class TickCombiner:
         ``staged`` is ``tick_staging``'s flat tuple of device arrays.
         ``slice_key`` (mesh serving, ADR 0115) labels the mesh slice
         this group executes on for the per-slice METRICS breakdown.
+
+        A group whose program misses the LRU runs to its end in here
+        (``compiled`` on the handle): the compile instrument times a
+        synchronous first call. So does a group with no plan or whose
+        dispatch raised: :meth:`collect` then only hands the results
+        over.
         """
         plan, planned_errors = plan_members(requests)
+        pending = PendingTick(
+            n_requests=len(requests),
+            plan=plan,
+            by_index={
+                i: CombinedPublish(None, (), error=err)
+                for i, err in planned_errors.items()
+            },
+            slice_key=slice_key,
+        )
         if not plan:
-            return [
-                CombinedPublish(None, (), error=planned_errors.get(i))
-                for i in range(len(requests))
-            ]
-        key = self._program_key(hist, group_key, staged, plan)
+            return pending
+        key = pending.key = self._program_key(hist, group_key, staged, plan)
         with self._programs_lock:
             fn = self._programs.get(key)
-            self.last_compiled = fn is None
+            self.last_compiled = pending.compiled = fn is None
             if fn is not None:
                 # LRU touch: the steady-state program runs every tick
                 # and must never be the eviction victim of key churn
@@ -168,12 +234,8 @@ class TickCombiner:
         flat_args = tuple(staged) + tuple(
             a for _i, req, *_ in plan for a in req.args
         )
-        by_index: dict[int, CombinedPublish] = {
-            i: CombinedPublish(None, (), error=err)
-            for i, err in planned_errors.items()
-        }
         try:
-            if self.last_compiled:
+            if pending.compiled:
                 # Compile-event instrument (ADR 0116): the first call of
                 # a fresh program pays trace + XLA compile + execute —
                 # the stall PERF round 7 could only EXCLUDE from RTT
@@ -187,58 +249,79 @@ class TickCombiner:
                 # the exact confusion the compile instrument exists to
                 # prevent.
                 t0 = time.perf_counter()
-                packed, statics, carries = fn(*flat_args)
-                flat, static_fetched = jax.device_get((packed, statics))
+                packed, statics, pending.carries = fn(*flat_args)
+                pending.fetched = jax.device_get((packed, statics))
                 self._record_compile(
                     hist, group_key, key, plan, time.perf_counter() - t0
                 )
             else:
-                # Per-tick tracer spans (ADR 0116), against the step
+                # Per-tick tracer span (ADR 0116), against the step
                 # worker's thread-bound trace id: the dispatch (host
                 # Python + ASYNC submit: it returns before the program
-                # has run) and the fetch (the wait for the chip, then
-                # the copy back: what a steady-state tick actually
-                # waits on) decompose separately in the slow-tick
-                # breakdown.
+                # has run). The wait for the chip is ``collect``'s
+                # ``fetch`` span, so the two decompose separately in
+                # the slow-tick breakdown.
                 with TRACER.span("tick_execute"):
-                    packed, statics, carries = fn(*flat_args)
-                flat, static_fetched = fetch_outputs((packed, statics))
+                    packed, statics, pending.carries = fn(*flat_args)
+                    for leaf in jax.tree_util.tree_leaves((packed, statics)):
+                        leaf.copy_to_host_async()
+                pending.outputs = (packed, statics)
         except Exception as err:
-            # Dispatch-level failure: per-member containment happens at
-            # the caller, which needs to know whose donated state the
-            # failed dispatch already consumed (state_lost — the step
-            # donates every member state, so a runtime failure may have
-            # invalidated all of them). The cached program is evicted:
-            # a poisoned entry (an AOT-warmed executable whose input
-            # placement drifted, a backend error pinned to this
-            # compilation) must not fail every later tick — the next
-            # tick recompiles fresh instead.
-            with self._programs_lock:
-                self._programs.pop(key, None)
-            logger.exception(
-                "tick program dispatch failed (%d jobs)", len(plan)
+            self._dispatch_failed(pending, err)
+        return pending
+
+    def collect(self, pending: PendingTick) -> list[CombinedPublish]:
+        """Wait for a dispatched tick program (the ``fetch`` span) and
+        serve every member's publish from the one packed fetch.
+
+        A failure of the program itself is asynchronous and surfaces
+        here: it is contained as a dispatch failure is, ``state_lost``
+        for exactly the members whose arguments the program consumed,
+        and no other pending handle is touched."""
+        plan = pending.plan
+        if pending.outputs is not None:
+            try:
+                pending.fetched = fetch_outputs(pending.outputs)
+            except Exception as err:
+                self._dispatch_failed(pending, err)
+        if pending.fetched is not None:
+            flat, static_fetched = pending.fetched
+            static_total = unpack_members(
+                plan, flat, static_fetched, pending.carries, pending.by_index
             )
-            for _i, req, *_ in plan:
-                by_index[_i] = CombinedPublish(
-                    None,
-                    (),
-                    error=err,
-                    state_lost=publish_args_consumed(req.args),
-                )
-            return [by_index[i] for i in range(len(requests))]
-        static_total = unpack_members(
-            plan, flat, static_fetched, carries, by_index
+            METRICS.record(
+                executes=1,
+                fetches=1,
+                dynamic_bytes=int(flat.nbytes),
+                static_bytes=static_total,
+                combined_jobs=len(plan),
+                tick=True,
+                slice_key=pending.slice_key,
+            )
+        return [pending.by_index[i] for i in range(pending.n_requests)]
+
+    def _dispatch_failed(self, pending: PendingTick, err: Exception) -> None:
+        """Dispatch-level failure, synchronous or surfacing at the
+        fetch: per-member containment happens at the caller, which
+        needs to know whose donated state the failed dispatch already
+        consumed (state_lost — the step donates every member state, so
+        a runtime failure may have invalidated all of them). The cached
+        program is evicted: a poisoned entry (an AOT-warmed executable
+        whose input placement drifted, a backend error pinned to this
+        compilation) must not fail every later tick — the next tick
+        recompiles fresh instead."""
+        with self._programs_lock:
+            self._programs.pop(pending.key, None)
+        logger.exception(
+            "tick program dispatch failed (%d jobs)", len(pending.plan)
         )
-        METRICS.record(
-            executes=1,
-            fetches=1,
-            dynamic_bytes=int(flat.nbytes),
-            static_bytes=static_total,
-            combined_jobs=len(plan),
-            tick=True,
-            slice_key=slice_key,
-        )
-        return [by_index[i] for i in range(len(requests))]
+        for _i, req, *_ in pending.plan:
+            pending.by_index[_i] = CombinedPublish(
+                None,
+                (),
+                error=err,
+                state_lost=publish_args_consumed(req.args),
+            )
 
     @staticmethod
     def _program_key(hist, group_key, staged: tuple, plan: list) -> tuple:

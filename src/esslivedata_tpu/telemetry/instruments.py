@@ -27,6 +27,7 @@ __all__ = [
     "SINK_BYTES",
     "SINK_SECONDS",
     "STAGED_EVENTS",
+    "TICK_GROUPS",
 ]
 
 #: Publish/tick device round-trip wall times as a labeled histogram
@@ -140,4 +141,19 @@ SINK_SECONDS = REGISTRY.counter(
 SINK_BYTES = REGISTRY.counter(
     "livedata_sink_bytes_total",
     "Serialized payload bytes handed to the producer",
+)
+
+#: How a tick's (stream, fuse-key) groups met the chip
+#: (``JobManager._run_tick_programs``), one count per group at its
+#: dispatch: ``ahead`` = an earlier group of the same tick was still
+#: uncollected, so this group's staging ran beside that one's program;
+#: ``alone`` = the first group of a tick, a one-group tick, a compile
+#: round. ahead / (ahead + alone) is the benchmark's
+#: ``groups_ahead_share``: (n - 1) / n of a warm n-group service, 0 of
+#: a one-group one.
+TICK_GROUPS = REGISTRY.counter(
+    "livedata_tick_groups_total",
+    "Tick-program groups dispatched, by whether an earlier group of "
+    "the same tick was still in flight (ahead) or not (alone)",
+    labelnames=("dispatched",),
 )

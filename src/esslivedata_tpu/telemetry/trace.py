@@ -16,8 +16,8 @@ The spans of the serial loop, in the order a tick runs them:
 - ``flatten``: host flatten / partition of one stream's events, once
   per stage-cache miss (``events``, ``padded``);
 - ``h2d``: the host copy and the ENQUEUE of the asynchronous
-  ``device_put`` (``bytes``); the transfer itself completes under the
-  next ``fetch``;
+  ``device_put`` (``bytes``); the transfer itself completes on the
+  device's queue, behind the program of the group before;
 - ``tick_execute`` (``publish_execute`` on the combined-publish path):
   the dispatch. It is asynchronous: host Python plus the submit, not
   the program's run time;
@@ -26,6 +26,12 @@ The spans of the serial loop, in the order a tick runs them:
   enqueued before the wait; what is left of the copy after the wait
   is the aggregate ``d2h``;
 - ``finalize``, ``sink``.
+
+A tick with several (stream, fuse-key) groups runs ``flatten``,
+``h2d``, ``tick_execute`` once per group and only then the groups'
+``fetch`` spans (``JobManager._run_tick_programs``): the host stages
+group i+1 while the chip runs group i, so the ``fetch`` spans add up to
+what of the chip's work is left to wait for after the last dispatch.
 
 The pipelined path adds ``prestage`` (its stage worker's flatten +
 H2D as one span). **The ring stays flat**: the spans one thread

@@ -255,6 +255,25 @@ class TestSerialTickIsTiledBySpans:
         assert deltas["d2h"][1] == deltas["fetch"][1] > 0
         assert 0.0 < deltas["d2h"][0] <= deltas["fetch"][0]
 
+    def test_a_one_group_service_dispatches_every_group_alone(self):
+        """``livedata_tick_groups_total``: one count per tick group at
+        its dispatch. This service has one group a tick, so nothing is
+        ever dispatched ahead (the benchmark's ``groups_ahead_share``
+        then reads 0, not nothing)."""
+        from esslivedata_tpu.ops.publish import METRICS
+
+        family = REGISTRY.get("livedata_tick_groups_total")
+        before = {
+            how: family.value(dispatched=how) for how in ("ahead", "alone")
+        }
+        ticks_before = METRICS.snapshot()["tick_publishes"]
+        _, deltas = serial_run_deltas()
+        ticks = METRICS.snapshot()["tick_publishes"] - ticks_before
+        assert family.value(dispatched="ahead") == before["ahead"]
+        assert family.value(dispatched="alone") - before["alone"] == ticks
+        # Compile rounds record no spans; every other group has its pair.
+        assert 0 < deltas["tick_execute"][1] == deltas["fetch"][1] <= ticks
+
     def test_fetch_enqueues_the_copies_before_it_waits(self):
         """``device_get`` alone enqueues every copy and then waits; the
         split into wait and copy must not lose that, or each fetch pays

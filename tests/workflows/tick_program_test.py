@@ -11,6 +11,9 @@ publish_combine_test pattern).
 
 from __future__ import annotations
 
+import time
+
+import jax
 import numpy as np
 import pytest
 
@@ -57,18 +60,22 @@ def _make_manager(
     make_workflows,
     stream="det0",
     *,
+    streams=None,
     combine_publish=True,
     tick_program=True,
     job_threads=2,
 ):
+    """One job per workflow, all on ``stream`` or each on its own of
+    ``streams``."""
     from esslivedata_tpu.workflows import WorkflowFactory
 
     created = []
     reg = WorkflowFactory()
-    identifiers = []
+    jobs = []
     for i, make in enumerate(make_workflows):
+        job_stream = stream if streams is None else streams[i]
         spec = WorkflowSpec(
-            instrument="test", name=f"tick{i}", source_names=[stream]
+            instrument="test", name=f"tick{i}", source_names=[job_stream]
         )
 
         def factory(*, source_name, params, _make=make):
@@ -77,17 +84,18 @@ def _make_manager(
             return wf
 
         reg.register_spec(spec).attach_factory(factory)
-        identifiers.append(spec.identifier)
+        jobs.append((spec.identifier, job_stream))
     mgr = JobManager(
         job_factory=JobFactory(reg),
         job_threads=job_threads,
         combine_publish=combine_publish,
         tick_program=tick_program,
     )
-    for identifier in identifiers:
+    for identifier, job_stream in jobs:
         mgr.schedule_job(
             WorkflowConfig(
-                identifier=identifier, job_id=JobId(source_name=stream)
+                identifier=identifier,
+                job_id=JobId(source_name=job_stream),
             )
         )
     return mgr, created
@@ -648,3 +656,354 @@ class TestProgramNamesAndScopes:
             program_name("tick", pubs) == "tick_detector_view_monitor_publish"
         )
         assert program_name("publish", pubs[:1]) == "publish_monitor"
+
+
+def _make_group_manager(n_groups):
+    """``n_groups`` detector views, each on a stream of its own: one
+    tick group each (the shape of NMX's three panels and DREAM's
+    seven views)."""
+    det = _det()
+    return _make_manager(
+        [lambda: DetectorViewWorkflow(projection=project_logical(det))]
+        * n_groups,
+        streams=[f"det{g}" for g in range(n_groups)],
+    )
+
+
+def _group_windows(seed, n_windows, n_groups):
+    rng = np.random.default_rng(seed)
+    return [
+        {
+            f"det{g}": _windows(rng, 1, 800, -5, 150)[0]
+            for g in range(n_groups)
+        }
+        for _ in range(n_windows)
+    ]
+
+
+def _process(mgr, window, w):
+    return mgr.process_jobs(
+        {stream: _staged(pid, toa) for stream, (pid, toa) in window.items()},
+        start=T(0),
+        end=T(w + 1),
+    )
+
+
+def _group_counts() -> dict[str, float]:
+    from esslivedata_tpu.telemetry.registry import REGISTRY
+
+    family = REGISTRY.get("livedata_tick_groups_total")
+    return {
+        how: family.value(dispatched=how) for how in ("ahead", "alone")
+    }
+
+
+def _counts_added(before) -> dict[str, float]:
+    after = _group_counts()
+    return {how: after[how] - before[how] for how in after}
+
+
+def _record_calls(combiner, calls):
+    """Wrap the combiner's two halves so that ``calls`` reads the order
+    the manager made them in: ("dispatch" | "collect", group number in
+    dispatch order)."""
+    dispatch, collect = combiner.dispatch, combiner.collect
+    numbers: dict[int, int] = {}
+
+    def recording_dispatch(*args, **kwargs):
+        pending = dispatch(*args, **kwargs)
+        numbers[id(pending)] = sum(c[0] == "dispatch" for c in calls)
+        calls.append(("dispatch", numbers[id(pending)]))
+        return pending
+
+    def recording_collect(pending):
+        calls.append(("collect", numbers[id(pending)]))
+        return collect(pending)
+
+    combiner.dispatch = recording_dispatch
+    combiner.collect = recording_collect
+
+
+def _collect_at_dispatch(combiner):
+    """The order of before: each group's program is waited for and
+    fetched before the next group is staged."""
+    dispatch, collect = combiner.dispatch, combiner.collect
+    done: dict[int, list] = {}
+
+    def dispatch_and_wait(*args, **kwargs):
+        pending = dispatch(*args, **kwargs)
+        done[id(pending)] = collect(pending)
+        return pending
+
+    combiner.dispatch = dispatch_and_wait
+    combiner.collect = lambda pending: done.pop(id(pending))
+
+
+class TestGroupsArePipelined:
+    """Every group of a tick is dispatched before the first is
+    collected (``JobManager._run_tick_programs``): the order on the
+    host moves, no result does."""
+
+    @pytest.mark.parametrize("n_groups", [1, 3, 7])
+    def test_every_dispatch_precedes_the_first_fetch(self, n_groups):
+        from esslivedata_tpu.telemetry.registry import REGISTRY
+        from esslivedata_tpu.telemetry.trace import TRACER
+
+        mgr, _ = _make_group_manager(n_groups)
+        windows = _group_windows(70, 3, n_groups)
+        for w in range(2):  # both program variants compile
+            assert len(_process(mgr, windows[w], w)) == n_groups
+        spans_family = REGISTRY.get("livedata_tick_span_seconds")
+        unspanned_before = spans_family.sum(span="unspanned")
+        counts_before = _group_counts()
+        was_enabled = TRACER.enabled
+        TRACER.enabled = True
+        trace_id = TRACER.new_trace()
+        try:
+            with TRACER.bind(trace_id):
+                t0 = time.perf_counter()
+                assert len(_process(mgr, windows[2], 2)) == n_groups
+                TRACER.finish_tick(
+                    trace_id, time.perf_counter() - t0, tiled=True
+                )
+            spans = [s for s in TRACER.spans() if s.trace_id == trace_id]
+        finally:
+            TRACER.enabled = was_enabled
+            mgr.shutdown()
+        executes = [s for s in spans if s.name == "tick_execute"]
+        fetches = [s for s in spans if s.name == "fetch"]
+        assert len(executes) == len(fetches) == n_groups
+        first_fetch = min(s.start_s for s in fetches)
+        assert all(
+            s.start_s + s.duration_s <= first_fetch for s in executes
+        )
+        # Stage i+1 lies between dispatch i and dispatch i+1, not
+        # behind fetch i: every flatten starts before the first fetch.
+        flattens = [s for s in spans if s.name == "flatten"]
+        assert len(flattens) == n_groups
+        assert all(s.start_s < first_fetch for s in flattens)
+        # The loop thread's spans still tile the tick: none overlaps
+        # another, and what they leave uncovered is positive.
+        loop = sorted(
+            (s for s in spans if s.thread == executes[0].thread),
+            key=lambda s: s.start_s,
+        )
+        for earlier, later in zip(loop, loop[1:]):
+            assert (
+                earlier.start_s + earlier.duration_s <= later.start_s
+            ), f"{earlier.name} overlaps {later.name}"
+        assert spans_family.sum(span="unspanned") > unspanned_before
+        assert _counts_added(counts_before) == {
+            "ahead": n_groups - 1, "alone": 1
+        }
+
+    @pytest.mark.parametrize("n_groups", [1, 3, 7])
+    def test_bit_identical_to_group_by_group(self, n_groups):
+        """Every output and every carried state equals what
+        ``collect(dispatch(...))`` gives group by group."""
+        piped, piped_wfs = _make_group_manager(n_groups)
+        serial, serial_wfs = _make_group_manager(n_groups)
+        _collect_at_dispatch(serial._tick_combiner)
+        for w, window in enumerate(_group_windows(71, 4, n_groups)):
+            res_p = _process(piped, window, w)
+            res_s = _process(serial, window, w)
+            assert len(res_p) == len(res_s) == n_groups
+            for rp, rs in zip(res_p, res_s):
+                assert list(rp.outputs) == list(rs.outputs)
+                assert _wire_bytes(rp) == _wire_bytes(rs), f"window {w}"
+            for wp, ws in zip(piped_wfs, serial_wfs, strict=True):
+                for lp, ls in zip(
+                    jax.tree_util.tree_leaves(wp._state),
+                    jax.tree_util.tree_leaves(ws._state),
+                    strict=True,
+                ):
+                    assert np.array_equal(
+                        np.asarray(lp), np.asarray(ls)
+                    ), f"window {w}: a carried state differs"
+        assert METRICS.drain()["tick_publishes"] >= 4 * n_groups
+        piped.shutdown()
+        serial.shutdown()
+
+    def test_a_compile_round_is_serial_and_counted_alone(self):
+        mgr, _ = _make_group_manager(3)
+        calls: list = []
+        _record_calls(mgr._tick_combiner, calls)
+        windows = _group_windows(72, 3, 3)
+        one_by_one = [
+            (half, g) for g in range(3) for half in ("dispatch", "collect")
+        ]
+        for w in range(2):  # static-inclusive, then dynamic-only
+            before = _group_counts()
+            calls.clear()
+            _process(mgr, windows[w], w)
+            assert calls == one_by_one, f"window {w}"
+            assert _counts_added(before) == {"ahead": 0, "alone": 3}
+        before = _group_counts()
+        calls.clear()
+        _process(mgr, windows[2], 2)
+        assert calls == [
+            *(("dispatch", g) for g in range(3)),
+            *(("collect", g) for g in range(3)),
+        ]
+        assert _counts_added(before) == {"ahead": 2, "alone": 1}
+        mgr.shutdown()
+
+    def test_a_record_in_two_groups_waits_for_its_first_collect(self):
+        """None today (``_split_tick_groups`` admits only single-stream
+        members), so the manager is handed such a tick directly: the
+        first group's collect adopts the state that the second's
+        dispatch would donate."""
+        from esslivedata_tpu.ops.publish import CombinedPublish
+
+        mgr, _ = _make_group_manager(2)
+        windows = _group_windows(73, 1, 2)
+        groups: list = []
+        run = mgr._run_tick_programs
+        mgr._run_tick_programs = lambda tick_groups: (
+            groups.extend(tick_groups) or run(tick_groups)
+        )
+        _process(mgr, windows[0], 0)
+        assert len(groups) == 2
+
+        calls: list = []
+
+        class Pending:
+            compiled = False
+
+        class Stub:
+            def dispatch(self, hist, key, staged, requests, **_kwargs):
+                calls.append("dispatch")
+                return Pending()
+
+            def collect(self, pending):
+                calls.append("collect")
+                # A plan-time error: the manager books nothing.
+                return [CombinedPublish(None, (), error=RuntimeError("x"))]
+
+        mgr._tick_combiner = Stub()
+        before = _group_counts()
+        run([groups[0], groups[1], groups[0]])
+        assert calls == [
+            "dispatch", "dispatch", "collect", "collect",
+            "dispatch", "collect",
+        ]
+        assert _counts_added(before) == {"ahead": 1, "alone": 2}
+        mgr.shutdown()
+
+
+class TestPipelinedContainment:
+    """A failure in group 2 of 3 leaves groups 1 and 3 served and
+    resets only the members whose buffers were consumed."""
+
+    def warm(self, seed):
+        mgr, _ = _make_group_manager(3)
+        windows = _group_windows(seed, 4, 3)
+        for w in range(2):
+            res = _process(mgr, windows[w], w)
+        return mgr, windows, [
+            float(r.outputs["counts_cumulative"].values) for r in res
+        ]
+
+    def assert_only_group_two_was_reset(self, mgr, windows, cum_before):
+        METRICS.drain()
+        res = _process(mgr, windows[2], 2)
+        assert len(res) == 3  # every job still publishes
+        assert METRICS.drain()["tick_publishes"] == 2  # groups 1 and 3
+        cur = [float(r.outputs["counts_current"].values) for r in res]
+        cum = [float(r.outputs["counts_cumulative"].values) for r in res]
+        assert cum[1] == cur[1]  # reset: windows 0-1 are gone
+        for g in (0, 2):
+            assert cum[g] == cum_before[g] + cur[g]  # untouched
+        assert "error" not in {str(s.state) for s in mgr.job_statuses()}
+        return cum
+
+    def test_chaos_after_the_second_dispatch(self):
+        from esslivedata_tpu.harness.chaos import ChaosSchedule, ChaosSpec
+
+        mgr, windows, cum_before = self.warm(74)
+        calls: list = []
+        _record_calls(mgr._tick_combiner, calls)
+        mgr.set_chaos(
+            ChaosSchedule(ChaosSpec(at={"tick_dispatch": frozenset({1})}))
+        )
+        cum = self.assert_only_group_two_was_reset(mgr, windows, cum_before)
+        # Group 2's handle is dropped; 1 and 3 were in flight together.
+        assert calls == [
+            ("dispatch", 0), ("dispatch", 1), ("dispatch", 2),
+            ("collect", 0), ("collect", 2),
+        ]
+        # Recovery: every group ticks again on its cached program.
+        res = _process(mgr, windows[3], 3)
+        assert METRICS.drain()["tick_publishes"] == 3
+        assert float(res[1].outputs["counts_cumulative"].values) > cum[1]
+        mgr.shutdown()
+
+    def test_a_program_that_fails_at_the_collect(self):
+        """What an asynchronous failure of the program looks like: the
+        dispatch returns, the wait raises."""
+        mgr, windows, cum_before = self.warm(75)
+        combiner = mgr._tick_combiner
+
+        class Poisoned:
+            def copy_to_host_async(self):
+                pass
+
+            def block_until_ready(self):
+                raise RuntimeError("asynchronous boom")
+
+        steady = [k for k in combiner._programs if not k[3][0][3]]
+        assert len(steady) == 3  # the dynamic-only variant of each group
+        victim = steady[1]
+        fn = combiner._programs[victim]
+
+        def poisoned(*args):
+            _packed, statics, carries = fn(*args)  # donates the states
+            return Poisoned(), statics, carries
+
+        combiner._programs[victim] = poisoned
+        cum = self.assert_only_group_two_was_reset(mgr, windows, cum_before)
+        assert victim not in combiner._programs  # evicted
+        res = _process(mgr, windows[3], 3)  # group 2 compiles afresh
+        assert METRICS.drain()["tick_publishes"] == 3
+        assert float(res[1].outputs["counts_cumulative"].values) > cum[1]
+        mgr.shutdown()
+
+
+class TestLinkObserverPerGroup:
+    def test_each_group_reports_its_dispatch_and_its_wait(self):
+        mgr, _ = _make_group_manager(3)
+        observer = TestLinkObserver._Observer()
+        mgr.set_link_observer(observer)
+        for w, window in enumerate(_group_windows(76, 4, 3)):
+            _process(mgr, window, w)
+        # Two compile ticks skipped, then three groups a tick.
+        assert len(observer.publishes) == 6
+        assert all(s > 0 for s in observer.publishes)
+        mgr.shutdown()
+
+    def test_a_sample_leaves_out_what_ran_between_the_two_halves(self):
+        """The first group's sample must not hold the host's work on
+        the second (here: a slow second dispatch), or every group reads
+        the whole tick and the publish tick widens for nothing."""
+        mgr, _ = _make_group_manager(2)
+        observer = TestLinkObserver._Observer()
+        mgr.set_link_observer(observer)
+        windows = _group_windows(77, 3, 2)
+        for w in range(2):
+            _process(mgr, windows[w], w)
+        combiner = mgr._tick_combiner
+        dispatch = combiner.dispatch
+        dispatched = []
+
+        def slow_second_dispatch(*args, **kwargs):
+            dispatched.append(None)
+            if len(dispatched) == 2:
+                time.sleep(0.3)
+            return dispatch(*args, **kwargs)
+
+        combiner.dispatch = slow_second_dispatch
+        observer.publishes.clear()
+        _process(mgr, windows[2], 2)
+        first, second = observer.publishes
+        assert 0 < first < 0.3 <= second
+        mgr.shutdown()
