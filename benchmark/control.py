@@ -5,7 +5,9 @@
 
 One sound run of the cell, and then the reference with one guarantee
 broken put in the program's place, once for each fault of
-``reference.break_guarantee``: the same publishes, judged against what
+``reference.break_guarantee`` and then, for the jobs of a view kind
+that ``references/<kind>.py`` answers, once for each of that kind's own
+``faults()`` (``<kind>.<name>``): the same publishes, judged against what
 the broken reference says. Prints the sound run's numbers and each
 control's, and exits 0 only where the sound run is correct and every
 control is not. The benchmark's own runs do not run this; PERF.md's
@@ -31,6 +33,12 @@ from harness import bench, manifest, reference  # noqa: E402
 from harness.service import BenchFailure  # noqa: E402
 
 
+def controls_of(cell: manifest.Cell) -> tuple[str, ...]:
+    """Every fault a cell's comparison has to catch."""
+    return (*reference.FAULTS,
+            *(f"{kind}.{name}" for kind, module in cell.kinds.items() for name in module.faults()))
+
+
 def main(argv=None) -> int:
     parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     parser.add_argument("--workload", required=True)
@@ -40,8 +48,7 @@ def main(argv=None) -> int:
     try:
         cell = manifest.load_cell(ROOT, args.workload)
         line, _report = bench.run_cell(
-            cell, args.seed, args.seconds, False, ROOT, STARTED,
-            controls=reference.FAULTS,
+            cell, args.seed, args.seconds, False, ROOT, STARTED, controls=controls_of(cell),
         )
     except (BenchFailure, manifest.ManifestError) as err:
         print(f"control: no result: {err}", file=sys.stderr)

@@ -18,6 +18,7 @@ from . import metrics as layer_metrics
 from . import prom, reference, results
 from .manifest import Cell
 from .service import BenchFailure, GeneratorChild, ServiceChild
+from .traffic import stream_events
 
 BASE_WINDOW = 14  # pulses: the batchers' 1 s base window on the 14 Hz grid
 #: The adaptive batcher relaxes one step after this long without data.
@@ -31,6 +32,18 @@ WARM_UP_ROUNDS = 24
 
 def log(message: str) -> None:
     print(f"bench: {message}", file=sys.stderr, flush=True)
+
+
+def streams_with_topics(config: dict) -> list[dict]:
+    """The configuration's streams, each with the topic it is sent on:
+    its own, or the configuration's ``detector_topic``."""
+    return [{"topic": config["detector_topic"], **stream} for stream in config["streams"]]
+
+
+def events_per_pulse(config: dict, traffic) -> dict[str, int]:
+    """job -> events a pulse of its own stream carries."""
+    of_stream = {s["name"]: stream_events(s, traffic) for s in config["streams"]}
+    return {job["name"]: of_stream[job["stream"]] for job in config["jobs"]}
 
 
 class Run:
@@ -62,8 +75,7 @@ class Run:
             {
                 "seed": self.seed,
                 "traffic": self.cell.traffic.__dict__,
-                "topic": self.config["detector_topic"],
-                "streams": self.config["streams"],
+                "streams": streams_with_topics(self.config),
                 "broker_dir": str(self.child.broker),
                 "log_path": str(self.work / "pulses.i64"),
             },
@@ -73,7 +85,7 @@ class Run:
         # The reference's tables are made while the service starts; the
         # comparison itself waits until the service has gone.
         self.pools = reference.make_pools(self.config, self.cell.traffic, self.seed)
-        self.refs = reference.build(self.config, self.cell.traffic, self.pools)
+        self.refs = self.references()
         self.device = self.child.await_device()
         if self.device["platform"] != "tpu" and not self.allow_cpu:
             raise BenchFailure(
@@ -89,10 +101,22 @@ class Run:
                                         SAMPLED_PUBLISHES, replace=False))
             for job in jobs.values()
         }
-        self.outputs = results.Outputs.from_config(self.config)
+        self.outputs = {
+            job["name"]: results.Outputs.from_config(self.config, job)
+            for job in self.config["jobs"]
+        }
         self.reader = results.ResultReader(
             self.child, jobs, time.monotonic_ns, self.outputs, lambda job, n: n in picks[job]
         )
+
+    def references(self, fault: str | None = None) -> dict:
+        """job -> its reference; with ``fault``, the reference with that
+        guarantee broken: one of ``reference.FAULTS`` in every stream's
+        events, or ``<kind>.<name>`` in the jobs of that kind."""
+        pools = self.pools
+        if fault in reference.FAULTS:
+            pools, fault = reference.break_guarantee(pools, fault), None
+        return reference.build(self.config, self.cell.traffic, pools, self.cell.kinds, fault)
 
     def send(self, pulses: int) -> None:
         self.sent = self.generator.ask(f"send {pulses}")["sent"]
@@ -117,9 +141,7 @@ class Run:
             if not items:
                 return 0
             if items[-1].prefix < 0:
-                results.assign_prefixes(
-                    {job: items[-1:]}, self.refs, self.sent, self.outputs.prefix_total
-                )
+                results.assign_prefixes({job: items[-1:]}, self.refs, self.sent)
             out.append(items[-1].prefix)
         return min(out)
 
@@ -252,7 +274,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, repo: Path,
     """Returns (result line as a dict, lines for stderr). Raises
     BenchFailure where no result can be given. ``controls`` names faults
     of ``reference.break_guarantee``: each is put in the program's place
-    and its readings go under the line's ``controls`` key."""
+    and its readings go under the line's ``controls`` key; so is each
+    ``<kind>.<name>`` of a reference kind's own ``faults()``."""
     run = Run(cell, seed, seconds, trace, repo, started, allow_cpu)
     try:
         run.start()
@@ -282,8 +305,7 @@ def finish(run: Run, window: dict, pulse_log, trace_events, controls=()):
     window_s = (t1 - t0) / 1e9
     sent = len(pulse_log)
     due_ns = pulse_log[:, 1]
-    prefix_total = run.outputs.prefix_total
-    results.assign_prefixes(reader.publishes, run.refs, sent, prefix_total)
+    results.assign_prefixes(reader.publishes, run.refs, sent)
     in_window = (pulse_log[:, 2] >= t0) & (pulse_log[:, 2] < t1)
     first_pulse = int(np.argmax(in_window)) if in_window.any() else sent
     offered = first_pulse + int(in_window.sum())  # pulses sent before the window closed
@@ -305,7 +327,7 @@ def finish(run: Run, window: dict, pulse_log, trace_events, controls=()):
     # The comparison, after the window and after the service has gone.
     def judged(refs):
         """(numbers beside their limits, publishes wrong, correct)."""
-        results.assign_prefixes(reader.publishes, refs, sent, prefix_total)
+        results.assign_prefixes(reader.publishes, refs, sent)
         numbers, wrong = results.compare(reader.publishes, refs, cell.limits, run.outputs, t0)
         uncovered = sum(
             -(-max(0, offered - (items[-1].prefix if items else 0)) // BASE_WINDOW)
@@ -316,10 +338,7 @@ def finish(run: Run, window: dict, pulse_log, trace_events, controls=()):
 
     control_readings = {}
     for fault in controls:
-        broken = reference.build(
-            cell.config, cell.traffic, reference.break_guarantee(run.pools, fault)
-        )
-        numbers, failed, correct = judged(broken)
+        numbers, failed, correct = judged(run.references(fault))
         control_readings[fault] = {
             "correct": correct, "failed": failed,
             **{k: e["value"] for k, e in numbers.items() if "limit" in e},
@@ -345,12 +364,14 @@ def finish(run: Run, window: dict, pulse_log, trace_events, controls=()):
                     job: results.pulses_covered(items, t0, t1)
                     for job, items in reader.publishes.items()
                 }
+                per_pulse = events_per_pulse(cell.config, cell.traffic)
                 least_s = roofline.least_seconds(
                     cell.config,
-                    {j: (c[1] - c[0]) * cell.traffic.events_per_pulse for j, c in by_job.items()},
+                    {j: (c[1] - c[0]) * per_pulse[j] for j, c in by_job.items()},
                     {j: sum(t0 <= p.received_ns < t1 for p in items)
                      for j, items in reader.publishes.items()},
                     run.device["kind"],
+                    cell.kinds,
                 )
                 reduced["tick_roofline_pct"] = 100.0 * least_s / reduced["busy_s"]
                 breakdown = reduced.pop("breakdown")

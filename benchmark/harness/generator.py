@@ -27,29 +27,26 @@ from pathlib import Path
 import numpy as np
 
 from .broker import Producer
-from .traffic import Traffic, make_pool, pulse_time_ns
+from .traffic import Traffic, pulse_time_ns, stream_events, stream_pool
 from .wire import Ev44Template
 
 
 class Generator:
     def __init__(self, spec: dict) -> None:
         self.traffic = Traffic.from_dict(spec["traffic"])
-        self.topic = spec["topic"]
         self.producer = Producer(Path(spec["broker_dir"]))
         m = self.traffic.messages_per_pulse
-        chunk = self.traffic.events_per_pulse // m
-        self.templates = []  # [pool entry][message of the pulse]
+        self.templates = []  # [pool entry][message of the pulse]: (topic, template)
         for entry in range(self.traffic.pool_pulses):
             self.templates.append([])
         for index, stream in enumerate(spec["streams"]):
-            pool = make_pool(
-                spec["seed"], index, stream["first_id"], stream["n_pixels"], self.traffic
-            )
+            chunk = stream_events(stream, self.traffic) // m
+            pool = stream_pool(spec["seed"], index, stream, self.traffic)
             for entry, (ids, toa) in enumerate(pool):
                 for part in range(m):
                     sel = slice(part * chunk, (part + 1) * chunk)
                     self.templates[entry].append(
-                        Ev44Template(stream["wire_source"], toa[sel], ids[sel])
+                        (stream["topic"], Ev44Template(stream["wire_source"], toa[sel], ids[sel]))
                     )
         self.messages_per_pulse = len(self.templates[0])
         self.base_index = int(time.time_ns() * 14 // 10**9)
@@ -60,8 +57,8 @@ class Generator:
     def send_pulse(self, due_ns: int | None) -> None:
         pulse = self.next_pulse
         stamp = pulse_time_ns(self.base_index + pulse)
-        for template in self.templates[pulse % len(self.templates)]:
-            self.producer.produce(self.topic, template.stamp(self.message_id, stamp))
+        for topic, template in self.templates[pulse % len(self.templates)]:
+            self.producer.produce(topic, template.stamp(self.message_id, stamp))
             self.message_id += 1
         sent = time.monotonic_ns()
         self.log.append((pulse, sent if due_ns is None else due_ns, sent))

@@ -4,19 +4,22 @@ Everything of one configuration, one traffic mix, one cell or one
 per-layer metric is a file of its own, found by the name the manifest
 gives: ``configs/<config>.json`` (the manifest's ``file``),
 ``traffic/<traffic>.json``, ``workloads/<cell>.json`` with the limits
-of its comparison in ``limits/<cell>.json``, and
-``metrics/<metric>.json`` under ``benchmark/``. Adding one is adding
-files and manifest entries; no code knows a name.
+of its comparison in ``limits/<cell>.json``,
+``metrics/<metric>.json``, and ``references/<kind>.py`` for what the
+jobs of a view kind should give (``harness/reference.py``), under
+``benchmark/``. Adding one is adding files and manifest entries; no
+code knows a name.
 """
 
 from __future__ import annotations
 
 import json
 import re
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from pathlib import Path
 
-from .traffic import Traffic
+from . import reference
+from .traffic import STREAM_KINDS, Traffic, stream_events
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
@@ -49,6 +52,7 @@ class Cell:
     end_to_end: list[dict]  # the manifest's entries this cell reports
     per_layer: list[dict]  # the metric files' contents (with ``reader``)
     limits: dict[str, float]
+    kinds: dict = field(default_factory=dict)  # view kind -> its module of references/
 
 
 def load_manifest(root: Path) -> dict:
@@ -91,17 +95,59 @@ def load_cell(root: Path, workload: str) -> Cell:
                 if doc.get(key) != metric[key]:
                     raise ManifestError(f"metrics/{metric['name']}.json disagrees on {key!r}")
             per_layer.append({**doc, "name": metric["name"]})
+    traffic = Traffic.from_dict(traffic_doc)
+    limits = _load(bench / "limits" / f"{workload}.json")["limits"]
+    kinds, broken = plugs(bench, config, traffic, limits)
+    if broken:
+        raise ManifestError("; ".join(broken))
     return Cell(
         name=workload,
         chips=entry["chips"],
         config_name=entry["config"],
         config=config,
         traffic_name=entry["traffic"],
-        traffic=Traffic.from_dict(traffic_doc),
+        traffic=traffic,
         end_to_end=[m for m in manifest["end_to_end"] if reports(m)],
         per_layer=per_layer,
-        limits=_load(bench / "limits" / f"{workload}.json")["limits"],
+        limits=limits,
+        kinds=kinds,
     )
+
+
+def plugs(bench: Path, config: dict, traffic: Traffic, limits: dict) -> tuple[dict, list[str]]:
+    """What a configuration plugs into the harness by name, seen before
+    a run: (view kind -> its module, every broken plug as a sentence)."""
+    broken = []
+    streams = {s["name"] for s in config["streams"]}
+    for stream in config["streams"]:
+        try:
+            stream_events(stream, traffic)
+        except ValueError as err:
+            broken.append(str(err))
+        if stream.get("kind", "detector") not in STREAM_KINDS:
+            broken.append(f"stream {stream['name']}: kind {stream['kind']!r}")
+    kinds = {}
+    for job in config["jobs"]:
+        if job["stream"] not in streams:
+            broken.append(f"job {job['name']}: no stream {job['stream']!r}")
+        for role, name in job.get("aux_source_names", {}).items():
+            if name not in streams:
+                broken.append(f"job {job['name']}: aux {role!r} names no stream {name!r}")
+        kind = job["view"]["kind"]
+        if kind in reference.VIEW_KINDS or kind in kinds:
+            continue
+        if not isinstance(kind, str) or not NAME.match(kind):
+            broken.append(f"job {job['name']}: view kind {kind!r} is not a name")
+            continue
+        try:
+            kinds[kind] = reference.load_kind(bench, kind)
+        except ValueError as err:
+            broken.append(str(err))
+    if not broken:
+        for name in reference.check_names(config, kinds):
+            if name not in limits:
+                broken.append(f"check {name} has no limit")
+    return kinds, broken
 
 
 def check(root: Path) -> list[str]:

@@ -11,29 +11,46 @@ import numpy as np
 
 from .wire import decode_da00
 
+#: An ``arrays`` output no larger than this is kept for every publish;
+#: a larger one is sampled like the images.
+KEEP_BYTES = 64 * 1024
+
+
 @dataclass(frozen=True)
 class Outputs:
-    """The outputs of one publish that are compared, as the
-    configuration file lists them under ``outputs``. A name ends in
-    ``_current`` (the pulses since the previous publish) or
-    ``_cumulative`` (all pulses). ``prefix_total`` is the cumulative
-    scalar that tells which pulses a publish holds; it comes last of
-    them on the wire and marks the publish as received."""
+    """The outputs of one job's publish that are read, as the
+    configuration file lists them under ``outputs`` (a job may list its
+    own). ``scalars`` are kept as floats and not compared; ``spectra``
+    (kept for every publish) and ``images`` (kept for the sampled
+    publishes) are what a detector view gives; ``arrays`` is any other
+    named output, of any shape. A name that ends in ``_current`` holds
+    the pulses since the previous publish, any other all pulses.
+    ``prefix_total`` is the output whose total tells which pulses a
+    publish holds: a scalar, or an array whose sum is taken; a
+    ``*_current`` one is summed over the job's publishes so far. It
+    marks the publish as received."""
 
     scalars: tuple[str, ...]
     spectra: tuple[str, ...]
     images: tuple[str, ...]
     prefix_total: str
+    arrays: tuple[str, ...] = ()
 
     @classmethod
-    def from_config(cls, config: dict) -> "Outputs":
-        doc = config["outputs"]
-        return cls(tuple(doc["scalars"]), tuple(doc["spectra"]), tuple(doc["images"]),
-                   doc["prefix_total"])
+    def from_config(cls, config: dict, job: dict | None = None) -> "Outputs":
+        """The configuration's outputs, or those of ``job`` where it
+        lists its own (``outputs``, ``prefix_total``)."""
+        job = job or {}
+        doc = job.get("outputs", config["outputs"])
+        return cls(
+            *(tuple(doc.get(key, ())) for key in ("scalars", "spectra", "images")),
+            job.get("prefix_total", config["outputs"]["prefix_total"]),
+            tuple(doc.get("arrays", ())),
+        )
 
     @property
-    def compared(self) -> tuple[str, ...]:
-        return self.scalars + self.spectra + self.images
+    def read(self) -> tuple[str, ...]:
+        return self.scalars + self.spectra + self.images + self.arrays
 
 
 @dataclass
@@ -46,18 +63,21 @@ class Publish:
     scalars: dict[str, float] = field(default_factory=dict)
     spectra: dict[str, np.ndarray] = field(default_factory=dict)
     images: dict[str, np.ndarray] = field(default_factory=dict)  # kept for the sample only
+    arrays: dict[str, np.ndarray] = field(default_factory=dict)  # the large ones: for the sample only
+    total: float = 0.0  # the prefix total: events counted in pulses [0, prefix)
     prefix: int = -1  # pulses [0, prefix) are in the prefix total
     off_by: float = 0.0
 
 
 class ResultReader:
-    """Keeps every publish's scalars and spectra, and the images of the
-    publishes that ``sampled`` names plus each job's newest (at NMX size
-    all of them would not fit). The outputs of one publish share their
+    """Keeps every publish's scalars, spectra and small arrays, and the
+    images and large arrays of the publishes that ``sampled`` names plus
+    each job's newest (at NMX size all of them would not fit).
+    ``outputs`` is each job's. The outputs of one publish share their
     da00 timestamp, which is used as a grouping key and nothing else."""
 
-    def __init__(self, child, jobs_by_number: dict[str, str], clock, outputs: Outputs,
-                 sampled=lambda job, ordinal: False) -> None:
+    def __init__(self, child, jobs_by_number: dict[str, str], clock,
+                 outputs: dict[str, Outputs], sampled=lambda job, ordinal: False) -> None:
         self._child = child
         self._jobs = jobs_by_number
         self._clock = clock
@@ -65,6 +85,7 @@ class ResultReader:
         self._sampled = sampled
         self._open: dict[tuple[str, int], tuple[Publish, list[int]]] = {}
         self.publishes: dict[str, list[Publish]] = {j: [] for j in jobs_by_number.values()}
+        self._running = dict.fromkeys(self.publishes, 0.0)  # of a *_current prefix total
         self.bytes_read = 0
 
     def drain(self) -> int:
@@ -77,27 +98,35 @@ class ResultReader:
                 source, stamp, variables = decode_da00(raw)
                 _wid, _src, number, output = source.split("|")
                 job = self._jobs.get(number)
-                if job is None or output not in self.outputs.compared:
+                if job is None or output not in self.outputs[job].read:
                     continue
+                outputs = self.outputs[job]
                 publish, seen = self._open.setdefault(
                     (job, stamp), (Publish(job, -1), [0])
                 )
                 seen[0] += 1
                 signal = variables["signal"]
-                if output in self.outputs.scalars:
+                if output in outputs.scalars:
                     publish.scalars[output] = float(signal)
-                elif output in self.outputs.spectra:
+                elif output in outputs.spectra:
                     publish.spectra[output] = np.array(signal, np.float64)
-                else:
+                elif output in outputs.images:
                     publish.images[output] = np.array(signal)
-                if output == self.outputs.prefix_total:
+                else:
+                    publish.arrays[output] = np.array(signal)
+                if output == outputs.prefix_total:
                     publish.received_ns = now
-                if seen[0] == len(self.outputs.compared):
+                    publish.total = float(np.sum(signal, dtype=np.float64))
+                    if output.endswith("_current"):
+                        self._running[job] = publish.total = self._running[job] + publish.total
+                if seen[0] == len(outputs.read):
                     del self._open[(job, stamp)]
                     items = self.publishes[job]
                     publish.ordinal = len(items)
                     if items and not self._sampled(job, items[-1].ordinal):
                         items[-1].images.clear()
+                        for name in [n for n, a in items[-1].arrays.items() if a.nbytes > KEEP_BYTES]:
+                            del items[-1].arrays[name]
                     items.append(publish)
                     done += 1
         return done
@@ -107,13 +136,10 @@ def percentile(values, q: float) -> float:
     return float(np.percentile(np.asarray(values, np.float64), q))
 
 
-def assign_prefixes(publishes: dict[str, list[Publish]], refs, pulses_sent: int,
-                    prefix_total: str) -> None:
+def assign_prefixes(publishes: dict[str, list[Publish]], refs, pulses_sent: int) -> None:
     for job, items in publishes.items():
         for publish in items:
-            publish.prefix, publish.off_by = refs[job].prefix_of(
-                publish.scalars[prefix_total], pulses_sent
-            )
+            publish.prefix, publish.off_by = refs[job].prefix_of(publish.total, pulses_sent)
 
 
 def freshness(publishes, due_ns: np.ndarray, t0: int, t1: int, offered: int,
@@ -167,17 +193,37 @@ def bins_off(got: np.ndarray, want: np.ndarray, rounding: float = ROUNDING) -> i
     return int(np.count_nonzero(np.abs(got.astype(np.float64) - want) > slack))
 
 
-def compare(publishes, refs, limits: dict[str, float], outputs: Outputs,
+def bins_outside(got: np.ndarray, want: np.ndarray, rel: float, abs_: float) -> tuple[int, float]:
+    """How many bins of the float output ``got`` miss ``want`` by more
+    than ``abs_ + rel * |want|``, and the largest miss as a share of
+    that room. A bin that is not finite on one side only is a miss."""
+    got, want = got.astype(np.float64), np.asarray(want, np.float64)
+    with np.errstate(divide="ignore", invalid="ignore"):
+        miss = np.abs(got - want)
+        share = miss / (abs_ + rel * np.abs(want))
+    same = (miss == 0) | (np.isnan(got) & np.isnan(want)) | ((got == want) & np.isinf(want))
+    share = np.where(same, 0.0, np.where(np.isnan(share), np.inf, share))
+    return int(np.count_nonzero(share > 1.0)), float(share.max(initial=0.0))
+
+
+def compare(publishes, refs, limits: dict[str, float], outputs: dict[str, Outputs],
             since_ns: int = 0) -> tuple[dict, int]:
     """The numbers compared over every publish of the run, each beside
     its limit, and how many publishes received from ``since_ns`` on
-    arrived wrong. Bins are compared exactly as long as float32 can
-    hold them (``bins_off``). The float32 totals pass 2**24 within
-    seconds; the cumulative one is used only to find the publish's pulse
-    prefix, to the nearest pulse.
+    arrived wrong. What an output should be, which pulses it holds, how
+    it is judged and the check it counts into are its job's reference's
+    to say (``harness/reference.py``). Where the reference states no
+    tolerance, bins are compared exactly as long as float32 can hold
+    them (``bins_off``); a stated one is printed beside the check with
+    the largest miss seen, as a share of the room it gives. The float32
+    totals pass 2**24 within seconds; the prefix total is used only to
+    find the publish's pulse prefix, to the nearest pulse.
     """
-    wrong = {"spectrum_bins_wrong": 0, "image_bins_wrong": 0, "prefix_off_pulses": 0.0}
+    wrong = {name: 0.0 if name == "prefix_off_pulses" else 0 for name in limits}
+    stated: dict[str, dict] = {}
     compared = {"spectra": 0, "images": 0}
+    if any(out.arrays for out in outputs.values()):
+        compared["arrays"] = 0
     bad_publishes = 0
     for job, items in publishes.items():
         ref = refs[job]
@@ -186,29 +232,33 @@ def compare(publishes, refs, limits: dict[str, float], outputs: Outputs,
             bad = 0
             wrong["prefix_off_pulses"] = max(wrong["prefix_off_pulses"], publish.off_by)
             bad += publish.off_by > limits["prefix_off_pulses"] or publish.prefix <= previous
-            spans = {"current": (previous, publish.prefix), "cumulative": (0, publish.prefix)}
-            for output, got in publish.spectra.items():
-                lo, hi = spans[output.rsplit("_", 1)[1]]
-                miss = bins_off(got, ref.spectrum(lo, max(hi, lo)))
-                wrong["spectrum_bins_wrong"] += miss
-                compared["spectra"] += 1
-                bad += miss > 0
-            for output, got in publish.images.items():
-                lo, hi = spans[output.rsplit("_", 1)[1]]
-                want = ref.image(lo, max(hi, lo))
-                if got.shape != want.shape:
-                    miss = want.size
-                else:
-                    miss = bins_off(got, want)
-                wrong["image_bins_wrong"] += miss
-                compared["images"] += 1
-                bad += miss > 0
-            if len(publish.spectra) != len(outputs.spectra):
+            for output_class in compared:
+                for output, got in getattr(publish, output_class).items():
+                    lo, hi = ref.span(output, previous, publish.prefix)
+                    want = ref.expected(output, lo, max(hi, lo))
+                    tolerance, check = ref.tolerance(output), ref.check(output)
+                    if got.shape != want.shape:
+                        miss = want.size
+                    elif tolerance is None:
+                        miss = bins_off(got, want)
+                    else:
+                        rel, abs_, reason = tolerance
+                        miss, share = bins_outside(got, want, rel, abs_)
+                        entry = stated.setdefault(check, {
+                            "tolerance": {"rel": rel, "abs": abs_}, "worst_share": 0.0,
+                            "reason": reason,
+                        })
+                        entry["worst_share"] = max(entry["worst_share"], share)
+                    wrong[check] += miss
+                    compared[output_class] += 1
+                    bad += miss > 0
+            if len(publish.spectra) != len(outputs[job].spectra):
                 bad += 1
             bad_publishes += bad > 0 and publish.received_ns >= since_ns
             previous = max(previous, publish.prefix)
     numbers = {
-        name: {"value": value, "limit": limits[name]} for name, value in wrong.items()
+        name: {"value": value, "limit": limits[name], **stated.get(name, {})}
+        for name, value in wrong.items()
     }
     numbers["largest_bin"] = {"value": max(
         (float(s.max()) for items in publishes.values() for p in items

@@ -46,9 +46,17 @@ def job_bytes(job: dict, toa_bins: int, events: int, publishes: int) -> int:
     return events * EVENT_BYTES + publishes * (FOLD_PASSES * bins * 4 + fetched)
 
 
-def least_seconds(config: dict, events_per_job: dict, publishes_per_job: dict, device_kind: str) -> float:
-    total = sum(
-        job_bytes(job, config["toa_bins"], events_per_job[job["name"]], publishes_per_job[job["name"]])
-        for job in config["jobs"]
-    )
+def least_seconds(config: dict, events_per_job: dict, publishes_per_job: dict, device_kind: str,
+                  kinds: dict | None = None) -> float:
+    """The bytes of every job over the chip's peak bytes/s. A job of a
+    kind that ``job_bytes`` does not know is reckoned by its kind's own
+    ``work_bytes`` (``benchmark/references/<kind>.py``)."""
+    total = 0
+    for job in config["jobs"]:
+        events, publishes = events_per_job[job["name"]], publishes_per_job[job["name"]]
+        kind = (kinds or {}).get(job["view"]["kind"])
+        if kind is None:
+            total += job_bytes(job, config["toa_bins"], events, publishes)
+        else:
+            total += kind.work_bytes(job, config, events, publishes)
     return total / peak(device_kind)["hbm_bytes_per_s"]

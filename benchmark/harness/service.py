@@ -58,6 +58,25 @@ def free_port() -> int:
         return sock.getsockname()[1]
 
 
+def start_command(instrument: str, job: dict, number: str) -> bytes:
+    """The ``start_job`` command of one configured job, as it goes on
+    the commands topic: the job's workflow on its source, with its
+    parameters and the auxiliary streams it binds (none by default)."""
+    namespace, name = job["workflow"]
+    return json.dumps({
+        "kind": "start_job",
+        "config": {
+            "identifier": {
+                "instrument": instrument, "namespace": namespace, "name": name, "version": 1,
+            },
+            "job_id": {"source_name": job["job_source"], "job_number": number},
+            "params": job.get("params", {}),
+            "aux_source_names": job.get("aux_source_names", {}),
+            "schedule": {"start_time_ns": None, "end_time_ns": None},
+        },
+    }).encode()
+
+
 class ServiceChild:
     """One ``python -m <service module>`` over a private file broker."""
 
@@ -69,7 +88,11 @@ class ServiceChild:
             name: f"{self.instrument}_livedata_{name}"
             for name in ("data", "status", "commands", "responses")
         }
-        ensure_topics(self.broker, [*self.topic.values(), config["detector_topic"]])
+        ensure_topics(
+            self.broker,
+            [*self.topic.values(), config["detector_topic"],
+             *(s["topic"] for s in config["streams"] if "topic" in s)],
+        )
         self.producer = Producer(self.broker)
         self._consumers = {
             name: Consumer(self.broker, self.topic[name])
@@ -135,21 +158,9 @@ class ServiceChild:
         for job in config["jobs"]:
             number = str(uuid.uuid4())
             numbers[number] = job["name"]
-            namespace, name = job["workflow"]
-            command = {
-                "kind": "start_job",
-                "config": {
-                    "identifier": {
-                        "instrument": self.instrument, "namespace": namespace,
-                        "name": name, "version": 1,
-                    },
-                    "job_id": {"source_name": job["job_source"], "job_number": number},
-                    "params": job.get("params", {}),
-                    "aux_source_names": {},
-                    "schedule": {"start_time_ns": None, "end_time_ns": None},
-                },
-            }
-            self.producer.produce(self.topic["commands"], json.dumps(command).encode())
+            self.producer.produce(
+                self.topic["commands"], start_command(self.instrument, job, number)
+            )
         waiting = set(numbers)
 
         def probe():

@@ -18,7 +18,7 @@ the pool, so that every second of a run holds the same mix.
 
 from __future__ import annotations
 
-from dataclasses import dataclass
+from dataclasses import dataclass, replace
 
 import numpy as np
 
@@ -68,6 +68,48 @@ class Traffic:
         if not 0 <= 2 * traffic.out_of_range_probes <= traffic.events_per_pulse:
             raise ValueError("out_of_range_probes does not fit in a pulse")
         return traffic
+
+
+STREAM_KINDS = ("detector", "monitor")
+
+
+def stream_events(stream: dict, traffic: Traffic) -> int:
+    """Events in one pulse of ``stream``: its ``rate_share`` (1 where
+    the configuration gives none) of the mix's ``events_per_pulse``, a
+    whole number that ``messages_per_pulse`` divides and that holds the
+    pulse's out-of-range probes."""
+    if "rate_share" not in stream:
+        return traffic.events_per_pulse
+    events = stream["rate_share"] * traffic.events_per_pulse
+    if not (
+        events > 0
+        and events == int(events)
+        and int(events) % traffic.messages_per_pulse == 0
+        and 2 * traffic.out_of_range_probes <= events
+    ):
+        raise ValueError(
+            f"stream {stream['name']}: rate_share {stream['rate_share']} of "
+            f"{traffic.events_per_pulse} events is no multiple of "
+            f"{traffic.messages_per_pulse} messages a pulse that holds the probes"
+        )
+    return int(events)
+
+
+def stream_pool(seed: int, stream_index: int, stream: dict, traffic: Traffic):
+    """``make_pool`` for one stream of a configuration, at the stream's
+    own size. A ``monitor`` stream is ev44 with TOA only (as
+    ``services/fake_sources.py:FakeMonitorStream`` sends one): the same
+    draws in the same order, the ids left out."""
+    kind = stream.get("kind", "detector")
+    if kind not in STREAM_KINDS:
+        raise ValueError(f"stream {stream['name']}: kind {kind!r}")
+    own = replace(traffic, events_per_pulse=stream_events(stream, traffic))
+    pool = make_pool(
+        seed, stream_index, stream.get("first_id", 0), stream.get("n_pixels", 1), own
+    )
+    if kind == "monitor":
+        pool = [(ids[:0], toa) for ids, toa in pool]
+    return pool
 
 
 def make_pool(seed: int, stream_index: int, first_id: int, n_pixels: int, traffic: Traffic):

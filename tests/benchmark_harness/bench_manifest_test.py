@@ -4,13 +4,12 @@ files and manifest entries only."""
 
 from __future__ import annotations
 
-import copy
 import json
 import re
 
 import pytest
-from bench_support import FIXTURE, PARTS, REPO
-from harness import manifest, metrics
+from bench_support import FIXTURE, KINDS, PARTS, PLUGS, REPO, edit_json, overlay_fixture
+from harness import manifest, metrics, reference
 from harness.traffic import Traffic
 
 MANIFEST = json.loads((REPO / "BENCHMARK.json").read_text())
@@ -92,12 +91,8 @@ def test_data_files_carry_names_the_manifest_could_use(part):
             assert {k: doc[k] for k in entry} == entry
 
 
-def _broken(edit):
-    doc = copy.deepcopy(MANIFEST)
-    edit(doc)
-    return doc
-
-
+#: Each edits the manifest of a root that holds the benchmark's data and
+#: the fixture's; the fixture's entries come after the benchmark's.
 BREACHES = {
     "a unit with a space": lambda d: d["end_to_end"][0].update(unit="ms per tick"),
     "a name with a slash": lambda d: d["per_layer"][0].update(name="a/b"),
@@ -123,22 +118,32 @@ BREACHES = {
 }
 
 
-@pytest.mark.parametrize("breach", sorted(BREACHES))
+@pytest.mark.parametrize("breach", [*sorted(BREACHES), *sorted(PLUGS)])
 def test_check_names_each_breach(tmp_path, breach):
-    root = tmp_path
-    (root / "benchmark").symlink_to(BENCH)
-    (root / "BENCHMARK.json").write_text(json.dumps(_broken(BREACHES[breach])))
-    assert manifest.check(root), breach
+    overlay_fixture(tmp_path)
+    assert manifest.check(tmp_path) == []
+    if breach in BREACHES:
+        edit_json(tmp_path / "BENCHMARK.json", BREACHES[breach])
+        assert manifest.check(tmp_path), breach
+        return
+    edit, word = PLUGS[breach]
+    edit(tmp_path / "benchmark")
+    sentences = manifest.check(tmp_path)
+    assert len(sentences) == 1 and sentences[0].startswith("cell toy_loki.toy_iq: "), sentences
+    assert word in sentences[0]
+    with pytest.raises(manifest.ManifestError, match=re.escape(word)):
+        manifest.load_cell(tmp_path, "toy_loki.toy_iq")
+    manifest.load_cell(tmp_path, "toy_panel.toy_paced")  # the other cells are as they were
 
 
 def test_fixture_adds_files_and_entries_and_edits_nothing(toy_root):
-    """A configuration, two cells with their limits, two traffic mixes
-    and a prometheus metric arrive as new files and new manifest
-    entries; the harness finds them by name with no edit to any file
-    that was there."""
+    """Two configurations, three cells with their limits, three traffic
+    mixes, a prometheus metric and a reference kind arrive as new files
+    and new manifest entries; the harness finds them by name with no
+    edit to any file that was there."""
     assert manifest.check(toy_root) == []
-    for part in PARTS:
-        for path in (BENCH / part).iterdir():
+    for part in (*PARTS, KINDS):
+        for path in (BENCH / part).glob("*.*"):
             assert (toy_root / "benchmark" / part / path.name).read_bytes() == path.read_bytes()
         assert list((FIXTURE / part).iterdir())
     paced = manifest.load_cell(toy_root, "toy_panel.toy_paced")
@@ -150,8 +155,36 @@ def test_fixture_adds_files_and_entries_and_edits_nothing(toy_root):
         "freshness_p50_ms", "freshness_p95_ms", "setup_s"
     }
     assert blob.limits == paced.limits
+    assert paced.kinds == blob.kinds == {}  # a detector view needs no file
     with pytest.raises(manifest.ManifestError):
         manifest.load_cell(toy_root, "toy_panel.nowhere")
+
+
+def test_fixture_plugs_a_kind_a_stream_and_outputs_of_its_own(toy_root):
+    """The toy LOKI: ``sans/iq`` on the data-reduction service, its
+    reference found by the view's kind, a monitor stream on its own
+    topic at an eighth of the rate bound as an aux source, outputs and
+    a prefix total that the job lists itself."""
+    cell = manifest.load_cell(toy_root, "toy_loki.toy_iq")
+    assert cell.config["service"] == "data_reduction"
+    assert list(cell.kinds) == ["sans_iq"]
+    kind = cell.kinds["sans_iq"]
+    assert all(callable(getattr(kind, name)) for name in reference.KIND_FUNCTIONS)
+    assert set(kind.faults()) == {"monitor_twice", "toa_bin_off_by_one"}
+    detector, monitor = cell.config["streams"]
+    assert "topic" not in detector and "kind" not in detector and "rate_share" not in detector
+    assert (monitor["kind"], monitor["topic"], monitor["rate_share"]) == ("monitor", "loki_monitor", 0.125)
+    job = cell.config["jobs"][0]
+    assert job["aux_source_names"] == {"monitor": monitor["name"]}
+    assert job["prefix_total"] == "counts_q_current" and "arrays" in job["outputs"]
+    assert reference.check_names(cell.config, cell.kinds) == [
+        "iq_bins_off", "q_counts_wrong", "monitor_counts_wrong", "prefix_off_pulses"]
+    assert set(cell.limits) == set(reference.check_names(cell.config, cell.kinds))
+    for accepted in ("nmx_panels.paced14", "dream_banks.paced14"):
+        loaded = manifest.load_cell(toy_root, accepted)
+        assert loaded.kinds == {}
+        assert reference.check_names(loaded.config, {}) == list(loaded.limits) == [
+            "spectrum_bins_wrong", "image_bins_wrong", "prefix_off_pulses"]
 
 
 def test_unknown_traffic_keys_and_modes_are_refused():

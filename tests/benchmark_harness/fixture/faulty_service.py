@@ -1,8 +1,9 @@
-"""The detector service with one fault planted under the timed path.
+"""A service of the package with one fault planted under the timed path.
 
 Started by the harness in the service's place (a configuration's
 ``service`` key names this module), with the fault named in
-``BENCH_TEST_FAULT``. Each fault breaks one of the guarantees the
+``BENCH_TEST_FAULT`` and the service in ``BENCH_TEST_SERVICE`` (the
+detector service where it is not set). Each fault breaks one of the guarantees the
 configurations state, where the program produces the answer:
 
 ``half_batch``       every ev44 message loses the second half of its events.
@@ -57,7 +58,9 @@ def plant(fault: str) -> None:
 
 
 if __name__ == "__main__":
-    plant(os.environ["BENCH_TEST_FAULT"])
-    from esslivedata_tpu.services.detector_data import main
+    import importlib
 
+    plant(os.environ["BENCH_TEST_FAULT"])
+    service = os.environ.get("BENCH_TEST_SERVICE", "detector_data")
+    main = importlib.import_module(f"esslivedata_tpu.services.{service}").main
     raise SystemExit(main(sys.argv[1:]))
