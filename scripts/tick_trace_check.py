@@ -10,7 +10,8 @@ of any service will do. The report, JSON on stdout, is what PERF.md
 section 6 quotes:
 
 - **scopes**: device time by ``jax.named_scope`` (``scatter`` / ``fold`` /
-  ``publish_reduce`` / ``pack``) and by jitted program, with the heaviest
+  ``publish_reduce`` / ``pack``; ``qmap_gather`` / ``q_bincount`` of a Q
+  step) and by jitted program, with the heaviest
   ops of each scope (cut to the dump's steady ticks where there is a dump);
 - **twins**: the ``TraceAnnotation`` twins of the tick spans in the host
   planes, counted by name;
@@ -29,7 +30,7 @@ import sys
 import time
 from pathlib import Path
 
-SCOPES = ("scatter", "fold", "publish_reduce", "pack")
+SCOPES = ("scatter", "fold", "publish_reduce", "pack", "qmap_gather", "q_bincount")
 # The profiler's own names for a TPU's plane and its two lines.
 DEVICE_PLANE = "/device:TPU:"
 OPS_LINE = "XLA Ops"

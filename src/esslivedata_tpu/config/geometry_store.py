@@ -41,6 +41,9 @@ logger = logging.getLogger(__name__)
 #: windows; files are never replaced in place (new date = new file).
 GEOMETRY_REGISTRY: dict[str, str | None] = {
     "geometry-loki-2026-01-01.nxs": None,
+    # the nine straw-tube banks beside the toy plane: a new date, so that
+    # a cache that holds the older file does not answer for them
+    "geometry-loki-2026-09-01.nxs": None,
     "geometry-dream-2026-01-01.nxs": None,
     "geometry-bifrost-2026-01-01.nxs": None,
     "geometry-estia-2026-01-01.nxs": None,

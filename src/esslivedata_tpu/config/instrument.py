@@ -13,6 +13,7 @@ from __future__ import annotations
 import importlib
 import logging
 import threading
+from collections.abc import Callable
 from dataclasses import dataclass, field
 from typing import TYPE_CHECKING
 
@@ -33,23 +34,64 @@ __all__ = [
 logger = logging.getLogger(__name__)
 
 
+class _DeferredGeometry:
+    """``DetectorConfig.positions`` / ``.pixel_ids``: the value given,
+    or what ``geometry_loader`` returns, read on the first access."""
+
+    def __init__(self, name: str) -> None:
+        self._slot = f"_{name}"
+
+    def __get__(self, obj, objtype=None):
+        if obj is None:
+            return self
+        if getattr(obj, self._slot) is None and obj.geometry_loader is not None:
+            obj._positions, obj._pixel_ids = obj.geometry_loader()
+        return getattr(obj, self._slot)
+
+    def __set__(self, obj, value) -> None:
+        setattr(obj, self._slot, value)
+
+
 @dataclass
 class DetectorConfig:
-    """One detector bank and how to view it."""
+    """One detector bank and how to view it.
+
+    ``geometry_loader`` defers a geometric bank's ``positions`` and
+    ``pixel_ids`` to their first read (a job on the bank starting), so
+    that declaring a large bank costs nothing at import."""
 
     name: str  # canonical stream name, e.g. 'bank0'
     source_name: str  # ECDC source name on the wire
     detector_number: np.ndarray | None = None  # logical [ny, nx] grid
-    positions: np.ndarray | None = None  # geometric [n, 3]
-    pixel_ids: np.ndarray | None = None  # ids matching positions rows
+    # geometric [n, 3] and the ids of its rows: out of repr and ==, which
+    # would read a deferred geometry
+    positions: np.ndarray | None = field(default=None, repr=False, compare=False)
+    pixel_ids: np.ndarray | None = field(default=None, repr=False, compare=False)
     projection: str = "logical"  # 'logical' | 'xy_plane' | 'cylinder_mantle_z'
     resolution: tuple[int, int] = (128, 128)
     noise_sigma: float = 0.0
     n_replica: int = 1
+    #: () -> (positions, pixel_ids), called once, on the first read of either.
+    geometry_loader: Callable[[], tuple[np.ndarray, np.ndarray]] | None = field(
+        default=None, repr=False, compare=False
+    )
 
     def __post_init__(self) -> None:
-        if self.detector_number is None and self.positions is None:
+        if (
+            self.detector_number is None
+            and self.geometry_loader is None
+            and self.positions is None
+        ):
             raise ValueError(f"Detector {self.name}: need a layout or positions")
+
+    @property
+    def geometry_loaded(self) -> bool:
+        """Whether positions are in memory (given, or deferred and read)."""
+        return self._positions is not None
+
+
+DetectorConfig.positions = _DeferredGeometry("positions")
+DetectorConfig.pixel_ids = _DeferredGeometry("pixel_ids")
 
 
 @dataclass

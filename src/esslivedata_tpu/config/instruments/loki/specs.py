@@ -9,6 +9,7 @@ two pipelines a real deployment feeds with downloaded ESS files.
 
 from __future__ import annotations
 
+from functools import partial
 
 from ....config.instrument import (
     DetectorConfig,
@@ -27,7 +28,7 @@ from .._common import (
     register_parsed_catalog,
     register_timeseries_spec,
 )
-from .geometry import rear_bank_geometry
+from .geometry import BANK_PIXELS, bank_geometry
 
 from .streams_parsed import PARSED_STREAMS
 
@@ -36,19 +37,29 @@ INSTRUMENT = Instrument(
     _factories_module="esslivedata_tpu.config.instruments.loki.factories",
 )
 
-_positions, _pixel_ids = rear_bank_geometry()
 INSTRUMENT.add_detector(
     DetectorConfig(
         name="larmor_detector",
         source_name="loki_rear_detector",
-        positions=_positions,
-        pixel_ids=_pixel_ids,
+        geometry_loader=partial(bank_geometry, "larmor_detector"),
         projection="xy_plane",
         resolution=(256, 256),
         noise_sigma=0.002,
         n_replica=4,
     )
 )
+# The nine straw-tube banks as deployed (3 211 264 pixels), beside the
+# toy plane: positions and ids are read when a job on a bank starts.
+for _bank in BANK_PIXELS:
+    INSTRUMENT.add_detector(
+        DetectorConfig(
+            name=_bank,
+            source_name=_bank,
+            geometry_loader=partial(bank_geometry, _bank),
+            projection="xy_plane",
+            resolution=(256, 256),
+        )
+    )
 INSTRUMENT.add_monitor(MonitorConfig(name="monitor_1", source_name="loki_mon_1"))
 INSTRUMENT.add_monitor(MonitorConfig(name="monitor_2", source_name="loki_mon_2"))
 INSTRUMENT.add_log("sample_stage_x", "loki_mtr_sx")
@@ -62,7 +73,7 @@ DETECTOR_VIEW_HANDLE = workflow_registry.register_spec(
         namespace="detector_view",
         name="rear_view",
         title="Rear bank 2-D view",
-        source_names=INSTRUMENT.detector_names,
+        source_names=["larmor_detector"],
         params_model=DetectorViewParams,
         outputs={
             **detector_view_outputs(),  # incl. the ROI readbacks

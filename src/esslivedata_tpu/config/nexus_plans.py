@@ -32,6 +32,7 @@ from .nexus_synthesis import (
     InstrumentNexusPlan,
     LogPlan,
     MonitorPlan,
+    StrawPanel,
 )
 
 __all__ = ["NEXUS_PLANS", "plan_for"]
@@ -123,10 +124,71 @@ def _vacuum(instrument: str, n: int = 4) -> tuple[LogPlan, ...]:
     )
 
 
+#: LOKI as deployed (upstream config/instruments/loki/specs.py, from
+#: memory): nine straw-tube banks ``loki_detector_0`` .. ``_8`` of
+#: 4 layers x this many tubes x 7 straws x 512 pixels, ids one bank
+#: after another from 1: 3 211 264 pixels, 802 816 of them in bank 0.
+_LOKI_BANK_TUBES = (56, 16, 12, 16, 12, 28, 32, 20, 32)
+_LOKI_LAYERS, _LOKI_STRAWS, _LOKI_PIXELS = 4, 7, 512
+
+_X, _Y, _Z = (1.0, 0.0, 0.0), (0.0, 1.0, 0.0), (0.0, 0.0, 1.0)
+#: Where each bank's panel stands (synthesized; a real artifact in the
+#: data directory replaces it): centre in m and the direction of its
+#: straws. Bank 0 is the rear panel, 5 m downstream on the beam; banks
+#: 1-4 frame the beam 3.0 / 3.2 m from the sample (top, left, bottom,
+#: right), banks 5-8 1.5 / 1.7 m from it. Tubes stack across the
+#: straws, layers along the beam.
+_LOKI_PANELS = (
+    ((0.0, 0.0, 5.0), _X),
+    ((0.0, 0.45, 3.0), _X),
+    ((0.4, 0.0, 3.2), _Y),
+    ((0.0, -0.45, 3.0), _X),
+    ((-0.4, 0.0, 3.2), _Y),
+    ((0.0, 0.65, 1.5), _X),
+    ((0.7, 0.0, 1.7), _Y),
+    ((0.0, -0.65, 1.5), _X),
+    ((-0.7, 0.0, 1.7), _Y),
+)
+
+
+def _loki_banks() -> tuple[BankPlan, ...]:
+    """The nine banks, ids consecutive from 1. Straws are 1 m long
+    (512 pixels of 1/512 m); tubes 28.4 mm apart, layers 24.6 mm, the
+    six outer straws 7.8 mm off the tube's axis."""
+    banks, first_id = [], 1
+    for i, (tubes, (centre, along)) in enumerate(
+        zip(_LOKI_BANK_TUBES, _LOKI_PANELS, strict=True)
+    ):
+        shape = (_LOKI_LAYERS, tubes, _LOKI_STRAWS, _LOKI_PIXELS)
+        banks.append(
+            BankPlan(
+                name=f"loki_detector_{i}",
+                source=f"loki_detector_{i}",
+                topic="loki_detector",
+                shape=shape,
+                first_id=first_id,
+                panel=StrawPanel(
+                    centre=centre,
+                    along=along,
+                    across=_Y if along == _X else _X,
+                    normal=_Z,
+                    pixel_pitch=1.0 / _LOKI_PIXELS,
+                    tube_pitch=0.0284,
+                    layer_pitch=0.0246,
+                    straw_radius=0.0078,
+                ),
+            )
+        )
+        first_id += shape[0] * shape[1] * shape[2] * shape[3]
+    return tuple(banks)
+
+
 _LOKI = InstrumentNexusPlan(
     name="loki",
     title="LOKI small-angle scattering",
     banks=(
+        # the toy plane the detector view and the package's tests run on;
+        # its ids overlap bank 0's (another source on the same topic)
         BankPlan(
             name="larmor_detector",
             source="loki_rear_detector",
@@ -135,6 +197,7 @@ _LOKI = InstrumentNexusPlan(
             extent=(1.0, 1.0),
             z=5.0,
         ),
+        *_loki_banks(),
     ),
     monitors=tuple(
         MonitorPlan(

@@ -34,6 +34,8 @@ import numpy as np
 
 __all__ = [
     "BankPlan",
+    "StrawPanel",
+    "straw_positions",
     "ChopperPlan",
     "DevicePlan",
     "InstrumentNexusPlan",
@@ -44,11 +46,71 @@ __all__ = [
 
 
 @dataclass(frozen=True)
+class StrawPanel:
+    """Where a flat panel of straw tubes stands, for a ``BankPlan`` of
+    shape (layers, tubes, straws, pixels): see ``straw_positions``.
+
+    ``along`` runs along a straw (the pixel axis), ``across`` from tube
+    to tube within a layer, ``normal`` from layer to layer; unit vectors.
+    """
+
+    centre: tuple[float, float, float]  # m, sample at the origin, beam +z
+    along: tuple[float, float, float]
+    across: tuple[float, float, float]
+    normal: tuple[float, float, float]
+    pixel_pitch: float  # m between pixel centres along a straw
+    tube_pitch: float  # m between tube axes within a layer
+    layer_pitch: float  # m between layers
+    straw_radius: float  # m from a tube's axis to its six outer straws
+
+
+def straw_positions(shape: tuple[int, ...], panel: StrawPanel) -> np.ndarray:
+    """[n, 3] pixel centres in m, C order over (layer, tube, straw, pixel):
+
+        u = (pixel - (pixels - 1) / 2) * pixel_pitch
+        v = (tube - (tubes - 1) / 2) * tube_pitch + (layer % 2) * tube_pitch / 2
+            + straw_radius * cos(phi)
+        w = (layer - (layers - 1) / 2) * layer_pitch + straw_radius * sin(phi)
+        position = centre + u * along + v * across + w * normal
+
+    Straw 0 lies on the tube's axis (its radius term is 0); straws 1 to
+    ``straws - 1`` ring it at ``phi = 2 pi (straw - 1) / (straws - 1)``.
+    Odd layers sit half a tube pitch over, as close-packed tubes do.
+    """
+    layers, tubes, straws, pixels = shape
+    layer, tube, straw, pixel = np.meshgrid(
+        *(np.arange(n, dtype=np.float64) for n in shape), indexing="ij"
+    )
+    phi = 2.0 * np.pi * (straw - 1.0) / (straws - 1.0)
+    ring = np.where(straw > 0, panel.straw_radius, 0.0)
+    u = (pixel - (pixels - 1) / 2.0) * panel.pixel_pitch
+    v = (
+        (tube - (tubes - 1) / 2.0) * panel.tube_pitch
+        + (layer % 2) * (panel.tube_pitch / 2.0)
+        + ring * np.cos(phi)
+    )
+    w = (layer - (layers - 1) / 2.0) * panel.layer_pitch + ring * np.sin(phi)
+    return np.stack(
+        [
+            (
+                panel.centre[axis]
+                + u * panel.along[axis]
+                + v * panel.across[axis]
+                + w * panel.normal[axis]
+            ).ravel()
+            for axis in range(3)
+        ],
+        axis=1,
+    )
+
+
+@dataclass(frozen=True)
 class BankPlan:
     """One detector bank.
 
     ``logical=False``: a rectangular (or cylinder-mantle) geometric bank —
-    ``shape`` is (ny, nx) and pixel offsets are written.
+    ``shape`` is (ny, nx) and pixel offsets are written; with ``panel``
+    a straw-tube panel of shape (layers, tubes, straws, pixels).
     ``logical=True``: an N-d logical bank (DREAM/BIFROST style) — ``shape``
     may have any rank, only ``detector_number`` is written (named axes live
     in the instrument's view specs, not the file).
@@ -63,6 +125,7 @@ class BankPlan:
     first_id: int = 1
     curvature_radius: float | None = None  # cylinder mantle around z axis
     logical: bool = False
+    panel: StrawPanel | None = None  # a straw-tube panel: shape is 4-d
 
 
 @dataclass(frozen=True)
@@ -189,6 +252,11 @@ def _write_bank(instr, plan: BankPlan) -> None:
             source=plan.source,
         )
         return
+    if plan.panel is not None:
+        xyz = straw_positions(plan.shape, plan.panel)
+        gx, gy, gz = (xyz[:, axis].reshape(plan.shape) for axis in range(3))
+        _write_offsets(det, plan, (gx, gy, gz), opts)
+        return
     ny, nx = plan.shape
     h, w = plan.extent
     ys = np.linspace(-h / 2, h / 2, ny)
@@ -203,10 +271,12 @@ def _write_bank(instr, plan: BankPlan) -> None:
         gphi, gy = np.meshgrid(phi, ys)
         gx = r * np.sin(gphi)
         gz = plan.z + r * (np.cos(gphi) - 1.0)
-    for dsname, grid in (
-        ("x_pixel_offset", gx),
-        ("y_pixel_offset", gy),
-        ("z_pixel_offset", gz),
+    _write_offsets(det, plan, (gx, gy, gz), opts)
+
+
+def _write_offsets(det, plan: BankPlan, grids, opts: dict) -> None:
+    for dsname, grid in zip(
+        ("x_pixel_offset", "y_pixel_offset", "z_pixel_offset"), grids, strict=True
     ):
         d = det.create_dataset(dsname, data=grid.astype(np.float64), **opts)
         d.attrs["units"] = "m"

@@ -30,7 +30,7 @@ from pydantic import BaseModel, model_validator
 
 from ..config.workflow_spec import JobId, WorkflowConfig
 from ..preprocessors.event_data import StagedEvents
-from ..telemetry.instruments import TICK_GROUPS
+from ..telemetry.instruments import JOB_WINDOWS, TICK_GROUPS
 from ..telemetry.trace import TRACER
 from ..workflows.workflow_factory import WorkflowFactory, workflow_registry
 from .device_event_cache import DeviceEventCache
@@ -1669,11 +1669,25 @@ class JobManager:
             )
             tick_served, tick_streams = self._run_tick_programs(tick_groups)
         fused_streams = self._run_fused_steps(fuse_groups)
+        for rec, job_data in work:
+            if job_data:
+                JOB_WINDOWS.inc(
+                    path="tick"
+                    if rec.job.job_id in tick_streams
+                    else "fused"
+                    if rec.job.job_id in fused_streams
+                    else "private"
+                )
         for job_id, streams in tick_streams.items():
             fused_streams.setdefault(job_id, set()).update(streams)
 
+        trace_id = TRACER.current()
+
         def run_accumulate(item: tuple[_JobRecord, dict[str, Any]]) -> None:
-            rec, job_data = item
+            with TRACER.bind(trace_id):  # a pool thread has none of its own
+                accumulate(*item)
+
+        def accumulate(rec: _JobRecord, job_data: dict[str, Any]) -> None:
             skip_streams = fused_streams.get(rec.job.job_id, frozenset())
             job = rec.job
             # Deliver pending context in its own try: a failure keeps the

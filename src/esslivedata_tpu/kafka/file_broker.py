@@ -25,6 +25,7 @@ code paths.
 from __future__ import annotations
 
 import fcntl
+import os
 import logging
 import struct
 import time
@@ -195,31 +196,65 @@ class FileBrokerConsumer:
         self._rr %= len(topics)
         order = topics[self._rr:] + topics[: self._rr]
         self._rr = (self._rr + 1) % len(topics)
+        # One cut through all topics, taken at one instant, and no topic
+        # is read past it. Reading takes time (a burst of detector data
+        # is hundreds of MB): a topic read later would otherwise run
+        # ahead of one read earlier, and a window would close on its
+        # later pulse while the earlier topic's last pulse was still
+        # unread. A producer appends a pulse's messages in time order,
+        # so such a cut never holds a message without those before it.
+        sizes = self._cut(order)
         for topic in order:
             if len(out) >= num_messages:
                 break
             out.extend(
-                self._read_topic(topic, num_messages - len(out))
+                self._read_topic(
+                    topic, num_messages - len(out), sizes[topic]
+                )
             )
         return out
 
-    def _read_topic(self, topic: str, limit: int) -> list[FileMessage]:
-        path = _topic_path(self._root, topic)
+    def _cut(self, topics: list[str]) -> dict[str, int]:
+        """Every topic's size at one instant. An append holds its
+        topic's exclusive ``flock`` (both producers), so while a shared
+        lock is held on every topic none of them grows: sizes read one
+        after another with no lock are a millisecond apart (more when
+        the thread loses the GIL between them), which in a burst is a
+        monitor message and the next pulse's first detector message."""
+        held: dict[str, int] = {}
         try:
-            size = path.stat().st_size
-        except FileNotFoundError:
-            return []
+            for topic in topics:
+                try:
+                    fd = os.open(_topic_path(self._root, topic), os.O_RDONLY)
+                except FileNotFoundError:
+                    continue  # no such topic yet: nothing to read
+                held[topic] = fd
+                fcntl.flock(fd, fcntl.LOCK_SH)
+            return {
+                topic: os.fstat(held[topic]).st_size if topic in held else 0
+                for topic in topics
+            }
+        finally:
+            for fd in held.values():
+                os.close(fd)  # and with it the lock
+
+    def _read_topic(
+        self, topic: str, limit: int, size: int
+    ) -> list[FileMessage]:
+        """Up to ``limit`` whole frames that end at or before ``size``."""
         offset = self._offsets.get(topic, 0)
         if size <= offset:
             return []
         out: list[FileMessage] = []
-        with open(path, "rb") as f:
+        with open(_topic_path(self._root, topic), "rb") as f:
             f.seek(offset)
-            while len(out) < limit:
+            while len(out) < limit and offset + _HEADER.size <= size:
                 header = f.read(_HEADER.size)
                 if len(header) < _HEADER.size:
                     break
                 key_len, value_len = _HEADER.unpack(header)
+                if offset + _HEADER.size + key_len + value_len > size:
+                    break  # written after the cut: the next poll's
                 payload = f.read(key_len + value_len)
                 if len(payload) < key_len + value_len:
                     # Partial frame: a writer is mid-append; retry later.

@@ -122,6 +122,11 @@ class ConsumerHealth(Enum):
     STOPPED = "stopped"
 
 
+#: Full polls the worker waits through for the consumer to catch up
+#: (never more than the queue holds: a full queue drops its oldest).
+_HELD_BATCHES = 16
+
+
 class BackgroundMessageSource:
     """Daemon consume thread feeding a bounded drop-oldest batch queue.
 
@@ -146,6 +151,7 @@ class BackgroundMessageSource:
         self._max_messages = max_messages
         self._timeout_s = timeout_s
         self._queue: deque[list[KafkaMessage]] = deque(maxlen=max_queued_batches)
+        self._held_batches = min(_HELD_BATCHES, max_queued_batches)
         self._lock = threading.Lock()
         self._running = threading.Event()
         self._thread: threading.Thread | None = None
@@ -156,6 +162,11 @@ class BackgroundMessageSource:
         self._last_success = time.monotonic()
         self._dropped_batches = 0
         self._consumed_messages = 0
+        #: The newest consume filled its budget: the consumer is in the
+        #: middle of reading what the topics hold, and what is queued is
+        #: a cut through them that may hold one topic's later messages
+        #: without another's earlier ones (``get_messages`` waits).
+        self._mid_read = False
         # Next-consume offset per topic of everything HANDED TO the
         # worker (not merely consumed into the queue): the durability
         # plane's bookmark surface (ADR 0118). Updated under the queue
@@ -187,6 +198,8 @@ class BackgroundMessageSource:
         if self._thread is not None:
             self._thread.join(timeout=5.0)
             self._thread = None
+        with self._lock:
+            self._mid_read = False  # what is queued is all there will be
 
     def __enter__(self) -> "BackgroundMessageSource":
         self.start()
@@ -201,6 +214,8 @@ class BackgroundMessageSource:
             try:
                 batch = self._consumer.consume(self._max_messages, self._timeout_s)
             except Exception:
+                with self._lock:
+                    self._mid_read = False  # no read is under way: hand over
                 self._consecutive_errors += 1
                 logger.exception(
                     "Consume error (%d consecutive)", self._consecutive_errors
@@ -223,10 +238,12 @@ class BackgroundMessageSource:
                 None,
             )
             good = [m for m in batch if m.error() is None]
-            if good:
-                # Enqueue before opening the circuit: good messages consumed
-                # alongside a fatal error event must still reach the worker.
-                with self._lock:
+            with self._lock:
+                self._mid_read = len(batch) >= self._max_messages
+                if good:
+                    # Enqueue before opening the circuit: good messages
+                    # consumed alongside a fatal error event must still
+                    # reach the worker.
                     if len(self._queue) == self._queue.maxlen:
                         self._dropped_batches += 1
                     self._queue.append(good)
@@ -270,6 +287,15 @@ class BackgroundMessageSource:
         # queue is empty does the open circuit surface as an error.
         with self._lock:
             out: list[KafkaMessage] = []
+            # A consume that filled its budget stopped in the middle of
+            # the topics: the worker batches by data time across them,
+            # and a window must not close on one topic's later message
+            # while another's earlier ones are still unread. So what is
+            # queued is handed over once the consumer has caught up (a
+            # consume below its budget), or when it cannot (a backlog
+            # of ``_HELD_BATCHES`` full polls: progress before order).
+            if self._mid_read and len(self._queue) < self._held_batches and not self._broken:
+                return out
             while self._queue:
                 out.extend(self._queue.popleft())
             for message in out:

@@ -12,13 +12,18 @@ on host and swaps it in without stalling the stream.
 
 from __future__ import annotations
 
+import functools
 import itertools
+import time
+import weakref
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..telemetry.instruments import TABLE_BUILD_SECONDS, TABLE_BYTES
+from ..telemetry.trace import TRACER
 from .event_batch import EventBatch, stage_for, stage_raw
 
 __all__ = [
@@ -62,10 +67,31 @@ class PixelBinMap(NamedTuple):
     sequential space, and a globally-indexed table would be ~95% dead
     rows of device memory. ``table`` is int16 when the bin count fits
     (halving HBM for LOKI/DREAM-scale maps), int32 otherwise; -1 = drop.
+    ``family`` names the builder that made it, as the label of
+    ``livedata_table_bytes`` / ``livedata_table_build_seconds_total``.
     """
 
     table: np.ndarray
     id_base: int
+    family: str = "q"
+
+
+def _table_build(family: str):
+    """A map builder whose host time counts into
+    ``livedata_table_build_seconds_total{family}`` and whose map
+    carries the family's name."""
+
+    def wrap(build):
+        @functools.wraps(build)
+        def timed(**kwargs) -> PixelBinMap:
+            began = time.perf_counter()
+            built = build(**kwargs)._replace(family=family)
+            TABLE_BUILD_SECONDS.inc(time.perf_counter() - began, family=family)
+            return built
+
+        return timed
+
+    return wrap
 
 
 def _toa_centers_s(toa_edges: np.ndarray, toa_offset_ns: float) -> np.ndarray:
@@ -86,6 +112,7 @@ def _assemble_map(
     return PixelBinMap(table=table, id_base=id_base)
 
 
+@_table_build("sans_iq")
 def build_sans_qmap(
     *,
     positions: np.ndarray,  # [n_pixel, 3] in m, sample at origin, beam +z
@@ -133,6 +160,7 @@ def build_sans_qmap(
     return _assemble_map(pixel_ids, q_bin, len(q_edges) - 1)
 
 
+@_table_build("dspacing")
 def build_dspacing_map(
     *,
     two_theta: np.ndarray,  # [n_pixel] scattering angle (rad)
@@ -167,6 +195,7 @@ def build_dspacing_map(
     return _assemble_map(pixel_ids, d_bin, len(d_edges) - 1)
 
 
+@_table_build("qz")
 def build_qz_map(
     *,
     grazing_angle: np.ndarray,  # [n_pixel] incidence+reflection angle (rad)
@@ -209,6 +238,7 @@ def build_qz_map(
     return _assemble_map(pixel_ids, qz_bin, len(qz_edges) - 1)
 
 
+@_table_build("qe")
 def build_qe_map(
     *,
     two_theta: np.ndarray,  # [n_pixel] scattering angle (rad)
@@ -280,6 +310,7 @@ def build_qe_map(
     )
 
 
+@_table_build("wavelength")
 def build_wavelength_map(
     *,
     l_total: np.ndarray,  # [n_pixel] moderator->sample->pixel path (m)
@@ -317,6 +348,7 @@ def build_wavelength_map(
     return _assemble_map(pixel_ids, w_bin, len(wavelength_edges) - 1)
 
 
+@_table_build("elastic_q2d")
 def build_elastic_q2d_map(
     *,
     two_theta: np.ndarray,  # [n_pixel] scattering angle (rad)
@@ -424,21 +456,23 @@ def table_scatter_delta(
     with the VMEM one-hot kernel (ops/pallas_hist.py) instead of the
     serial scatter — every Q-family bin space fits its bound."""
     n_pix, n_toa = table.shape
-    tb = jnp.floor((toa - lo) * inv_width).astype(jnp.int32)
-    t_ok = (toa >= lo) & (toa < hi)
-    tb = jnp.clip(tb, 0, n_toa - 1)
-    local = pixel_id - id_base
-    p_ok = (local >= 0) & (local < n_pix)
-    pid = jnp.clip(local, 0, n_pix - 1)
-    qb = table[pid, tb].astype(jnp.int32)
-    ok = p_ok & t_ok & (qb >= 0)
-    qb = jnp.where(ok, qb, n_bins)  # OOB-high: dropped
-    if method == "pallas":
-        from .pallas_hist import bincount_pallas
+    with jax.named_scope("qmap_gather"):
+        tb = jnp.floor((toa - lo) * inv_width).astype(jnp.int32)
+        t_ok = (toa >= lo) & (toa < hi)
+        tb = jnp.clip(tb, 0, n_toa - 1)
+        local = pixel_id - id_base
+        p_ok = (local >= 0) & (local < n_pix)
+        pid = jnp.clip(local, 0, n_pix - 1)
+        qb = table[pid, tb].astype(jnp.int32)
+        ok = p_ok & t_ok & (qb >= 0)
+        qb = jnp.where(ok, qb, n_bins)  # OOB-high: dropped
+    with jax.named_scope("q_bincount"):
+        if method == "pallas":
+            from .pallas_hist import bincount_pallas
 
-        return bincount_pallas(qb, n_bins).astype(dtype)
-    delta = jnp.zeros((n_bins,), dtype=dtype)
-    return delta.at[qb].add(1.0, mode="drop")
+            return bincount_pallas(qb, n_bins).astype(dtype)
+        delta = jnp.zeros((n_bins,), dtype=dtype)
+        return delta.at[qb].add(1.0, mode="drop")
 
 
 #: Process-unique instance tokens for Q fuse keys: two histogrammers
@@ -483,9 +517,12 @@ class QHistogrammer:
         if method not in ("auto", "scatter", "pallas"):
             raise ValueError(f"Unknown method {method!r}")
         if method == "auto":
-            # Q-family bin spaces all fit the VMEM one-hot kernel, which
-            # measured 6x the serial scatter on v5e (PERF.md r5): take it
-            # whenever the bound holds on a TPU backend.
+            # Q-family bin spaces all fit the VMEM one-hot kernel: take
+            # it whenever the bound holds on a TPU backend. Per step of
+            # 4 Mi events from a 321 MB table into 100 bins on a v5e the
+            # table gather takes 48.2 ms and this kernel 2.4 ms (device
+            # time by scope over 522 steps; my chip run, PR 27, PERF.md
+            # section 5): the gather, not the bincount, is the step.
             from .pallas_hist import MAX_PALLAS_BINS
 
             method = (
@@ -508,12 +545,19 @@ class QHistogrammer:
             table, id_base = qmap.table, qmap.id_base
         else:
             table, id_base = np.asarray(qmap), 0
+        self._family = getattr(qmap, "family", "q")
         toa_edges = np.asarray(toa_edges, dtype=np.float64)
         if table.shape[1] != toa_edges.size - 1:
             raise ValueError("qmap toa axis must match toa_edges")
         if table.max(initial=-1) >= n_q:
             raise ValueError("qmap entries must be < n_q")
-        self._qmap = jnp.asarray(table)
+        self._install_table(table, wait=True)
+        # a swap keeps shape and dtype, so the bytes stand until the
+        # kernel goes (a stopped job releases its workflow)
+        TABLE_BYTES.inc(table.nbytes, family=self._family)
+        weakref.finalize(
+            self, TABLE_BYTES.dec, table.nbytes, family=self._family
+        )
         self._id_base = int(id_base)
         self._table_shape = table.shape
         self._n_q = int(n_q)
@@ -535,6 +579,18 @@ class QHistogrammer:
         self._step = jax.jit(self._step_impl, donate_argnums=(0,))
         self._step_fused = jax.jit(self._step_fused_impl, donate_argnums=(0,))
         self._clear_window = jax.jit(self._clear_window_impl, donate_argnums=(0,))
+
+    def _install_table(self, table: np.ndarray, *, wait: bool = False) -> None:
+        """Put ``table`` on the device as the live one; its placement
+        counts into the family's build seconds (with ``wait``, at
+        construction, the transfer too: part of what set-up costs)."""
+        began = time.perf_counter()
+        self._qmap = jnp.asarray(table)
+        if wait:
+            jax.block_until_ready(self._qmap)
+        TABLE_BUILD_SECONDS.inc(
+            time.perf_counter() - began, family=self._family
+        )
 
     @property
     def n_q(self) -> int:
@@ -721,7 +777,12 @@ class QHistogrammer:
         the Q-map itself rides as a jit argument, so the staged wire is
         layout-independent."""
         pixel_id, toa = stage_raw(batch, cache, batch_tag)
-        return self._step(state, self._qmap, pixel_id, toa, monitor_count)
+        # the dispatch alone (asynchronous, like ``tick_execute``): the
+        # staging above has its own ``h2d`` span
+        with TRACER.span("q_step"):
+            return self._step(
+                state, self._qmap, pixel_id, toa, monitor_count
+            )
 
     def swap_table(self, qmap: "np.ndarray | PixelBinMap") -> None:
         """Replace the bin table WITHOUT recompiling the step.
@@ -750,7 +811,7 @@ class QHistogrammer:
                 f"{self._table_shape}; rebuild the histogrammer for a "
                 "TOA-binning change"
             )
-        self._qmap = jnp.asarray(table)
+        self._install_table(table)
         # New table epoch: per-slice copies restage lazily and the
         # layout label moves. Deliberately NOT in any staging/fuse key —
         # the table is a jit argument (ADR 0105), so a same-shape swap

@@ -23,10 +23,13 @@ __all__ = [
     "DECODE_BYTES",
     "DECODE_ERRORS",
     "EVENTS_FILTERED",
+    "JOB_WINDOWS",
     "PUBLISH_RTT_SECONDS",
     "SINK_BYTES",
     "SINK_SECONDS",
     "STAGED_EVENTS",
+    "TABLE_BUILD_SECONDS",
+    "TABLE_BYTES",
     "TICK_GROUPS",
 ]
 
@@ -156,4 +159,39 @@ TICK_GROUPS = REGISTRY.counter(
     "Tick-program groups dispatched, by whether an earlier group of "
     "the same tick was still in flight (ahead) or not (alone)",
     labelnames=("dispatched",),
+)
+
+#: Which of the manager's three ways to step a job each window took
+#: (``JobManager.process_jobs``), one count per job per window that
+#: carried data for it: ``tick`` = stepped and published by its group's
+#: tick program, ``fused`` = stepped with its group in one dispatch and
+#: published apart, ``private`` = the workflow's own ``accumulate`` (a
+#: window that carries more than the job's one primary stream, such as
+#: the monitor events of every LOKI window). private / all is the
+#: benchmark's ``private_windows_share``.
+JOB_WINDOWS = REGISTRY.counter(
+    "livedata_job_windows_total",
+    "Job-windows stepped, by the path that stepped them "
+    "(tick, fused or private)",
+    labelnames=("path",),
+)
+
+#: The (pixel, TOA bin) -> bin tables resident beside the Q family's
+#: states (``ops/qhistogram.QHistogrammer``), by family: bytes as the
+#: host array holds them (the device's tiled layout may pad the TOA
+#: axis), summed over live tables. Set-up has no span, so what building
+#: them cost is the counter below: both change at construction and at
+#: ``swap_table`` only, and neither can be read by a windowed metric.
+TABLE_BYTES = REGISTRY.gauge(
+    "livedata_table_bytes",
+    "Bytes of precompiled event->bin tables resident on the device, "
+    "by kernel family",
+    labelnames=("family",),
+)
+
+TABLE_BUILD_SECONDS = REGISTRY.counter(
+    "livedata_table_build_seconds_total",
+    "Seconds spent building event->bin tables on the host and placing "
+    "them on the device (job start, swap_table), by kernel family",
+    labelnames=("family",),
 )

@@ -136,6 +136,47 @@ class TestEvaluation:
         assert slo_gate.evaluate_rule(rule, fams)[0] is green
         assert slo_gate.evaluate_rule(rule, parse_prometheus_text(COUNTERS))[0]
 
+    @pytest.mark.parametrize(
+        ("seconds", "green"), [((13.7, 41.5), True), ((80.0, 60.5), False)]
+    )
+    def test_default_rules_bound_the_table_builds(self, slo_gate, seconds, green):
+        """``livedata_table_build_seconds_total`` is the only reader of
+        what a Q job's tables cost at job start (set-up has no span):
+        the shipped gate sums the families and goes red past 120 s; a
+        service that hosts no Q job has no such family and passes."""
+        import json
+
+        rules = json.loads(
+            (REPO / "scripts" / "slo_rules" / "default.json").read_text()
+        )["rules"]
+        (rule,) = [r for r in rules if r["name"] == "table_build_bounded"]
+        assert rule["metric"] == "livedata_table_build_seconds_total"
+        fams = parse_prometheus_text(
+            "# HELP livedata_table_build_seconds_total build\n"
+            "# TYPE livedata_table_build_seconds_total counter\n"
+            f'livedata_table_build_seconds_total{{family="sans_iq"}} {seconds[0]}\n'
+            f'livedata_table_build_seconds_total{{family="qe"}} {seconds[1]}\n'
+        )
+        assert slo_gate.evaluate_rule(rule, fams)[0] is green
+        assert slo_gate.evaluate_rule(rule, parse_prometheus_text(COUNTERS))[0]
+        # A start-up gate (the rule's comment): the counter also grows
+        # at every swap_table and restart, so a long-lived service is
+        # gated over the increase since a baseline; bare, it reads red.
+        assert "--baseline" in rule["comment"]
+
+        def lifetime(sans_iq, qe):
+            return parse_prometheus_text(
+                "# TYPE livedata_table_build_seconds_total counter\n"
+                f'livedata_table_build_seconds_total{{family="sans_iq"}} {sans_iq}\n'
+                f'livedata_table_build_seconds_total{{family="qe"}} {qe}\n'
+            )
+
+        before = lifetime(400.0, 90.0)
+        after = lifetime(400.0 + seconds[0], 90.0 + seconds[1])
+        assert not slo_gate.evaluate_rule(rule, after)[0]
+        passed, observed, _ = slo_gate.evaluate_rule(rule, slo_gate.subtract(after, before))
+        assert passed is green and observed == pytest.approx(sum(seconds))
+
     def test_subtract_deltas_counters_keeps_gauges(self, slo_gate):
         before = parse_prometheus_text(COUNTERS)
         after_text = COUNTERS.replace(

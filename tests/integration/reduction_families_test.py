@@ -16,7 +16,7 @@ from .backend import (
 
 pytestmark = pytest.mark.integration
 
-PORT = 8941
+PORT = 8951  # its own: lifecycle_scenarios_test.py holds 8941 and may run beside it (xdist)
 H_OVER_MN = 3956.034
 
 
