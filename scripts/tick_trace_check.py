@@ -30,7 +30,15 @@ import sys
 import time
 from pathlib import Path
 
-SCOPES = ("scatter", "fold", "publish_reduce", "pack", "qmap_gather", "q_bincount")
+SCOPES = (
+    "scatter",
+    "fold",
+    "publish_reduce",
+    "pack",
+    "qmap_gather",
+    "qmap_sort",
+    "q_bincount",
+)
 # The profiler's own names for a TPU's plane and its two lines.
 DEVICE_PLANE = "/device:TPU:"
 OPS_LINE = "XLA Ops"

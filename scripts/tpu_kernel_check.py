@@ -8,8 +8,16 @@ Sections:
   1-D: bincount_pallas vs XLA scatter at monitor scale (1000 bins).
   2-D: scatter_add_pallas2d (bf16 + int8) vs XLA scatter at LOKI
        headline scale (1.5M px x 100 toa), incl. host partition rate.
+  lookup (``--lookup`` runs this section alone): the Q step's
+       ``table[pixel, TOA bin]`` at LOKI's shapes (802 816 x 200 and
+       172 032 x 200, a 4 Mi bucket holding 14 pulses of 229 376, the
+       cell's id distribution and every event in one pixel): the
+       windowed lookup of ops/pallas_lookup.py exact against the
+       gather, and ms a step for every rung of the ladder (PERF.md
+       section 6).
 """
 
+import functools
 import sys
 import time
 from pathlib import Path
@@ -19,9 +27,173 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 import numpy as np  # noqa: E402
 
 
+def _ms(fn, *args, repeats: int = 10) -> float:
+    """Milliseconds a call of the jitted ``fn``, device-resident."""
+    import jax
+
+    jax.block_until_ready(fn(*args))
+    t0 = time.perf_counter()
+    for _ in range(repeats):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    return (time.perf_counter() - t0) / repeats * 1e3
+
+
+def lookup_section(
+    n: int = 1 << 22,
+    n_valid: int = 14 * 229_376,
+    banks: tuple[int, ...] = (802_816, 172_032),
+) -> None:
+    """Parity and the ladder at LOKI's shapes (the defaults; a rehearsal
+    on the CPU passes small ones); raises on a mismatch."""
+    import jax
+    import jax.numpy as jnp
+
+    from esslivedata_tpu.ops import pallas_lookup
+    from esslivedata_tpu.ops.qhistogram import table_scatter_delta
+
+    n_toa, n_q = 200, 100
+    interpret = jax.default_backend() != "tpu"
+    rng = np.random.default_rng(28)
+    lo, hi = 0.0, 1e9 / 14
+
+    def staged(ids):
+        pid = np.full(n, -1, np.int32)
+        pid[:n_valid] = ids
+        toa = np.zeros(n, np.float32)
+        toa[:n_valid] = (rng.integers(0, n_toa, n_valid) + 0.5) * (
+            (hi - lo) / n_toa
+        )
+        return jax.device_put(pid), jax.device_put(toa)
+
+    for n_pix in banks:
+        table = rng.integers(-1, n_q, (n_pix, n_toa)).astype(np.int16)
+        assert pallas_lookup.packable(table, n_q)
+        dev = jax.device_put(table)
+        packed = pallas_lookup.pack_table(dev)
+        centre = 0.7 * n_pix
+        blob = np.rint(rng.normal(centre, n_pix / 8, n_valid)).astype(
+            np.int64
+        ) % n_pix
+        cases = {
+            "blob": staged(blob.astype(np.int32) + 1),
+            "one_pixel": staged(np.full(n_valid, n_pix // 3, np.int32)),
+        }
+
+        def delta(tbl, pid, toa, *, packed_shape=None):
+            return table_scatter_delta(
+                tbl, pid, toa, id_base=1, lo=lo, hi=hi,
+                inv_width=n_toa / (hi - lo), n_bins=n_q,
+                dtype=jnp.float32, method="pallas",
+                packed_shape=packed_shape,
+            )
+
+        step_gather = jax.jit(delta)
+        step_windowed = jax.jit(
+            functools.partial(delta, packed_shape=(n_pix, n_toa))
+        )
+        for name, (pid, toa) in cases.items():
+            want = np.asarray(step_gather(dev, pid, toa))
+            got = np.asarray(step_windowed(packed, pid, toa))
+            np.testing.assert_array_equal(got, want)
+            assert want.sum() > 0
+            print(
+                f"lookup {n_pix}x{n_toa} {name}: step parity OK; "
+                f"gather {_ms(step_gather, dev, pid, toa):.2f} ms a step, "
+                f"windowed {_ms(step_windowed, packed, pid, toa):.2f}",
+                flush=True,
+            )
+
+        # the ladder: the lookup alone, clipped indices as the step's
+        pid, toa = cases["blob"]
+        local = jnp.clip(pid - 1, 0, n_pix - 1)
+        tb = jnp.clip(
+            jnp.floor(toa * (n_toa / (hi - lo))).astype(jnp.int32),
+            0, n_toa - 1,
+        )
+        ok = pid >= 1
+        flat = local * n_toa + tb
+        flat_sorted = jnp.sort(flat)
+        dev1 = dev.reshape(-1)
+        dev32 = dev.astype(jnp.int32)
+        shift = pallas_lookup._toa_bits(packed.shape[0])
+        n_windows = packed.shape[1] // pallas_lookup.WINDOW
+        keys = jnp.where(ok, (local << shift) | tb, np.iinfo(np.int32).max)
+        keys_sorted = jnp.sort(keys)
+        rungs = {
+            "gather [pid, tb] int16": (lambda t, p, b: t[p, b], dev, local, tb),
+            "gather flat 1-D int16": (lambda t, f: t[f], dev1, flat),
+            "gather [pid, tb] int32": (lambda t, p, b: t[p, b], dev32, local, tb),
+            "gather packed [tb, pid] bf16": (
+                lambda t, p, b: t[b, p], packed, local, tb
+            ),
+            "gather flat sorted, indices_are_sorted": (
+                lambda t, f: t.at[f].get(
+                    indices_are_sorted=True, mode="promise_in_bounds"
+                ),
+                dev1, flat_sorted,
+            ),
+            "sort s32 keys (stable)": (jnp.sort, keys),
+            "sort s32 keys (unstable)": (
+                lambda k: jax.lax.sort(k, is_stable=False), keys
+            ),
+            "windowed lookup (sort + items + kernel)": (
+                pallas_lookup.lookup, packed, local, tb, ok,
+            ),
+            "windowed kernel + items, keys sorted": (
+                lambda t, k: pallas_lookup._lookup_sorted(
+                    t, k, shift, interpret
+                ),
+                packed, keys_sorted,
+            ),
+            "work items alone": (
+                lambda k: pallas_lookup._work_items(k, n_windows, shift),
+                keys_sorted,
+            ),
+        }
+        for name, (fn, *args) in rungs.items():
+            print(
+                f"lookup {n_pix}x{n_toa} rung {name}: "
+                f"{_ms(jax.jit(fn), *args):.2f} ms",
+                flush=True,
+            )
+        items = pallas_lookup._work_items(keys_sorted, n_windows, shift)
+        print(
+            f"lookup {n_pix}x{n_toa}: {int(items[2][0])} work items of "
+            f"{items[0].shape[0]} grid steps",
+            flush=True,
+        )
+
+        # the crossover: both paths of ``lookup`` on the packed table,
+        # by batch size (its two constants are read at trace time)
+        rule = pallas_lookup.MIN_EVENTS, pallas_lookup.EVENTS_PER_WINDOW
+        for log2 in range(14, 21):
+            m = min(1 << log2, n)
+            args = (packed, local[:m], tb[:m], ok[:m])
+            ms = {}
+            for kind, forced in (("gather", (n + 1, 0)), ("windowed", (0, 0))):
+                pallas_lookup.MIN_EVENTS, pallas_lookup.EVENTS_PER_WINDOW = forced
+                # a function of its own: jit's cache goes by function
+                ms[kind] = _ms(
+                    jax.jit(lambda *a: pallas_lookup.lookup(*a)), *args
+                )
+            pallas_lookup.MIN_EVENTS, pallas_lookup.EVENTS_PER_WINDOW = rule
+            print(
+                f"lookup {n_pix}x{n_toa} crossover n={m}: gather "
+                f"{ms['gather']:.3f} ms, windowed {ms['windowed']:.3f}, "
+                f"the rule takes {pallas_lookup.lookup_kind(m, n_pix)}",
+                flush=True,
+            )
+
+
 def main() -> None:
     import jax
     import jax.numpy as jnp
+
+    print("device:", jax.devices()[0], flush=True)
+    if "--lookup" in sys.argv[1:]:
+        lookup_section()
+        return
 
     from esslivedata_tpu.ops.pallas_hist import bincount_pallas
     from esslivedata_tpu.ops.pallas_hist2d import (
@@ -30,7 +202,6 @@ def main() -> None:
         scatter_add_pallas2d,
     )
 
-    print("device:", jax.devices()[0], flush=True)
     rng = np.random.default_rng(0)
     n = 1 << 22
 
@@ -127,6 +298,8 @@ def main() -> None:
         "device-resident",
         flush=True,
     )
+
+    lookup_section()
 
 
 if __name__ == "__main__":

@@ -1,0 +1,259 @@
+"""Pallas TPU table lookup without a random access per event.
+
+``qb = table[pixel, toa_bin]`` as an XLA gather costs ~12.4 ns an entry
+on a v5e wherever the entry lies (the per-entry issue of a random
+access, not HBM bytes): 52 ms for LOKI's 4 Mi-event step, 95 % of the
+chip's work there (PERF.md section 5). Dense work is 20 times cheaper
+per event and a key sort is cheap, so the lookup here is sort + dense
+windows:
+
+1. **Sort** one packed key per event, ``pixel << toa_bits | toa_bin``,
+   dropped events keyed to ``INT32_MAX`` so that they sort to the end.
+   Key only: the step's result is a histogram, so nothing is scattered
+   back.
+2. **Work items.** The sorted events are cut into blocks of ``BLOCK``
+   and the table's pixels into windows of ``WINDOW``. A block's first
+   and last valid key give the windows it touches; the list of
+   ``(block, window)`` items, at most ``n / BLOCK + n_pix / WINDOW``
+   long, is built by dense XLA ops in the same program and
+   scalar-prefetched (the ``chunk -> block`` map of ``pallas_hist2d``).
+3. **Per item, on the MXU**: ``table_window [n_toa, WINDOW]`` (bf16) ``@
+   onehot(pixel - window * WINDOW) [WINDOW, events]`` with float32
+   accumulation gives each event its table row; a one-hot over the TOA
+   bin selects the entry. A block's output accumulates across the items
+   that revisit it (an event's pixel lies in exactly one of them).
+   Items past the last valid event are skipped: padding costs nothing.
+
+The table lives on the device **packed** for this kernel: transposed,
+bfloat16 (exact for -1..256), TOA axis padded to the bf16 sublane tile
+and the pixel axis to whole windows: one resident copy, the same 2 B an
+entry. ``lookup`` reads that layout on both of its paths (a batch under
+the crossover gathers from it), so the choice is made per compiled
+shape from what the trace observes and the table never exists twice.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+__all__ = [
+    "BLOCK",
+    "EVENTS_PER_WINDOW",
+    "MAX_VALUE",
+    "MIN_EVENTS",
+    "WINDOW",
+    "lookup",
+    "lookup_kind",
+    "pack_table",
+    "packable",
+]
+
+#: Events per block: 8 sublane rows of BLOCK / 8 lanes, so that the
+#: sorted keys and the output are dense (8, 128) tiles in HBM.
+BLOCK = 1024
+#: Pixels per table window (the one-hot's contraction length).
+WINDOW = 128
+#: Largest table value bfloat16 holds exactly next to -1 (8 bits of
+#: mantissa): the packed layout takes tables whose bin count stays
+#: under it.
+MAX_VALUE = 256
+#: The crossover against the XLA gather, measured on a v5e with LOKI's
+#: tables and id distribution (scripts/tpu_kernel_check.py --lookup; my
+#: chip run, PR 28, PERF.md section 6): the gather costs 13 ns an event,
+#: the windows 0.5 us an item (most of a table's windows are touched
+#: whatever the batch) plus ~1.4 ns an event of sort and blocks. For the
+#: 802 816-pixel bank (6 272 windows) the two meet at 2**18 events (3.40
+#: against 3.32 ms), for a 172 032-pixel bank (1 344) at 2**16 (0.75
+#: against 0.77): 40-50 events a window. Under 2**16 nothing was
+#: measured ahead, so the gather stays.
+EVENTS_PER_WINDOW = 48
+MIN_EVENTS = 1 << 16
+
+_SENTINEL = np.iinfo(np.int32).max
+_SUBLANES = 8
+_BF16_ROWS = 16
+
+
+def _toa_bits(n_toa_padded: int) -> int:
+    """Bits of a packed key that hold the TOA bin."""
+    return (n_toa_padded - 1).bit_length()
+
+
+def _padded(n: int, to: int) -> int:
+    return -(-n // to) * to
+
+
+def packable(table: np.ndarray, n_bins: int) -> bool:
+    """Whether ``table`` may live packed: int16 (the builders' choice
+    for small bin spaces), every value and the bin count exact in
+    bfloat16, and a packed key per event inside int32."""
+    n_pix, n_toa = table.shape
+    return (
+        table.dtype == np.int16
+        and n_bins + 1 <= MAX_VALUE
+        and _padded(n_pix, WINDOW) << _toa_bits(_padded(n_toa, _BF16_ROWS))
+        < _SENTINEL
+    )
+
+
+@jax.jit
+def pack_table(table: jax.Array) -> jax.Array:
+    """int16 ``[n_pix, n_toa]`` -> the packed layout, bfloat16
+    ``[n_toa padded to 16, n_pix padded to WINDOW]``. Padding holds 0
+    and is never selected (a valid event's pixel and TOA bin lie inside
+    the table); it must be finite, since the MXU multiplies it by 0."""
+    n_pix, n_toa = table.shape
+    packed = table.T.astype(jnp.bfloat16)
+    return jnp.pad(
+        packed,
+        (
+            (0, _padded(n_toa, _BF16_ROWS) - n_toa),
+            (0, _padded(n_pix, WINDOW) - n_pix),
+        ),
+    )
+
+
+def lookup_kind(n_events: int, n_pix: int) -> str:
+    """The path ``lookup`` takes for a batch of ``n_events`` on a packed
+    table of ``n_pix`` pixels: ``'windowed'`` or ``'gather'`` (the label
+    of ``livedata_q_lookup_steps_total``)."""
+    n_windows = -(-n_pix // WINDOW)
+    dense = n_events >= max(MIN_EVENTS, EVENTS_PER_WINDOW * n_windows)
+    return "windowed" if dense else "gather"
+
+
+def _work_items(keys: jax.Array, n_windows: int, shift: int):
+    """``(block, window, n_items)`` of the sorted ``keys``: per item its
+    event block and table window, int32 ``[n / BLOCK + n_windows]``;
+    entries from ``n_items`` on repeat the last item, so that a skipped
+    grid step moves no block. Dense ops only: a gather here would pay
+    the per-entry price the kernel exists to avoid."""
+    n_blocks = keys.shape[0] // BLOCK
+    by_block = keys.reshape(n_blocks, BLOCK)
+    first = by_block[:, 0]
+    last = jnp.max(jnp.where(by_block == _SENTINEL, -1, by_block), axis=1)
+    w_lo = (first >> shift) // WINDOW
+    w_hi = (last >> shift) // WINDOW
+    count = jnp.where(last >= 0, w_hi - w_lo + 1, 0)
+    ends = jnp.cumsum(count)
+    n_items = ends[-1]
+    j = jnp.minimum(
+        jnp.arange(n_blocks + n_windows, dtype=jnp.int32),
+        jnp.maximum(n_items - 1, 0),
+    )
+    # block(j) = how many blocks end at or before item j; window(j) =
+    # j + g(block(j)) with g(b) = w_lo[b] - starts[b], summed from its
+    # differences under the same comparison
+    before = ends[None, :] <= j[:, None]
+    g = w_lo - (ends - count)
+    dg = jnp.diff(g, append=g[-1:])
+    block = jnp.sum(before, axis=1, dtype=jnp.int32)
+    window = j + g[0] + jnp.sum(jnp.where(before, dg[None, :], 0), axis=1)
+    return (
+        jnp.minimum(block, n_blocks - 1),
+        jnp.clip(window, 0, n_windows - 1).astype(jnp.int32),
+        n_items.astype(jnp.int32).reshape(1),
+    )
+
+
+def _lookup_sorted(packed, keys, shift: int, interpret: bool):
+    """Table value per sorted key, float32 ``[n]``; what a dropped key
+    (``INT32_MAX``) reads is undefined."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    n_toa_p, n_pix_p = packed.shape
+    n_blocks = keys.shape[0] // BLOCK
+    lanes = BLOCK // _SUBLANES
+    block, window, n_items = _work_items(keys, n_pix_p // WINDOW, shift)
+    toa_mask = (1 << shift) - 1
+
+    def kernel(block_ref, window_ref, n_ref, keys_ref, table_ref, out_ref):
+        j = pl.program_id(0)
+
+        @pl.when(j < n_ref[0])
+        def _item():
+            b = block_ref[j]
+
+            @pl.when((j == 0) | (b != block_ref[jnp.maximum(j - 1, 0)]))
+            def _first_visit():
+                out_ref[...] = jnp.zeros_like(out_ref)
+
+            base = window_ref[j] * WINDOW
+            table = table_ref[...]
+            pixels = jax.lax.broadcasted_iota(jnp.int32, (WINDOW, lanes), 0)
+            toas = jax.lax.broadcasted_iota(jnp.int32, (n_toa_p, lanes), 0)
+            # Static unroll over the 8 sublane rows, each loaded
+            # straight from the ref (as in pallas_hist): events lie
+            # along the lanes, so both one-hots are sublane broadcasts
+            # and the selected entry is a sum over sublanes.
+            for s in range(_SUBLANES):
+                key = keys_ref[0, s : s + 1, :]  # [1, lanes]
+                in_window = (pixels == (key >> shift) - base).astype(
+                    jnp.bfloat16
+                )
+                rows = jnp.dot(
+                    table, in_window, preferred_element_type=jnp.float32
+                )  # [n_toa_p, lanes]: each event's table row
+                out_ref[0, s : s + 1, :] += jnp.sum(
+                    jnp.where(toas == (key & toa_mask), rows, 0.0),
+                    axis=0,
+                    keepdims=True,
+                )
+
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(block.shape[0],),
+            in_specs=[
+                pl.BlockSpec(
+                    (1, _SUBLANES, lanes), lambda j, b, w, n: (b[j], 0, 0)
+                ),
+                pl.BlockSpec((n_toa_p, WINDOW), lambda j, b, w, n: (0, w[j])),
+            ],
+            out_specs=pl.BlockSpec(
+                (1, _SUBLANES, lanes), lambda j, b, w, n: (b[j], 0, 0)
+            ),
+        ),
+        out_shape=jax.ShapeDtypeStruct(
+            (n_blocks, _SUBLANES, lanes), jnp.float32
+        ),
+        interpret=interpret,
+        name="lookup_windowed",
+    )(block, window, n_items, keys.reshape(n_blocks, _SUBLANES, lanes), packed)
+    return out.reshape(-1)
+
+
+def lookup(
+    packed: jax.Array,
+    pid: jax.Array,
+    tb: jax.Array,
+    ok: jax.Array,
+    *,
+    interpret: bool | None = None,
+) -> jax.Array:
+    """``table[pid, tb]`` as int32 ``[n]`` from the packed table, -1
+    where ``ok`` is false. Traceable. ``pid`` and ``tb`` lie inside the
+    table (the caller clips them). **On the windowed path the values
+    come in sorted-key order, not the events'**: the consumer is a
+    histogram."""
+    n = pid.shape[0]
+    if lookup_kind(n, packed.shape[1]) == "gather":
+        return jnp.where(ok, packed[tb, pid].astype(jnp.int32), -1)
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    shift = _toa_bits(packed.shape[0])
+    keys = jnp.where(ok, (pid << shift) | tb, _SENTINEL)
+    pad = (-n) % BLOCK
+    if pad:
+        keys = jnp.concatenate([keys, jnp.full((pad,), _SENTINEL, jnp.int32)])
+    with jax.named_scope("qmap_sort"):
+        # equal keys are one entry: a stable sort would carry an index
+        # along (five times the compile, twice the code on a v5e)
+        keys = jax.lax.sort(keys, is_stable=False)
+    values = _lookup_sorted(packed, keys, shift, bool(interpret))
+    # select before the cast: an unvisited block holds whatever was there
+    return jnp.where(keys != _SENTINEL, values, -1.0).astype(jnp.int32)[:n]

@@ -22,7 +22,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..telemetry.instruments import TABLE_BUILD_SECONDS, TABLE_BYTES
+from ..telemetry.instruments import (
+    Q_LOOKUP_STEPS,
+    TABLE_BUILD_SECONDS,
+    TABLE_BYTES,
+)
 from ..telemetry.trace import TRACER
 from .event_batch import EventBatch, stage_for, stage_raw
 
@@ -447,6 +451,7 @@ def table_scatter_delta(
     n_bins: int,
     dtype,
     method: str = "scatter",
+    packed_shape: tuple[int, int] | None = None,
 ):
     """Traceable event -> bin-delta core shared by the single-device and
     table-sharded kernels: TOA binning, bank-local id shift, table
@@ -454,8 +459,12 @@ def table_scatter_delta(
     ``id_base`` may be a traced value (the sharded kernel derives it
     from the shard index). ``method='pallas'`` accumulates the delta
     with the VMEM one-hot kernel (ops/pallas_hist.py) instead of the
-    serial scatter — every Q-family bin space fits its bound."""
-    n_pix, n_toa = table.shape
+    serial scatter — every Q-family bin space fits its bound.
+    ``packed_shape`` says that ``table`` is in the packed layout of
+    ops/pallas_lookup.py and gives the ``(n_pix, n_toa)`` it stands
+    for: the lookup is then that module's (sort + dense windows at or
+    above its crossover, the gather below it)."""
+    n_pix, n_toa = table.shape if packed_shape is None else packed_shape
     with jax.named_scope("qmap_gather"):
         tb = jnp.floor((toa - lo) * inv_width).astype(jnp.int32)
         t_ok = (toa >= lo) & (toa < hi)
@@ -463,8 +472,16 @@ def table_scatter_delta(
         local = pixel_id - id_base
         p_ok = (local >= 0) & (local < n_pix)
         pid = jnp.clip(local, 0, n_pix - 1)
-        qb = table[pid, tb].astype(jnp.int32)
-        ok = p_ok & t_ok & (qb >= 0)
+        if packed_shape is None:
+            qb = table[pid, tb].astype(jnp.int32)
+            ok = p_ok & t_ok & (qb >= 0)
+        else:
+            from .pallas_lookup import lookup
+
+            # -1 for a dropped event; in sorted-key order at or above
+            # the crossover, which a histogram does not see
+            qb = lookup(table, pid, tb, p_ok & t_ok)
+            ok = qb >= 0
         qb = jnp.where(ok, qb, n_bins)  # OOB-high: dropped
     with jax.named_scope("q_bincount"):
         if method == "pallas":
@@ -519,10 +536,13 @@ class QHistogrammer:
         if method == "auto":
             # Q-family bin spaces all fit the VMEM one-hot kernel: take
             # it whenever the bound holds on a TPU backend. Per step of
-            # 4 Mi events from a 321 MB table into 100 bins on a v5e the
-            # table gather takes 48.2 ms and this kernel 2.4 ms (device
-            # time by scope over 522 steps; my chip run, PR 27, PERF.md
-            # section 5): the gather, not the bincount, is the step.
+            # 4 Mi events from a 321 MB table into 100 bins on a v5e
+            # this kernel takes 2.4 ms and XLA's table gather took 52.0
+            # (device time by scope; my chip run, PR 27, PERF.md section
+            # 5): the lookup was the step. Sorted and read window by
+            # window (ops/pallas_lookup.py, below) it takes 6.9 ms, the
+            # whole step 9.3 against 61.8 on one chip (my chip run,
+            # PR 28, PERF.md section 6).
             from .pallas_hist import MAX_PALLAS_BINS
 
             method = (
@@ -551,13 +571,24 @@ class QHistogrammer:
             raise ValueError("qmap toa axis must match toa_edges")
         if table.max(initial=-1) >= n_q:
             raise ValueError("qmap entries must be < n_q")
+        # The table's layout on the device follows what this kernel can
+        # observe, like ``method='auto'``: packed for the dense lookup
+        # of ops/pallas_lookup.py where the backend is a TPU and the
+        # values are exact there, the host's int table otherwise. The
+        # module is imported here, inside the Q path, as
+        # ``bincount_pallas`` is: a service that steps no Q kernel (and
+        # any run on the CPU) never loads it.
+        self._packed = False
+        if jax.default_backend() == "tpu":
+            from .pallas_lookup import packable
+
+            self._packed = packable(table, n_q)
         self._install_table(table, wait=True)
-        # a swap keeps shape and dtype, so the bytes stand until the
+        # a swap keeps shape and layout, so the bytes stand until the
         # kernel goes (a stopped job releases its workflow)
-        TABLE_BYTES.inc(table.nbytes, family=self._family)
-        weakref.finalize(
-            self, TABLE_BYTES.dec, table.nbytes, family=self._family
-        )
+        nbytes = self._qmap.nbytes
+        TABLE_BYTES.inc(nbytes, family=self._family)
+        weakref.finalize(self, TABLE_BYTES.dec, nbytes, family=self._family)
         self._id_base = int(id_base)
         self._table_shape = table.shape
         self._n_q = int(n_q)
@@ -586,6 +617,11 @@ class QHistogrammer:
         construction, the transfer too: part of what set-up costs)."""
         began = time.perf_counter()
         self._qmap = jnp.asarray(table)
+        if self._packed:
+            from .pallas_lookup import pack_table
+
+            # one pass on the device; the int16 copy goes with the call
+            self._qmap = pack_table(self._qmap)
         if wait:
             jax.block_until_ready(self._qmap)
         TABLE_BUILD_SECONDS.inc(
@@ -618,6 +654,7 @@ class QHistogrammer:
             n_bins=self._n_q,
             dtype=self._dtype,
             method=self._method,
+            packed_shape=self._table_shape if self._packed else None,
         )
         mc = jnp.asarray(monitor_count, dtype=self._dtype)
         return QState(
@@ -674,6 +711,8 @@ class QHistogrammer:
             self._n_q,
             np.dtype(self._dtype).str,
             self._method,
+            self._table_shape,
+            self._packed,
         )
 
     def _qmap_for(self, device):
@@ -688,6 +727,17 @@ class QHistogrammer:
             cached = stage_for(self._qmap, device)
             self._qmap_by_device[token] = cached
         return cached
+
+    def _count_step(self, n_events: int) -> None:
+        """One ``livedata_q_lookup_steps_total`` count for a step of
+        ``n_events`` staged events, at its dispatch, by the lookup its
+        program was traced with."""
+        lookup = "gather"
+        if self._packed:
+            from .pallas_lookup import lookup_kind
+
+            lookup = lookup_kind(n_events, self._table_shape[0])
+        Q_LOOKUP_STEPS.inc(lookup=lookup)
 
     def stage_events(
         self,
@@ -723,6 +773,7 @@ class QHistogrammer:
         (ADR 0105) — never a retrace of the tick program."""
         kwargs = {} if device is None else {"device": device}
         pid, toa = stage_raw(batch, cache, batch_tag, **kwargs)
+        self._count_step(pid.shape[0])
         return (self._qmap_for(device), pid, toa)
 
     def tick_step(self, states, *staged):
@@ -757,6 +808,7 @@ class QHistogrammer:
             return ()
         kwargs = {} if device is None else {"device": device}
         pid, toa = stage_raw(batch, cache, batch_tag, **kwargs)
+        self._count_step(pid.shape[0])
         return self._step_fused(
             states, self._qmap_for(device), pid, toa, monitor_count
         )
@@ -780,6 +832,7 @@ class QHistogrammer:
         # the dispatch alone (asynchronous, like ``tick_execute``): the
         # staging above has its own ``h2d`` span
         with TRACER.span("q_step"):
+            self._count_step(pixel_id.shape[0])
             return self._step(
                 state, self._qmap, pixel_id, toa, monitor_count
             )

@@ -25,6 +25,7 @@ __all__ = [
     "EVENTS_FILTERED",
     "JOB_WINDOWS",
     "PUBLISH_RTT_SECONDS",
+    "Q_LOOKUP_STEPS",
     "SINK_BYTES",
     "SINK_SECONDS",
     "STAGED_EVENTS",
@@ -177,11 +178,13 @@ JOB_WINDOWS = REGISTRY.counter(
 )
 
 #: The (pixel, TOA bin) -> bin tables resident beside the Q family's
-#: states (``ops/qhistogram.QHistogrammer``), by family: bytes as the
-#: host array holds them (the device's tiled layout may pad the TOA
-#: axis), summed over live tables. Set-up has no span, so what building
-#: them cost is the counter below: both change at construction and at
-#: ``swap_table`` only, and neither can be read by a windowed metric.
+#: states (``ops/qhistogram.QHistogrammer``), by family: bytes of the
+#: array the kernel keeps (the host's int table, or the packed layout
+#: of ``ops/pallas_lookup.py`` with its padding; the device's tiling
+#: may pad further), summed over live tables. Set-up has no span, so
+#: what building them cost is the counter below: both change at
+#: construction and at ``swap_table`` only, and neither can be read by a
+#: windowed metric.
 TABLE_BYTES = REGISTRY.gauge(
     "livedata_table_bytes",
     "Bytes of precompiled event->bin tables resident on the device, "
@@ -194,4 +197,17 @@ TABLE_BUILD_SECONDS = REGISTRY.counter(
     "Seconds spent building event->bin tables on the host and placing "
     "them on the device (job start, swap_table), by kernel family",
     labelnames=("family",),
+)
+
+#: How each Q step looked its events up in the table
+#: (``ops/qhistogram.QHistogrammer``), one count per step at its
+#: dispatch: ``windowed`` = sort + dense windows on the MXU
+#: (``ops/pallas_lookup.py``: a packed table and a batch at or above
+#: its crossover), ``gather`` = XLA's element gather (everything else).
+#: windowed / both is the benchmark's ``q_lookup_windowed_share``.
+Q_LOOKUP_STEPS = REGISTRY.counter(
+    "livedata_q_lookup_steps_total",
+    "Q-histogram steps dispatched, by how the (pixel, TOA bin) table "
+    "was read (windowed or gather)",
+    labelnames=("lookup",),
 )
