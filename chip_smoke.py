@@ -58,7 +58,6 @@ sys.path.insert(0, str(SRC))
 PULSE_PERIOD_NS = 1e9 / 14
 WINDOW_PULSES = 14  # the batchers' 1 s base window
 MAX_WINDOW_SCALE = 8  # AdaptiveMessageBatcher's escalation cap
-MAX_PUBLISH_COALESCE = 8  # LinkMonitor's publish-coalescing cap
 TOA_BINS = 100  # DetectorViewParams / MonitorParams default
 TOA_EDGES = np.linspace(0.0, PULSE_PERIOD_NS, TOA_BINS + 1)
 #: Detector pulses per phase: >= 4 windows even if the batcher escalates
@@ -431,9 +430,7 @@ class PulseFeed:
 
     A window closes when data time moves past it, so after the data
     comes a *closing pulse*: nothing but out-of-range events, far enough
-    ahead to close even a fully escalated window. ``nudge`` sends one
-    more, which closes the (empty) window the previous one sits in — how
-    a publish tick that the link policy coalesced away is rolled in.
+    ahead to close even a fully escalated window.
     """
 
     #: A closing pulse lands beyond the widest window the batcher can
@@ -446,10 +443,9 @@ class PulseFeed:
         self._child, self._topic, self._source = child, topic, source
         self._n_pulses = n_pulses
         self._make_events, self._closing_events = make_events, closing_events
-        span = n_pulses + (MAX_PUBLISH_COALESCE + 2) * self.CLOSING_STRIDE
+        span = n_pulses + 2 * self.CLOSING_STRIDE
         self._base = Timestamp.now().pulse_index() - span
         self._next = 0
-        self._nudges = 0
 
     def _produce(self, pulse: int, ids, toa) -> None:
         from esslivedata_tpu.core.timestamp import Timestamp
@@ -475,16 +471,9 @@ class PulseFeed:
 
     def finish(self) -> None:
         self.send(self._n_pulses)
-        self.nudge()
-
-    def nudge(self) -> None:
-        """One more closing pulse, up to the coalescing cap's worth."""
-        if self._nudges <= MAX_PUBLISH_COALESCE:
-            self._nudges += 1
-            self._produce(
-                self._n_pulses + self._nudges * self.CLOSING_STRIDE,
-                *self._closing_events,
-            )
+        self._produce(
+            self._n_pulses + self.CLOSING_STRIDE, *self._closing_events
+        )
 
 
 class ResultReader:
@@ -519,27 +508,21 @@ class ResultReader:
 
 
 def await_total(
-    child, reader, configs, expected: int, output="counts_cumulative", on_stall=None
+    child, reader, configs, expected: int, output="counts_cumulative"
 ):
-    """Wait until every job's latest ``output`` sums to ``expected``.
-    ``on_stall`` is called whenever no result has arrived for 2 s."""
+    """Wait until every job's latest ``output`` sums to ``expected``."""
     jobs = [str(c.job_id.job_number) for c in configs]
-    last_change = time.monotonic()
     last_seen = None
 
     def probe():
-        nonlocal last_change, last_seen
+        nonlocal last_seen
         reader.drain()
         totals = [reader.total(job, output) for job in jobs]
         if any(t is not None and t > expected for t in totals):
             raise SmokeFailure(
                 f"{output} overshot the reference {expected}: {totals}"
             )
-        if totals != last_seen:
-            last_seen, last_change = totals, time.monotonic()
-        elif on_stall is not None and time.monotonic() - last_change > 2.0:
-            on_stall()
-            last_change = time.monotonic()
+        last_seen = totals
         return all(t == expected for t in totals)
 
     try:
@@ -592,20 +575,10 @@ def await_clean_heartbeats(child: ServiceChild, configs) -> int:
     return seen
 
 
-def publish_coalesce(parsed: dict) -> int:
-    """The link policy's latched publish-coalescing width (1 = every
-    window publishes; the family exists only under --pipeline)."""
-    if "livedata_link_policy" not in parsed:
-        return 1
-    return int(metric(parsed, "livedata_link_policy", axis="publish_coalesce"))
-
-
 def check_counters(child, n_publishes, step_executes_mark, hbm_floor):
     """The tick program served, nothing was lost, the state is on the
-    device. Returns (scrape, report, documented exceptions that fired)."""
+    device. Returns (scrape, report)."""
     parsed = child.scrape()
-    exceptions = []
-    coalesce = publish_coalesce(parsed)
     ticks = metric(parsed, "livedata_publish_events", kind="tick_publishes")
     if ticks < n_publishes:
         raise SmokeFailure(
@@ -614,15 +587,9 @@ def check_counters(child, n_publishes, step_executes_mark, hbm_floor):
         )
     steps = metric(parsed, "livedata_publish_events", kind="step_executes")
     if steps > step_executes_mark:
-        if coalesce <= 1:
-            raise SmokeFailure(
-                f"step_executes grew {step_executes_mark:.0f} -> {steps:.0f} "
-                "after the first window: separate step dispatches are running"
-            )
-        exceptions.append(
-            f"coalesced windows: the link policy latched publish_coalesce="
-            f"{coalesce}, so windows between publish ticks stepped without "
-            f"publishing (step_executes {step_executes_mark:.0f} -> {steps:.0f})"
+        raise SmokeFailure(
+            f"step_executes grew {step_executes_mark:.0f} -> {steps:.0f} "
+            "after the first window: separate step dispatches are running"
         )
     for family in (
         "livedata_state_lost",
@@ -647,10 +614,9 @@ def check_counters(child, n_publishes, step_executes_mark, hbm_floor):
     report = {
         "tick_publishes": ticks,
         "step_executes": steps,
-        "publish_coalesce": coalesce,
         "hbm_bytes_in_use": in_use,
     }
-    return parsed, report, exceptions
+    return parsed, report
 
 
 def await_device(child: ServiceChild, allow_cpu: bool) -> dict:
@@ -751,16 +717,7 @@ def run_detector_phase(child, dep, seed, allow_cpu, wrong_reference):
     feed.finish()
     if wrong_reference:
         reference.image[0] += 1
-
-    def roll_coalesced_tick():
-        # Everything is accumulated but the policy holds the publish
-        # for a later window: roll (empty) windows in until it ticks.
-        if publish_coalesce(child.scrape()) > 1:
-            feed.nudge()
-
-    await_total(
-        child, reader, configs, reference.counts, on_stall=roll_coalesced_tick
-    )
+    await_total(child, reader, configs, reference.counts)
     for job, method in zip(jobs, methods, strict=True):
         for output, want in (
             ("image_cumulative", reference.image),
@@ -775,17 +732,17 @@ def run_detector_phase(child, dep, seed, allow_cpu, wrong_reference):
                 reader.latest[(jobs[0], output)],
             )
     n_publishes = min(reader.publishes[job] for job in jobs)
-    parsed, counters, exceptions = check_counters(
+    parsed, counters = check_counters(
         child,
         n_publishes,
         step_executes_mark,
         # The CPU client reports no memory statistics (--allow-cpu).
         dep.min_hbm_bytes if device["platform"] == "tpu" else None,
     )
-    if n_publishes < 4 and counters["publish_coalesce"] <= 1:
+    if n_publishes < 4:
         raise SmokeFailure(f"only {n_publishes} windows published, need >= 4")
     heartbeats = await_clean_heartbeats(child, configs)
-    return device, exceptions, {
+    return device, {
         "events": N_PULSES * dep.events_per_pulse,
         "events_in_range": reference.counts,
         "windows_published": n_publishes,
@@ -827,13 +784,13 @@ def run_monitor_phase(child, dep, seed, allow_cpu, wrong_reference):
     job = str(config.job_id.job_number)
     check_equal("monitor cumulative", reader.latest[(job, "cumulative")], expected)
     n_publishes = reader.publishes.get(job, 0)
-    parsed, counters, exceptions = check_counters(
+    parsed, counters = check_counters(
         child, n_publishes, float("inf"), None
     )
     if n_publishes < 4:
         raise SmokeFailure(f"only {n_publishes} windows published, need >= 4")
     heartbeats = await_clean_heartbeats(child, [config])
-    return device, exceptions, {
+    return device, {
         "events": int(n_pulses * n),
         "events_in_range": int(expected.sum()),
         "windows_published": n_publishes,
@@ -929,9 +886,9 @@ def main(argv=None) -> int:
         )
         t0 = time.monotonic()
         failure = None
-        report, exceptions = {}, []
+        report = {}
         try:
-            device, exceptions, report = run(
+            device, report = run(
                 child, dep, args.seed, args.allow_cpu, args.inject_wrong_reference
             )
             devices.append(device)
@@ -949,8 +906,6 @@ def main(argv=None) -> int:
             **report,
         }
         print(f"chip_smoke: {json.dumps(report)}", flush=True)
-        for exception in exceptions:
-            print(f"chip_smoke: documented exception in {name}: {exception}")
         if failure is not None:
             print(f"chip_smoke: FAILED in phase {name}: {failure}")
             print(f"--- tail of {child.log_path} ---\n{child.log_tail()}")

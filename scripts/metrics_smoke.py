@@ -56,7 +56,6 @@ RELAY_METRICS_PORT = int(
 REQUIRED_FAMILIES = (
     "livedata_publish_events",
     "livedata_publish_slice_events",
-    "livedata_publish_rtt_seconds",
     "livedata_jit_compiles_total",
     "livedata_jit_compile_seconds",
     "livedata_tick_span_seconds",

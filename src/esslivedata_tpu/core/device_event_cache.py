@@ -312,9 +312,8 @@ class StreamStageSlot:
                 raise
             finally:
                 entry.event.set()
-            # Real staging timings are the link monitor's only probe
-            # (ADR 0111): wall time of the flatten+dispatch against the
-            # bytes it moved, measured where the work actually happens.
+            # Wall time of the flatten+dispatch against the bytes it
+            # moved, measured where the work actually happens.
             self._cache._record_miss(
                 _staged_nbytes(entry.value), time.perf_counter() - t0
             )
@@ -397,10 +396,6 @@ class DeviceEventCache:
         self._cum_misses = 0
         self._cum_bytes_staged = 0
         self._cum_staging_s = 0.0
-        #: Optional core.link_monitor.LinkMonitor (duck-typed:
-        #: ``observe_staging(nbytes, seconds)``) fed from real staging
-        #: timings — the pipelined ingest attaches it (ADR 0111).
-        self.link_observer: Any = None
 
     # -- window lifecycle -------------------------------------------------
     def new_generation(self) -> WindowGeneration:
@@ -441,14 +436,6 @@ class DeviceEventCache:
             self._cum_misses += 1
             self._cum_bytes_staged += nbytes
             self._cum_staging_s += seconds
-        observer = self.link_observer
-        if observer is not None:
-            try:
-                observer.observe_staging(nbytes, seconds)
-            except Exception:
-                # The estimate is advisory; a broken observer must not
-                # take staging down — but it should be visible.
-                logger.debug("link observer failed", exc_info=True)
 
     def _record_hit(self) -> None:
         with self._stats_lock:

@@ -52,10 +52,6 @@ drops, no reorders — pinned by tests/core/ingest_pipeline_test.py), and
 joins the workers. A worker failure latches the exception and re-raises
 it on the service thread at the next submit, preserving the serial
 loop's fail-fast supervisor contract (core/service.py).
-
-The pipeline depth adapts to the link (``core/link_monitor.py``): a
-degraded or high-RTT link runs deeper (keep the transfer stage fed), a
-healthy one shallower (latency).
 """
 
 from __future__ import annotations
@@ -71,7 +67,6 @@ from typing import Any, Callable
 from ..telemetry.e2e import observe_stage
 from ..telemetry.trace import TRACER
 from ..utils.profiling import StageTimer
-from .link_monitor import LinkMonitor, LinkPolicy
 
 __all__ = ["IngestPipeline", "PipelineWindow"]
 
@@ -95,7 +90,6 @@ class PipelineWindow:
     context: dict[str, Any] = field(default_factory=dict)
     fresh_context: set[str] | None = None
     generation: Any = None  # WindowGeneration, attached by the stage stage
-    policy: LinkPolicy | None = None
     results: list = field(default_factory=list)
     #: Wall seconds per stage for THIS window (the completion callback's
     #: load signal: the slowest stage is the pipeline's service time).
@@ -139,21 +133,13 @@ class IngestPipeline:
         submission order, only when results are nonempty.
     on_complete:
         Optional ``on_complete(window)`` called after publish with the
-        per-stage timings and the applied link policy (the processor
-        feeds the batcher and its metrics from this).
+        per-stage timings (the processor feeds the batcher and its
+        metrics from this).
     depth:
-        Base bound on in-flight windows (the link policy may raise it
-        up to ``max_depth``). Depth 1 degenerates to serial-with-threads.
-    max_depth:
-        Queue capacity and the ceiling for link-adaptive deepening.
+        Bound on in-flight windows, and the capacity of each stage's
+        queue. Depth 1 degenerates to serial-with-threads.
     flatten_workers:
         >1 enables the chunked parallel host flatten in prestaging.
-    link_monitor:
-        Optional LinkMonitor; when present it is attached to the
-        JobManager (bandwidth from the stage-once cache's real staging
-        timings, publish RTT from the combined publish's execute+fetch
-        round trips — ADR 0113) and consulted per window for the
-        wire/batch/depth/publish-coalescing policy.
     """
 
     def __init__(
@@ -164,9 +150,7 @@ class IngestPipeline:
         publish: Callable[[list, Any], None],
         on_complete: Callable[[PipelineWindow], None] | None = None,
         depth: int = 2,
-        max_depth: int = 4,
         flatten_workers: int = 0,
-        link_monitor: LinkMonitor | None = None,
         name: str = "ingest",
     ) -> None:
         if depth < 1:
@@ -175,13 +159,8 @@ class IngestPipeline:
         self._decode = decode
         self._publish = publish
         self._on_complete = on_complete
-        self._base_depth = depth
-        self._max_depth = max(max_depth, depth)
-        self._link_monitor = link_monitor
-        if link_monitor is not None and hasattr(
-            job_manager, "set_link_observer"
-        ):
-            job_manager.set_link_observer(link_monitor)
+        #: In-flight window bound (the submit gate).
+        self.depth = depth
         self._flatten_pool = (
             ThreadPoolExecutor(
                 max_workers=flatten_workers,
@@ -190,17 +169,16 @@ class IngestPipeline:
             if flatten_workers > 1
             else None
         )
-        # Bounded stage hand-offs (JGL010): capacity = max depth; the
-        # real in-flight bound is the submit gate below, which follows
-        # the link policy between base and max depth.
+        # Bounded stage hand-offs (JGL010): the submit gate below
+        # admits at most ``depth`` windows, so no queue can hold more.
         self._decode_q: queue.Queue[PipelineWindow] = queue.Queue(
-            maxsize=self._max_depth
+            maxsize=depth
         )
         self._stage_q: queue.Queue[PipelineWindow] = queue.Queue(
-            maxsize=self._max_depth
+            maxsize=depth
         )
         self._step_q: queue.Queue[PipelineWindow] = queue.Queue(
-            maxsize=self._max_depth
+            maxsize=depth
         )
         self._inflight = 0
         self._state_lock = threading.Condition()
@@ -232,19 +210,6 @@ class IngestPipeline:
             worker.start()
 
     # -- submission --------------------------------------------------------
-    @property
-    def depth(self) -> int:
-        """Current in-flight window bound: the link policy's depth,
-        clamped to this pipeline's ceiling. The monitor's neutral depth
-        is its ``base_depth`` — construct the two with the same base
-        (OrchestratingProcessor does) so a configured ``--pipeline-depth``
-        is honored verbatim until the link asks for more."""
-        if self._link_monitor is None:
-            return self._base_depth
-        return min(
-            self._max_depth, max(1, self._link_monitor.policy().depth)
-        )
-
     def submit(
         self, payload, *, start=None, end=None, oldest_ts_ns=None
     ) -> int:
@@ -485,23 +450,8 @@ class IngestPipeline:
             t0 = time.perf_counter()
             with self._timer.stage("stage"):
                 window.generation = self._job_manager.open_window(window.data)
-                if self._link_monitor is not None:
-                    window.policy = self._link_monitor.policy()
-                # Wire flips re-key staging — safe against the window
-                # currently mid-step because every staging pass
-                # snapshots the flag once, key and payload together
-                # (EventHistogrammer._staged_partition); the worst case
-                # at a flip boundary is one private re-stage, and flips
-                # are rare by construction (the policy latch has a
-                # hysteresis dead zone).
                 self._job_manager.prestage_window(
-                    window.data,
-                    pool=self._flatten_pool,
-                    wire_compact=(
-                        None
-                        if window.policy is None
-                        else window.policy.compact_wire
-                    ),
+                    window.data, pool=self._flatten_pool
                 )
             window.stage_s["stage"] = time.perf_counter() - t0
             TRACER.record(
@@ -541,13 +491,8 @@ class IngestPipeline:
                         # "published" means results actually left: an
                         # empty window (no jobs due) records nothing.
                         observe_stage("published", window.source_ts_ns)
-                # Publish-stage time here is sink serialization only:
-                # the RTT observation moved to the device round trip
-                # itself (JobManager times every combined execute+fetch
-                # — and every whole-tick program — into the monitor,
-                # ADR 0113/0114, compile rounds excluded) — feeding
-                # sink time as "RTT" would anchor the
-                # publish-coalescing policy on the wrong quantity.
+                # Publish-stage time here is sink serialization only;
+                # the device round trip is inside the step.
                 window.stage_s["publish"] = time.perf_counter() - t0
             finally:
                 if window.generation is not None:

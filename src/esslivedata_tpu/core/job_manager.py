@@ -18,7 +18,6 @@ from __future__ import annotations
 import bisect
 import logging
 import threading
-import time
 import uuid
 from collections.abc import Mapping
 from concurrent.futures import ThreadPoolExecutor
@@ -123,10 +122,6 @@ class _JobRecord:
     # the job may accumulate again, so data from the old and new run can
     # never mix in a wedged workflow.
     needs_reset: bool = False
-    # True once this record completed a finalize that was timed (or
-    # could have been): the FIRST offer-less finalize may compile its
-    # publish program, so its wall time must not feed the RTT estimate.
-    publish_timed: bool = False
     # Context streams whose latest cached value this job has not received
     # yet. Persisted across windows so an update arriving while the job is
     # idle (no data, nothing pending) is delivered before its next add —
@@ -191,22 +186,10 @@ class JobManager:
         #: a sticky mesh slice — a single device round-robin for
         #: single-device histogrammers, the whole mesh for bank-sharded
         #: ones. Staging keys carry the slice (one transfer per slice),
-        #: member states are committed to it once at assignment, mesh
-        #: groups run through the slice's MeshTickCombiner, and each
-        #: slice's publish RTT reports separately to the link monitor.
+        #: member states are committed to it once at assignment, and
+        #: mesh groups run through the slice's MeshTickCombiner.
         #: None = classic single-placement behavior, byte-identical.
         self._placement = placement
-        #: Publish-coalescing window (link policy, ADR 0113): finalize
-        #: only every Nth data window — accumulation continues every
-        #: window, so the publish round trip is paid less often.
-        #: 1 = publish every window; finishing jobs and idle
-        #: flushes always publish.
-        self._publish_coalesce = 1
-        self._window_seq = 0
-        #: LinkMonitor (duck-typed ``observe_publish``), attached via
-        #: ``set_link_observer``: combined publishes time the real
-        #: device round trip into it.
-        self._link_observer = None
         #: Job-retirement observer (``set_retire_observer``): called
         #: with each removed JobId so downstream caches — the result
         #: fan-out tier's ResultCache (ADR 0117) — drop the job's
@@ -232,7 +215,7 @@ class JobManager:
         #: forever (pinned in tests/durability).
         self._reset_seq = self._seed_reset_seq(durability)
         #: Optional AOT warm-up service (durability/warmup.py): job
-        #: commits/removals and wire flips plan the next tick's program
+        #: commits and removals plan the next tick's program
         #: keys and compile them off the hot path before the change
         #: goes live.
         self._warmup = None
@@ -396,7 +379,7 @@ class JobManager:
 
     def set_warmup(self, service) -> None:
         """Attach the AOT warm-up service (durability/warmup.py):
-        commits, removals and wire flips submit tick-program warm-up
+        commits and removals submit tick-program warm-up
         requests through it."""
         self._warmup = service
 
@@ -500,9 +483,9 @@ class JobManager:
 
     def request_warmup(self, trigger: str) -> None:
         """Plan + submit tick-program warm-up for the current job set
-        (ADR 0118). Called internally on commits/removals/wire flips;
-        public so the processor (policy changes) and layout-swap
-        appliers can pre-compile before a change goes live."""
+        (ADR 0118). Called internally on commits/removals; public so
+        layout-swap appliers can pre-compile before a change goes
+        live."""
         self._queue_warmup(trigger)
 
     def _queue_warmup(self, trigger: str) -> None:
@@ -851,16 +834,8 @@ class JobManager:
                 graduated.add(job_id)
         return graduated
 
-    # -- publish combining / coalescing (ADR 0113) -------------------------
-    def set_publish_coalesce(self, n: int) -> None:
-        """Retarget the publish-coalescing window (link policy): finalize
-        runs only every ``n``th data window, so K windows' accumulation
-        publishes in one device round trip.
-        Finishing jobs and idle flushes always publish immediately."""
-        with self._lock:
-            self._publish_coalesce = max(1, int(n))
-
-    def _run_combined_publish(self, due: list[_JobRecord]) -> set[int]:
+    # -- publish combining (ADR 0113) --------------------------------------
+    def _run_combined_publish(self, due: list[_JobRecord]) -> None:
         """Serve every due job's publish from one execute + one packed
         fetch per device (ADR 0113).
 
@@ -871,26 +846,15 @@ class JobManager:
         ``job.get()`` then consumes the prefetched outputs instead of
         dispatching privately. Singletons ride the combiner too: in the
         manager-driven flow the workflow's private publish jit never
-        compiles, so a K=1 program is the only compile either way, and
-        routing it here gives every publish the same timing probe. Each
-        group's execute+fetch wall time feeds the link monitor — the
-        EWMA RTT behind the publish-coalescing policy is measured on
-        the real device round trip, never on sink serialization.
+        compiles, so a K=1 program is the only compile either way.
 
         Containment mirrors the fused stepping layer: a member whose
         unpack failed still adopts its (valid) folded carry and
         republishes privately; a dispatch failure that consumed the
         donated buffers resets that member's state with a visible
-        warning; everyone else is unaffected.
-
-        Returns the ``id()`` set of the records served here (offer
-        collected): their device round trip is already timed into the
-        link monitor, so the finalize phase must not time them again —
-        and conversely, records NOT in the set publish inside their
-        finalize, which is where their round trip gets timed instead
-        (sharded collective reads, ``combine_publish=False``)."""
+        warning; everyone else is unaffected."""
         if self._publish_combiner is None:
-            return set()
+            return
         from ..ops.publish import (
             PublishRequest,
             publish_args_consumed,
@@ -921,7 +885,6 @@ class JobManager:
                 PublishRequest(o.publisher, o.args, o.static_token)
                 for _, o in members
             ]
-            t0 = time.perf_counter()
             try:
                 results = self._publish_combiner.publish(requests)
             except Exception:
@@ -946,18 +909,6 @@ class JobManager:
                         )
                         self._after_state_loss(rec)
                 continue
-            observer = self._link_observer
-            # Compile rounds are one-off XLA work, not round trips —
-            # feeding them would latch coalescing on every startup.
-            if (
-                observer is not None
-                and not self._publish_combiner.last_compiled
-                and any(res.error is None for res in results)
-            ):
-                try:
-                    observer.observe_publish(time.perf_counter() - t0)
-                except Exception:
-                    logger.debug("link observer failed", exc_info=True)
             for (rec, offer), res in zip(members, results, strict=True):
                 if res.error is not None:
                     if res.state_lost:
@@ -995,7 +946,6 @@ class JobManager:
                     logger.exception(
                         "publish consume failed for %s", rec.job.job_id
                     )
-        return {id(rec) for rec, _offer in offers}
 
     # -- one-dispatch tick programs (ops/tick.py, ADR 0114) ----------------
     def _split_tick_groups(
@@ -1142,23 +1092,6 @@ class JobManager:
         buffers were consumed (``state_lost``), with a visible warning,
         and the private path re-adds THIS window's batch into the fresh
         state.
-
-        The link monitor is fed, per group, the host's time in the
-        group's two halves: its dispatch plus its wait at the collect.
-        Dispatched alone that is the execute+fetch round trip as
-        before. Dispatched ahead, what of the round trip ran under the
-        staging of later groups is not in it (the tick did not wait for
-        it), and the programs queued before the group are, as they are
-        behind any busy chip; with prestaged windows, where the
-        dispatches follow each other at once, each group reads its own
-        program as before. The wall time from dispatch to collected
-        would add the whole queue to every group, and a three-group
-        NMX service then read one 200 ms round trip where it has three
-        of 60 and latched ``publish_coalesce`` at its maximum (PERF.md
-        section 6, PR 25). Compile rounds are excluded via the handle's
-        ``compiled`` (ADR 0113's mechanism, threaded through this path
-        too so a first-tick compile cannot latch ``publish_coalesce``
-        spuriously).
         """
         served: set[int] = set()
         streams_done: dict[JobId, set[str]] = {}
@@ -1166,17 +1099,15 @@ class JobManager:
             return served, streams_done
         from ..ops.publish import PublishRequest
 
-        # (members, combiner, pending handle, slice label, seconds its
-        # dispatch took) of every group dispatched and not yet
-        # collected, and the records among their members.
+        # (members, combiner, pending handle) of every group dispatched
+        # and not yet collected, and the records among their members.
         in_flight: list[tuple] = []
         flying: set[int] = set()
 
         def drain() -> None:
-            for members, combiner, pending, slice_key, took in in_flight:
+            for members, combiner, pending in in_flight:
                 self._collect_tick_group(
-                    members, combiner, pending, slice_key, took,
-                    served, streams_done,
+                    members, combiner, pending, served, streams_done
                 )
             in_flight.clear()
             flying.clear()
@@ -1212,13 +1143,11 @@ class JobManager:
                 slice_key = plc.label
                 if plc.combiner is not None:
                     combiner = plc.combiner
-            t0 = time.perf_counter()
             try:
                 pending = combiner.dispatch(
                     ingest0.hist, key, staged, requests,
                     slice_key=slice_key,
                 )
-                took = time.perf_counter() - t0
                 TICK_GROUPS.labels(
                     dispatched=(
                         "ahead"
@@ -1236,7 +1165,7 @@ class JobManager:
             except Exception:
                 self._tick_group_failed(members)
                 continue
-            in_flight.append((members, combiner, pending, slice_key, took))
+            in_flight.append((members, combiner, pending))
             flying.update(id(rec) for rec, *_ in members)
             if pending.compiled:
                 drain()
@@ -1272,37 +1201,17 @@ class JobManager:
         members: list,
         combiner,
         pending,
-        slice_key,
-        dispatch_s: float,
         served: set[int],
         streams_done: dict[JobId, set[str]],
     ) -> None:
         """The second half of one tick group: wait for its program,
         then the per-member bookkeeping into ``served`` and
         ``streams_done`` (``_run_tick_programs``)."""
-        t0 = time.perf_counter()
         try:
             results = combiner.collect(pending)
         except Exception:
             self._tick_group_failed(members)
             return
-        observer = self._link_observer
-        # Compile rounds are one-off XLA work, not round trips —
-        # feeding them would latch coalescing on every startup,
-        # layout swap or wire flip (the combiner-path rule, threaded
-        # through the tick path too). Slice-placed groups report
-        # under their slice label so the policy reacts to the WORST
-        # slice (ADR 0115).
-        if (
-            observer is not None
-            and not pending.compiled
-            and any(res.error is None for res in results)
-        ):
-            self._observe_publish(
-                observer,
-                dispatch_s + time.perf_counter() - t0,
-                slice_key,
-            )
         for (rec, strm, _value, _ingest, offer), res in zip(
             members, results, strict=True
         ):
@@ -1353,32 +1262,7 @@ class JobManager:
             served.add(id(rec))
             streams_done.setdefault(rec.job.job_id, set()).add(strm)
 
-    @staticmethod
-    def _observe_publish(observer, seconds: float, slice_key) -> None:
-        """Feed one publish RTT sample, with the per-slice label when a
-        placement is active. The observer slot is duck-typed (stub
-        observers in tests take only ``seconds``), so the slice kwarg
-        degrades to the sliceless call instead of losing the sample."""
-        try:
-            if slice_key is None:
-                observer.observe_publish(seconds)
-            else:
-                try:
-                    observer.observe_publish(seconds, slice_key=slice_key)
-                except TypeError:
-                    observer.observe_publish(seconds)
-        except Exception:
-            logger.debug("link observer failed", exc_info=True)
-
     # -- pipelined ingest (core/ingest_pipeline.py, ADR 0111) --------------
-    def set_link_observer(self, observer) -> None:
-        """Attach a LinkMonitor: every staging miss reports (bytes,
-        wall seconds) through the stage-once cache, and every combined
-        publish reports its execute+fetch round trip (ADR 0113) — both
-        estimates come from real work, never probes."""
-        self._event_cache.link_observer = observer
-        self._link_observer = observer
-
     def open_window(self, data: Mapping[str, Any]):
         """Attach a fresh, caller-owned cache generation to this window's
         staged event values and return it.
@@ -1400,7 +1284,6 @@ class JobManager:
         data: Mapping[str, Any],
         *,
         pool=None,
-        wire_compact: bool | None = None,
     ) -> None:
         """Warm the window's stream slots ahead of the job fan-out.
 
@@ -1416,10 +1299,6 @@ class JobManager:
         at step time — prestaging is an overlap optimization, never a
         correctness dependency. Failures are contained per offer: the
         slot drops a poisoned entry, so the step stage retries privately.
-
-        ``wire_compact`` (link policy, ADR 0108) applies the int32 vs
-        uint16 partitioned-wire selection to each offered histogrammer
-        before staging, so the whole window stages in one format.
         """
         with self._lock:
             # ACTIVE jobs, plus SCHEDULED ones with no start gate: the
@@ -1443,7 +1322,6 @@ class JobManager:
                 )
             ]
         staged_keys: set[tuple] = set()
-        wire_flipped = False
         for name, value in data.items():
             if not isinstance(value, StagedEvents) or value.cache is None:
                 continue
@@ -1466,10 +1344,6 @@ class JobManager:
                 stage = getattr(offer.hist, "stage_events", None)
                 if stage is None:
                     continue
-                if wire_compact is not None:
-                    set_wire = getattr(offer.hist, "set_wire_format", None)
-                    if set_wire is not None and set_wire(wire_compact):
-                        wire_flipped = True
                 key = (name, offer.key)
                 if key in staged_keys:
                     continue
@@ -1507,13 +1381,6 @@ class JobManager:
                         name,
                         rec.job.job_id,
                     )
-        if wire_flipped:
-            # The link policy just flipped the partitioned wire: every
-            # pallas2d tick program re-keys on its next publish. Warm
-            # the new-wire programs off the hot path (ADR 0118); the
-            # race with the very next window is best-effort — losing it
-            # costs exactly the compile the instrument reports today.
-            self._queue_warmup("wire_flip")
 
     def peek_pending_streams(self) -> set[str]:
         """Context streams still gating some job (the processor uses this
@@ -1541,13 +1408,10 @@ class JobManager:
         per-job add over the thread pool, then serve every due job's
         publish from one combined device round trip per device and fan
         the finalize/serialization back out — per-job errors contained
-        at every phase (ADR 0113). The publish-coalescing window
-        (``set_publish_coalesce``) may skip the finalize phase entirely
-        on intermediate windows; accumulation persists and flushes on
-        the next publish tick.
+        at every phase (ADR 0113).
 
-        On publish ticks, fused-step groups whose every member is due
-        take the tick-program fast path (ops/tick.py, ADR 0114): step
+        Fused-step groups whose every member is due take the
+        tick-program fast path (ops/tick.py, ADR 0114): step
         AND publish ride one jitted dispatch + one fetch, so a
         steady-state tick is a single device round trip instead of the
         stage/step/publish triple. Groups that can't (extra streams in
@@ -1643,27 +1507,15 @@ class JobManager:
                 work, fuse_groups = self._apply_fleet_filter(
                     work, fuse_groups
                 )
-            # Publish-coalescing gate (ADR 0113): on a widened tick,
-            # accumulation still runs every window but finalize (the
-            # device round trip) only fires every Nth — idle flushes
-            # (no data: a stop must complete) always publish, and a
-            # finishing job forces the tick below.
-            self._window_seq += 1
-            coalesce = max(1, self._publish_coalesce)
-            publish_now = (
-                coalesce <= 1
-                or not data
-                or self._window_seq % coalesce == 0
-            )
 
-        # Tick fast path (outside the lock, same as the fan-out): on a
-        # publish tick, groups whose every member is due step AND
-        # publish in ONE dispatch (ops/tick.py, ADR 0114). Remaining
-        # groups of >= 2 jobs sharing a (stream, fuse-key) advance all
-        # their states in ONE fused dispatch from ONE cached staging.
+        # Tick fast path (outside the lock, same as the fan-out): groups
+        # whose every member is due step AND publish in ONE dispatch
+        # (ops/tick.py, ADR 0114). Remaining groups of >= 2 jobs sharing
+        # a (stream, fuse-key) advance all their states in ONE fused
+        # dispatch from ONE cached staging.
         tick_served: set[int] = set()
         tick_streams: dict[JobId, set[str]] = {}
-        if publish_now and self._tick_combiner is not None:
+        if self._tick_combiner is not None:
             fuse_groups, tick_groups = self._split_tick_groups(
                 work, fuse_groups
             )
@@ -1736,41 +1588,15 @@ class JobManager:
                 run_accumulate(item)
 
         # Every accumulated state is final for this window: jobs due a
-        # publish (fresh or coalesced-over primary data) finalize below,
-        # prefetched through ONE combined device round trip per device.
+        # publish finalize below, prefetched through ONE combined device
+        # round trip per device.
         due = [rec for rec, _ in work if rec.has_primary_data]
-        if due and not publish_now and any(rec.finishing for rec in due):
-            # A stop's final flush must not wait out the coalescing
-            # window (beam-off could stall it indefinitely).
-            publish_now = True
 
         def run_finalize(rec: _JobRecord) -> JobResult | None:
             # Finalize: a failure here is an error; has_primary_data stays
             # set so the next window retries.
             try:
-                t0 = time.perf_counter()
                 result = rec.job.get()
-                if id(rec) not in served:
-                    # Offer-less publish (sharded collective reads,
-                    # combining disabled): the device fetch happens
-                    # inside finalize, so time it here — the RTT axes
-                    # must never go dark for these deployments. The
-                    # record's FIRST offer-less finalize is skipped: it
-                    # may compile the private publish program (also
-                    # after ticks of combined serving — the private jit
-                    # never compiled there), and a compile sample would
-                    # latch coalescing on a healthy link.
-                    observer = self._link_observer
-                    if rec.publish_timed and observer is not None:
-                        try:
-                            observer.observe_publish(
-                                time.perf_counter() - t0
-                            )
-                        except Exception:
-                            logger.debug(
-                                "link observer failed", exc_info=True
-                            )
-                    rec.publish_timed = True
                 rec.error = ""
                 rec.has_primary_data = False
                 if rec.job.none_outputs:
@@ -1785,11 +1611,11 @@ class JobManager:
                 return None
 
         results: list[JobResult | None] = []
-        if due and publish_now:
+        if due:
             # Tick-served records already published inside their tick
             # program; combining them again would dispatch a second
             # publish over the already-folded state.
-            served = tick_served | self._run_combined_publish(
+            self._run_combined_publish(
                 [rec for rec in due if id(rec) not in tick_served]
             )
             # One finalize span per window (ADR 0116), recorded from
@@ -1948,9 +1774,9 @@ class JobManager:
             if len(members) < 2:
                 continue
             rec0, _stream0, value0, offer0 = members[0]
-            # Same sticky slice as the tick path (coalesced windows run
-            # here; a group must not alternate devices between publish
-            # and non-publish windows — that would re-stage the wire
+            # Same sticky slice as the tick path (a group the tick
+            # refuses for one window runs here; it must not alternate
+            # devices between windows — that would re-stage the wire
             # and re-commit every state per window).
             plc = self._group_placement((stream, _key), members)
             device = None if plc is None else plc.device

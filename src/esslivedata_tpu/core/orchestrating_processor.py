@@ -210,7 +210,6 @@ class OrchestratingProcessor:
         pipelined: bool = False,
         pipeline_depth: int = 2,
         flatten_threads: int = 0,
-        link_monitor=None,
         result_fanout=None,
         durability=None,
     ) -> None:
@@ -244,17 +243,13 @@ class OrchestratingProcessor:
         self.stage_timer = StageTimer()
         # Pipelined ingest (ADR 0111): decode | prestage | step/publish
         # overlap across successive windows instead of summing on this
-        # thread. The link policy produced on the step worker is applied
-        # to the batcher HERE on the service thread (batcher mutable
-        # state is single-thread-owned by contract).
+        # thread.
         self._pipeline = None
-        self._link_monitor = None
         #: Result fan-out tier (serving/plane.py, ADR 0117), duck-typed:
         #: ``publish_results(results, timestamp)`` mirrors the sink
-        #: publish and ``qos()`` feeds the link monitor's demand axis.
-        #: None = no serving plane (classic deployments, tests).
+        #: publish. None = no serving plane (classic deployments,
+        #: tests).
         self._result_fanout = result_fanout
-        self._last_fanout_qos = -float("inf")
         #: Durability plane (durability/checkpoint.py, ADR 0118):
         #: periodic state + offset checkpoints taken HERE, on the
         #: service thread, only at quiescent window boundaries (no
@@ -275,27 +270,9 @@ class OrchestratingProcessor:
             set_retire = getattr(job_manager, "set_retire_observer", None)
             if drop_job is not None and set_retire is not None:
                 set_retire(drop_job)
-        # Step-worker -> service-thread policy mailbox (graftlint JGL012:
-        # the step worker posts, the service thread swaps-and-applies;
-        # unlocked, the swap's read..None-store window can eat a
-        # concurrently posted policy and leave the batcher one decision
-        # stale until the next window completes).
-        self._policy_lock = threading.Lock()
-        self._pending_policy = None
-        self._applied_window_scale = 1.0
-        self._applied_publish_coalesce = 1
-        self._base_window = getattr(batcher, "window", None)
         if pipelined:
             from .ingest_pipeline import IngestPipeline
-            from .link_monitor import LinkMonitor
 
-            # The monitor's neutral depth IS the configured pipeline
-            # depth — otherwise a --pipeline-depth below the monitor's
-            # default would be silently deepened on healthy links.
-            self._link_monitor = link_monitor or LinkMonitor(
-                base_depth=pipeline_depth,
-                max_depth=max(4, pipeline_depth),
-            )
             self._pipeline = IngestPipeline(
                 job_manager=job_manager,
                 decode=self._decode_window,
@@ -303,32 +280,11 @@ class OrchestratingProcessor:
                 on_complete=self._on_window_complete,
                 depth=pipeline_depth,
                 flatten_workers=flatten_threads,
-                link_monitor=self._link_monitor,
                 name=f"{service_name}-ingest",
             )
-        elif result_fanout is not None:
-            # Serial service with a serving plane (ADR 0117): no
-            # pipeline means no bandwidth/RTT observations, but the
-            # fan-out demand axis still applies — an unwatched service
-            # backs its publish cadence off, and the processor applies
-            # the (otherwise neutral) policy itself at heartbeat
-            # cadence since no step worker posts one.
-            from .link_monitor import LinkMonitor
-
-            self._link_monitor = link_monitor or LinkMonitor()
-        if self._durability is not None and self._link_monitor is not None:
-            # Cadence governance (ADR 0118): the plane stretches its
-            # interval while the link is degraded or the publish tick
-            # widened — snapshot fetches must never compete with a
-            # congested publish path.
-            set_monitor = getattr(
-                self._durability, "set_link_monitor", None
-            )
-            if set_monitor is not None:
-                set_monitor(self._link_monitor)
         # Unified telemetry (ADR 0116): one keyed collector per
-        # processor feeding the process registry at scrape time — link
-        # estimates, pipeline depths/utilization, stream/sink/source
+        # processor feeding the process registry at scrape time —
+        # pipeline depths/utilization, stream/sink/source
         # counters, stage-once cache totals and HBM gauges all ride it.
         # Keyed by service name so a rebuilt processor (tests, restarts)
         # REPLACES its predecessor instead of stacking dead callbacks.
@@ -388,32 +344,8 @@ class OrchestratingProcessor:
                 results = self._job_manager.process_jobs({})
                 if results:
                     self._publish_results(results, Timestamp.now())
-        if self._pipeline is not None:
-            self._apply_link_policy()
 
         now = self._clock()
-        if (
-            self._result_fanout is not None
-            and self._link_monitor is not None
-            and now - self._last_fanout_qos >= self._heartbeat_interval_s
-        ):
-            # Demand axis (ADR 0117): subscriber count + worst queue
-            # pressure from the broadcast plane, at heartbeat cadence —
-            # a hub-lock probe, far off the per-window hot path.
-            self._last_fanout_qos = now
-            try:
-                qos = self._result_fanout.qos()
-                self._link_monitor.observe_fanout(
-                    int(qos["subscribers"]), float(qos["queue_pressure"])
-                )
-            except Exception:
-                logger.debug("fan-out qos probe failed", exc_info=True)
-            if self._pipeline is None:
-                # Serial mode has no step worker posting policies:
-                # apply the (fanout-only) decision here.
-                with self._policy_lock:
-                    self._pending_policy = self._link_monitor.policy()
-                self._apply_link_policy()
         if now - self._last_heartbeat >= self._heartbeat_interval_s:
             self._last_heartbeat = now
             self._publish_status()
@@ -535,56 +467,9 @@ class OrchestratingProcessor:
     # graft: thread=step   (IngestPipeline step-worker completion callback)
     def _on_window_complete(self, window) -> None:
         """Step-worker callback: fold the window's stage timings into
-        the metrics timer and queue the link policy for the service
-        thread (batcher state is single-thread-owned by contract, so it
-        is never touched from here)."""
+        the metrics timer."""
         for stage, seconds in window.stage_s.items():
             self.stage_timer.record(stage, seconds)
-        if window.policy is not None:
-            with self._policy_lock:
-                self._pending_policy = window.policy
-
-    def _apply_link_policy(self) -> None:
-        """Service thread: retarget the batcher window per link policy.
-
-        Only batchers exposing ``set_window`` (rate-aware) retarget
-        explicitly; the adaptive batcher already reacts to the same
-        degradation through ``report_processing_time`` backpressure."""
-        with self._policy_lock:
-            policy, self._pending_policy = self._pending_policy, None
-        if policy is None:
-            return
-        # Publish-coalescing width (ADR 0113): idempotent retarget on
-        # the JobManager — applied independently of the batcher axis so
-        # a fixed-window batcher still gets the RTT adaptation.
-        coalesce = getattr(policy, "publish_coalesce", 1)
-        if coalesce != self._applied_publish_coalesce:
-            set_coalesce = getattr(
-                self._job_manager, "set_publish_coalesce", None
-            )
-            if set_coalesce is not None:
-                set_coalesce(coalesce)
-                self._applied_publish_coalesce = coalesce
-                logger.info("link policy: publish_coalesce=%d", coalesce)
-        if self._base_window is None:
-            return
-        if policy.window_scale == self._applied_window_scale:
-            return
-        set_window = getattr(self._batcher, "set_window", None)
-        if set_window is None:
-            return
-        set_window(
-            Duration(max(1, round(self._base_window.ns * policy.window_scale)))
-        )
-        self._applied_window_scale = policy.window_scale
-        logger.info(
-            "link policy: window_scale=%.2f compact_wire=%s depth=%d "
-            "publish_coalesce=%d",
-            policy.window_scale,
-            policy.compact_wire,
-            policy.depth,
-            coalesce,
-        )
 
     def _process_batch(self, batch, hold_s: float = 0.0) -> None:
         self._last_batch_len = len(batch.messages)
@@ -886,66 +771,6 @@ class OrchestratingProcessor:
                     ],
                 )
             )
-        if self._link_monitor is not None:
-            link = self._link_monitor.stats()
-            families.append(
-                family(
-                    "livedata_link_bandwidth_bps",
-                    "gauge",
-                    "EWMA effective staging bandwidth (ADR 0111)",
-                    [((), link["bandwidth_bps"] or 0.0)],
-                )
-            )
-            rtt_rows = [((("slice", "all"),), link["rtt_s"] or 0.0)]
-            rtt_rows += [
-                ((("slice", str(slice_key)),), rtt)
-                for slice_key, rtt in sorted(link["rtt_by_slice"].items())
-            ]
-            families.append(
-                family(
-                    "livedata_link_rtt_ewma_seconds",
-                    "gauge",
-                    "EWMA publish RTT, per mesh slice (ADR 0115); the "
-                    "policy reacts to the worst slice",
-                    rtt_rows,
-                )
-            )
-            families.append(
-                family(
-                    "livedata_link_policy",
-                    "gauge",
-                    "Latched link-adaptation decision (ADR 0111): "
-                    "window_scale / depth / publish_coalesce / degraded "
-                    "(0|1) / compact_wire (0|1, -1 = construction default)",
-                    [
-                        ((("axis", "window_scale"),), link["window_scale"]),
-                        ((("axis", "depth"),), link["depth"]),
-                        (
-                            (("axis", "publish_coalesce"),),
-                            link["publish_coalesce"],
-                        ),
-                        ((("axis", "degraded"),), int(link["degraded"])),
-                        (
-                            (("axis", "compact_wire"),),
-                            -1
-                            if link["compact_wire"] is None
-                            else int(link["compact_wire"]),
-                        ),
-                        (
-                            (("axis", "fanout_coalesce"),),
-                            link.get("fanout_coalesce", 1),
-                        ),
-                        (
-                            # -1 = no serving plane has reported (axis
-                            # neutral), else the attached-viewer count.
-                            (("axis", "fanout_subscribers"),),
-                            -1
-                            if link.get("fanout_subscribers") is None
-                            else link["fanout_subscribers"],
-                        ),
-                    ],
-                )
-            )
         if self._pipeline is not None:
             pipe = self._pipeline.telemetry()
             families.append(
@@ -963,7 +788,7 @@ class OrchestratingProcessor:
                 family(
                     "livedata_pipeline_inflight",
                     "gauge",
-                    "In-flight windows vs the link-adaptive depth bound",
+                    "In-flight windows vs the configured depth bound",
                     [
                         ((("kind", "inflight"),), pipe["inflight"]),
                         ((("kind", "depth"),), pipe["depth"]),
@@ -1122,8 +947,6 @@ class OrchestratingProcessor:
             extra["stages"] = stages
         if self._pipeline is not None:
             extra["pipeline"] = self._pipeline.stats()
-        if self._link_monitor is not None:
-            extra["link"] = self._link_monitor.stats()
         # Device dispatch decomposition (ADR 0113/0114): publish/tick
         # executes+fetches and separate step dispatches since process
         # start. SNAPSHOT, not drain — the counters are process-wide and

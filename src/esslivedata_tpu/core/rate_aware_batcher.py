@@ -262,10 +262,6 @@ class RateAwareMessageBatcher:
             pending += len(state.bucket)
         return pending
 
-    def set_window(self, window: Duration) -> None:
-        """Change the window length; takes effect at the next batch start."""
-        self._pending_window = window
-
     def is_gating(self, stream: StreamId) -> bool:
         state = self._streams.get(stream)
         return state.is_gating if state is not None else False
@@ -277,10 +273,9 @@ class RateAwareMessageBatcher:
     def report_processing_time(self, duration: Duration) -> None:
         load = duration.ns / max(self._last_emitted_window.ns, 1)
         if self._governor.observe(load):
-            self.set_window(
-                Duration(
-                    max(1, round(self._base_window.ns * self._governor.scale))
-                )
+            # Takes effect at the next batch start.
+            self._pending_window = Duration(
+                max(1, round(self._base_window.ns * self._governor.scale))
             )
 
     def batch(self, messages: list[Message]) -> MessageBatch | None:
@@ -386,8 +381,8 @@ class RateAwareMessageBatcher:
         assert self._start is not None
         start = self._start
         # The closing batch's window length: captured before the stream
-        # refresh, which may apply a pending set_window() — that takes
-        # effect at the *next* batch start, not on this one.
+        # refresh, which may apply the governor's pending window — that
+        # takes effect at the *next* batch start, not on this one.
         closing_window = self._window
         self._refresh_streams(start)
         messages = self._drain_all()

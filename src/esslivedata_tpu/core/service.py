@@ -204,16 +204,15 @@ def setup_arg_parser(description: str = "") -> argparse.ArgumentParser:
         type=float,
         default=None,
         metavar="SECONDS",
-        help="checkpoint cadence (default 30 s), stretched "
-        "automatically while the link is congested "
-        "(LIVEDATA_CHECKPOINT_INTERVAL equivalently)",
+        help="checkpoint cadence (default 30 s; "
+        "LIVEDATA_CHECKPOINT_INTERVAL equivalently)",
     )
     parser.add_argument(
         "--warmup",
         action="store_true",
         default=False,
         help="AOT warm-up (ADR 0118): compile tick programs on a "
-        "background thread at job-commit/policy-flip time so the hot "
+        "background thread at job-commit/regroup time so the hot "
         "path never pays a jit compile at commit "
         "(LIVEDATA_WARMUP equivalently)",
     )

@@ -3,7 +3,7 @@
 Three pieces, composable and individually optional:
 
 - :mod:`.warmup` — ``CompileWarmupService``: a background thread that
-  AOT-lowers and compiles tick programs at job-commit (and policy-flip)
+  AOT-lowers and compiles tick programs at job-commit (and regroup)
   time, seeding the :class:`~..ops.tick.TickCombiner` program LRU so
   the first post-commit tick is a cache hit — commit-time compile count
   on the hot path is 0 (measured by the ADR 0116 instrument) and
@@ -13,8 +13,8 @@ Three pieces, composable and individually optional:
 - :mod:`.checkpoint` — ``CheckpointPlane``: periodic, epoch-tagged
   device→host snapshots of rolling-histogram state plus per-stream
   Kafka offset bookmarks, written atomically under a manifest
-  (write-tmp/fsync/rename — the JGL020 discipline), on a cadence the
-  ``LinkMonitor`` stretches when the publish path is congested.
+  (write-tmp/fsync/rename — the JGL020 discipline), at a fixed
+  interval.
 - :mod:`.replay` — restore the newest consistent manifest on restart
   (stale manifests from before the last run-boundary reset are
   rejected), seek consumers to the bookmarks, and replay the gap

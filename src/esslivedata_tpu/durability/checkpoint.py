@@ -21,11 +21,7 @@ gone too. This plane generalizes it into the periodic channel
   or half-referenced checkpoint. The newest ``keep`` generations are
   retained; older manifests and unreferenced state files are garbage
   collected only after a successful write.
-- **Cadence.** ``due()`` answers at the configured interval, stretched
-  (×4) while the attached :class:`~..core.link_monitor.LinkMonitor`
-  reports a degraded link or a widened publish tick — a checkpoint's
-  device→host fetches must never compete with a congested publish
-  path.
+- **Cadence.** ``due()`` answers at the configured interval.
 - **Staleness.** Run-boundary resets bump a persistent ``reset_seq``
   marker (``note_reset``, written atomically). A manifest written
   BEFORE the most recent reset is rejected by :func:`.replay.
@@ -109,13 +105,11 @@ class CheckpointPlane:
         *,
         interval_s: float = 30.0,
         keep: int = 2,
-        link_monitor=None,
     ) -> None:
         self._dir = Path(directory)
         self._dir.mkdir(parents=True, exist_ok=True)
         self._interval_s = max(0.0, float(interval_s))
         self._keep = max(1, int(keep))
-        self._link_monitor = link_monitor
         self._lock = threading.Lock()
         self._last_wall: float | None = None
         self._last_bytes = 0
@@ -134,32 +128,14 @@ class CheckpointPlane:
     def directory(self) -> Path:
         return self._dir
 
-    def set_link_monitor(self, link_monitor) -> None:
-        self._link_monitor = link_monitor
-
     # -- cadence -----------------------------------------------------------
     def due(self, now: float | None = None) -> bool:
-        """True when the next checkpoint should be taken. The interval
-        stretches ×4 while the link monitor reports a degraded link or
-        a widened publish tick: snapshot fetches share the device→host
-        path, and a congested publish path must win that contention."""
+        """True when the next checkpoint should be taken: none taken
+        yet, or the interval has passed since the last."""
         now = time.monotonic() if now is None else now
         with self._lock:
             last = self._last_wall
-        if last is None:
-            return True
-        interval = self._interval_s
-        monitor = self._link_monitor
-        if monitor is not None:
-            try:
-                stats = monitor.stats()
-                if stats.get("degraded") or stats.get(
-                    "publish_coalesce", 1
-                ) > 1:
-                    interval *= 4.0
-            except Exception:  # pragma: no cover - defensive
-                logger.debug("link monitor probe failed", exc_info=True)
-        return now - last >= interval
+        return last is None or now - last >= self._interval_s
 
     # -- write side --------------------------------------------------------
     def _newest_epoch(self) -> int:

@@ -1,11 +1,11 @@
 """AOT warm-up: compile the tick program BEFORE the job goes live.
 
-Every job commit, layout swap, wire flip or regroup re-keys the tick
+Every job commit, layout swap or regroup re-keys the tick
 program LRU, and the next live window pays trace + XLA compile + first
 execute on the hot path — the exact p99 spike class the PR 9 compile
-instrument (``livedata_jit_compiles_total{site,trigger}``) measures and
-PERF rounds 7–10 had to exclude from RTT estimates. This module closes
-the loop (ROADMAP item 1, SNIPPETS.md [1] ``Lowered`` AOT path):
+instrument (``livedata_jit_compiles_total{site,trigger}``) measures.
+This module closes the loop (ROADMAP item 1, SNIPPETS.md [1]
+``Lowered`` AOT path):
 
 - The :class:`~..core.job_manager.JobManager` plans, at commit time,
   exactly the (histogrammer, group key, staged signature, member set)
@@ -20,8 +20,8 @@ the loop (ROADMAP item 1, SNIPPETS.md [1] ``Lowered`` AOT path):
   stages it exactly as the live tick would (same ``tick_staging``, same
   device), and calls :meth:`~..ops.tick.TickCombiner.warm` — which
   AOT-lowers, compiles, and seeds the program LRU with the ready
-  executable. The next live tick is a cache hit: no compile event, no
-  ``last_compiled`` RTT exclusion, first-tick latency == steady state.
+  executable. The next live tick is a cache hit: no compile event,
+  first-tick latency == steady state.
 - The runners' persistent compilation cache (utils/runtime.py, every
   entry, no minimum size/time) is written by ``Lowered.compile`` too,
   so a process restart re-lowers but skips XLA entirely.
@@ -51,7 +51,7 @@ logger = logging.getLogger(__name__)
 _WARMUP_COMPILES = REGISTRY.counter(
     "livedata_durability_warmup_compiles_total",
     "Tick programs AOT-compiled off the hot path by the warm-up "
-    "service, by trigger (commit/regroup/wire_flip/layout_swap)",
+    "service, by trigger (commit/regroup/layout_swap)",
     labelnames=("trigger",),
 )
 _WARMUP_FAILURES = REGISTRY.counter(

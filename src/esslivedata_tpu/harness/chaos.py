@@ -4,7 +4,7 @@ The containment code claims to survive four fault classes: a
 post-donation dispatch failure (``note_state_lost`` + re-seed, ADR
 0113/0114/0118), wedged/slow SSE subscribers (bounded queues +
 coalesce-to-keyframe, ADR 0117), slow-tick storms (watchdog +
-link-policy backoff, ADR 0111/0116), and a consumer restart mid-window
+batcher backpressure, ADR 0111/0116), and a consumer restart mid-window
 (replay through the normal ingest path, ADR 0118). This module injects
 exactly those faults — through hooks the production classes already
 carry (``JobManager.set_chaos``, ``IngestPipeline.set_chaos``,

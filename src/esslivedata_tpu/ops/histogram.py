@@ -816,19 +816,12 @@ class EventHistogrammer:
     def partition_key(self) -> tuple:
         """Cache key for the pallas2d partitioned wire: the partition
         additionally depends on the block/chunk geometry and compaction."""
-        return self.partition_key_for(self._p2_compact)
-
-    def partition_key_for(self, compact: bool) -> tuple:
-        """``partition_key`` for an explicit compaction flag — staging
-        snapshots the flag once so a concurrent ``set_wire_format`` flip
-        (link policy, ADR 0111) can never cache a payload under a key
-        claiming the other wire."""
         return (
             "part",
             self._proj.layout_digest,
             self._bpb,
             self._p2_chunk,
-            compact,
+            self._p2_compact,
         )
 
     @property
@@ -884,27 +877,17 @@ class EventHistogrammer:
     ):
         """Block-partitioned (events, chunk_map) staged for the pallas2d
         kernel — once per window per (stream, tag, partition layout,
-        slice); the same two leaf spans on a miss.
-
-        The compaction flag is read ONCE and threaded through both the
-        key and the partition pass: a link-policy wire flip arriving
-        between the two would otherwise cache a payload whose format
-        contradicts its key."""
-        compact = self._p2_compact
+        slice); the same two leaf spans on a miss."""
 
         def stage():
             with TRACER.span("flatten", args=_flatten_args(batch)):
-                wire = self.flatten_partition_host(
-                    batch.pixel_id, batch.toa, compact=compact
-                )
+                wire = self.flatten_partition_host(batch.pixel_id, batch.toa)
             return ship(batch, wire, device)
 
         if cache is None:
             return stage()
         return cache.get_or_stage(
-            (tag,) + self.partition_key_for(compact)
-            + (device_token(device),),
-            stage,
+            (tag,) + self.partition_key + (device_token(device),), stage
         )
 
     def stage_events(
@@ -941,36 +924,6 @@ class EventHistogrammer:
             )
         else:
             stage_raw(batch, cache, batch_tag, device=device)
-
-    @property
-    def wire_format(self) -> str | None:
-        """The current partitioned-wire format: ``"compact"`` (uint16) /
-        ``"wide"`` (int32) for ``method='pallas2d'``, None for methods
-        without a partitioned wire. The compile-event instrument
-        (telemetry, ADR 0116) reads this to label a tick-program
-        recompile as a wire flip vs a layout swap."""
-        if self._method != "pallas2d":
-            return None
-        return "compact" if self._p2_compact else "wide"
-
-    def set_wire_format(self, compact: bool) -> bool:
-        """Runtime int32 <-> uint16 wire switch for ``method='pallas2d'``
-        (ADR 0108/0111). Returns True when the format actually changed.
-
-        The partition/fuse keys carry the compaction flag, so a switch
-        re-keys staging (next window misses and stages in the new
-        format) and splits fused groups across the flip — never a stale
-        mixed wire. Counts are bit-identical across both wires (pinned
-        by the partition parity tests), so the link policy may flip this
-        mid-stream without touching results. No-op for other methods and
-        for block sizes whose offsets don't fit uint16."""
-        if self._method != "pallas2d":
-            return False
-        compact = bool(compact) and self._bpb <= 0xFFFF
-        if compact == self._p2_compact:
-            return False
-        self._p2_compact = compact
-        return True
 
     #: Below this many events per chunk the pool dispatch overhead beats
     #: the parallel flatten; chunks are sized to keep every worker fed.
@@ -1038,11 +991,11 @@ class EventHistogrammer:
 
         The private/fallback step paths resolve their staging placement
         from the STATE: a slice-placed group that drops to the private
-        path (coalesced window, tick ineligibility, a contained tick
-        failure) must stage onto its slice — default-device staging
-        would hand the jitted step arguments committed to two devices,
-        which jax rejects on real multi-chip hardware (the CPU backend
-        masks it: ``dispatch_safe`` returns uncommitted numpy there).
+        path (tick ineligibility, a contained tick failure) must stage
+        onto its slice — default-device staging would hand the jitted
+        step arguments committed to two devices, which jax rejects on
+        real multi-chip hardware (the CPU backend masks it:
+        ``dispatch_safe`` returns uncommitted numpy there).
         Committedness is the discriminator, not device identity: a
         group PLACED on the default device still returns it (so the
         staging cache key matches the tick path's slice token — no
@@ -1144,8 +1097,8 @@ class EventHistogrammer:
     def _dispatch_fused(self, fn, states, *staged):
         """Dispatch one fused-step jit with compile-event detection
         (telemetry, ADR 0116): a cache miss on the jitted ``fn`` — a
-        new K, a layout swap re-keying the staged wire, a link-policy
-        wire flip — records its wall time into the labeled compile
+        new K, a layout swap re-keying the staged wire — records its
+        wall time into the labeled compile
         histogram. The probe is jax's jit cache size (guarded: absent
         on exotic wrappers), read before and after the call; compile is
         synchronous at first call, so the unblocked wall time is the
@@ -1169,7 +1122,6 @@ class EventHistogrammer:
                     (id(self), len(states)),
                     time.perf_counter() - t0,
                     layout_digest=self.layout_digest,
-                    wire=self.wire_format,
                     staged_sig=tuple(
                         (tuple(getattr(a, "shape", ())),
                          str(getattr(a, "dtype", "")))
@@ -1227,23 +1179,15 @@ class EventHistogrammer:
         return self._step_fused_impl(states, *staged)
 
     def flatten_partition_host(
-        self,
-        pixel_id: np.ndarray,
-        toa: np.ndarray,
-        *,
-        compact: bool | None = None,
+        self, pixel_id: np.ndarray, toa: np.ndarray
     ) -> tuple[np.ndarray, np.ndarray]:
         """Host ingest for ``method='pallas2d'``: raw (pixel_id, toa) to
         block-partitioned ``(events, chunk_map)`` for the tiled kernel.
 
         One fused native pass (``ld_flatten_partition``) when the
         configuration is uniform-edged and pixel-block-aligned; otherwise
-        ``flatten_host`` + ``partition_events_host``. ``compact``
-        overrides the instance's wire flag (staging snapshots it so a
-        concurrent ``set_wire_format`` flip stays key-coherent).
+        ``flatten_host`` + ``partition_events_host``.
         """
-        if compact is None:
-            compact = self._p2_compact
         from .pallas_hist2d import (
             bucketed_chunks,
             chunk_capacity,
@@ -1273,7 +1217,7 @@ class EventHistogrammer:
                     ppb_shift=self._ppb_shift,
                     chunk=chunk,
                     cap_chunks=cap,
-                    compact=compact,
+                    compact=self._p2_compact,
                 )
                 if res is not None:
                     events, chunk_map, used = res
@@ -1285,7 +1229,7 @@ class EventHistogrammer:
             self._n_bins + 1,
             bpb=self._bpb,
             chunk=self._p2_chunk,
-            compact=compact,
+            compact=self._p2_compact,
         )
 
     def step_flat(self, state: HistogramState, flat) -> HistogramState:

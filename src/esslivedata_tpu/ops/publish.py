@@ -241,7 +241,7 @@ def _publish_metric_families():
     already paid. NOTE: benches/tests ``drain()`` these around measured
     loops, so a scrape across a drain can observe a reset; the
     operator-facing monotone signals are the direct telemetry
-    instruments (compile events, RTT/span histograms)."""
+    instruments (compile events, span histograms)."""
     from ..telemetry.registry import MetricFamily, Sample
 
     snap = METRICS.snapshot()
@@ -775,12 +775,6 @@ class PublishCombiner:
     def __init__(self, max_programs: int = 16) -> None:
         self._programs: OrderedDict[tuple, Callable] = OrderedDict()
         self._max_programs = int(max_programs)
-        #: True when the last ``publish`` compiled its program (cache
-        #: miss). RTT observers must skip those rounds: a mega-publish
-        #: compile is hundreds of ms of one-off XLA work, and folding it
-        #: into the EWMA RTT would latch the publish-coalescing policy
-        #: on every startup.
-        self.last_compiled = False
 
     def publish(
         self, requests: Sequence[PublishRequest]
@@ -799,7 +793,7 @@ class PublishCombiner:
             ]
         key = member_signature(plan)
         fn = self._programs.get(key)
-        self.last_compiled = fn is None
+        compiled = fn is None
         if fn is not None:
             # LRU touch: the steady-state program runs every tick and
             # must never be the eviction victim of key churn (layout
@@ -824,11 +818,10 @@ class PublishCombiner:
             for i, err in planned_errors.items()
         }
         try:
-            if self.last_compiled:
+            if compiled:
                 # Compile-event instrument (ADR 0116): the miss round's
                 # wall time (trace + XLA + first execute+fetch) becomes
-                # a labeled histogram sample instead of only an
-                # RTT-estimate exclusion. Job-set changes are command-
+                # a labeled histogram sample. Job-set changes are command-
                 # time events, so the expected trigger here is
                 # new_group/regroup; per-member signature churn (batch
                 # shape, static inclusion) classifies via residual. No

@@ -800,8 +800,8 @@ class QHistogrammer:
         device=None,
     ) -> tuple[QState, ...]:
         """Advance K states of THIS kernel from one staged batch in one
-        fused dispatch (the coalesced-window path between publish
-        ticks). Equal fuse keys imply the same instance, so all states
+        fused dispatch (the path of a group the tick program does not
+        take). Equal fuse keys imply the same instance, so all states
         reduce under the one live table."""
         states = tuple(states)
         if not states:

@@ -40,12 +40,10 @@ Keying: the jitted-program LRU is keyed on (histogrammer identity, the
 group's fuse key + batch tag, the staged wire's signature, the exact
 member tuple). The fuse key already folds in the projection layout
 digest and — for ``method='pallas2d'`` — the wire format, so a live LUT
-swap or a link-policy int32↔uint16 flip re-keys cleanly: the next tick
-compiles (marked via ``last_compiled`` so RTT observers skip it, the
-ADR 0113 mechanism) and staged payloads can never meet a program traced
-for the other wire. The staged signature is in the key so a batch-shape
-change is also visible as a compile, not silently folded into the RTT
-estimate.
+swap re-keys cleanly: the next tick compiles (``compiled`` on its
+handle) and staged payloads can never meet a program traced for another
+layout or wire. The staged signature is in the key so a batch-shape
+change is also visible as a compile.
 
 Containment mirrors ADR 0113 exactly (the plan/unpack machinery is
 shared with :class:`~.publish.PublishCombiner`): a member whose plan
@@ -103,7 +101,7 @@ class PendingTick:
     slice_key: str | None = None
     key: tuple | None = None
     #: The dispatch missed the program LRU and ran the compile round
-    #: to its end (``TickCombiner.last_compiled`` of this group).
+    #: to its end.
     compiled: bool = False
     #: ``(packed, statics)`` on the device, their copies enqueued.
     outputs: tuple | None = None
@@ -119,8 +117,8 @@ class TickCombiner:
     (histogrammer, group key, staged signature, member tuple): the
     group's fused step runs first, then each member's packed publish
     body over its stepped state, all under one ``jax.jit``. Member
-    composition changes at command time and layouts/wire formats flip
-    rarely (hysteresis-latched), so recompiles are rare; the cache bound
+    composition changes at command time and layouts swap rarely, so
+    recompiles are rare; the cache bound
     caps how many retired programs (and the publishers/histogrammers
     they close over) stay alive.
     """
@@ -135,15 +133,6 @@ class TickCombiner:
         # lock covers only dict operations (never a build/compile), so
         # it costs nanoseconds against a millisecond tick.
         self._programs_lock = threading.Lock()
-        #: True when the last ``dispatch`` compiled its program (cache
-        #: miss; the handle's ``compiled`` says it per group, which is
-        #: what a caller with several groups in flight reads). RTT
-        #: observers must skip those rounds — same contract
-        #: as ``PublishCombiner.last_compiled`` (ADR 0113): a tick
-        #: compile is one-off XLA work, and folding it into the EWMA
-        #: publish RTT would latch the coalescing policy on every
-        #: startup, layout swap or wire flip.
-        self.last_compiled = False
 
     def publish(
         self,
@@ -209,12 +198,12 @@ class TickCombiner:
         key = pending.key = self._program_key(hist, group_key, staged, plan)
         with self._programs_lock:
             fn = self._programs.get(key)
-            self.last_compiled = pending.compiled = fn is None
+            pending.compiled = fn is None
             if fn is not None:
                 # LRU touch: the steady-state program runs every tick
                 # and must never be the eviction victim of key churn
-                # (layout swaps, wire flips) — eviction means a
-                # surprise whole-tick recompile in the hot path.
+                # (layout swaps) — eviction means a surprise whole-tick
+                # recompile in the hot path.
                 self._programs.move_to_end(key)
         if fn is None:
             fn = self._build(
@@ -237,10 +226,9 @@ class TickCombiner:
         try:
             if pending.compiled:
                 # Compile-event instrument (ADR 0116): the first call of
-                # a fresh program pays trace + XLA compile + execute —
-                # the stall PERF round 7 could only EXCLUDE from RTT
-                # estimates. Time it and label WHY the key missed
-                # (layout swap / wire flip / batch shape / new group) so
+                # a fresh program pays trace + XLA compile + execute.
+                # Time it and label WHY the key missed
+                # (layout swap / batch shape / new group) so
                 # compile spikes decompose on the scrape. The execute is
                 # async-dispatched; the device_get inside the timed
                 # region bounds the compile+first-round wall time. No
@@ -430,12 +418,11 @@ class TickCombiner:
                 self.compile_site,
                 # WHO is compiling: this histogrammer serving this
                 # publisher set. The key dimensions that churn (layout,
-                # wire, staged shape, residual key material) are passed
+                # staged shape, residual key material) are passed
                 # separately for trigger classification.
                 (id(hist), tuple(id(req.publisher) for _i, req, *_ in plan)),
                 seconds,
                 layout_digest=getattr(hist, "layout_digest", None),
-                wire=getattr(hist, "wire_format", None),
                 staged_sig=key[2],
                 # Object-free residual: the raw member signature holds
                 # live publishers, which must not be pinned in the

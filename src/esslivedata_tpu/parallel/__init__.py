@@ -19,8 +19,8 @@ replicated fetch per tick), and ``DevicePlacement`` assigns every
 (stream, fuse-key) tick group a sticky mesh slice: single-device jobs
 spread round-robin across chips, bank-sharded LOKI-scale jobs take the
 whole mesh. Service surface: ``--mesh data,bank`` / ``LIVEDATA_MESH``
-(services/service_factory.py); per-slice dispatch counts and publish
-RTTs report through ``ops/publish.METRICS`` and the link monitor.
+(services/service_factory.py); per-slice dispatch counts report
+through ``ops/publish.METRICS``.
 """
 
 from .mesh import make_mesh, mesh_from_spec

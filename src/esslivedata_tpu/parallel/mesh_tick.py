@@ -30,8 +30,8 @@ the real serving topology (ADR 0115, ROADMAP item 1):
 Readback stays O(1) fetch per slice per tick: single-device slices fetch
 their own packed vector; the mesh slice fetches one replicated vector.
 Per-slice execute/fetch counts land in ``ops/publish.METRICS`` under
-``slices`` and per-slice publish RTTs in the LinkMonitor, so the bench
-(``bench.py --mesh``) asserts the contract directly.
+``slices``, so the bench (``bench.py --mesh``) asserts the contract
+directly.
 """
 
 from __future__ import annotations
@@ -99,7 +99,7 @@ class TickSlice:
     ``device`` is set for single-device slices (the group's staged wire
     and donated states are committed there); ``mesh``/``combiner`` are
     set for whole-mesh groups. ``label`` keys the per-slice METRICS
-    breakdown and the LinkMonitor's per-slice RTT estimate.
+    breakdown.
     """
 
     label: str

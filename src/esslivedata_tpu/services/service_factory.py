@@ -363,8 +363,10 @@ class DataServiceRunner:
             "--batcher",
             default="adaptive",
             choices=["naive", "simple", "adaptive", "rate_aware"],
-            help="rate_aware additionally accepts the link monitor's "
-            "explicit window retargeting under --pipeline (ADR 0111)",
+            help="how messages are cut into windows: adaptive and "
+            "rate_aware widen the window under load (LoadGovernor); "
+            "rate_aware also closes a window when every gated "
+            "stream's last expected pulse has arrived",
         )
         parser.add_argument("--job-threads", type=int, default=5)
         parser.add_argument(
@@ -373,15 +375,13 @@ class DataServiceRunner:
             default=False,
             help="pipelined ingest (ADR 0111): decode | prestage | "
             "step/publish overlap across windows with bounded "
-            "backpressure and link-adaptive batching "
-            "(LIVEDATA_PIPELINE=1 equivalently)",
+            "backpressure (LIVEDATA_PIPELINE=1 equivalently)",
         )
         parser.add_argument(
             "--pipeline-depth",
             type=int,
             default=None,
-            help="base in-flight window bound (the link monitor may "
-            "deepen it on degraded links)",
+            help="in-flight window bound of --pipeline",
         )
         parser.add_argument(
             "--flatten-threads",

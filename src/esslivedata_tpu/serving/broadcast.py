@@ -531,10 +531,8 @@ class BroadcastServer:
 
     # -- QoS ----------------------------------------------------------------
     def qos(self) -> dict[str, float | int]:
-        """Subscriber count + worst send-queue pressure in [0, 1] — the
-        LinkMonitor's fan-out axis reads this (back off publish
-        coalescing when nobody is watching, hold cadence when someone
-        is; core/link_monitor.py)."""
+        """Subscriber count + worst send-queue pressure in [0, 1] (the
+        load harness, harness/load.py, reports both)."""
         with self._lock:
             n = sum(len(subs) for subs in self._subscribers.values())
             pressure = 0.0

@@ -156,8 +156,8 @@ class ServingPlane:
 
     # -- QoS feedback ------------------------------------------------------
     def qos(self) -> dict[str, float | int]:
-        """Subscriber count + worst queue pressure for the link
-        monitor's fan-out axis (core/link_monitor.py)."""
+        """Subscriber count + worst queue pressure of the broadcast
+        hub (``bench.py --fanout`` reports it)."""
         return self.server.qos()
 
     def close(self) -> None:

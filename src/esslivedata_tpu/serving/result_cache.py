@@ -17,8 +17,8 @@ stream's integer epoch, which forces the delta encoder onto a keyframe
 and tells subscribers the accumulation restarted (a delta across
 epochs would splice unrelated state generations).
 
-Locking: ONE lock, ONE acquisition per operation — the discipline PR 9
-gave ``LinkMonitor.stats()``. ``latest`` returns frame, epoch and seq
+Locking: ONE lock, ONE acquisition per operation.
+``latest`` returns frame, epoch and seq
 from the same critical section, so a scraping subscriber can never pair
 a frame with the wrong epoch tag (pinned by the lock hammer in
 tests/serving/result_cache_test.py); ``put`` is a dict store + deque

@@ -6,7 +6,7 @@ text-exposition HTTP plane (``/metrics`` + ``/healthz``,
 ``--metrics-port`` on every service runner), a per-tick tracer with
 Chrome ``trace_event`` export (``--trace-dump``) and a slow-tick
 watchdog, and the compile-event instrument that turns jit-cache misses
-from an RTT-estimate exclusion into a labeled histogram.
+into a labeled histogram.
 
 See ``docs/observability.md`` for the metric name catalog, the
 trace-id lifecycle and how to wire a new workflow metric.
@@ -15,7 +15,6 @@ trace-id lifecycle and how to wire a new workflow metric.
 from .compile import COMPILE_EVENTS, CompileEventRecorder
 from .e2e import E2E_LATENCY, E2E_STAGES, observe_stage
 from .health import HEALTH, STATE_LOST, HealthState
-from .instruments import PUBLISH_RTT_SECONDS
 from .exposition import (
     CONTENT_TYPE,
     ParsedMetric,
@@ -51,7 +50,6 @@ __all__ = [
     "MetricFamily",
     "MetricsRegistry",
     "MetricsServer",
-    "PUBLISH_RTT_SECONDS",
     "ParsedMetric",
     "Sample",
     "Span",

@@ -1,12 +1,9 @@
 """The compile-event instrument: jit-cache misses as a labeled histogram.
 
-PERF.md round 7 had to EXCLUDE compile rounds from the publish-RTT
-estimates because a compile stall (hundreds of ms to seconds of one-off
-XLA work) would have latched the coalescing policy — which means the
-stalls themselves were invisible everywhere except as excluded samples.
-They are real user-visible p99 (ROADMAP item 4: every job commit,
-layout swap or wire flip pays one on the hot path), so this module
-makes them a first-class signal instead of an exclusion:
+A compile stall (hundreds of ms to seconds of one-off XLA work) is
+real user-visible p99 (every job commit or layout swap pays one on the
+hot path unless it was warmed), so this module makes them a first-class
+signal:
 
 - ``livedata_jit_compiles_total{site,trigger}`` — count of cache
   misses per compile site (tick / mesh_tick / publish / step_many);
@@ -21,8 +18,6 @@ a p99 spike actually asks:
   job commits, service start;
 - ``layout_swap`` — same group, the layout digest changed (live LUT /
   geometry swap, ADR 0105);
-- ``wire_flip``   — same group, the int32<->uint16 wire flag flipped
-  (link policy, ADR 0108);
 - ``batch_shape`` — same group, the staged wire's signature changed
   (batch-size regime change);
 - ``regroup``     — same members, some other key component changed
@@ -72,8 +67,8 @@ class CompileEventRecorder:
 
     def __init__(self) -> None:
         self._lock = threading.Lock()
-        # (site, group identity) -> last-seen (layout digest, wire,
-        # staged signature, residual key)
+        # (site, group identity) -> last-seen (layout digest, staged
+        # signature, residual key)
         self._memory: OrderedDict[tuple, tuple] = OrderedDict()
 
     def record(self, site: str, trigger: str, seconds: float) -> None:
@@ -87,7 +82,6 @@ class CompileEventRecorder:
         group: Hashable,
         *,
         layout_digest: Hashable = None,
-        wire: Hashable = None,
         staged_sig: Hashable = None,
         residual: Hashable = None,
     ) -> str:
@@ -96,7 +90,7 @@ class CompileEventRecorder:
         (histogrammer id + member set); the keyword components are the
         key dimensions that can churn (see module docstring)."""
         key = (site, group)
-        seen = (layout_digest, wire, staged_sig, residual)
+        seen = (layout_digest, staged_sig, residual)
         with self._lock:
             prev = self._memory.get(key)
             self._memory[key] = seen
@@ -105,11 +99,9 @@ class CompileEventRecorder:
                 self._memory.popitem(last=False)
         if prev is None:
             return "new_group"
-        prev_digest, prev_wire, prev_sig, prev_residual = prev
+        prev_digest, prev_sig, prev_residual = prev
         if layout_digest != prev_digest:
             return "layout_swap"
-        if wire != prev_wire:
-            return "wire_flip"
         if staged_sig != prev_sig:
             return "batch_shape"
         if residual != prev_residual:
@@ -126,7 +118,6 @@ class CompileEventRecorder:
         seconds: float,
         *,
         layout_digest: Hashable = None,
-        wire: Hashable = None,
         staged_sig: Hashable = None,
         residual: Hashable = None,
     ) -> str:
@@ -134,7 +125,6 @@ class CompileEventRecorder:
             site,
             group,
             layout_digest=layout_digest,
-            wire=wire,
             staged_sig=staged_sig,
             residual=residual,
         )

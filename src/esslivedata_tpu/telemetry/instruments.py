@@ -2,10 +2,9 @@
 
 Instruments defined here exist on the FIRST scrape of any process that
 imports telemetry at all — not only once their producer module happens
-to load. The concrete case: ``livedata_publish_rtt_seconds`` is
-recorded by ``core/link_monitor.py``, which only a pipelined service
-imports; a serial service must still EXPOSE the family (an absent name
-reads as 'not instrumented', the wrong answer) with zero samples.
+to load: a service that hosts no workload family must still EXPOSE
+``livedata_calibration_swaps`` (an absent name reads as 'not
+instrumented', the wrong answer) with zero samples.
 Span and compile-event instruments live with their single producers
 (telemetry/trace.py, telemetry/compile.py), which this package's
 ``__init__`` imports for the same always-registered guarantee.
@@ -24,7 +23,6 @@ __all__ = [
     "DECODE_ERRORS",
     "EVENTS_FILTERED",
     "JOB_WINDOWS",
-    "PUBLISH_RTT_SECONDS",
     "Q_LOOKUP_STEPS",
     "SINK_BYTES",
     "SINK_SECONDS",
@@ -33,16 +31,6 @@ __all__ = [
     "TABLE_BYTES",
     "TICK_GROUPS",
 ]
-
-#: Publish/tick device round-trip wall times as a labeled histogram
-#: (ADR 0116): the EWMA drives the link policy, but a scrape needs the
-#: DISTRIBUTION — a bimodal RTT (healthy ticks + stalls) averages
-#: into a lie. ``slice`` carries the mesh slice (ADR 0115) or "all".
-PUBLISH_RTT_SECONDS = REGISTRY.histogram(
-    "livedata_publish_rtt_seconds",
-    "Publish/tick device round-trip wall time (compile rounds excluded)",
-    labelnames=("slice",),
-)
 
 #: Calibration-plane swaps (workloads/calibration.py, ADR 0122): every
 #: live table replacement that re-keyed staged wires/tick programs,

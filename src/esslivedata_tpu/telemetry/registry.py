@@ -1,27 +1,27 @@
 """Process-wide metrics registry: counters, gauges, histograms, collectors.
 
 The serving stack grew one ad-hoc counter surface per layer —
-``ops/publish.METRICS``, ``LinkMonitor.stats()``, the ingest pipeline's
+``ops/publish.METRICS``, the ingest pipeline's
 ``StageTimer``, kafka stream/sink/breaker counts — each with its own
 snapshot method and no export surface beyond a 30 s log line. This
 module is the one registry they all meet in (ADR 0116): a scrape of
 ``/metrics`` (``telemetry/http.py``) renders every instrument in
 Prometheus text exposition format, and ``bench.py`` embeds the same
 snapshot in its JSON metric lines so BENCH trajectories carry the
-dispatch/compile/RTT decomposition alongside throughput.
+dispatch/compile decomposition alongside throughput.
 
 Two registration styles, chosen by hot-path cost:
 
 - **Direct instruments** (:class:`Counter`, :class:`Gauge`,
   :class:`Histogram`): for NEW first-class signals recorded at the
-  event (jit compile events, publish RTT samples, tick span
+  event (jit compile events, tick span
   durations). Increments take one uncontended lock (tens of ns against
   a >=71 ms window) and never allocate on the steady-state path — the
   per-labelset child is resolved once and cached by the caller
   (:meth:`Counter.labels`).
 
 - **Collectors**: for EXISTING thread-safe snapshot surfaces
-  (``PublishMetrics.snapshot``, ``LinkMonitor.stats``,
+  (``PublishMetrics.snapshot``,
   ``IngestPipeline`` depths, kafka counters, HBM stats). A collector
   is a zero-hot-path-cost pull: the producer keeps its own lock and
   counters, and the registry polls it only at scrape time. Collectors

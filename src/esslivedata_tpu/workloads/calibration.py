@@ -413,13 +413,14 @@ class CalibratedHistogrammer(EventHistogrammer):
         # a staged array (ADR 0110's keys-capture-everything rule).
         return ("calflat", self.layout_digest)
 
-    def partition_key_for(self, compact: bool) -> tuple:
+    @property
+    def partition_key(self) -> tuple:
         return (
             "calpart",
             self.layout_digest,
             self._bpb,
             self._p2_chunk,
-            compact,
+            self._p2_compact,
         )
 
     @property
@@ -527,16 +528,10 @@ class CalibratedHistogrammer(EventHistogrammer):
         return flat
 
     def flatten_partition_host(
-        self,
-        pixel_id: np.ndarray,
-        toa: np.ndarray,
-        *,
-        compact: bool | None = None,
+        self, pixel_id: np.ndarray, toa: np.ndarray
     ) -> tuple[np.ndarray, np.ndarray]:
         # The base's fused native pass computes RAW-toa indices; the
         # calibrated axis must always go flatten -> generic partition.
-        if compact is None:
-            compact = self._p2_compact
         from ..ops.pallas_hist2d import partition_events_host
 
         return partition_events_host(
@@ -544,7 +539,7 @@ class CalibratedHistogrammer(EventHistogrammer):
             self._n_bins + 1,
             bpb=self._bpb,
             chunk=self._p2_chunk,
-            compact=compact,
+            compact=self._p2_compact,
         )
 
     # The raw device path would bin raw TOA by the derived-axis edges;

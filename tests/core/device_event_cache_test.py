@@ -233,33 +233,3 @@ class TestWindowGenerations:
         hit = gen.slot("s").get_or_stage("k", lambda: np.arange(4) * 7)
         np.testing.assert_array_equal(hit, [0, 1, 2, 3])
 
-    def test_link_observer_fed_from_staging(self):
-        class Recorder:
-            def __init__(self):
-                self.samples = []
-
-            def observe_staging(self, nbytes, seconds):
-                self.samples.append((nbytes, seconds))
-
-        cache = DeviceEventCache()
-        cache.link_observer = Recorder()
-        gen = cache.new_generation()
-        arr = np.zeros(1024, np.int32)
-        gen.slot("s").get_or_stage("k", lambda: arr)
-        gen.slot("s").get_or_stage("k", lambda: arr)  # hit: no sample
-        samples = cache.link_observer.samples
-        assert len(samples) == 1
-        assert samples[0][0] == arr.nbytes
-        assert samples[0][1] >= 0.0
-
-    def test_broken_link_observer_is_contained(self):
-        class Broken:
-            def observe_staging(self, nbytes, seconds):
-                raise RuntimeError("observer bug")
-
-        cache = DeviceEventCache()
-        cache.link_observer = Broken()
-        gen = cache.new_generation()
-        out = gen.slot("s").get_or_stage("k", lambda: np.arange(2))
-        np.testing.assert_array_equal(out, [0, 1])
-        assert cache.stats()["misses"] == 1

@@ -14,7 +14,6 @@ import pytest
 from esslivedata_tpu.config import JobId, WorkflowConfig, WorkflowSpec
 from esslivedata_tpu.core.ingest_pipeline import IngestPipeline
 from esslivedata_tpu.core.job_manager import JobFactory, JobManager
-from esslivedata_tpu.core.link_monitor import LinkMonitor
 from esslivedata_tpu.core.timestamp import Timestamp
 from esslivedata_tpu.ops import EventBatch
 from esslivedata_tpu.preprocessors.event_data import StagedEvents
@@ -249,26 +248,50 @@ class TestPrestageWarming:
             pipe.stop(drain=True)
             mgr.shutdown()
 
-    def test_depth_follows_link_policy(self):
+    @pytest.mark.parametrize("depth", [1, 2, 4])
+    def test_depth_is_the_configured_depth(self, depth):
+        """With the step stage stuck, exactly ``depth`` windows are
+        admitted (no fewer, no more), the next submit waits, and both
+        introspection surfaces report the configured bound."""
         mgr = make_manager()
-        monitor = LinkMonitor()
+        release = threading.Event()
+        real_process = mgr.process_jobs
+
+        def stuck_process(*args, **kwargs):
+            release.wait(timeout=10.0)
+            return real_process(*args, **kwargs)
+
+        mgr.process_jobs = stuck_process
         pipe = IngestPipeline(
             job_manager=mgr,
             decode=lambda payload: (payload, {}, None),
             publish=lambda results, end: None,
-            depth=2,
-            max_depth=4,
-            link_monitor=monitor,
+            depth=depth,
         )
         try:
-            assert pipe.depth == 2
-            for _ in range(40):  # degraded link: deeper pipeline
-                monitor.observe_staging(16_000_000, 0.4)
-            assert pipe.depth == 4
-            for _ in range(40):  # healthy: back to base
-                monitor.observe_staging(16_000_000, 0.02)
-            assert pipe.depth == 2
+            for i in range(depth):  # every one admitted without waiting
+                pipe.submit(staged_window(i), start=T(0), end=T(i + 1))
+            assert pipe.depth == depth
+            assert pipe.stats()["depth"] == depth
+            telemetry = pipe.telemetry()
+            assert telemetry["depth"] == depth
+            assert telemetry["inflight"] == depth
+            admitted = threading.Event()
+
+            def submit_one_more():
+                pipe.submit(staged_window(depth), start=T(0), end=T(depth + 1))
+                admitted.set()
+
+            thread = threading.Thread(target=submit_one_more)
+            thread.start()
+            assert not admitted.wait(timeout=0.3)
+            assert pipe.telemetry()["inflight"] == depth
+            release.set()
+            assert admitted.wait(timeout=10.0)
+            thread.join()
+            assert pipe.flush(timeout=30.0)
         finally:
+            release.set()
             pipe.stop(drain=True)
             mgr.shutdown()
 

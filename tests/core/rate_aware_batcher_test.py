@@ -193,10 +193,18 @@ class TestBootstrap:
         assert b.batch([]) is None
 
 
-class TestSetWindow:
+def overload(b: RateAwareMessageBatcher) -> None:
+    """Two batches over the governor's high-load mark: it doubles the
+    scale, and the batcher holds the wider window for its next batch."""
+    for _ in range(2):
+        b.report_processing_time(Duration.from_s(0.9))
+    assert b.scale == 2.0
+
+
+class TestGovernedWindow:
     def test_window_change_applies_at_next_batch(self):
         b = RateAwareMessageBatcher(Duration.from_s(1.0))
-        b.set_window(Duration.from_s(2.0))
+        overload(b)
         assert b.window == Duration.from_s(1.0)  # active batch unchanged
         period = round(NS / 14)
         b.batch(pulses(DET, 0, 8, period))
@@ -213,14 +221,14 @@ def test_only_event_kinds_gate(kind):
     assert not b.is_gating(sid)
 
 
-def test_set_window_does_not_shrink_closing_batch():
-    """A pending window change must not retroactively shorten the batch
+def test_pending_window_does_not_resize_closing_batch():
+    """A pending window change must not retroactively resize the batch
     being closed (its end stays start + the window it was opened with)."""
     b = RateAwareMessageBatcher(Duration.from_s(1.0))
     period = round(NS / 14)
     b.batch(pulses(DET, 0, 8, period))
     t0 = 7 * period
-    b.set_window(Duration.from_s(0.5))
+    overload(b)
     out = None
     t = t0 + period
     while out is None:

@@ -17,7 +17,6 @@ import jax
 
 from esslivedata_tpu.config import JobId, WorkflowConfig, WorkflowSpec
 from esslivedata_tpu.core.job_manager import JobFactory, JobManager
-from esslivedata_tpu.core.link_monitor import LinkMonitor
 from esslivedata_tpu.core.timestamp import Timestamp
 from esslivedata_tpu.kafka.da00_compat import dataarray_to_da00
 from esslivedata_tpu.kafka.wire import encode_da00
@@ -293,8 +292,6 @@ class TestPlacement:
             job_factory=JobFactory(reg), job_threads=2,
             placement=placement,
         )
-        monitor = LinkMonitor()
-        mgr.set_link_observer(monitor)
         for ident, stream in idents:
             mgr.schedule_job(
                 WorkflowConfig(
@@ -342,19 +339,12 @@ class TestPlacement:
             assert counts["fetches"] == n, (label, counts)
             assert counts["tick_publishes"] == n, (label, counts)
         assert m["step_executes"] == 0
-        rtt = monitor.stats()["rtt_by_slice"]
-        assert set(rtt) == set(slices)
-        assert all(v > 0.0 for v in rtt.values())
-        # The policy reacts to the worst slice when slices report.
-        assert monitor.rtt_s(mesh_labels[0]) == rtt[mesh_labels[0]]
         mgr.shutdown()
 
-    def test_fused_path_keeps_the_slice_on_coalesced_windows(
-        self, devices
-    ):
-        """With publish coalescing, intermediate windows run the fused
-        step (no publish) — the group must keep its assigned slice so
-        the wire stages once per slice, never alternating devices."""
+    def test_fused_path_keeps_the_slice(self, devices):
+        """Without the tick program every window runs the fused step
+        and a separate publish — the group must keep its assigned slice
+        so the wire stages once per slice, never alternating devices."""
         mesh = make_mesh(2, data=1, bank=2)
         placement = DevicePlacement(mesh)
         from esslivedata_tpu.workflows.detector_view import (
@@ -377,7 +367,7 @@ class TestPlacement:
         )
         mgr = JobManager(
             job_factory=JobFactory(reg), job_threads=2,
-            placement=placement,
+            placement=placement, tick_program=False,
         )
         for _ in range(2):
             mgr.schedule_job(
@@ -386,7 +376,6 @@ class TestPlacement:
                     job_id=JobId(source_name="s0"),
                 )
             )
-        mgr.set_publish_coalesce(2)
 
         def win(i):
             rng = np.random.default_rng(i)
@@ -402,12 +391,17 @@ class TestPlacement:
                 )
             }
 
+        METRICS.drain()
         for i in range(6):
-            mgr.process_jobs(win(i), start=T(0), end=T(i + 1))
+            assert len(
+                mgr.process_jobs(win(i), start=T(0), end=T(i + 1))
+            ) == 2
+        m = METRICS.drain()
+        assert m["step_executes"] == 6 and m["tick_publishes"] == 0
         assert len(placement.slices()) == 1
         (slice_,) = placement.slices().values()
         # Every member state stayed committed to the assigned slice
-        # across publish AND coalesced (fused-step-only) windows.
+        # across every fused step and its separate publish.
         for rec in mgr._records.values():
             state = rec.job.workflow.state
             assert DevicePlacement.state_on(state, slice_.device)
@@ -417,8 +411,9 @@ class TestPlacement:
         self, devices
     ):
         """A placed SINGLETON group drops to the workflow-private
-        accumulate on coalesced windows (no fused group at K=1, no tick
-        off publish ticks): the private step must stage onto the
+        accumulate on the windows its tick is refused (no fused group
+        at K=1; here its publish offer is withheld two windows in
+        three): the private step must stage onto the
         state's slice — default-device staging would hand the jitted
         step mixed-committed-device arguments, which real multi-chip
         backends reject (the JGL017 hazard; ``_state_slice_device``
@@ -457,7 +452,12 @@ class TestPlacement:
                 identifier=spec.identifier, job_id=JobId(source_name="s0")
             )
         )
-        mgr.set_publish_coalesce(3)
+        (wf,) = created
+        offer = wf.publish_offer
+        window_no = [0]
+        wf.publish_offer = lambda: (
+            offer() if window_no[0] % 3 == 0 else None
+        )
 
         def win(i, n=2048):
             rng = np.random.default_rng(3000 + i)
@@ -474,13 +474,17 @@ class TestPlacement:
             }
 
         results = []
+        METRICS.drain()
         for i in range(6):
+            window_no[0] = i
             results.extend(
                 mgr.process_jobs(win(i), start=T(0), end=T(i + 1))
             )
+        assert METRICS.drain()["tick_publishes"] == 2  # windows 0 and 3
+        assert len(results) == 6
         (slice_,) = placement.slices().values()
         assert slice_.device is not None
-        # The state stayed on its slice through coalesced windows (the
+        # The state stayed on its slice through the private windows (the
         # private accumulate ran there, it never bounced to default),
         # nothing errored, and the published cumulative carries every
         # window's events.
@@ -500,7 +504,7 @@ class TestReKeying:
         """A live LUT swap re-fingerprints the layout: stage/fuse keys
         change, so staged wires can never be consumed by a program
         traced for the other table, and the next tick compiles a fresh
-        program (``last_compiled`` — the RTT-exclusion signal)."""
+        program (``compiled`` on its handle)."""
         mesh = make_mesh(4, data=2, bank=2)
         edges = np.linspace(0.0, 7e7, 9)
         lut = (np.arange(64) % 8).astype(np.int32)
@@ -530,30 +534,22 @@ class TestReKeying:
             np.full(64, 1e6, np.float32),
         )
         staged = h.tick_staging(batch, None)
-        res = combiner.publish(
-            h,
-            ("g",) + h.fuse_key,
-            staged,
-            [PublishRequest(pub, (h.init_state(),))],
-        )
-        assert combiner.last_compiled
-        assert res[0].error is None
-        res = combiner.publish(
-            h,
-            ("g",) + h.fuse_key,
-            staged,
-            [PublishRequest(pub, (h.init_state(),))],
-        )
-        assert not combiner.last_compiled  # steady state: cache hit
+
+        def tick():
+            pending = combiner.dispatch(
+                h,
+                ("g",) + h.fuse_key,
+                staged,
+                [PublishRequest(pub, (h.init_state(),))],
+            )
+            (res,) = combiner.collect(pending)
+            assert res.error is None
+            return pending.compiled
+
+        assert tick()
+        assert not tick()  # steady state: cache hit
         assert h.swap_projection((lut + 2) % 8)
-        res = combiner.publish(
-            h,
-            ("g",) + h.fuse_key,
-            staged,
-            [PublishRequest(pub, (h.init_state(),))],
-        )
-        assert combiner.last_compiled  # digest moved -> re-keyed
-        assert res[0].error is None
+        assert tick()  # digest moved -> re-keyed
 
 
 class TestContainment:

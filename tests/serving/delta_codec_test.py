@@ -185,6 +185,28 @@ class TestDecoderContinuity:
         assert out == b
         assert decoder.seq == 1
 
+    def test_a_length_change_rebases_and_its_replay_is_a_noop(self):
+        """8 -> 9 bytes in one epoch: the encoder emits a keyframe (no
+        delta can bridge two lengths), the decoder rebases on it, and
+        the same blob delivered again returns the held frame. A delta
+        of the NEXT tick against a decoder that never saw that keyframe
+        is refused: the decoder cannot patch a frame it does not hold."""
+        old, new = b"8 bytes.", b"9 bytes.."
+        encoder, decoder, late = DeltaEncoder(), DeltaDecoder(), DeltaDecoder()
+        first = encoder.encode(old, epoch=0, seq=0)
+        decoder.apply(first)
+        late.apply(first)
+        blob = encoder.encode(new, epoch=0, seq=1)
+        assert decode_header(blob).keyframe
+        assert decoder.apply(blob) == new
+        assert decoder.apply(blob) == new  # the replay
+        assert (decoder.epoch, decoder.seq) == (0, 1)
+        unchanged = encoder.encode(new, epoch=0, seq=1)
+        assert not decode_header(unchanged).keyframe
+        assert decoder.apply(unchanged) == new  # stale: seq 1 is held
+        with pytest.raises(DeltaError, match="length 9 != held 8"):
+            late.apply(unchanged)
+
     def test_seq_gap_raises(self):
         a, b, c = self._pair()
         decoder = DeltaDecoder()

@@ -1,8 +1,8 @@
 """ResultCache (serving/result_cache.py, ADR 0117): epoch/ring/locking.
 
 The satellite fix this PR carries: the cache snapshot must follow the
-ONE-acquisition discipline PR 9 gave ``LinkMonitor.stats()`` — a
-scraping subscriber can never pair a frame with the wrong epoch tag.
+ONE-acquisition discipline — a scraping subscriber can never pair a
+frame with the wrong epoch tag.
 The lock hammer at the bottom pins that under a real writer/reader
 race.
 """

@@ -119,12 +119,11 @@ class TestScrapeExposesTheStack:
         assert scrapes
         parsed = parse_prometheus_text(scrapes[-1])
         # The acceptance list: dispatch counters (+ per-slice family),
-        # RTT histograms, pipeline queue depths, kafka/stream counts,
+        # pipeline queue depths, kafka/stream counts,
         # HBM gauges, compile-event histograms, span decomposition.
         for family in (
             "livedata_publish_events",
             "livedata_publish_slice_events",
-            "livedata_publish_rtt_seconds",
             "livedata_pipeline_queue_depth",
             "livedata_pipeline_stage_busy_seconds",
             "livedata_stream_messages",
@@ -134,8 +133,6 @@ class TestScrapeExposesTheStack:
             "livedata_jit_compiles_total",
             "livedata_jit_compile_seconds",
             "livedata_tick_span_seconds",
-            "livedata_link_rtt_ewma_seconds",
-            "livedata_link_policy",
         ):
             assert family in parsed, f"scrape missing {family}"
         # The producers actually produced: compile events fired for the

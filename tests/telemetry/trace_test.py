@@ -260,7 +260,7 @@ class TestCompileClassification:
     def test_trigger_taxonomy(self):
         rec = CompileEventRecorder()
         group = ("hist", ("pub",))
-        base = dict(layout_digest="d1", wire="wide", staged_sig="s1")
+        base = dict(layout_digest="d1", staged_sig="s1")
         assert rec.classify("tick", group, **base) == "new_group"
         assert (
             rec.classify("tick", group, **{**base, "layout_digest": "d2"})
@@ -270,16 +270,7 @@ class TestCompileClassification:
             rec.classify(
                 "tick",
                 group,
-                **{**base, "layout_digest": "d2", "wire": "compact"},
-            )
-            == "wire_flip"
-        )
-        assert (
-            rec.classify(
-                "tick",
-                group,
                 layout_digest="d2",
-                wire="compact",
                 staged_sig="s2",
             )
             == "batch_shape"
@@ -289,7 +280,6 @@ class TestCompileClassification:
                 "tick",
                 group,
                 layout_digest="d2",
-                wire="compact",
                 staged_sig="s2",
                 residual="tag-b",
             )
@@ -301,7 +291,6 @@ class TestCompileClassification:
                 "tick",
                 group,
                 layout_digest="d2",
-                wire="compact",
                 staged_sig="s2",
                 residual="tag-b",
             )

@@ -1186,8 +1186,8 @@ def test_tools_tree_is_clean():
 # -- whole-program pass (JGL011-014, docs/adr/0112) ------------------------
 
 # The regression fixture the tentpole demands: the real batcher/pipeline
-# lock pair split across TWO modules, inverted. Modeled on
-# core/rate_aware_batcher.py (RLock'd set_window) and
+# lock pair split across TWO modules, inverted. Modeled on a batcher
+# with an RLock'd set_window and
 # core/ingest_pipeline.py (Condition'd submit): if a completion callback
 # ever called back into the batcher under the pipeline's state lock
 # while the batcher submits under its own lock, these would deadlock.

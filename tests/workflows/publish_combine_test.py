@@ -338,7 +338,7 @@ class TestStaticCache:
         assert wf._publish.static_keys  # zero blocks are static again
 
 
-class TestPublishCoalescing:
+class TestPendingAccumulationFlushes:
     def _mgr(self):
         det = np.arange(144).reshape(12, 12)
         return _make_manager(
@@ -346,121 +346,49 @@ class TestPublishCoalescing:
             job_threads=1,
         )
 
-    def test_coalesced_windows_accumulate_then_flush(self):
-        mgr, _ = self._mgr()
-        mgr.set_publish_coalesce(2)
-        rng = np.random.default_rng(39)
-        windows = _windows(rng, 4, 1000, 0, 144)
-        counts, published = [], 0
-        for w, (pid, toa) in enumerate(windows):
-            res = mgr.process_jobs(
-                {"det0": _staged(pid, toa)}, start=T(0), end=T(w + 1)
-            )
-            if res:
-                published += 1
-                counts.append(
-                    float(res[0].outputs["counts_current"].values)
-                )
-        assert published == 2  # every second window
-        # Each publish flushed BOTH windows' accumulation: pairwise sums
-        # of an every-window reference manager over the same windows.
-        ref, _ = self._mgr()
-        ref_counts = [
-            float(
-                ref.process_jobs(
-                    {"det0": _staged(pid, toa)}, start=T(0), end=T(w + 1)
-                )[0].outputs["counts_current"].values
-            )
-            for w, (pid, toa) in enumerate(windows)
-        ]
-        assert counts[0] == ref_counts[0] + ref_counts[1]
-        assert counts[1] == ref_counts[2] + ref_counts[3]
-        ref.shutdown()
-        mgr.shutdown()
-
     def test_idle_flush_publishes_immediately(self):
-        mgr, _ = self._mgr()
-        mgr.set_publish_coalesce(8)
+        mgr, created = self._mgr()
         rng = np.random.default_rng(40)
         pid, toa = _windows(rng, 1, 1000, 0, 144)[0]
+        # The window's finalize fails once: its accumulation stays
+        # pending (``has_primary_data``) with the job in error.
+        wf = created[0]
+        real_finalize = wf.finalize
+
+        def failing_finalize():
+            wf.finalize = real_finalize
+            raise RuntimeError("sink hiccup")
+
+        wf.finalize = failing_finalize
         assert mgr.process_jobs(
             {"det0": _staged(pid, toa)}, start=T(0), end=T(1)
-        ) == []  # coalesced away
+        ) == []
         # Idle tick (no data): the pending accumulation must flush — a
-        # stop during beam-off cannot wait out the coalescing window.
+        # stop during beam-off cannot wait for the next data window.
         res = mgr.process_jobs({})
         assert len(res) == 1
+        ref, _ = self._mgr()
+        want = ref.process_jobs(
+            {"det0": _staged(pid, toa)}, start=T(0), end=T(1)
+        )[0].outputs["counts_cumulative"].values
+        assert res[0].outputs["counts_cumulative"].values == want > 0
+        assert mgr.process_jobs({}) == []  # nothing pending any more
+        ref.shutdown()
         mgr.shutdown()
 
     def test_finishing_job_forces_the_tick(self):
         from esslivedata_tpu.core.job_manager import JobCommand
 
         mgr, _ = self._mgr()
-        mgr.set_publish_coalesce(8)
         rng = np.random.default_rng(41)
         windows = _windows(rng, 2, 1000, 0, 144)
-        assert mgr.process_jobs(
+        assert len(mgr.process_jobs(
             {"det0": _staged(*windows[0])}, start=T(0), end=T(1)
-        ) == []
+        )) == 1
         assert mgr.handle_command(JobCommand(action="stop")) == 1
         res = mgr.process_jobs(
             {"det0": _staged(*windows[1])}, start=T(0), end=T(2)
         )
-        assert len(res) == 1  # final flush ignored the coalescing window
+        assert len(res) == 1  # the window that carried the stop flushed
         assert not mgr.has_finishing_jobs()
         mgr.shutdown()
-
-
-class TestLinkMonitorCoalesceAxis:
-    def test_rtt_latch_widens_and_recovers_with_hysteresis(self):
-        from esslivedata_tpu.core.link_monitor import LinkMonitor
-
-        mon = LinkMonitor(alpha=1.0)  # no smoothing: direct injection
-        assert mon.policy().publish_coalesce == 1
-        mon.observe_publish(0.0877)  # round-5 measured publish RTT
-        assert mon.policy().publish_coalesce == 4
-        # In the dead zone (25..50 ms) the latch holds.
-        mon.observe_publish(0.03)
-        assert mon.policy().publish_coalesce == 2
-        # Recovery below threshold/recover_factor releases the latch.
-        mon.observe_publish(0.01)
-        assert mon.policy().publish_coalesce == 1
-        # Back in the dead zone from BELOW: stays released.
-        mon.observe_publish(0.03)
-        assert mon.policy().publish_coalesce == 1
-        # A catastrophic RTT caps at the bound.
-        mon.observe_publish(0.5)
-        assert mon.policy().publish_coalesce == 8
-
-    def test_policy_reaches_job_manager_through_processor(self):
-        from esslivedata_tpu.core.link_monitor import LinkPolicy
-
-        class Recorder:
-            coalesce = None
-
-            def set_publish_coalesce(self, n):
-                self.coalesce = n
-
-        rec = Recorder()
-
-        class Processor:
-            # Borrow the real _apply_link_policy against stand-ins.
-            from esslivedata_tpu.core.orchestrating_processor import (
-                OrchestratingProcessor as _P,
-            )
-
-            _apply_link_policy = _P._apply_link_policy
-
-        import threading
-
-        p = Processor()
-        p._policy_lock = threading.Lock()
-        p._pending_policy = LinkPolicy(
-            window_scale=1.0, compact_wire=None, depth=2, publish_coalesce=4
-        )
-        p._applied_publish_coalesce = 1
-        p._applied_window_scale = 1.0
-        p._base_window = None
-        p._job_manager = rec
-        p._apply_link_policy()
-        assert rec.coalesce == 4

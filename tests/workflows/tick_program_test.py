@@ -208,46 +208,6 @@ class TestTickVsThreeDispatchParity:
         assert m["executes"] == 1 and m["fetches"] == 1
         mgr.shutdown()
 
-    def test_coalescing_ticks_only_on_publish_windows(self):
-        """Intermediate coalesced windows keep the fused-step dispatch;
-        the flush window ticks and publishes BOTH windows' counts."""
-        det = _det()
-        mgr, _ = _make_manager(
-            [lambda: DetectorViewWorkflow(projection=project_logical(det))]
-            * 2,
-        )
-        ref, _ = _make_manager(
-            [lambda: DetectorViewWorkflow(projection=project_logical(det))]
-            * 2,
-        )
-        mgr.set_publish_coalesce(2)
-        rng = np.random.default_rng(54)
-        windows = _windows(rng, 4, 1000, 0, 144)
-        counts = []
-        ref_counts = []
-        for w, (pid, toa) in enumerate(windows):
-            res = mgr.process_jobs(
-                {"det0": _staged(pid, toa)}, start=T(0), end=T(w + 1)
-            )
-            if res:
-                counts.append(
-                    float(res[0].outputs["counts_current"].values)
-                )
-            ref_counts.append(
-                float(
-                    ref.process_jobs(
-                        {"det0": _staged(pid, toa)},
-                        start=T(0),
-                        end=T(w + 1),
-                    )[0].outputs["counts_current"].values
-                )
-            )
-        assert counts[0] == ref_counts[0] + ref_counts[1]
-        assert counts[1] == ref_counts[2] + ref_counts[3]
-        mgr.shutdown()
-        ref.shutdown()
-
-
 class TestContextOrdering:
     def test_fresh_context_windows_bypass_the_tick(self):
         """A window that carries a fresh context update for a job never
@@ -395,33 +355,34 @@ class TestStaticOutputs:
         mgr.shutdown()
 
 
-class TestWireFormatFlip:
-    def test_mid_stream_flip_stays_bit_identical(self):
-        """A link-policy int32<->uint16 wire flip between windows
-        re-keys staging AND the tick program (the fuse key carries the
-        compaction flag); counts stay bit-identical to the
-        separate-dispatch reference across the flip."""
+class TestWireFormats:
+    @pytest.mark.parametrize(
+        ("toa_bins", "compact"), [(100, True), (64, False)]
+    )
+    def test_either_wire_stays_bit_identical(self, toa_bins, compact):
+        """The partitioned wire is chosen at construction: uint16 block
+        offsets where a block of bins fits them (100 TOA bins: blocks
+        of 51 200), int32 where it does not (64: blocks of 65 536).
+        On either, the tick program's counts are bit-identical to the
+        separate-dispatch reference."""
         det = _det()
 
         def make():
             return DetectorViewWorkflow(
                 projection=project_logical(det),
-                params=DetectorViewParams(histogram_method="pallas2d"),
+                params=DetectorViewParams(
+                    histogram_method="pallas2d", toa_bins=toa_bins
+                ),
             )
 
-        if make()._hist._method != "pallas2d":  # config rejected it
-            pytest.skip("pallas2d unavailable for this configuration")
         tick, created_t = _make_manager([make] * 2)
         ref, created_r = _make_manager([make] * 2, tick_program=False)
+        for wf in (*created_t, *created_r):
+            assert wf.histogrammer.fuse_key[1] == "pallas2d"
+            assert wf.histogrammer.partition_key[-1] is compact
         rng = np.random.default_rng(58)
-        windows = _windows(rng, 4, 1000, 0, 144)
-        for w, (pid, toa) in enumerate(windows):
-            if w == 2:  # mid-stream flip, both managers identically
-                for wf in (*created_t, *created_r):
-                    assert wf.histogrammer.set_wire_format(False)
-            if w == 3:  # and back
-                for wf in (*created_t, *created_r):
-                    assert wf.histogrammer.set_wire_format(True)
+        METRICS.drain()
+        for w, (pid, toa) in enumerate(_windows(rng, 3, 1000, 0, 144)):
             res_t = tick.process_jobs(
                 {"det0": _staged(pid, toa)}, start=T(0), end=T(w + 1)
             )
@@ -431,7 +392,8 @@ class TestWireFormatFlip:
             assert len(res_t) == len(res_r) == 2
             for rt, rr in zip(res_t, res_r):
                 for bt, br in zip(_wire_bytes(rt), _wire_bytes(rr)):
-                    assert bt == br, f"window {w}: flip broke parity"
+                    assert bt == br, f"window {w}: the wires disagree"
+        assert METRICS.drain()["tick_publishes"] == 3
         states = {str(s.state) for s in tick.job_statuses()}
         assert "error" not in states
         tick.shutdown()
@@ -540,51 +502,6 @@ class TestContainment:
         states = {str(s.state) for s in mgr.job_statuses()}
         assert "error" not in states
         mgr.shutdown()
-
-
-class TestLinkObserver:
-    class _Observer:
-        def __init__(self):
-            self.publishes: list[float] = []
-            self.stagings: list[tuple[int, float]] = []
-
-        def observe_publish(self, seconds):
-            self.publishes.append(seconds)
-
-        def observe_staging(self, nbytes, seconds):
-            self.stagings.append((nbytes, seconds))
-
-    def test_compile_rounds_do_not_feed_the_rtt_estimate(self):
-        """The tick path threads last_compiled through: the first tick
-        (static-inclusive compile) and the second (dynamic-only compile)
-        are NOT observed; steady-state ticks are."""
-        det = _det()
-        mgr, _ = _make_manager(
-            [lambda: DetectorViewWorkflow(projection=project_logical(det))]
-            * 2,
-        )
-        observer = self._Observer()
-        mgr.set_link_observer(observer)
-        rng = np.random.default_rng(61)
-        windows = _windows(rng, 5, 1000, 0, 144)
-        for w, (pid, toa) in enumerate(windows):
-            mgr.process_jobs(
-                {"det0": _staged(pid, toa)}, start=T(0), end=T(w + 1)
-            )
-        # 5 windows: 2 compile ticks skipped, 3 steady ticks observed.
-        assert len(observer.publishes) == 3
-        assert all(s > 0 for s in observer.publishes)
-        mgr.shutdown()
-
-    def test_link_monitor_ignores_compiled_samples(self):
-        from esslivedata_tpu.core.link_monitor import LinkMonitor
-
-        mon = LinkMonitor(alpha=1.0)
-        mon.observe_publish(0.5, compiled=True)  # a compile round
-        assert mon.rtt_s() is None
-        assert mon.policy().publish_coalesce == 1
-        mon.observe_publish(0.0877)
-        assert mon.policy().publish_coalesce == 4
 
 
 class TestProgramNamesAndScopes:
@@ -966,44 +883,4 @@ class TestPipelinedContainment:
         res = _process(mgr, windows[3], 3)  # group 2 compiles afresh
         assert METRICS.drain()["tick_publishes"] == 3
         assert float(res[1].outputs["counts_cumulative"].values) > cum[1]
-        mgr.shutdown()
-
-
-class TestLinkObserverPerGroup:
-    def test_each_group_reports_its_dispatch_and_its_wait(self):
-        mgr, _ = _make_group_manager(3)
-        observer = TestLinkObserver._Observer()
-        mgr.set_link_observer(observer)
-        for w, window in enumerate(_group_windows(76, 4, 3)):
-            _process(mgr, window, w)
-        # Two compile ticks skipped, then three groups a tick.
-        assert len(observer.publishes) == 6
-        assert all(s > 0 for s in observer.publishes)
-        mgr.shutdown()
-
-    def test_a_sample_leaves_out_what_ran_between_the_two_halves(self):
-        """The first group's sample must not hold the host's work on
-        the second (here: a slow second dispatch), or every group reads
-        the whole tick and the publish tick widens for nothing."""
-        mgr, _ = _make_group_manager(2)
-        observer = TestLinkObserver._Observer()
-        mgr.set_link_observer(observer)
-        windows = _group_windows(77, 3, 2)
-        for w in range(2):
-            _process(mgr, windows[w], w)
-        combiner = mgr._tick_combiner
-        dispatch = combiner.dispatch
-        dispatched = []
-
-        def slow_second_dispatch(*args, **kwargs):
-            dispatched.append(None)
-            if len(dispatched) == 2:
-                time.sleep(0.3)
-            return dispatch(*args, **kwargs)
-
-        combiner.dispatch = slow_second_dispatch
-        observer.publishes.clear()
-        _process(mgr, windows[2], 2)
-        first, second = observer.publishes
-        assert 0 < first < 0.3 <= second
         mgr.shutdown()

@@ -412,7 +412,7 @@ def broadcast_fanout_state(ctx: FileContext):
 
     Methods named ``*_locked`` are exempt from the registry hazard —
     the codebase's caller-holds-the-lock convention (see
-    ``LinkMonitor._policy_locked``); the lock discipline is checked at
+    ``CheckpointPlane._gc_locked``); the lock discipline is checked at
     their call sites.
     """
     if not ctx.is_threaded_module:

@@ -1287,8 +1287,7 @@ _INVALIDATION_HINTS = ("digest", "version", "token", "epoch", "invalidate")
 #: plain cache dict named `_table` in an unrelated class is not a
 #: staged-wire hazard.
 _KEY_SURFACE = frozenset(
-    {"layout_digest", "stage_key", "partition_key", "partition_key_for",
-     "fuse_key"}
+    {"layout_digest", "stage_key", "partition_key", "fuse_key"}
 )
 
 

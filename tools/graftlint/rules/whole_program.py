@@ -231,9 +231,9 @@ def jit_key_coherence(project: ProjectContext):
     compiled program at trace time, and the stage-once cache + fused
     stepping reuse staged arrays and grouped dispatches by the class's
     ``stage_key``/``partition_key``/``fuse_key`` tuples (ADR 0110/0111).
-    An attribute the kernel reads but no key mentions is exactly the
-    re-keying bug ``set_wire_format`` dodged by hand: flip the attribute
-    and the cache keeps serving bytes staged under the old value.
+    An attribute the kernel reads but no key mentions is a re-keying
+    bug: change the attribute and the cache keeps serving bytes staged
+    under the old value.
     Coverage is by attribute root (``self._proj.layout_digest`` in a key
     covers every ``self._proj.*`` read); attributes that are pure
     functions of keyed ones are declared once per class with
