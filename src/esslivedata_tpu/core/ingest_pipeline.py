@@ -90,6 +90,7 @@ class PipelineWindow:
     context: dict[str, Any] = field(default_factory=dict)
     fresh_context: set[str] | None = None
     generation: Any = None  # WindowGeneration, attached by the stage stage
+    #: Every result the window published, ahead or at its end.
     results: list = field(default_factory=list)
     #: Wall seconds per stage for THIS window (the completion callback's
     #: load signal: the slowest stage is the pipeline's service time).
@@ -467,6 +468,16 @@ class IngestPipeline:
             window = self._get(self._step_q)
             if window is None:
                 return
+
+            def publish(results: list, window=window) -> None:
+                # The window's publisher: the manager calls it with
+                # each tick group's results while later groups are
+                # still on the chip (ADR 0128), this loop once more
+                # with what is left.
+                with TRACER.span("sink", window.trace):
+                    self._publish(results, window.end)
+                window.results.extend(results)
+
             try:
                 t0 = time.perf_counter()
                 # Bind the window's trace for everything the step runs:
@@ -474,25 +485,28 @@ class IngestPipeline:
                 # finalize) read the thread-bound id — they don't know
                 # the window.
                 with self._timer.stage("step"), TRACER.bind(window.trace):
-                    window.results = self._job_manager.process_jobs(
+                    rest = self._job_manager.process_jobs(
                         window.data,
                         context=window.context,
                         fresh_context=window.fresh_context,
                         start=window.start,
                         end=window.end,
                         prestaged=True,
+                        publish=publish,
                     )
                 window.stage_s["step"] = time.perf_counter() - t0
                 t0 = time.perf_counter()
                 with self._timer.stage("publish"):
+                    if rest:
+                        publish(rest)
                     if window.results:
-                        with TRACER.span("sink", window.trace):
-                            self._publish(window.results, window.end)
                         # "published" means results actually left: an
                         # empty window (no jobs due) records nothing.
+                        # Once per window, at its last publish.
                         observe_stage("published", window.source_ts_ns)
-                # Publish-stage time here is sink serialization only;
-                # the device round trip is inside the step.
+                # Publish-stage time here is the serialization of what
+                # was left at the window's end; the device round trip
+                # and the groups published ahead are inside the step.
                 window.stage_s["publish"] = time.perf_counter() - t0
             finally:
                 if window.generation is not None:

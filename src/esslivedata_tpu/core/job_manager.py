@@ -19,7 +19,7 @@ import bisect
 import logging
 import threading
 import uuid
-from collections.abc import Mapping
+from collections.abc import Callable, Mapping
 from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
 from enum import StrEnum
@@ -29,7 +29,7 @@ from pydantic import BaseModel, model_validator
 
 from ..config.workflow_spec import JobId, WorkflowConfig
 from ..preprocessors.event_data import StagedEvents
-from ..telemetry.instruments import JOB_WINDOWS, TICK_GROUPS
+from ..telemetry.instruments import JOB_PUBLISHES, JOB_WINDOWS, TICK_GROUPS
 from ..telemetry.trace import TRACER
 from ..workflows.workflow_factory import WorkflowFactory, workflow_registry
 from .device_event_cache import DeviceEventCache
@@ -1049,7 +1049,10 @@ class JobManager:
             return None
 
     def _run_tick_programs(
-        self, tick_groups: list[tuple[tuple, Any, list]]
+        self,
+        tick_groups: list[tuple[tuple, Any, list]],
+        hand_over: Callable[[list[tuple[_JobRecord, dict[str, Any]]]], None]
+        | None = None,
     ) -> tuple[set[int], dict[JobId, set[str]]]:
         """Execute every ((stream, key), slice, members) tick group as
         ONE device dispatch + ONE fetch, the groups of a tick
@@ -1060,6 +1063,15 @@ class JobManager:
         runs under the next group's program. The per-group contract
         (ADR 0114) and every result are unchanged; only the host's
         position in time moves.
+
+        In that second pass a group's served members are handed to
+        ``hand_over``, each with the window data its program stepped,
+        right after its collect, as long as a later group is still
+        uncollected:
+        ``process_jobs`` finalizes and publishes them there, beside the
+        chip's work on the groups behind (ADR 0128). The last group of
+        a tick, and whatever a drain inside the dispatch pass collects,
+        is handed to nobody and leaves at the end of the window.
 
         Three cases stay serial, by what can be observed here and by
         no switch. A group whose program misses the program LRU ran
@@ -1104,12 +1116,15 @@ class JobManager:
         in_flight: list[tuple] = []
         flying: set[int] = set()
 
-        def drain() -> None:
-            for members, combiner, pending in in_flight:
-                self._collect_tick_group(
+        def drain(hand_over=None) -> None:
+            while in_flight:
+                members, combiner, pending = in_flight.pop(0)
+                collected = self._collect_tick_group(
                     members, combiner, pending, served, streams_done
                 )
-            in_flight.clear()
+                # Ahead means ahead of a group still on the chip.
+                if hand_over is not None and in_flight and collected:
+                    hand_over(collected)
             flying.clear()
 
         for (stream, key), plc, members in tick_groups:
@@ -1169,7 +1184,7 @@ class JobManager:
             flying.update(id(rec) for rec, *_ in members)
             if pending.compiled:
                 drain()
-        drain()
+        drain(hand_over)
         return served, streams_done
 
     def _tick_group_failed(self, members: list) -> None:
@@ -1203,16 +1218,19 @@ class JobManager:
         pending,
         served: set[int],
         streams_done: dict[JobId, set[str]],
-    ) -> None:
+    ) -> list[tuple[_JobRecord, dict[str, Any]]]:
         """The second half of one tick group: wait for its program,
         then the per-member bookkeeping into ``served`` and
-        ``streams_done`` (``_run_tick_programs``)."""
+        ``streams_done`` (``_run_tick_programs``). Returns the members
+        it served, each with the window data the program stepped (all
+        of the member's window: ``_split_tick_groups``)."""
+        collected: list[tuple[_JobRecord, dict[str, Any]]] = []
         try:
             results = combiner.collect(pending)
         except Exception:
             self._tick_group_failed(members)
-            return
-        for (rec, strm, _value, _ingest, offer), res in zip(
+            return collected
+        for (rec, strm, value, _ingest, offer), res in zip(
             members, results, strict=True
         ):
             if res.error is not None:
@@ -1261,6 +1279,8 @@ class JobManager:
                 continue
             served.add(id(rec))
             streams_done.setdefault(rec.job.job_id, set()).add(strm)
+            collected.append((rec, {strm: value}))
+        return collected
 
     # -- pipelined ingest (core/ingest_pipeline.py, ADR 0111) --------------
     def open_window(self, data: Mapping[str, Any]):
@@ -1403,12 +1423,22 @@ class JobManager:
         start: Timestamp | None = None,
         end: Timestamp | None = None,
         prestaged: bool = False,
+        publish: Callable[[list[JobResult]], None] | None = None,
     ) -> list[JobResult]:
         """One window: fire due resets, advance phases, open gates, fan
         per-job add over the thread pool, then serve every due job's
         publish from one combined device round trip per device and fan
         the finalize/serialization back out — per-job errors contained
         at every phase (ADR 0113).
+
+        ``publish`` is the window's publisher. Given one, the results
+        of a tick group leave through it as soon as the group is
+        collected while a later group of the tick is still on the chip
+        (ADR 0128); the return value is what has not left yet, for the
+        caller to publish as it always has. Per job nothing changes:
+        one result per closed window, in order. An exception of the
+        publisher is raised from here once the window's work is done.
+        Without one every result is returned.
 
         Fused-step groups whose every member is due take the
         tick-program fast path (ops/tick.py, ADR 0114): step
@@ -1508,39 +1538,17 @@ class JobManager:
                     work, fuse_groups
                 )
 
-        # Tick fast path (outside the lock, same as the fan-out): groups
-        # whose every member is due step AND publish in ONE dispatch
-        # (ops/tick.py, ADR 0114). Remaining groups of >= 2 jobs sharing
-        # a (stream, fuse-key) advance all their states in ONE fused
-        # dispatch from ONE cached staging.
-        tick_served: set[int] = set()
-        tick_streams: dict[JobId, set[str]] = {}
-        if self._tick_combiner is not None:
-            fuse_groups, tick_groups = self._split_tick_groups(
-                work, fuse_groups
-            )
-            tick_served, tick_streams = self._run_tick_programs(tick_groups)
-        fused_streams = self._run_fused_steps(fuse_groups)
-        for rec, job_data in work:
-            if job_data:
-                JOB_WINDOWS.inc(
-                    path="tick"
-                    if rec.job.job_id in tick_streams
-                    else "fused"
-                    if rec.job.job_id in fused_streams
-                    else "private"
-                )
-        for job_id, streams in tick_streams.items():
-            fused_streams.setdefault(job_id, set()).update(streams)
-
         trace_id = TRACER.current()
 
         def run_accumulate(item: tuple[_JobRecord, dict[str, Any]]) -> None:
-            with TRACER.bind(trace_id):  # a pool thread has none of its own
-                accumulate(*item)
-
-        def accumulate(rec: _JobRecord, job_data: dict[str, Any]) -> None:
+            rec, job_data = item
             skip_streams = fused_streams.get(rec.job.job_id, frozenset())
+            with TRACER.bind(trace_id):  # a pool thread has none of its own
+                accumulate(rec, job_data, skip_streams)
+
+        def accumulate(
+            rec: _JobRecord, job_data: dict[str, Any], skip_streams
+        ) -> None:
             job = rec.job
             # Deliver pending context in its own try: a failure keeps the
             # names queued (retried next window) and does not block this
@@ -1581,17 +1589,6 @@ class JobManager:
                 rec.warning = f"{type(err).__name__}: {err}"
                 logger.exception("Job %s failed accumulating", job.job_id)
 
-        if self._executor is not None and len(work) > 1:
-            list(self._executor.map(run_accumulate, work))
-        else:
-            for item in work:
-                run_accumulate(item)
-
-        # Every accumulated state is final for this window: jobs due a
-        # publish finalize below, prefetched through ONE combined device
-        # round trip per device.
-        due = [rec for rec, _ in work if rec.has_primary_data]
-
         def run_finalize(rec: _JobRecord) -> JobResult | None:
             # Finalize: a failure here is an error; has_primary_data stays
             # set so the next window retries.
@@ -1610,15 +1607,9 @@ class JobManager:
                 logger.exception("Job %s failed finalizing", rec.job.job_id)
                 return None
 
-        results: list[JobResult | None] = []
-        if due:
-            # Tick-served records already published inside their tick
-            # program; combining them again would dispatch a second
-            # publish over the already-folded state.
-            self._run_combined_publish(
-                [rec for rec in due if id(rec) not in tick_served]
-            )
-            # One finalize span per window (ADR 0116), recorded from
+        def finalize(due: list[_JobRecord]) -> list[JobResult]:
+            # One finalize span per group handed over ahead and one for
+            # the rest of the window (ADR 0116, ADR 0128), recorded from
             # THIS thread (the step worker carries the window's bound
             # trace id; the pool threads inside wouldn't).
             with TRACER.span("finalize"):
@@ -1626,6 +1617,86 @@ class JobManager:
                     results = list(self._executor.map(run_finalize, due))
                 else:
                     results = [run_finalize(rec) for rec in due]
+            return [r for r in results if r is not None]
+
+        # Results leave per tick group (ADR 0128): the members a group's
+        # collect served run here what the rest of the window runs for
+        # them below (the add is bookkeeping: the program stepped all
+        # of a tick-served member's window, and it has no queued
+        # context), then go to the window's publisher while the chip
+        # works on the groups behind. A publisher that raises is
+        # remembered and raised when the window is done: nothing more
+        # is handed over ahead, every pending group is still collected
+        # and every state adopted.
+        ahead: set[int] = set()
+        publish_failure: Exception | None = None
+
+        def publish_ahead(
+            collected: list[tuple[_JobRecord, dict[str, Any]]]
+        ) -> None:
+            nonlocal publish_failure
+            if publish_failure is not None:
+                return
+            for rec, stepped in collected:
+                accumulate(rec, stepped, frozenset(stepped))
+                ahead.add(id(rec))
+            results = finalize(
+                [rec for rec, _ in collected if rec.has_primary_data]
+            )
+            if results:
+                JOB_PUBLISHES.inc(len(results), when="ahead")
+                try:
+                    publish(results)
+                except Exception as err:
+                    publish_failure = err
+
+        # Tick fast path (outside the lock, same as the fan-out): groups
+        # whose every member is due step AND publish in ONE dispatch
+        # (ops/tick.py, ADR 0114). Remaining groups of >= 2 jobs sharing
+        # a (stream, fuse-key) advance all their states in ONE fused
+        # dispatch from ONE cached staging.
+        tick_served: set[int] = set()
+        tick_streams: dict[JobId, set[str]] = {}
+        if self._tick_combiner is not None:
+            fuse_groups, tick_groups = self._split_tick_groups(
+                work, fuse_groups
+            )
+            tick_served, tick_streams = self._run_tick_programs(
+                tick_groups, None if publish is None else publish_ahead
+            )
+        fused_streams = self._run_fused_steps(fuse_groups)
+        for rec, job_data in work:
+            if job_data:
+                JOB_WINDOWS.inc(
+                    path="tick"
+                    if rec.job.job_id in tick_streams
+                    else "fused"
+                    if rec.job.job_id in fused_streams
+                    else "private"
+                )
+        for job_id, streams in tick_streams.items():
+            fused_streams.setdefault(job_id, set()).update(streams)
+        work = [item for item in work if id(item[0]) not in ahead]
+
+        if self._executor is not None and len(work) > 1:
+            list(self._executor.map(run_accumulate, work))
+        else:
+            for item in work:
+                run_accumulate(item)
+
+        # Every accumulated state is final for this window: jobs due a
+        # publish finalize below, prefetched through ONE combined device
+        # round trip per device.
+        due = [rec for rec, _ in work if rec.has_primary_data]
+        results: list[JobResult] = []
+        if due:
+            # Tick-served records already published inside their tick
+            # program; combining them again would dispatch a second
+            # publish over the already-folded state.
+            self._run_combined_publish(
+                [rec for rec in due if id(rec) not in tick_served]
+            )
+            results = finalize(due)
 
         with self._lock:
             for rec in list(self._records.values()):
@@ -1649,7 +1720,11 @@ class JobManager:
             # windows: the pipeline closes its own generation after the
             # publish instead.)
             self._event_cache.end_window()
-        return [r for r in results if r is not None]
+        if publish_failure is not None:
+            raise publish_failure
+        if results:
+            JOB_PUBLISHES.inc(len(results), when="end")
+        return results
 
     # graft: protocol=fleet (ADR 0124: the per-group owns() consult
     # below is the modeled filter of the single-owner invariant)

@@ -504,22 +504,36 @@ class OrchestratingProcessor:
             fresh_context = self._preprocessor.fresh_context_names()
         observe_stage("decode", decode_ts_ns)
         self._record_lag(batch)
-        with self.stage_timer.stage("process_jobs"), TRACER.bind(trace_id):
-            results = self._job_manager.process_jobs(
-                window,
-                context=context,
-                fresh_context=fresh_context,
-                start=batch.start,
-                end=batch.end,
-            )
-        try:
+        published = False
+
+        def publish(results: list[JobResult]) -> None:
+            # The window's publisher: the manager calls it with each
+            # tick group's results while later groups are still on the
+            # chip (ADR 0128), this loop once more with what is left.
+            nonlocal published
             with self.stage_timer.stage("publish"), TRACER.span(
                 "sink", trace_id
             ):
                 self._publish_results(results, batch.end)
-            if results:
+            published = published or bool(results)
+
+        try:
+            with self.stage_timer.stage("process_jobs"), TRACER.bind(
+                trace_id
+            ):
+                results = self._job_manager.process_jobs(
+                    window,
+                    context=context,
+                    fresh_context=fresh_context,
+                    start=batch.start,
+                    end=batch.end,
+                    publish=publish,
+                )
+            publish(results)
+            if published:
                 # "published" means results actually left: a window
-                # with no due jobs records nothing.
+                # with no due jobs records nothing. Once per window,
+                # at its last publish.
                 observe_stage("published", source_ts_ns)
         finally:
             self._preprocessor.release()

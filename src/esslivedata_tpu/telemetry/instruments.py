@@ -22,6 +22,7 @@ __all__ = [
     "DECODE_BYTES",
     "DECODE_ERRORS",
     "EVENTS_FILTERED",
+    "JOB_PUBLISHES",
     "JOB_WINDOWS",
     "Q_LOOKUP_STEPS",
     "SINK_BYTES",
@@ -148,6 +149,23 @@ TICK_GROUPS = REGISTRY.counter(
     "Tick-program groups dispatched, by whether an earlier group of "
     "the same tick was still in flight (ahead) or not (alone)",
     labelnames=("dispatched",),
+)
+
+#: When a window's job results left the manager
+#: (``JobManager.process_jobs``), one count per job result handed to the
+#: window's publisher: ``ahead`` = handed over right after its tick
+#: group's collect, while a later group of the same tick was still
+#: uncollected, so its finalize, encode and write ran beside the chip's
+#: work; ``end`` = returned at the end of the window (the last group of
+#: a tick, a one-group tick, a compile round, a member that fell back,
+#: every job outside a tick group). ahead / (ahead + end) is the
+#: benchmark's ``publishes_ahead_share``: (n - 1) / n of a warm service
+#: of n one-job groups, 0 of one with no tick group.
+JOB_PUBLISHES = REGISTRY.counter(
+    "livedata_job_publishes_total",
+    "Job results handed to the window's publisher, by whether a tick "
+    "group of the same tick was still uncollected (ahead) or not (end)",
+    labelnames=("when",),
 )
 
 #: Which of the manager's three ways to step a job each window took

@@ -32,6 +32,10 @@ A tick with several (stream, fuse-key) groups runs ``flatten``,
 ``fetch`` spans (``JobManager._run_tick_programs``): the host stages
 group i+1 while the chip runs group i, so the ``fetch`` spans add up to
 what of the chip's work is left to wait for after the last dispatch.
+Each ``fetch`` but the last is followed by the ``finalize`` and the
+``sink`` of the group it collected (ADR 0128: a group's results leave
+while the chip works on the groups behind); the last group's, and
+every job's outside a tick group, come once more at the tick's end.
 
 The pipelined path adds ``prestage`` (its stage worker's flatten +
 H2D as one span). **The ring stays flat**: the spans one thread

@@ -271,6 +271,20 @@ class TestSerialTickIsTiledBySpans:
         # Compile rounds record no spans; every other group has its pair.
         assert 0 < deltas["tick_execute"][1] == deltas["fetch"][1] <= ticks
 
+    def test_a_one_group_service_publishes_every_result_at_the_end(self):
+        """``livedata_job_publishes_total``: one count per job result
+        by the way it left (ADR 0128). One group a tick has no group
+        behind it to publish under: ``end`` alone, one ``finalize`` and
+        one ``sink`` a tick (the benchmark's ``publishes_ahead_share``
+        then reads 0, not nothing)."""
+        family = REGISTRY.get("livedata_job_publishes_total")
+        before = {when: family.value(when=when) for when in ("ahead", "end")}
+        spans, deltas = serial_run_deltas()
+        ticks = len({s.trace_id for s in spans})
+        assert family.value(when="ahead") == before["ahead"]
+        assert family.value(when="end") - before["end"] == ticks
+        assert deltas["finalize"][1] == deltas["sink"][1] == ticks
+
     def test_fetch_enqueues_the_copies_before_it_waits(self):
         """``device_get`` alone enqueues every copy and then waits; the
         split into wait and copy must not lose that, or each fetch pays

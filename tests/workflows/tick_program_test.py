@@ -776,8 +776,8 @@ class TestGroupsArePipelined:
         windows = _group_windows(73, 1, 2)
         groups: list = []
         run = mgr._run_tick_programs
-        mgr._run_tick_programs = lambda tick_groups: (
-            groups.extend(tick_groups) or run(tick_groups)
+        mgr._run_tick_programs = lambda tick_groups, hand_over=None: (
+            groups.extend(tick_groups) or run(tick_groups, hand_over)
         )
         _process(mgr, windows[0], 0)
         assert len(groups) == 2
@@ -806,6 +806,45 @@ class TestGroupsArePipelined:
         ]
         assert _counts_added(before) == {"ahead": 1, "alone": 2}
         mgr.shutdown()
+
+
+class TestGroupsPublishAhead:
+    """A group's results go to the window's publisher right after its
+    collect while a later group is still uncollected (ADR 0128): the
+    moment on the host moves, no result and no state does. The order,
+    the fallbacks and the loops: ``publish_ahead_test.py``."""
+
+    @pytest.mark.parametrize("n_groups", [1, 3, 7])
+    def test_bit_identical_to_end_of_window(self, n_groups):
+        early, early_wfs = _make_group_manager(n_groups)
+        late, late_wfs = _make_group_manager(n_groups)
+        for w, window in enumerate(_group_windows(76, 4, n_groups)):
+            handed: list = []
+            rest = early.process_jobs(
+                {s: _staged(pid, toa) for s, (pid, toa) in window.items()},
+                start=T(0),
+                end=T(w + 1),
+                publish=handed.extend,
+            )
+            res_l = _process(late, window, w)
+            # Windows 0 and 1 are compile rounds, collected on the spot.
+            assert len(handed) == (n_groups - 1 if w >= 2 else 0)
+            assert len(handed) + len(rest) == len(res_l) == n_groups
+            for re, rl in zip([*handed, *rest], res_l):
+                assert re.job_id.source_name == rl.job_id.source_name
+                assert list(re.outputs) == list(rl.outputs)
+                assert _wire_bytes(re) == _wire_bytes(rl), f"window {w}"
+            for we, wl in zip(early_wfs, late_wfs, strict=True):
+                for le, ll in zip(
+                    jax.tree_util.tree_leaves(we._state),
+                    jax.tree_util.tree_leaves(wl._state),
+                    strict=True,
+                ):
+                    assert np.array_equal(
+                        np.asarray(le), np.asarray(ll)
+                    ), f"window {w}: a carried state differs"
+        early.shutdown()
+        late.shutdown()
 
 
 class TestPipelinedContainment:
