@@ -23,6 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..telemetry.instruments import (
+    Q_BINCOUNT_STEPS,
     Q_LOOKUP_STEPS,
     TABLE_BUILD_SECONDS,
     TABLE_BYTES,
@@ -729,15 +730,19 @@ class QHistogrammer:
         return cached
 
     def _count_step(self, n_events: int) -> None:
-        """One ``livedata_q_lookup_steps_total`` count for a step of
-        ``n_events`` staged events, at its dispatch, by the lookup its
-        program was traced with."""
+        """One ``livedata_q_lookup_steps_total`` and one
+        ``livedata_q_bincount_steps_total`` count for a step of
+        ``n_events`` staged events, at its dispatch, by the lookup and
+        the bincount its program was traced with."""
         lookup = "gather"
         if self._packed:
             from .pallas_lookup import lookup_kind
 
             lookup = lookup_kind(n_events, self._table_shape[0])
         Q_LOOKUP_STEPS.inc(lookup=lookup)
+        Q_BINCOUNT_STEPS.inc(
+            method="onehot" if self._method == "pallas" else "scatter"
+        )
 
     def stage_events(
         self,

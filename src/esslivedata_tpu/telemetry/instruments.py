@@ -24,6 +24,7 @@ __all__ = [
     "EVENTS_FILTERED",
     "JOB_PUBLISHES",
     "JOB_WINDOWS",
+    "Q_BINCOUNT_STEPS",
     "Q_LOOKUP_STEPS",
     "SINK_BYTES",
     "SINK_SECONDS",
@@ -216,4 +217,18 @@ Q_LOOKUP_STEPS = REGISTRY.counter(
     "Q-histogram steps dispatched, by how the (pixel, TOA bin) table "
     "was read (windowed or gather)",
     labelnames=("lookup",),
+)
+
+#: How each Q step counted its looked-up bins, the kernel's other
+#: choice (``QHistogrammer(method="auto")``), counted beside the one
+#: above: ``onehot`` = the VMEM one-hot kernel of ``ops/pallas_hist.py``
+#: (a TPU backend and a bin space of at most ``MAX_PALLAS_BINS``),
+#: ``scatter`` = XLA's scatter-add (the CPU, and wider bin spaces such
+#: as the powder reduction's 2000 x 17). scatter / both is the
+#: benchmark's ``q_bincount_scatter_share``.
+Q_BINCOUNT_STEPS = REGISTRY.counter(
+    "livedata_q_bincount_steps_total",
+    "Q-histogram steps dispatched, by how the looked-up bins were "
+    "counted (onehot or scatter)",
+    labelnames=("method",),
 )

@@ -18,6 +18,7 @@ counts persist because the d bin space is unchanged.
 
 from __future__ import annotations
 
+import time
 from collections.abc import Mapping
 from typing import Any
 
@@ -27,6 +28,7 @@ from pydantic import BaseModel, ConfigDict, Field
 from ..config.models import TOARange
 from ..ops.chopper_cascade import ALPHA_NS_PER_M_A
 from ..ops.qhistogram import PixelBinMap, QHistogrammer, build_dspacing_map
+from ..telemetry.instruments import TABLE_BUILD_SECONDS
 from ..utils.labeled import DataArray, Variable
 from .qshared import QStreamingMixin, latest_sample_value
 
@@ -180,6 +182,7 @@ class PowderDiffractionWorkflow(QStreamingMixin):
         # guarantee (mantle-scale tables are ~GB as int32).
         from ..ops.qhistogram import _MAP_CHUNK
 
+        began = time.perf_counter()
         ids = self._geometry["pixel_ids"]
         band_by_row = np.zeros(dmap.table.shape[0], dtype=np.int32)
         band_by_row[np.asarray(ids) - dmap.id_base] = self._band
@@ -192,7 +195,12 @@ class PowderDiffractionWorkflow(QStreamingMixin):
             composite[sl] = np.where(
                 t >= 0, t * self._n_bands + band_by_row[sl, None], -1
             ).astype(dtype)
-        return PixelBinMap(table=composite, id_base=dmap.id_base)
+        # The composite is still the builder's table: it keeps the
+        # family, and this pass is part of what the table cost.
+        TABLE_BUILD_SECONDS.inc(
+            time.perf_counter() - began, family=dmap.family
+        )
+        return dmap._replace(table=composite)
 
     def set_context(self, context: Mapping[str, Any]) -> None:
         """A live emission-time calibration (WFM subframe T0) arrives as

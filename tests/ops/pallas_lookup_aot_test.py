@@ -1,4 +1,5 @@
-"""The windowed lookup compiles for a v5e at LOKI's widths.
+"""The windowed lookup compiles for a v5e at LOKI's widths, and the
+step's other side (XLA's gather and scatter) at DREAM's powder widths.
 
 Interpret mode cannot show what Mosaic refuses (a misaligned slice, too
 much VMEM). libtpu is installed here, so the kernel is compiled for a
@@ -50,3 +51,30 @@ def test_kernel_and_items_compile_at_lokis_widths(one_chip, n_pix):
     stats = compiled.memory_analysis()
     # the table is read in place: no second copy, no relayout of it
     assert stats.temp_size_in_bytes < packed.size * 2 // 8
+
+
+@pytest.mark.parametrize("n_pix", [491_520, 157_696])
+def test_the_gather_side_compiles_at_dreams_powder_widths(one_chip, n_pix):
+    """The step's other side (an int32 table, 2000 x 17 = 34 000 bins:
+    XLA's element gather and XLA's scatter-add), at the widths of
+    ``dream_powder.paced14``: the table is taken as it is, 500 columns
+    unpadded, and read in place."""
+    import functools
+
+    from esslivedata_tpu.ops.qhistogram import table_scatter_delta
+
+    n = 1 << 22
+    step = functools.partial(
+        table_scatter_delta, id_base=1, lo=0.0, hi=1e9 / 14, inv_width=500 * 14 / 1e9,
+        n_bins=34_000, dtype=jnp.float32, method="scatter",
+    )
+    table = jax.ShapeDtypeStruct((n_pix, 500), jnp.int32, sharding=one_chip)
+    ids = jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip)
+    toa = jax.ShapeDtypeStruct((n,), jnp.float32, sharding=one_chip)
+    compiled = jax.jit(step).lower(table, ids, toa).compile()
+    text = compiled.as_text()
+    assert " gather(" in text and " scatter(" in text and "tpu_custom_call" not in text
+    stats = compiled.memory_analysis()
+    table_bytes = n_pix * 500 * 4
+    assert stats.argument_size_in_bytes < table_bytes + 2 * n * 4 + (16 << 20)  # no padding to 512 columns
+    assert stats.temp_size_in_bytes < table_bytes // 8  # no second copy of the table
