@@ -9,11 +9,14 @@ Sections:
   2-D: scatter_add_pallas2d (bf16 + int8) vs XLA scatter at LOKI
        headline scale (1.5M px x 100 toa), incl. host partition rate.
   lookup (``--lookup`` runs this section alone): the Q step's
-       ``table[pixel, TOA bin]`` at LOKI's shapes (802 816 x 200 and
-       172 032 x 200, a 4 Mi bucket holding 14 pulses of 229 376, the
-       cell's id distribution and every event in one pixel): the
-       windowed lookup of ops/pallas_lookup.py exact against the
-       gather, and ms a step for every rung of the ladder (PERF.md
+       ``table[pixel, TOA bin]`` at LOKI's shapes (int16 over 100 bins,
+       one byte plane: 802 816 x 200 and 172 032 x 200) and at DREAM's
+       powder shapes (int32 over 34 000 bins, two planes: the mantle's
+       491 520 x 500 and the SANS bank's 30 720 x 500), a 4 Mi bucket
+       holding 14 pulses of 229 376, the cell's id distribution and
+       every event in one pixel: the windowed lookup of
+       ops/pallas_lookup.py exact against the gather, ms a step for
+       every rung of the ladder and the crossover sweep (PERF.md
        section 6).
 """
 
@@ -39,20 +42,35 @@ def _ms(fn, *args, repeats: int = 10) -> float:
     return (time.perf_counter() - t0) / repeats * 1e3
 
 
+#: ``lookup_section``'s shapes for DREAM's powder reduction: the
+#: largest and the smallest bank, behind XLA's scatter as in the cell.
+DREAM_POWDER = {
+    "banks": (491_520, 30_720),
+    "n_toa": 500,
+    "n_q": 2000 * 17,
+    "dtype": np.int32,
+    "method": "scatter",
+}
+
+
 def lookup_section(
     n: int = 1 << 22,
     n_valid: int = 14 * 229_376,
     banks: tuple[int, ...] = (802_816, 172_032),
+    n_toa: int = 200,
+    n_q: int = 100,
+    dtype=np.int16,
+    method: str = "pallas",
 ) -> None:
-    """Parity and the ladder at LOKI's shapes (the defaults; a rehearsal
-    on the CPU passes small ones); raises on a mismatch."""
+    """Parity and the ladder at LOKI's shapes (the defaults;
+    ``DREAM_POWDER`` holds DREAM's; a rehearsal on the CPU passes small
+    ones); raises on a mismatch."""
     import jax
     import jax.numpy as jnp
 
     from esslivedata_tpu.ops import pallas_lookup
     from esslivedata_tpu.ops.qhistogram import table_scatter_delta
 
-    n_toa, n_q = 200, 100
     interpret = jax.default_backend() != "tpu"
     rng = np.random.default_rng(28)
     lo, hi = 0.0, 1e9 / 14
@@ -67,10 +85,13 @@ def lookup_section(
         return jax.device_put(pid), jax.device_put(toa)
 
     for n_pix in banks:
-        table = rng.integers(-1, n_q, (n_pix, n_toa)).astype(np.int16)
-        assert pallas_lookup.packable(table, n_q)
+        table = rng.integers(-1, n_q, (n_pix, n_toa), dtype=dtype)
+        planes = pallas_lookup.packable(table, n_q)
+        assert planes
         dev = jax.device_put(table)
-        packed = pallas_lookup.pack_table(dev)
+        del table
+        packed = pallas_lookup.pack_table(dev, planes=planes)
+        tag = f"{n_pix}x{n_toa} {planes}-plane"
         centre = 0.7 * n_pix
         blob = np.rint(rng.normal(centre, n_pix / 8, n_valid)).astype(
             np.int64
@@ -84,7 +105,7 @@ def lookup_section(
             return table_scatter_delta(
                 tbl, pid, toa, id_base=1, lo=lo, hi=hi,
                 inv_width=n_toa / (hi - lo), n_bins=n_q,
-                dtype=jnp.float32, method="pallas",
+                dtype=jnp.float32, method=method,
                 packed_shape=packed_shape,
             )
 
@@ -98,7 +119,7 @@ def lookup_section(
             np.testing.assert_array_equal(got, want)
             assert want.sum() > 0
             print(
-                f"lookup {n_pix}x{n_toa} {name}: step parity OK; "
+                f"lookup {tag} {name}: step parity OK; "
                 f"gather {_ms(step_gather, dev, pid, toa):.2f} ms a step, "
                 f"windowed {_ms(step_windowed, packed, pid, toa):.2f}",
                 flush=True,
@@ -115,17 +136,18 @@ def lookup_section(
         flat = local * n_toa + tb
         flat_sorted = jnp.sort(flat)
         dev1 = dev.reshape(-1)
-        dev32 = dev.astype(jnp.int32)
-        shift = pallas_lookup._toa_bits(packed.shape[0])
-        n_windows = packed.shape[1] // pallas_lookup.WINDOW
+        shift = pallas_lookup._toa_bits(packed.shape[1])
+        n_windows = packed.shape[2] // pallas_lookup.WINDOW
         keys = jnp.where(ok, (local << shift) | tb, np.iinfo(np.int32).max)
         keys_sorted = jnp.sort(keys)
         rungs = {
-            "gather [pid, tb] int16": (lambda t, p, b: t[p, b], dev, local, tb),
-            "gather flat 1-D int16": (lambda t, f: t[f], dev1, flat),
-            "gather [pid, tb] int32": (lambda t, p, b: t[p, b], dev32, local, tb),
-            "gather packed [tb, pid] bf16": (
-                lambda t, p, b: t[b, p], packed, local, tb
+            f"gather [pid, tb] {dev.dtype}": (
+                lambda t, p, b: t[p, b], dev, local, tb
+            ),
+            f"gather flat 1-D {dev.dtype}": (lambda t, f: t[f], dev1, flat),
+            "gather packed, an element a plane, bf16": (
+                lambda t, p, b: [t[k, b, p] for k in range(planes)],
+                packed, local, tb,
             ),
             "gather flat sorted, indices_are_sorted": (
                 lambda t, f: t.at[f].get(
@@ -151,15 +173,19 @@ def lookup_section(
                 keys_sorted,
             ),
         }
+        if dev.dtype != jnp.int32:
+            rungs["gather [pid, tb] int32"] = (
+                lambda t, p, b: t[p, b], dev.astype(jnp.int32), local, tb
+            )
         for name, (fn, *args) in rungs.items():
             print(
-                f"lookup {n_pix}x{n_toa} rung {name}: "
+                f"lookup {tag} rung {name}: "
                 f"{_ms(jax.jit(fn), *args):.2f} ms",
                 flush=True,
             )
         items = pallas_lookup._work_items(keys_sorted, n_windows, shift)
         print(
-            f"lookup {n_pix}x{n_toa}: {int(items[2][0])} work items of "
+            f"lookup {tag}: {int(items[2][0])} work items of "
             f"{items[0].shape[0]} grid steps",
             flush=True,
         )
@@ -167,8 +193,10 @@ def lookup_section(
         # the crossover: both paths of ``lookup`` on the packed table,
         # by batch size (its two constants are read at trace time)
         rule = pallas_lookup.MIN_EVENTS, pallas_lookup.EVENTS_PER_WINDOW
-        for log2 in range(14, 21):
-            m = min(1 << log2, n)
+        for log2 in range(14, 23):
+            m = 1 << log2
+            if m > n:
+                break
             args = (packed, local[:m], tb[:m], ok[:m])
             ms = {}
             for kind, forced in (("gather", (n + 1, 0)), ("windowed", (0, 0))):
@@ -179,9 +207,9 @@ def lookup_section(
                 )
             pallas_lookup.MIN_EVENTS, pallas_lookup.EVENTS_PER_WINDOW = rule
             print(
-                f"lookup {n_pix}x{n_toa} crossover n={m}: gather "
+                f"lookup {tag} crossover n={m}: gather "
                 f"{ms['gather']:.3f} ms, windowed {ms['windowed']:.3f}, "
-                f"the rule takes {pallas_lookup.lookup_kind(m, n_pix)}",
+                f"the rule takes {pallas_lookup.lookup_kind(m, packed.shape)}",
                 flush=True,
             )
 
@@ -193,6 +221,7 @@ def main() -> None:
     print("device:", jax.devices()[0], flush=True)
     if "--lookup" in sys.argv[1:]:
         lookup_section()
+        lookup_section(**DREAM_POWDER)
         return
 
     from esslivedata_tpu.ops.pallas_hist import bincount_pallas
@@ -300,6 +329,7 @@ def main() -> None:
     )
 
     lookup_section()
+    lookup_section(**DREAM_POWDER)
 
 
 if __name__ == "__main__":

@@ -24,15 +24,27 @@ windows:
    that revisit it (an event's pixel lies in exactly one of them).
    Items past the last valid event are skipped: padding costs nothing.
 
-The table lives on the device **packed** for this kernel: transposed,
-bfloat16 (exact for -1..256), TOA axis padded to the bf16 sublane tile
-and the pixel axis to whole windows: one resident copy, the same 2 B an
-entry. ``lookup`` reads that layout on both of its paths (a batch under
-the crossover gathers from it), so the choice is made per compiled
-shape from what the trace observes and the table never exists twice.
+The table lives on the device **packed** for this kernel: byte planes
+``[planes, n_toa, n_pix]``, transposed, bfloat16, TOA axis padded to the
+bf16 sublane tile and the pixel axis to whole windows. bfloat16 holds
+the integers -1..256 and no more, so a bin space of up to 255 bins is
+one plane (the value itself; LOKI's I(Q): 2 B an entry, what the int16
+table was) and one of up to 65 535 bins is two, ``hi = v >> 8``
+(arithmetic: -1 for the builders' -1) and ``lo = v & 255``, each exact
+in bfloat16, with ``v = hi * 256 + lo`` (-256 + 255 for -1): DREAM's
+powder tables, 34 000 bins, 4 B an entry, what the int32 table was. The
+same one-hot product reads each plane (a float32 sum over a one-hot has
+one non-zero term: exact) and the planes recombine in float32, where
+every integer below 2**24 is exact: the result is the gather's, entry
+for entry. One resident copy: ``lookup`` reads that layout on both of
+its paths (a batch under the crossover gathers from it), so the choice
+is made per compiled shape from what the trace observes and the table
+never exists twice.
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -55,10 +67,12 @@ __all__ = [
 BLOCK = 1024
 #: Pixels per table window (the one-hot's contraction length).
 WINDOW = 128
-#: Largest table value bfloat16 holds exactly next to -1 (8 bits of
-#: mantissa): the packed layout takes tables whose bin count stays
-#: under it.
+#: What one plane of the packed layout counts to: bfloat16 (8 bits of
+#: mantissa) holds the integers -1..256 exactly, so a plane is a byte
+#: and the radix of the split is 256. A table whose bin count stays
+#: under it takes one plane, one under ``MAX_VALUE ** 2`` = 65 536 two.
 MAX_VALUE = 256
+_MAX_PLANES = 2
 #: The crossover against the XLA gather, measured on a v5e with LOKI's
 #: tables and id distribution (scripts/tpu_kernel_check.py --lookup; my
 #: chip run, PR 28, PERF.md section 6): the gather costs 13 ns an event,
@@ -70,6 +84,18 @@ MAX_VALUE = 256
 #: measured ahead, so the gather stays.
 EVENTS_PER_WINDOW = 48
 MIN_EVENTS = 1 << 16
+#: ``EVENTS_PER_WINDOW`` holds for LOKI's window of 208 rows (one plane
+#: x 208 padded TOA bins). An item's cost is its MXU rows, and the
+#: gather pays once a plane, so the crossover moves with the window's
+#: rows, and slowly: DREAM's powder tables (two planes x 512 = 1 024
+#: rows, 1.5 us an item against 28.5 ns an event for one element gather
+#: a plane) cross at 58 events a window for the mantle (3 840 windows:
+#: 2**18 events 7.47 against 6.47 ms, 2**17 3.78 against 6.24) and under
+#: 68 for the SANS bank (240: a tie at 2**14); my chip run, PR 32. So a
+#: third more at 1 024 rows, 64, on the line through the two windows
+#: measured: one 2 448th of the constant a row.
+_ROWS_MEASURED = 208
+_ROWS_PER_CONSTANT = 2448
 
 _SENTINEL = np.iinfo(np.int32).max
 _SUBLANES = 8
@@ -85,42 +111,64 @@ def _padded(n: int, to: int) -> int:
     return -(-n // to) * to
 
 
-def packable(table: np.ndarray, n_bins: int) -> bool:
-    """Whether ``table`` may live packed: int16 (the builders' choice
-    for small bin spaces), every value and the bin count exact in
-    bfloat16, and a packed key per event inside int32."""
+def packable(table: np.ndarray, n_bins: int) -> int:
+    """How many byte planes ``table`` takes in the packed layout, 0
+    where it cannot live packed: an int16 or int32 table (the builders'
+    two choices) whose values and bin count are exact in that many
+    bfloat16 planes, with a packed key per event inside int32."""
     n_pix, n_toa = table.shape
-    return (
-        table.dtype == np.int16
-        and n_bins + 1 <= MAX_VALUE
-        and _padded(n_pix, WINDOW) << _toa_bits(_padded(n_toa, _BF16_ROWS))
-        < _SENTINEL
-    )
+    if table.dtype not in (np.int16, np.int32) or (
+        _padded(n_pix, WINDOW) << _toa_bits(_padded(n_toa, _BF16_ROWS))
+        >= _SENTINEL
+    ):
+        return 0
+    for planes in range(1, _MAX_PLANES + 1):
+        if n_bins + 1 <= MAX_VALUE**planes:
+            return planes
+    return 0
 
 
-@jax.jit
-def pack_table(table: jax.Array) -> jax.Array:
-    """int16 ``[n_pix, n_toa]`` -> the packed layout, bfloat16
-    ``[n_toa padded to 16, n_pix padded to WINDOW]``. Padding holds 0
-    and is never selected (a valid event's pixel and TOA bin lie inside
-    the table); it must be finite, since the MXU multiplies it by 0."""
+@functools.partial(jax.jit, static_argnames="planes")
+def pack_table(table: jax.Array, *, planes: int) -> jax.Array:
+    """int16 or int32 ``[n_pix, n_toa]`` -> the packed layout, bfloat16
+    ``[planes, n_toa padded to 16, n_pix padded to WINDOW]``: the top
+    plane holds ``table >> 8 * (planes - 1)`` (arithmetic, so it keeps
+    the sign: -1 stays -1), every plane under it one byte, most
+    significant first. Padding holds 0 and is never selected (a valid
+    event's pixel and TOA bin lie inside the table); it must be finite,
+    since the MXU multiplies it by 0."""
     n_pix, n_toa = table.shape
-    packed = table.T.astype(jnp.bfloat16)
+    wide = table.T.astype(jnp.int32)
+    top = 8 * (planes - 1)
+    split = [wide >> top] + [
+        (wide >> shift) & (MAX_VALUE - 1) for shift in range(top - 8, -1, -8)
+    ]
     return jnp.pad(
-        packed,
+        jnp.stack(split).astype(jnp.bfloat16),
         (
+            (0, 0),
             (0, _padded(n_toa, _BF16_ROWS) - n_toa),
             (0, _padded(n_pix, WINDOW) - n_pix),
         ),
     )
 
 
-def lookup_kind(n_events: int, n_pix: int) -> str:
+def _join_planes(planes):
+    """The table value from its planes, most significant first (any
+    arithmetic type in which the value is exact)."""
+    return functools.reduce(lambda high, low: high * MAX_VALUE + low, planes)
+
+
+def lookup_kind(n_events: int, packed_shape: tuple[int, int, int]) -> str:
     """The path ``lookup`` takes for a batch of ``n_events`` on a packed
-    table of ``n_pix`` pixels: ``'windowed'`` or ``'gather'`` (the label
-    of ``livedata_q_lookup_steps_total``)."""
-    n_windows = -(-n_pix // WINDOW)
-    dense = n_events >= max(MIN_EVENTS, EVENTS_PER_WINDOW * n_windows)
+    table of shape ``packed_shape``: ``'windowed'`` or ``'gather'`` (the
+    label of ``livedata_q_lookup_steps_total``)."""
+    planes, n_toa_p, n_pix_p = packed_shape
+    rows = planes * n_toa_p
+    per_window = EVENTS_PER_WINDOW * (
+        1 + (rows - _ROWS_MEASURED) / _ROWS_PER_CONSTANT
+    )
+    dense = n_events >= max(MIN_EVENTS, per_window * (n_pix_p // WINDOW))
     return "windowed" if dense else "gather"
 
 
@@ -164,7 +212,7 @@ def _lookup_sorted(packed, keys, shift: int, interpret: bool):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    n_toa_p, n_pix_p = packed.shape
+    planes, n_toa_p, n_pix_p = packed.shape
     n_blocks = keys.shape[0] // BLOCK
     lanes = BLOCK // _SUBLANES
     block, window, n_items = _work_items(keys, n_pix_p // WINDOW, shift)
@@ -182,7 +230,7 @@ def _lookup_sorted(packed, keys, shift: int, interpret: bool):
                 out_ref[...] = jnp.zeros_like(out_ref)
 
             base = window_ref[j] * WINDOW
-            table = table_ref[...]
+            tables = [table_ref[p] for p in range(planes)]
             pixels = jax.lax.broadcasted_iota(jnp.int32, (WINDOW, lanes), 0)
             toas = jax.lax.broadcasted_iota(jnp.int32, (n_toa_p, lanes), 0)
             # Static unroll over the 8 sublane rows, each loaded
@@ -194,8 +242,11 @@ def _lookup_sorted(packed, keys, shift: int, interpret: bool):
                 in_window = (pixels == (key >> shift) - base).astype(
                     jnp.bfloat16
                 )
-                rows = jnp.dot(
-                    table, in_window, preferred_element_type=jnp.float32
+                rows = _join_planes(
+                    jnp.dot(
+                        table, in_window, preferred_element_type=jnp.float32
+                    )
+                    for table in tables
                 )  # [n_toa_p, lanes]: each event's table row
                 out_ref[0, s : s + 1, :] += jnp.sum(
                     jnp.where(toas == (key & toa_mask), rows, 0.0),
@@ -212,7 +263,9 @@ def _lookup_sorted(packed, keys, shift: int, interpret: bool):
                 pl.BlockSpec(
                     (1, _SUBLANES, lanes), lambda j, b, w, n: (b[j], 0, 0)
                 ),
-                pl.BlockSpec((n_toa_p, WINDOW), lambda j, b, w, n: (0, w[j])),
+                pl.BlockSpec(
+                    (planes, n_toa_p, WINDOW), lambda j, b, w, n: (0, 0, w[j])
+                ),
             ],
             out_specs=pl.BlockSpec(
                 (1, _SUBLANES, lanes), lambda j, b, w, n: (b[j], 0, 0)
@@ -241,11 +294,16 @@ def lookup(
     come in sorted-key order, not the events'**: the consumer is a
     histogram."""
     n = pid.shape[0]
-    if lookup_kind(n, packed.shape[1]) == "gather":
-        return jnp.where(ok, packed[tb, pid].astype(jnp.int32), -1)
+    if lookup_kind(n, packed.shape) == "gather":
+        # one element gather a plane: a slice over the planes (or of one)
+        # would have XLA copy the whole table into another layout first
+        planes = (
+            packed[p, tb, pid].astype(jnp.int32) for p in range(packed.shape[0])
+        )
+        return jnp.where(ok, _join_planes(planes), -1)
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    shift = _toa_bits(packed.shape[0])
+    shift = _toa_bits(packed.shape[1])
     keys = jnp.where(ok, (pid << shift) | tb, _SENTINEL)
     pad = (-n) % BLOCK
     if pad:

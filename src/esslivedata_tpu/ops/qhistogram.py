@@ -535,15 +535,20 @@ class QHistogrammer:
         if method not in ("auto", "scatter", "pallas"):
             raise ValueError(f"Unknown method {method!r}")
         if method == "auto":
-            # Q-family bin spaces all fit the VMEM one-hot kernel: take
-            # it whenever the bound holds on a TPU backend. Per step of
+            # Bin spaces up to MAX_PALLAS_BINS take the VMEM one-hot
+            # kernel on a TPU backend; a wider one (DREAM's I(d, 2-theta):
+            # 34 000) keeps XLA's scatter, 31.4 ms a step of 4 Mi events
+            # there (my chip run, PR 32, PERF.md section 5). Per step of
             # 4 Mi events from a 321 MB table into 100 bins on a v5e
-            # this kernel takes 2.4 ms and XLA's table gather took 52.0
-            # (device time by scope; my chip run, PR 27, PERF.md section
-            # 5): the lookup was the step. Sorted and read window by
-            # window (ops/pallas_lookup.py, below) it takes 6.9 ms, the
-            # whole step 9.3 against 61.8 on one chip (my chip run,
-            # PR 28, PERF.md section 6).
+            # the one-hot kernel takes 2.4 ms and XLA's table gather
+            # took 52.0 (device time by scope; my chip run, PR 27): the
+            # lookup was the step. The lookup is chosen apart from the
+            # bincount, below: sorted and read window by window
+            # (ops/pallas_lookup.py) it takes 6.9 ms from that table
+            # (one byte plane; the whole step 9.3 against 61.8; my chip
+            # run, PR 28) and 13.1 ms from DREAM's 983 MB int32 table
+            # held as two planes, against a gather of 66.3 (my chip run,
+            # PR 32, PERF.md section 6).
             from .pallas_hist import MAX_PALLAS_BINS
 
             method = (
@@ -575,16 +580,23 @@ class QHistogrammer:
         # The table's layout on the device follows what this kernel can
         # observe, like ``method='auto'``: packed for the dense lookup
         # of ops/pallas_lookup.py where the backend is a TPU and the
-        # values are exact there, the host's int table otherwise. The
+        # values are exact there (in one byte plane up to 255 bins, in
+        # two up to 65 535), the host's int table otherwise. The
         # module is imported here, inside the Q path, as
         # ``bincount_pallas`` is: a service that steps no Q kernel (and
         # any run on the CPU) never loads it.
-        self._packed = False
+        self._planes = 0
         if jax.default_backend() == "tpu":
             from .pallas_lookup import packable
 
-            self._packed = packable(table, n_q)
+            self._planes = packable(table, n_q)
         self._install_table(table, wait=True)
+        # Both lookup series exist from the first Q kernel on, so that a
+        # share of either label reads 0, and not "no sample", in a
+        # service whose every step takes the other path (one that runs
+        # no Q kernel still shows neither).
+        for kind in ("windowed", "gather"):
+            Q_LOOKUP_STEPS.inc(0.0, lookup=kind)
         # a swap keeps shape and layout, so the bytes stand until the
         # kernel goes (a stopped job releases its workflow)
         nbytes = self._qmap.nbytes
@@ -617,14 +629,15 @@ class QHistogrammer:
         counts into the family's build seconds (with ``wait``, at
         construction, the transfer too: part of what set-up costs)."""
         began = time.perf_counter()
-        self._qmap = jnp.asarray(table)
-        if self._packed:
+        qmap = jnp.asarray(table)
+        if self._planes:
             from .pallas_lookup import pack_table
 
-            # one pass on the device; the int16 copy goes with the call
-            self._qmap = pack_table(self._qmap)
+            # one pass on the device; the int copy goes with the call
+            qmap = pack_table(qmap, planes=self._planes)
+        self._qmap = qmap  # never the int table where the step reads planes
         if wait:
-            jax.block_until_ready(self._qmap)
+            jax.block_until_ready(qmap)
         TABLE_BUILD_SECONDS.inc(
             time.perf_counter() - began, family=self._family
         )
@@ -655,7 +668,7 @@ class QHistogrammer:
             n_bins=self._n_q,
             dtype=self._dtype,
             method=self._method,
-            packed_shape=self._table_shape if self._packed else None,
+            packed_shape=self._table_shape if self._planes else None,
         )
         mc = jnp.asarray(monitor_count, dtype=self._dtype)
         return QState(
@@ -713,7 +726,7 @@ class QHistogrammer:
             np.dtype(self._dtype).str,
             self._method,
             self._table_shape,
-            self._packed,
+            self._planes,
         )
 
     def _qmap_for(self, device):
@@ -735,10 +748,10 @@ class QHistogrammer:
         ``n_events`` staged events, at its dispatch, by the lookup and
         the bincount its program was traced with."""
         lookup = "gather"
-        if self._packed:
+        if self._planes:
             from .pallas_lookup import lookup_kind
 
-            lookup = lookup_kind(n_events, self._table_shape[0])
+            lookup = lookup_kind(n_events, self._qmap.shape)
         Q_LOOKUP_STEPS.inc(lookup=lookup)
         Q_BINCOUNT_STEPS.inc(
             method="onehot" if self._method == "pallas" else "scatter"

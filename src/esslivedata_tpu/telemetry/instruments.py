@@ -187,14 +187,16 @@ JOB_WINDOWS = REGISTRY.counter(
 #: The (pixel, TOA bin) -> bin tables resident beside the Q family's
 #: states (``ops/qhistogram.QHistogrammer``), by family: bytes of the
 #: array the kernel keeps (the host's int table, or the packed layout
-#: of ``ops/pallas_lookup.py`` with its padding; the device's tiling
-#: may pad further), summed over live tables. Set-up has no span, so
+#: of ``ops/pallas_lookup.py``: one bfloat16 byte plane up to 255 bins,
+#: two up to 65 535, with its padding; the device's tiling may pad
+#: further), summed over live tables. Set-up has no span, so
 #: what building them cost is the counter below: both change at
 #: construction and at ``swap_table`` only, and neither can be read by a
 #: windowed metric.
 TABLE_BYTES = REGISTRY.gauge(
     "livedata_table_bytes",
-    "Bytes of precompiled event->bin tables resident on the device, "
+    "Bytes of precompiled event->bin tables resident on the device "
+    "(the int table, or its packed byte planes with their padding), "
     "by kernel family",
     labelnames=("family",),
 )
@@ -209,13 +211,17 @@ TABLE_BUILD_SECONDS = REGISTRY.counter(
 #: How each Q step looked its events up in the table
 #: (``ops/qhistogram.QHistogrammer``), one count per step at its
 #: dispatch: ``windowed`` = sort + dense windows on the MXU
-#: (``ops/pallas_lookup.py``: a packed table and a batch at or above
-#: its crossover), ``gather`` = XLA's element gather (everything else).
-#: windowed / both is the benchmark's ``q_lookup_windowed_share``.
+#: (``ops/pallas_lookup.py``: a table packed in one or two byte planes
+#: and a batch at or above its crossover), ``gather`` = XLA's element
+#: gather (everything else: the CPU, a bin space past 65 535, a batch
+#: under the crossover). windowed / both is the benchmark's
+#: ``q_lookup_windowed_share``, gather / both its
+#: ``q_lookup_gather_share``.
 Q_LOOKUP_STEPS = REGISTRY.counter(
     "livedata_q_lookup_steps_total",
     "Q-histogram steps dispatched, by how the (pixel, TOA bin) table "
-    "was read (windowed or gather)",
+    "was read (windowed: sorted, dense windows of one or two byte "
+    "planes; or gather)",
     labelnames=("lookup",),
 )
 

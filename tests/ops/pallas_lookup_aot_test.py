@@ -1,5 +1,6 @@
-"""The windowed lookup compiles for a v5e at LOKI's widths, and the
-step's other side (XLA's gather and scatter) at DREAM's powder widths.
+"""The windowed lookup compiles for a v5e at LOKI's widths (one byte
+plane) and at DREAM's powder widths (two), and the step's other side
+(XLA's gather and scatter) at DREAM's powder widths.
 
 Interpret mode cannot show what Mosaic refuses (a misaligned slice, too
 much VMEM). libtpu is installed here, so the kernel is compiled for a
@@ -40,7 +41,7 @@ def one_chip():
 @pytest.mark.parametrize("n_pix", [802_816, 172_032])
 def test_kernel_and_items_compile_at_lokis_widths(one_chip, n_pix):
     n = 1 << 22
-    packed = jax.ShapeDtypeStruct((208, n_pix), jnp.bfloat16, sharding=one_chip)
+    packed = jax.ShapeDtypeStruct((1, 208, n_pix), jnp.bfloat16, sharding=one_chip)
     keys = jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip)
     compiled = (
         jax.jit(lambda t, k: pallas_lookup._lookup_sorted(t, k, 8, False))
@@ -51,6 +52,50 @@ def test_kernel_and_items_compile_at_lokis_widths(one_chip, n_pix):
     stats = compiled.memory_analysis()
     # the table is read in place: no second copy, no relayout of it
     assert stats.temp_size_in_bytes < packed.size * 2 // 8
+
+
+@pytest.mark.parametrize("n_pix", [491_520, 30_720])
+def test_two_plane_kernel_and_items_compile_at_dreams_powder_widths(one_chip, n_pix):
+    """An int32 table over 34 000 bins as two byte planes, 500 TOA bins
+    padded to 512 (a 28-bit key for the mantle), a 4 Mi batch."""
+    n = 1 << 22
+    table = jax.ShapeDtypeStruct((n_pix, 500), jnp.int32)
+    packed = jax.eval_shape(
+        lambda t: pallas_lookup.pack_table(t, planes=2), table
+    )
+    assert packed.shape == (2, 512, n_pix) and packed.dtype == jnp.bfloat16
+    assert pallas_lookup.lookup_kind(n, packed.shape) == "windowed"
+    packed = jax.ShapeDtypeStruct(packed.shape, packed.dtype, sharding=one_chip)
+    keys = jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip)
+    compiled = (
+        jax.jit(lambda t, k: pallas_lookup._lookup_sorted(t, k, 9, False))
+        .lower(packed, keys)
+        .compile()
+    )
+    assert "tpu_custom_call" in compiled.as_text()
+    stats = compiled.memory_analysis()
+    # both planes are read in place: no second copy, no relayout
+    assert stats.temp_size_in_bytes < packed.size * 2 // 8
+
+
+@pytest.mark.parametrize(
+    "shape", [(2, 512, 491_520), (1, 208, 802_816)], ids=["dream", "loki"]
+)
+def test_a_batch_under_the_crossover_gathers_from_the_planes_in_place(
+    one_chip, shape
+):
+    """The fallback inside ``lookup``: one element gather a plane. A
+    slice over the planes had XLA copy the whole table into another
+    layout first (1 GB and 3.2 ms a step for the mantle; my chip run,
+    PR 32)."""
+    n = 1 << 15
+    assert pallas_lookup.lookup_kind(n, shape) == "gather"
+    packed = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    index = jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip)
+    ok = jax.ShapeDtypeStruct((n,), jnp.bool_, sharding=one_chip)
+    compiled = jax.jit(pallas_lookup.lookup).lower(packed, index, index, ok).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < packed.size * 2 // 8
 
 
 @pytest.mark.parametrize("n_pix", [491_520, 157_696])
