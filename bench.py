@@ -328,11 +328,14 @@ def bench_secondary_configs(args, edges, batches, method: str) -> None:
             devices=n_dev,
         )
     else:
-        # Single chip: the REAL Q-E rebinning over BIFROST's 9-triplet
+        # Single chip: the REAL Q-E rebinning over BIFROST's 45-triplet
         # analyzer geometry (BASELINE wording: "multi-analyzer Q-E
-        # rebinning across 9 detector banks") — per-event physics rides
-        # the precompiled (pixel, toa-bin) -> (Q, E) table, so the
-        # streaming cost is the same gather+scatter as the histogram.
+        # rebinning across 9 detector banks": the nine channels) —
+        # per-event physics rides the precompiled (pixel, toa-bin) ->
+        # (Q, E) table, so the streaming cost is the same gather+scatter
+        # as the histogram. A kernel loop on an unwrapped TOA axis of
+        # its own; the deployment through its service, on the wire's
+        # frame, is the benchmark's cell bifrost_qe.paced14.
         from esslivedata_tpu.config.instrument import instrument_registry
 
         instrument_registry["bifrost"].load_factories()
@@ -384,7 +387,7 @@ def bench_secondary_configs(args, edges, batches, method: str) -> None:
                         "metric": label,
                         "value": args.events * args.batches / dt,
                         "unit": "events/s",
-                        "banks": 9,
+                        "banks": 45,
                     }
                 ),
                 file=sys.stderr,

@@ -46,6 +46,9 @@ GEOMETRY_REGISTRY: dict[str, str | None] = {
     "geometry-loki-2026-09-01.nxs": None,
     "geometry-dream-2026-01-01.nxs": None,
     "geometry-bifrost-2026-01-01.nxs": None,
+    # 45 triplets of 300 pixels where the older file has 9 banks of
+    # 3000: a new date, for the same reason as LOKI's
+    "geometry-bifrost-2026-10-01.nxs": None,
     "geometry-estia-2026-01-01.nxs": None,
     "geometry-nmx-2026-01-01.nxs": None,
     "geometry-odin-2026-01-01.nxs": None,

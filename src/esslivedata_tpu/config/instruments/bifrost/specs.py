@@ -1,12 +1,20 @@
-"""BIFROST declaration: 9 triplet banks, merged into one logical stream.
+"""BIFROST declaration: 45 analyzer triplets merged into one logical stream.
 
-The real instrument's banks come from its NeXus geometry; here each of the
-9 analyzer triplets is a 100x30 pixel bank with contiguous detector-number
-blocks — the right topology for the merged-stream + bank-sharded reduction
-path. Q-E per-analyzer rebinning (the full
-spectrometer physics) runs on the same kernel family via a precompiled
-(pixel, toa) -> (Q, E)-bin map — see QE_HANDLE below and
+The deployed instrument (upstream ``config/instruments/bifrost/specs.py``,
+as remembered: no network here) has 5 analyzer arcs (final energies 2.7,
+3.2, 3.8, 4.4, 5.0 meV) x 9 channels = 45 triplets of 3 position-sensitive
+tubes x 100 pixels = 13 500 pixels. Every triplet is its own ev44 source
+on the detector topic, and ``merge_detectors`` routes all of them onto one
+logical stream that the spectroscopy workflows reduce. Q-E per-analyzer
+rebinning (the full spectrometer physics) runs on the Q kernel family via
+a precompiled (pixel, toa) -> (Q, E)-bin map: see QE_HANDLE below and
 workflows/qe_spectroscopy.py.
+
+Declared departure from upstream: there the detector ids fold as
+arc x tube x channel x pixel, so a triplet's ids are three runs of 100;
+here a triplet is one contiguous block of 300 (triplet ``a * 9 + c``
+holds ids ``1 + 300 (a * 9 + c)`` onwards, tube-major). The merged id
+space is 1..13 500 either way.
 """
 
 from __future__ import annotations
@@ -27,9 +35,15 @@ from ....workflows.ratemeter import RatemeterParams
 from ....workflows.workflow_factory import workflow_registry
 from .._common import register_monitor_spec, register_parsed_catalog
 
-N_BANKS = 9
-BANK_NY, BANK_NX = 100, 30
-PIXELS_PER_BANK = BANK_NY * BANK_NX
+#: Analyzer final energy of each arc (meV): the energy axis.
+ARC_EF_MEV = (2.7, 3.2, 3.8, 4.4, 5.0)
+N_ARCS = len(ARC_EF_MEV)
+#: Channels (wedges) per arc: the angle axis.
+N_CHANNELS = 9
+N_TRIPLETS = N_ARCS * N_CHANNELS
+TUBES_PER_TRIPLET, PIXELS_PER_TUBE = 3, 100
+PIXELS_PER_TRIPLET = TUBES_PER_TRIPLET * PIXELS_PER_TUBE
+N_PIXELS = N_TRIPLETS * PIXELS_PER_TRIPLET
 
 from .streams_parsed import PARSED_STREAMS
 
@@ -39,27 +53,31 @@ INSTRUMENT = Instrument(
     _factories_module="esslivedata_tpu.config.instruments.bifrost.factories",
 )
 
+#: triplet -> its [tube, pixel] detector numbers, arc-major.
 BANK_DETECTOR_NUMBERS: dict[str, np.ndarray] = {}
-for b in range(N_BANKS):
-    start = 1 + b * PIXELS_PER_BANK
-    det = np.arange(start, start + PIXELS_PER_BANK).reshape(BANK_NY, BANK_NX)
-    name = f"triplet_{b}"
-    BANK_DETECTOR_NUMBERS[name] = det
-    INSTRUMENT.add_detector(
-        DetectorConfig(
-            name=name,
-            source_name=f"bifrost_{name}",
-            detector_number=det,
-            projection="logical",
+for arc in range(N_ARCS):
+    for channel in range(N_CHANNELS):
+        start = 1 + (arc * N_CHANNELS + channel) * PIXELS_PER_TRIPLET
+        det = np.arange(start, start + PIXELS_PER_TRIPLET).reshape(
+            TUBES_PER_TRIPLET, PIXELS_PER_TUBE
         )
-    )
+        name = f"triplet_{arc}_{channel}"
+        BANK_DETECTOR_NUMBERS[name] = det
+        INSTRUMENT.add_detector(
+            DetectorConfig(
+                name=name,
+                source_name=f"bifrost_{name}",
+                detector_number=det,
+                projection="logical",
+            )
+        )
 register_parsed_catalog(INSTRUMENT, PARSED_STREAMS)
 INSTRUMENT.add_monitor(
     MonitorConfig(name="monitor_1", source_name="bifrost_mon_1")
 )
 instrument_registry.register(INSTRUMENT)
 
-# The merged stream name all banks adapt onto (merge_detectors routing).
+# The merged stream name all triplets adapt onto (merge_detectors routing).
 MERGED_STREAM = "detector"
 
 MULTIBANK_HANDLE = workflow_registry.register_spec(
@@ -67,7 +85,7 @@ MULTIBANK_HANDLE = workflow_registry.register_spec(
         instrument="bifrost",
         namespace="spectrometer",
         name="bank_overview",
-        title="9-bank overview (mesh-shardable)",
+        title="45-triplet overview (mesh-shardable)",
         source_names=[MERGED_STREAM],
         # Consumes detector events: hosted by the detector service even
         # though its display namespace is 'spectrometer'.
@@ -93,54 +111,43 @@ MULTIBANK_HANDLE = workflow_registry.register_spec(
 MONITOR_HANDLE = register_monitor_spec(INSTRUMENT)
 
 
-def analyzer_geometry() -> dict[str, np.ndarray]:
-    """Synthetic per-pixel analyzer geometry for the 9-triplet layout.
+#: The placeholder geometry's numbers (``analyzer_geometry``).
+CHANNEL_TWO_THETA_DEG = (15.0, 150.0)  # first and last wedge centre
+CHANNEL_HALF_SPREAD_DEG = 4.0  # along a tube, either side of the centre
+TUBE_AZIMUTH_DEG = (-2.0, 0.0, 2.0)
+ARC_L2_M = (1.2, 0.25)  # innermost arc's secondary path, and the step an arc
 
-    Placeholder physics in the spirit of the instrument (real
-    deployments regenerate from the facility geometry file): the nine
-    wedges fan over scattering angles 15°-150° with the 30 detector
-    columns spreading ±4° inside each wedge, and the 100 rows split
-    into BIFROST's five analyzer energies (2.7-5.0 meV) with the
-    secondary flight path growing with the analyzer radius.
+
+def analyzer_geometry() -> dict[str, np.ndarray]:
+    """Synthetic per-pixel analyzer geometry for the 45-triplet layout,
+    in the order of the detector numbers.
+
+    Placeholder physics in the spirit of the instrument (a deployment
+    regenerates it from the facility geometry file): the scattering
+    angle goes by channel, the nine wedges fanning over 15-150 degrees
+    with a tube's 100 pixels spreading +-4 degrees inside the wedge;
+    a triplet's three tubes sit -2, 0 and +2 degrees out of the
+    scattering plane, which gives the elastic Qy axis its structure;
+    the final energy and the secondary flight path (sample -> analyzer
+    -> detector) go by arc, the path growing with the analyzer radius.
     """
-    ef_levels = np.array([2.7, 3.2, 3.8, 4.4, 5.0])
-    rows_per_ef = BANK_NY // len(ef_levels)
-    two_theta = np.empty(N_BANKS * PIXELS_PER_BANK)
-    azimuth = np.empty_like(two_theta)
-    ef = np.empty_like(two_theta)
-    l2 = np.empty_like(two_theta)
-    pixel_ids = np.empty(two_theta.shape, dtype=np.int64)
-    for b in range(N_BANKS):
-        bank_center = np.deg2rad(15.0 + b * (135.0 / (N_BANKS - 1)))
-        col_offset = np.deg2rad(np.linspace(-4.0, 4.0, BANK_NX))
-        row_ef = ef_levels[
-            np.minimum(np.arange(BANK_NY) // rows_per_ef, len(ef_levels) - 1)
-        ]
-        sl = slice(b * PIXELS_PER_BANK, (b + 1) * PIXELS_PER_BANK)
-        two_theta[sl] = np.repeat(
-            bank_center + col_offset[None, :], BANK_NY, axis=0
-        ).reshape(-1)
-        # Small out-of-plane fan across the rows of each triplet: the
-        # tubes have vertical extent, giving the elastic Qy axis
-        # structure (rows near the arc midplane sit near phi = 0).
-        azimuth[sl] = np.repeat(
-            np.deg2rad(np.linspace(-2.0, 2.0, BANK_NY))[:, None],
-            BANK_NX,
-            axis=1,
-        ).reshape(-1)
-        ef[sl] = np.repeat(row_ef[:, None], BANK_NX, axis=1).reshape(-1)
-        l2[sl] = 1.2 + 0.25 * np.repeat(
-            np.minimum(np.arange(BANK_NY) // rows_per_ef, 4)[:, None],
-            BANK_NX,
-            axis=1,
-        ).reshape(-1)
-        pixel_ids[sl] = BANK_DETECTOR_NUMBERS[f"triplet_{b}"].reshape(-1)
+    arc, channel, tube, pixel = np.unravel_index(
+        np.arange(N_PIXELS),
+        (N_ARCS, N_CHANNELS, TUBES_PER_TRIPLET, PIXELS_PER_TUBE),
+    )
+    first, last = CHANNEL_TWO_THETA_DEG
+    centre = first + channel * ((last - first) / (N_CHANNELS - 1))
+    along = np.linspace(
+        -CHANNEL_HALF_SPREAD_DEG, CHANNEL_HALF_SPREAD_DEG, PIXELS_PER_TUBE
+    )
     return {
-        "two_theta": two_theta,
-        "azimuth": azimuth,
-        "ef_mev": ef,
-        "l2": l2,
-        "pixel_ids": pixel_ids,
+        "two_theta": np.deg2rad(centre + along[pixel]),
+        "azimuth": np.deg2rad(np.asarray(TUBE_AZIMUTH_DEG)[tube]),
+        "ef_mev": np.asarray(ARC_EF_MEV)[arc],
+        "l2": ARC_L2_M[0] + ARC_L2_M[1] * arc,
+        "pixel_ids": np.concatenate(
+            [det.reshape(-1) for det in BANK_DETECTOR_NUMBERS.values()]
+        ).astype(np.int64),
     }
 
 

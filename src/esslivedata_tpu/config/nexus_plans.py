@@ -379,7 +379,13 @@ _BIFROST = _with_contiguous_bank_ids(
             ),
         ),
     ),
-    {f"triplet_{i}": (100, 30) for i in range(9)},
+    # 5 arcs x 9 channels of 3 tubes x 100 pixels, arc-major, as
+    # instruments/bifrost/specs.py declares them
+    {
+        f"triplet_{arc}_{channel}": (3, 100)
+        for arc in range(5)
+        for channel in range(9)
+    },
 )
 
 

@@ -443,11 +443,13 @@ class DeviceEventCache:
             self._cum_hits += 1
 
     def cumulative_stats(self) -> dict[str, int | float]:
-        """Monotone totals since construction (telemetry collector)."""
+        """Monotone totals since construction (telemetry collector).
+        ``lookups`` is hits + misses: what a share of either is over."""
         with self._stats_lock:
             return {
                 "hits": self._cum_hits,
                 "misses": self._cum_misses,
+                "lookups": self._cum_hits + self._cum_misses,
                 "bytes_staged": self._cum_bytes_staged,
                 "staging_s": self._cum_staging_s,
             }

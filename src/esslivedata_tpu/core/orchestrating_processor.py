@@ -778,7 +778,8 @@ class OrchestratingProcessor:
                     "counter",
                     "Stage-once cache totals (ADR 0110): misses ~= one "
                     "per (stream, window) regardless of job count; "
-                    "bytes_staged is the actual wire traffic",
+                    "lookups = hits + misses; bytes_staged is the "
+                    "actual wire traffic",
                     [
                         ((("kind", kind),), value)
                         for kind, value in sorted(cache_stats().items())

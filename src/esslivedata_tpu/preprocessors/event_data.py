@@ -12,6 +12,7 @@ zero-copy / release_buffers contract is the same as the reference's
 
 from __future__ import annotations
 
+import time
 from dataclasses import dataclass
 from typing import Any, ClassVar
 
@@ -24,6 +25,7 @@ from ..ops.event_batch import (
     make_staging_buffer,
     sanitize_pixel_id,
 )
+from ..telemetry.trace import TRACER
 
 __all__ = [
     "DetectorEvents",
@@ -166,6 +168,12 @@ class ToEventBatch:
     are adopted (:class:`_ArrayChunk`), refs arriving into an eager
     window materialize through their array properties — either mix is
     byte-identical to the all-eager path.
+
+    Landing a window's chunks into its one wire is observed as the
+    aggregate span ``land``, once per ``get()``: the eager appends of
+    the window (one copy a message, summed over ``add``) plus the pad
+    to the bucket, or the one arena fill of a ref-mode window. It lies
+    inside ``decode``, so it has no ring entry (telemetry/trace.py).
     """
 
     is_context: ClassVar[bool] = False
@@ -187,6 +195,8 @@ class ToEventBatch:
         self._chunks: list | None = None
         self._ref_total = 0
         self._ref_taken = False
+        #: Seconds the window's eager appends have taken so far.
+        self._land_s = 0.0
 
     def add(
         self,
@@ -248,7 +258,9 @@ class ToEventBatch:
                 )
             else:
                 pixel_id = data.pixel_id
+            began = time.perf_counter()
             self._buffer.add(pixel_id, toa)
+            self._land_s += time.perf_counter() - began
         if self._first is None or timestamp < self._first:
             self._first = timestamp
         if self._last is None or timestamp > self._last:
@@ -290,11 +302,14 @@ class ToEventBatch:
         )
 
     def get(self) -> StagedEvents:
+        began = time.perf_counter()
         batch = (
             self._take_ref_batch()
             if self._chunks is not None
             else self._buffer.take()
         )
+        TRACER.observe("land", self._land_s + time.perf_counter() - began)
+        self._land_s = 0.0
         staged = StagedEvents(
             batch=batch,
             first_timestamp=self._first,
@@ -308,6 +323,7 @@ class ToEventBatch:
         self._chunks = None
         self._ref_total = 0
         self._ref_taken = False
+        self._land_s = 0.0
         self._first = None
         self._last = None
         self._n_chunks = 0
@@ -317,6 +333,7 @@ class ToEventBatch:
         self._chunks = None
         self._ref_total = 0
         self._ref_taken = False
+        self._land_s = 0.0
         self._first = None
         self._last = None
         self._n_chunks = 0

@@ -13,6 +13,9 @@ The spans of the serial loop, in the order a tick runs them:
 
 - ``decode``: preprocess + collect of the window's messages (``args``:
   ``hold_us``, how long the window's last message sat in the batcher);
+  inside it the aggregate ``land``, one observation per stream of the
+  window: landing the stream's chunks into its one wire
+  (``preprocessors/event_data.ToEventBatch``);
 - ``flatten``: host flatten / partition of one stream's events, once
   per stage-cache miss (``events``, ``padded``);
 - ``h2d``: the host copy and the ENQUEUE of the asynchronous
@@ -40,7 +43,8 @@ every job's outside a tick group, come once more at the tick's end.
 The pipelined path adds ``prestage`` (its stage worker's flatten +
 H2D as one span). **The ring stays flat**: the spans one thread
 records never overlap, a span's parent is its tick (the trace id), and
-an enclosing or contained phase (``tick``, ``unspanned``, ``d2h``) is
+an enclosing or contained phase (``tick``, ``unspanned``, ``d2h``,
+``land``) is
 an aggregate on ``/metrics`` (:meth:`TickTracer.observe`), never a
 second ring entry. ``benchmark/harness/trace_reduce.py:name_gap`` adds
 the ring's spans up and relies on it.
@@ -104,7 +108,7 @@ _SPAN_SECONDS = REGISTRY.histogram(
     "livedata_tick_span_seconds",
     "Duration of per-tick phases (decode/flatten/h2d/prestage/"
     "tick_execute/fetch/finalize/sink; aggregate only: tick/unspanned/"
-    "d2h), labeled by span name",
+    "d2h/land), labeled by span name",
     labelnames=("span",),
 )
 

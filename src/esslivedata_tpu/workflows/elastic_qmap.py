@@ -16,7 +16,7 @@ from typing import Literal
 import numpy as np
 from pydantic import BaseModel, ConfigDict, Field, model_validator
 
-from ..config.models import TOARange
+from ..config.models import PULSE_PERIOD_NS, TOARange
 from ..ops.qhistogram import QHistogrammer, build_elastic_q2d_map
 from ..utils.labeled import DataArray, Variable
 from .qshared import QStreamingMixin
@@ -55,9 +55,10 @@ class ElasticQMapParams(BaseModel):
     )
     e_window_mev: float = 0.25  # |Ei - Ef| accepted as elastic
     toa_bins: int = 320
-    toa_range: TOARange = Field(
-        default_factory=lambda: TOARange(low=8.0e7, high=4.0e8)
-    )
+    # The wire's frame and the frame offset that turns a TOA into a
+    # flight time: see QESpectroscopyParams (qe_spectroscopy.py).
+    toa_range: TOARange = Field(default_factory=TOARange)
+    toa_offset_ns: float = 2 * PULSE_PERIOD_NS
     l1: float = 162.0  # m, moderator->sample
 
     @model_validator(mode="after")
@@ -104,6 +105,7 @@ class ElasticQMapWorkflow(QStreamingMixin):
             axis2_edges=e2,
             l1=params.l1,
             e_window_mev=params.e_window_mev,
+            toa_offset_ns=params.toa_offset_ns,
         )
         self._n1, self._n2 = a1.bins, a2.bins
         self._hist = QHistogrammer(

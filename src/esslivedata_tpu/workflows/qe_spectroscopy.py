@@ -15,7 +15,7 @@ from __future__ import annotations
 import numpy as np
 from pydantic import BaseModel, ConfigDict, Field
 
-from ..config.models import TOARange
+from ..config.models import PULSE_PERIOD_NS, TOARange
 from ..ops.qhistogram import QHistogrammer, build_qe_map
 from ..utils.labeled import DataArray, Variable
 from .qshared import QStreamingMixin
@@ -33,11 +33,17 @@ class QESpectroscopyParams(BaseModel):
     e_min: float = -3.0  # meV energy transfer
     e_max: float = 6.0
     toa_bins: int = 320
-    # Long-frame arrival window: BIFROST's 162 m incident path puts
-    # cold-neutron arrivals hundreds of ms after the pulse.
-    toa_range: TOARange = Field(
-        default_factory=lambda: TOARange(low=8.0e7, high=4.0e8)
-    )
+    # The TOA axis is the wire's: an ev44 time of arrival is relative
+    # to its own pulse and lies in [0, 1/14 s), whatever the flight
+    # time was. BIFROST's 162 m incident path puts cold-neutron
+    # arrivals hundreds of ms after the pulse that made them, so the
+    # flight time is the TOA plus a whole number of pulse periods: the
+    # frame offset, added to every TOA-bin centre when the table is
+    # built. Two periods put incident energies of ~3.0-6.9 meV into the
+    # frame, which the five analyzer energies turn into transfers of
+    # about -2..4 meV: inside the default E axis.
+    toa_range: TOARange = Field(default_factory=TOARange)
+    toa_offset_ns: float = 2 * PULSE_PERIOD_NS
     l1: float = 162.0  # m, moderator->sample
 
 
@@ -71,6 +77,7 @@ class QESpectroscopyWorkflow(QStreamingMixin):
             q_edges=q_edges,
             e_edges=e_edges,
             l1=params.l1,
+            toa_offset_ns=params.toa_offset_ns,
         )
         self._n_q = params.q_bins
         self._n_e = params.e_bins

@@ -37,10 +37,9 @@ class RatemeterParams(BaseModel):
     arc_ef_mev: float = 5.0
     pixel_start: int = 0  # index along the arc (two_theta order)
     pixel_stop: int = 900
-    # Accepted arrival window. BIFROST's 162 m incident path delivers
-    # long-frame arrivals far beyond one pulse period, so the default
-    # spans the whole frame rather than [0, pulse) — the same window
-    # family the QE/elastic maps use (qe_spectroscopy.py toa_range).
+    # Accepted arrival window. It covers the wire's frame [0, 1/14 s)
+    # (an ev44 TOA is relative to its own pulse) and, beyond it,
+    # unwrapped flight times where a producer sends those.
     toa_range: TOARange = Field(
         default_factory=lambda: TOARange(low=0.0, high=4.0e8)
     )

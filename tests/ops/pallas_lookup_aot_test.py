@@ -1,6 +1,8 @@
 """The windowed lookup compiles for a v5e at LOKI's widths (one byte
-plane) and at DREAM's powder widths (two), and the step's other side
-(XLA's gather and scatter) at DREAM's powder widths.
+plane) and at DREAM's powder widths (two), the step's other side
+(XLA's gather and scatter) at DREAM's powder widths, and both whole
+steps of BIFROST's merged stream (a tiny two-plane table in front of
+the one-hot bincount and in front of the scatter).
 
 Interpret mode cannot show what Mosaic refuses (a misaligned slice, too
 much VMEM). libtpu is installed here, so the kernel is compiled for a
@@ -123,3 +125,39 @@ def test_the_gather_side_compiles_at_dreams_powder_widths(one_chip, n_pix):
     table_bytes = n_pix * 500 * 4
     assert stats.argument_size_in_bytes < table_bytes + 2 * n * 4 + (16 << 20)  # no padding to 512 columns
     assert stats.temp_size_in_bytes < table_bytes // 8  # no second copy of the table
+
+
+@pytest.mark.parametrize(
+    "n_bins, method, kernels",
+    [(80 * 60, "pallas", 2), (100 * 100, "scatter", 1)],
+    ids=["qe_map", "elastic_qmap"],
+)
+def test_both_whole_steps_compile_at_bifrosts_widths(one_chip, monkeypatch, n_bins, method, kernels):
+    """``bifrost_qe.paced14``: 13 500 pixels x 320 TOA bins in two byte
+    planes (106 table windows against 16 384 event blocks), the cell's
+    16 Mi merged batch; S(Q, E)'s 4 800 bins take the one-hot bincount (38
+    lane groups where LOKI's 100 bins are one), the elastic map's
+    10 000 are past ``MAX_PALLAS_BINS`` and take XLA's scatter. The
+    step asks ``jax.default_backend()`` whether to interpret its
+    kernels; here it is told the backend it is being compiled for."""
+    import functools
+
+    from esslivedata_tpu.ops.qhistogram import table_scatter_delta
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    n = 1 << 24
+    table = jax.ShapeDtypeStruct((13_500, 320), jnp.int16)
+    packed = jax.eval_shape(lambda t: pallas_lookup.pack_table(t, planes=2), table)
+    assert packed.shape == (2, 320, 13_568) and pallas_lookup.lookup_kind(n, packed.shape) == "windowed"
+    step = functools.partial(
+        table_scatter_delta, id_base=1, lo=0.0, hi=1e9 / 14, inv_width=320 * 14 / 1e9,
+        n_bins=n_bins, dtype=jnp.float32, method=method, packed_shape=(13_500, 320),
+    )
+    packed = jax.ShapeDtypeStruct(packed.shape, packed.dtype, sharding=one_chip)
+    ids = jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip)
+    toa = jax.ShapeDtypeStruct((n,), jnp.float32, sharding=one_chip)
+    compiled = jax.jit(step).lower(packed, ids, toa).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == kernels and (" scatter(" in text) == (method == "scatter")
+    # the wire, the table and a few 16 Mi temporaries: nothing near the chip's 16 GB
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30

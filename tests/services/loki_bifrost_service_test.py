@@ -129,53 +129,136 @@ class TestLokiReduction:
         assert 0.99 * 3 * 500 <= img.data.sum() <= 3 * 500
 
 
-class TestBifrostMergedStream:
-    def test_nine_banks_one_stream(self):
-        from esslivedata_tpu.config.instruments.bifrost.specs import (
-            BANK_DETECTOR_NUMBERS,
-            MULTIBANK_HANDLE,
-            PIXELS_PER_BANK,
-        )
+def bifrost_service(make_builder, tag):
+    builder = make_builder(
+        instrument="bifrost", batcher=NaiveMessageBatcher(), job_threads=1
+    )
+    producer = FakeProducer()
+    sink = KafkaSink(
+        producer, make_default_serializer(builder.stream_mapping.livedata, tag)
+    )
+    return builder, producer, sink
 
-        streams = [
-            FakeDetectorStream(
-                topic="bifrost_detector",
-                source_name=f"bifrost_triplet_{b}",
-                detector_ids=det,
-                events_per_pulse=100,
-                seed=b,
-            )
-            for b, det in enumerate(BANK_DETECTOR_NUMBERS.values())
-        ]
-        builder = make_detector_service_builder(
-            instrument="bifrost", batcher=NaiveMessageBatcher(), job_threads=1
+
+def bank_overview_on_45_triplets():
+    """The detector service: every one of the 45 declared sources lands
+    on the merged stream and in its own bank of the overview."""
+    from esslivedata_tpu.config.instruments.bifrost.specs import (
+        BANK_DETECTOR_NUMBERS,
+        MULTIBANK_HANDLE,
+        N_TRIPLETS,
+    )
+
+    streams = [
+        FakeDetectorStream(
+            topic="bifrost_detector",
+            source_name=f"bifrost_{name}",
+            detector_ids=det,
+            events_per_pulse=100,
+            seed=b,
         )
-        raw = PulsedRawSource(streams)
-        producer = FakeProducer()
-        sink = KafkaSink(
-            producer, make_default_serializer(builder.stream_mapping.livedata, "b")
+        for b, (name, det) in enumerate(BANK_DETECTOR_NUMBERS.items())
+    ]
+    assert len(streams) == N_TRIPLETS == 45
+    builder, producer, sink = bifrost_service(make_detector_service_builder, "b")
+    raw = PulsedRawSource(streams)
+    service = builder.from_raw_source(raw, sink)
+    raw.inject(
+        start_command(
+            MULTIBANK_HANDLE.workflow_id, "detector", "bifrost_livedata_commands"
         )
-        service = builder.from_raw_source(raw, sink)
+    )
+    for _ in range(3):
+        service.step()
+    outputs = decoded_outputs(producer, "bifrost_livedata_data")
+    counts = next(
+        v for v in outputs["bank_counts_current"].variables if v.name == "signal"
+    )
+    assert counts.data.shape == (45,)
+    # every triplet produced events on the merged stream
+    assert (counts.data > 0).all()
+    total = next(
+        v for v in outputs["counts_cumulative"].variables if v.name == "signal"
+    )
+    assert float(total.data) == 45 * 100 * 3
+
+
+def qe_map_with_default_parameters_bins_the_elastic_line_off_the_wire():
+    """The reduction service applies the merged-detector adaptation
+    (once it did not: jobs at 'detector' saw no events), and a job
+    started with its default parameters, as a dashboard starts it, bins
+    TOAs as ev44 carries them: relative to their own pulse, below
+    1/14 s, the flight time being the TOA plus the frame offset."""
+    from esslivedata_tpu.config.instruments.bifrost.specs import (
+        ARC_EF_MEV,
+        ARC_L2_M,
+        BANK_DETECTOR_NUMBERS,
+        MERGED_STREAM,
+        QE_HANDLE,
+    )
+    from esslivedata_tpu.config.models import PULSE_PERIOD_NS
+    from esslivedata_tpu.ops.qhistogram import E_FROM_V2
+    from esslivedata_tpu.workflows.qe_spectroscopy import QESpectroscopyParams
+
+    builder, producer, sink = bifrost_service(make_reduction_service_builder, "qe")
+    raw = PulsedRawSource([])
+    service = builder.from_raw_source(raw, sink)
+    raw.inject(
+        start_command(
+            QE_HANDLE.workflow_id,
+            MERGED_STREAM,
+            "bifrost_livedata_commands",
+            aux={"monitor": "monitor_1"},
+        )
+    )
+    service.step()
+    # Elastic arrivals on the second arc (Ef 3.2 meV; the first arc's
+    # elastic line, 2.7 meV, lies outside the frame the offset selects).
+    arc = 1
+    v = np.sqrt(ARC_EF_MEV[arc] / E_FROM_V2)
+    flight_ns = (162.0 + ARC_L2_M[0] + ARC_L2_M[1] * arc) / v * 1e9
+    t_wire = flight_ns - QESpectroscopyParams().toa_offset_ns
+    assert 0 <= t_wire < PULSE_PERIOD_NS  # what a wire can carry
+    ids_of = BANK_DETECTOR_NUMBERS["triplet_1_0"].reshape(-1)
+    rng = np.random.default_rng(0)
+    for pulse in range(3):
+        t_pulse = 1_700_000_000_000_000_000 + pulse * int(1e9 / 14)
+        ids = rng.choice(ids_of, 1000).astype(np.int32)
+        toa = np.full(1000, t_wire, dtype=np.int32)
         raw.inject(
-            start_command(
-                MULTIBANK_HANDLE.workflow_id, "detector", "bifrost_livedata_commands"
+            FakeKafkaMessage(
+                wire.encode_ev44(
+                    "bifrost_triplet_1_0",
+                    pulse,
+                    np.array([t_pulse]),
+                    np.array([0]),
+                    toa,
+                    pixel_id=ids,
+                ),
+                "bifrost_detector",
             )
         )
-        for _ in range(3):
-            service.step()
-        outputs = decoded_outputs(producer, "bifrost_livedata_data")
-        counts = next(
-            v
-            for v in outputs["bank_counts_current"].variables
-            if v.name == "signal"
-        )
-        assert counts.data.shape == (9,)
-        # every bank produced events on the merged stream
-        assert (counts.data > 0).all()
-        total = next(
-            v for v in outputs["counts_cumulative"].variables if v.name == "signal"
-        )
-        assert float(total.data) == 9 * 100 * 3
+        service.step()
+    outputs = decoded_outputs(producer, "bifrost_livedata_data")
+    sqw = next(
+        var for var in outputs["sqw_cumulative"].variables if var.name == "signal"
+    )
+    assert float(np.asarray(sqw.data, np.float64).sum()) == 3000.0
+    # Elastic events concentrate in few (Q, E) bins around dE=0.
+    assert (np.asarray(sqw.data) > 0).sum() < 40
+
+
+class TestBifrostMergedStream:
+    @pytest.mark.parametrize(
+        "case",
+        [
+            bank_overview_on_45_triplets,
+            qe_map_with_default_parameters_bins_the_elastic_line_off_the_wire,
+        ],
+        ids=lambda case: case.__name__,
+    )
+    def test_45_triplets_one_stream(self, case):
+        case()
 
 
 class TestLokiParsedCatalogTimeseries:
@@ -288,69 +371,6 @@ class TestLokiParsedCatalogTimeseries:
         out = decoded_outputs(producer, "loki_livedata_data")
         assert any(name in key for key in out), sorted(out)
 
-
-
-class TestBifrostQEReduction:
-    def test_qe_map_on_merged_stream_with_elastic_line(self):
-        # Regression: the reduction service must apply the merged-detector
-        # adaptation (it didn't — jobs at 'detector' saw no events).
-        import numpy as np
-
-        from esslivedata_tpu.config.instruments.bifrost.specs import (
-            MERGED_STREAM,
-            QE_HANDLE,
-        )
-        from esslivedata_tpu.ops.qhistogram import E_FROM_V2
-
-        builder = make_reduction_service_builder(
-            instrument="bifrost", batcher=NaiveMessageBatcher(), job_threads=1
-        )
-        raw = PulsedRawSource([])
-        producer = FakeProducer()
-        sink = KafkaSink(
-            producer, make_default_serializer(builder.stream_mapping.livedata, "qe")
-        )
-        service = builder.from_raw_source(raw, sink)
-        raw.inject(
-            start_command(
-                QE_HANDLE.workflow_id,
-                MERGED_STREAM,
-                "bifrost_livedata_commands",
-                aux={"monitor": "monitor_1"},
-            )
-        )
-        service.step()
-        # Elastic arrivals for the first analyzer block (Ef=2.7, l2=1.2).
-        v = np.sqrt(2.7 / E_FROM_V2)
-        t_arr = (162.0 + 1.2) / v * 1e9
-        rng = np.random.default_rng(0)
-        for pulse in range(3):
-            t_pulse = 1_700_000_000_000_000_000 + pulse * int(1e9 / 14)
-            ids = rng.integers(1, 600, 1000).astype(np.int32)
-            toa = np.full(1000, t_arr, dtype=np.int32)
-            raw.inject(
-                FakeKafkaMessage(
-                    wire.encode_ev44(
-                        "bifrost_triplet_0",
-                        pulse,
-                        np.array([t_pulse]),
-                        np.array([0]),
-                        toa,
-                        pixel_id=ids,
-                    ),
-                    "bifrost_detector",
-                )
-            )
-            service.step()
-        outputs = decoded_outputs(producer, "bifrost_livedata_data")
-        sqw = next(
-            var
-            for var in outputs["sqw_cumulative"].variables
-            if var.name == "signal"
-        )
-        assert float(np.asarray(sqw.data, np.float64).sum()) == 3000.0
-        # Elastic events concentrate in few (Q, E) bins around dE=0.
-        assert (np.asarray(sqw.data) > 0).sum() < 40
 
 
 class TestDreamLiveEmissionOffset:

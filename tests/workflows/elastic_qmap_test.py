@@ -103,6 +103,12 @@ class TestElasticQ2dMap:
         assert abs((a_edges[b1] + 0.01) - qy) < 0.021
 
 
+def wire_toa_ns(l2):
+    """``elastic_toa_ns`` as an ev44 message carries it: relative to the
+    pulse it arrives in, i.e. less the default frame offset."""
+    return elastic_toa_ns(l2) - ElasticQMapParams().toa_offset_ns
+
+
 class TestElasticQMapWorkflow:
     def make(self, **params):
         return ElasticQMapWorkflow(
@@ -118,7 +124,7 @@ class TestElasticQMapWorkflow:
 
     def test_elastic_events_land(self):
         wf = self.make()
-        t = elastic_toa_ns(1.5)
+        t = wire_toa_ns(1.5)
         wf.accumulate({"detector": staged([1, 2, 3], [t, t, t])})
         out = wf.finalize()
         assert float(out["counts_current"].values) == 3.0
@@ -134,7 +140,7 @@ class TestElasticQMapWorkflow:
 
     def test_window_folds(self):
         wf = self.make()
-        t = elastic_toa_ns(1.5)
+        t = wire_toa_ns(1.5)
         wf.accumulate({"detector": staged([2], [t])})
         wf.finalize()
         out = wf.finalize()

@@ -104,6 +104,10 @@ class TestQEMapPhysics:
         assert qe_map.table.shape[0] == 1
 
 
+#: The default frame offset: an ev44 TOA is relative to its own pulse, so
+#: a flight time of ~187 ms arrives as that minus two pulse periods.
+FRAME_OFFSET_NS = QESpectroscopyParams().toa_offset_ns
+
 class TestWorkflowIntegration:
     def _workflow(self):
         n_pix = 16
@@ -119,7 +123,7 @@ class TestWorkflowIntegration:
     def test_events_bin_and_fold(self):
         wf = self._workflow()
         v = np.sqrt(4.0 / E_FROM_V2)
-        t_elastic = (162.0 + 1.5) / v * 1e9
+        t_elastic = (162.0 + 1.5) / v * 1e9 - FRAME_OFFSET_NS  # as on the wire
         rng = np.random.default_rng(0)
         pid = rng.integers(0, 16, 5000).astype(np.int32)
         toa = np.full(5000, t_elastic, dtype=np.float32)
@@ -138,7 +142,7 @@ class TestWorkflowIntegration:
     def test_monitor_normalization(self):
         wf = self._workflow()
         v = np.sqrt(4.0 / E_FROM_V2)
-        t_elastic = (162.0 + 1.5) / v * 1e9
+        t_elastic = (162.0 + 1.5) / v * 1e9 - FRAME_OFFSET_NS  # as on the wire
         wf.accumulate(
             {
                 "detector": staged(
