@@ -18,6 +18,15 @@ Sections:
        ops/pallas_lookup.py exact against the gather, ms a step for
        every rung of the ladder and the crossover sweep (PERF.md
        section 6).
+  bincount (``--bincount`` runs this section alone): the Q step's
+       count of looked-up bins, the three ways ``QHistogrammer`` has
+       (XLA's scatter-add, the flat one-hot ``bincount_pallas``, the
+       factorised one-hot on the MXU ``bincount_mxu``) at 1, 2, 4, 8,
+       38, 79 and 266 lane groups of 128 bins (LOKI's 100 bins,
+       BIFROST's 4 800 and 10 000, DREAM powder's 34 000) for a 4 Mi
+       and a 16 Mi batch with the cells' 23 % of padding routed to the
+       drop slot: each exact against ``np.bincount``, ns an event, and
+       what ``method="auto"`` takes (``MXU_LANE_GROUPS``).
 """
 
 import functools
@@ -214,11 +223,61 @@ def lookup_section(
             )
 
 
+#: ``bincount_section``'s bin spaces: 1, 2, 4, 8, 38, 79, 266 lane groups.
+BINCOUNT_BINS = (100, 256, 512, 1024, 4_800, 10_000, 34_000)
+
+
+def bincount_section(
+    sizes: tuple[int, ...] = (1 << 22, 1 << 24),
+    bin_spaces: tuple[int, ...] = BINCOUNT_BINS,
+    pad_share: float = 0.23,
+) -> None:
+    """Parity with numpy and ns an event of the three bincounts (a
+    rehearsal on the CPU passes small sizes); raises on a mismatch."""
+    import jax
+    import jax.numpy as jnp
+
+    from esslivedata_tpu.ops import pallas_hist
+
+    rng = np.random.default_rng(34)
+    for n in sizes:
+        for n_bins in bin_spaces:
+            flat = rng.integers(0, n_bins, n).astype(np.int32)
+            # dropped events and the bucket's padding, as the step
+            # routes them: to the slot one past the last bin
+            flat[rng.random(n) < pad_share] = n_bins
+            want = np.bincount(flat[flat < n_bins], minlength=n_bins)
+            dev = jax.device_put(flat)
+            ways = {
+                "scatter": lambda f, k=n_bins: jnp.zeros((k,), jnp.float32)
+                .at[f]
+                .add(1.0, mode="drop"),
+                "mxu": lambda f, k=n_bins: pallas_hist.bincount_mxu(f, k),
+            }
+            if n_bins + 1 <= pallas_hist.MAX_PALLAS_BINS:
+                ways["pallas"] = lambda f, k=n_bins: pallas_hist.bincount_pallas(f, k)
+            line = []
+            for name, fn in sorted(ways.items()):
+                fn = jax.jit(fn)
+                np.testing.assert_array_equal(np.asarray(fn(dev)), want)
+                took = _ms(fn, dev)
+                line.append(f"{name} {took:.3f} ms = {took * 1e6 / n:.3f} ns an event")
+            print(
+                f"bincount n={n} bins={n_bins} ({-(-n_bins // 128)} lane "
+                "groups), exact: " + "; ".join(line)
+                + f"; auto takes {pallas_hist.tpu_bincount(n_bins)}",
+                flush=True,
+            )
+
+
 def main() -> None:
     import jax
     import jax.numpy as jnp
 
     print("device:", jax.devices()[0], flush=True)
+    if "--bincount" in sys.argv[1:]:
+        bincount_section()
+        return
     if "--lookup" in sys.argv[1:]:
         lookup_section()
         lookup_section(**DREAM_POWDER)
@@ -330,6 +389,7 @@ def main() -> None:
 
     lookup_section()
     lookup_section(**DREAM_POWDER)
+    bincount_section()
 
 
 if __name__ == "__main__":

@@ -460,7 +460,8 @@ def table_scatter_delta(
     ``id_base`` may be a traced value (the sharded kernel derives it
     from the shard index). ``method='pallas'`` accumulates the delta
     with the VMEM one-hot kernel (ops/pallas_hist.py) instead of the
-    serial scatter — every Q-family bin space fits its bound.
+    serial scatter, ``method='mxu'`` with that module's factorised
+    one-hot on the MXU (bin spaces of up to ``MAX_MXU_BINS``).
     ``packed_shape`` says that ``table`` is in the packed layout of
     ops/pallas_lookup.py and gives the ``(n_pix, n_toa)`` it stands
     for: the lookup is then that module's (sort + dense windows at or
@@ -489,9 +490,17 @@ def table_scatter_delta(
             from .pallas_hist import bincount_pallas
 
             return bincount_pallas(qb, n_bins).astype(dtype)
+        if method == "mxu":
+            from .pallas_hist import bincount_mxu
+
+            return bincount_mxu(qb, n_bins).astype(dtype)
         delta = jnp.zeros((n_bins,), dtype=dtype)
         return delta.at[qb].add(1.0, mode="drop")
 
+
+#: ``livedata_q_bincount_steps_total``'s label for each way
+#: ``table_scatter_delta`` counts bins.
+_BINCOUNT_LABELS = {"scatter": "scatter", "pallas": "onehot", "mxu": "mxu"}
 
 #: Process-unique instance tokens for Q fuse keys: two histogrammers
 #: carry independent tables, so only states of the SAME instance may
@@ -532,41 +541,37 @@ class QHistogrammer:
         dtype=jnp.float32,
         method: str = "scatter",
     ) -> None:
-        if method not in ("auto", "scatter", "pallas"):
+        if method not in ("auto", "scatter", "pallas", "mxu"):
             raise ValueError(f"Unknown method {method!r}")
-        if method == "auto":
-            # Bin spaces up to MAX_PALLAS_BINS take the VMEM one-hot
-            # kernel on a TPU backend; a wider one (DREAM's I(d, 2-theta):
-            # 34 000) keeps XLA's scatter, 31.4 ms a step of 4 Mi events
-            # there (my chip run, PR 32, PERF.md section 5). Per step of
-            # 4 Mi events from a 321 MB table into 100 bins on a v5e
-            # the one-hot kernel takes 2.4 ms and XLA's table gather
-            # took 52.0 (device time by scope; my chip run, PR 27): the
-            # lookup was the step. The lookup is chosen apart from the
-            # bincount, below: sorted and read window by window
-            # (ops/pallas_lookup.py) it takes 6.9 ms from that table
-            # (one byte plane; the whole step 9.3 against 61.8; my chip
-            # run, PR 28) and 13.1 ms from DREAM's 983 MB int32 table
-            # held as two planes, against a gather of 66.3 (my chip run,
-            # PR 32, PERF.md section 6).
-            from .pallas_hist import MAX_PALLAS_BINS
+        from .pallas_hist import MAX_MXU_BINS, MAX_PALLAS_BINS, tpu_bincount
 
+        if method == "auto":
+            # How the looked-up bins are counted follows what the code
+            # can see, the backend and ``n_q``. On a TPU
+            # (``tpu_bincount``): the flat one-hot kernel while the bin
+            # space is under ``MXU_LANE_GROUPS`` groups of 128 lanes
+            # (LOKI's 100 bins: one group), the factorised one-hot on
+            # the MXU from there to ``MAX_MXU_BINS`` (S(Q, E)'s 4 800,
+            # the elastic map's 10 000, I(d, 2-theta)'s 34 000), XLA's
+            # scatter past that; the scatter on every other backend. The
+            # readings that set the crossover stand beside the constant
+            # in ops/pallas_hist.py. The lookup is chosen apart from the
+            # bincount, below (ops/pallas_lookup.py).
             method = (
-                "pallas"
-                if (
-                    n_q + 1 <= MAX_PALLAS_BINS
-                    and jax.default_backend() == "tpu"
-                )
+                tpu_bincount(n_q)
+                if jax.default_backend() == "tpu"
                 else "scatter"
             )
-        if method == "pallas":
-            from .pallas_hist import MAX_PALLAS_BINS
-
-            if n_q + 1 > MAX_PALLAS_BINS:
-                raise ValueError(
-                    f"method='pallas' supports at most "
-                    f"{MAX_PALLAS_BINS - 1} bins; this map has {n_q}"
-                )
+        if method == "pallas" and n_q + 1 > MAX_PALLAS_BINS:
+            raise ValueError(
+                f"method='pallas' supports at most "
+                f"{MAX_PALLAS_BINS - 1} bins; this map has {n_q}"
+            )
+        if method == "mxu" and n_q > MAX_MXU_BINS:
+            raise ValueError(
+                f"method='mxu' supports at most {MAX_MXU_BINS} bins; "
+                f"this map has {n_q}"
+            )
         if isinstance(qmap, PixelBinMap):
             table, id_base = qmap.table, qmap.id_base
         else:
@@ -591,12 +596,14 @@ class QHistogrammer:
 
             self._planes = packable(table, n_q)
         self._install_table(table, wait=True)
-        # Both lookup series exist from the first Q kernel on, so that a
-        # share of either label reads 0, and not "no sample", in a
-        # service whose every step takes the other path (one that runs
-        # no Q kernel still shows neither).
+        # Both lookup series and all three bincount series exist from
+        # the first Q kernel on, so that a share of any label reads 0,
+        # and not "no sample", in a service whose every step takes
+        # another path (one that runs no Q kernel still shows none).
         for kind in ("windowed", "gather"):
             Q_LOOKUP_STEPS.inc(0.0, lookup=kind)
+        for kind in _BINCOUNT_LABELS.values():
+            Q_BINCOUNT_STEPS.inc(0.0, method=kind)
         # a swap keeps shape and layout, so the bytes stand until the
         # kernel goes (a stopped job releases its workflow)
         nbytes = self._qmap.nbytes
@@ -753,9 +760,7 @@ class QHistogrammer:
 
             lookup = lookup_kind(n_events, self._qmap.shape)
         Q_LOOKUP_STEPS.inc(lookup=lookup)
-        Q_BINCOUNT_STEPS.inc(
-            method="onehot" if self._method == "pallas" else "scatter"
-        )
+        Q_BINCOUNT_STEPS.inc(method=_BINCOUNT_LABELS[self._method])
 
     def stage_events(
         self,

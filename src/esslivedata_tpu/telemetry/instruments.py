@@ -227,14 +227,19 @@ Q_LOOKUP_STEPS = REGISTRY.counter(
 
 #: How each Q step counted its looked-up bins, the kernel's other
 #: choice (``QHistogrammer(method="auto")``), counted beside the one
-#: above: ``onehot`` = the VMEM one-hot kernel of ``ops/pallas_hist.py``
-#: (a TPU backend and a bin space of at most ``MAX_PALLAS_BINS``),
-#: ``scatter`` = XLA's scatter-add (the CPU, and wider bin spaces such
-#: as the powder reduction's 2000 x 17). scatter / both is the
-#: benchmark's ``q_bincount_scatter_share``.
+#: above, three labels: ``onehot`` = the flat VMEM one-hot kernel of
+#: ``ops/pallas_hist.py`` (a TPU backend and a bin space under
+#: ``MXU_LANE_GROUPS`` groups of 128 bins: LOKI's 100), ``mxu`` = that
+#: module's factorised one-hot on the MXU (a TPU backend, from there to
+#: ``MAX_MXU_BINS`` = 65 536 bins: the powder reduction's 2000 x 17,
+#: BIFROST's 80 x 60 and 100 x 100), ``scatter`` = XLA's scatter-add
+#: (every other backend, and bin spaces past one VMEM tile). All three
+#: have a sample from the first Q kernel on. scatter / all is the
+#: benchmark's ``q_bincount_scatter_share``, mxu / all its
+#: ``q_bincount_mxu_share``.
 Q_BINCOUNT_STEPS = REGISTRY.counter(
     "livedata_q_bincount_steps_total",
     "Q-histogram steps dispatched, by how the looked-up bins were "
-    "counted (onehot or scatter)",
+    "counted (onehot, mxu or scatter)",
     labelnames=("method",),
 )

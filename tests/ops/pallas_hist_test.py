@@ -1,13 +1,24 @@
-"""Pallas bincount kernel: parity with the XLA scatter path.
+"""Pallas bincount kernels (the flat one-hot and the factorised one on
+the MXU): parity with numpy and with the XLA scatter path, and which of
+the three ``QHistogrammer(method="auto")`` takes.
 
 Runs in interpret mode on the CPU test mesh; the compiled path is what
-bench.py --method pallas measures on real TPU hardware."""
+``scripts/tpu_kernel_check.py --bincount`` measures on the chip."""
 
+import jax
 import numpy as np
 import pytest
 
 from esslivedata_tpu.ops import EventBatch, EventHistogrammer
-from esslivedata_tpu.ops.pallas_hist import MAX_PALLAS_BINS, bincount_pallas
+from esslivedata_tpu.ops.pallas_hist import (
+    MAX_MXU_BINS,
+    MAX_PALLAS_BINS,
+    MXU_CHUNK,
+    MXU_LANE_GROUPS,
+    bincount_mxu,
+    bincount_pallas,
+)
+from esslivedata_tpu.telemetry.instruments import Q_BINCOUNT_STEPS
 
 
 class TestBincountKernel:
@@ -33,6 +44,119 @@ class TestBincountKernel:
     def test_bin_bound_enforced(self):
         with pytest.raises(ValueError, match="VMEM"):
             bincount_pallas(np.zeros(4, np.int32), MAX_PALLAS_BINS + 1)
+
+
+def _mxu_case(case: str, n_bins: int) -> np.ndarray:
+    rng = np.random.default_rng(n_bins)
+    if case == "unaligned":  # no multiple of the chunk, every bin's edge
+        flat = rng.integers(0, n_bins, 3 * MXU_CHUNK + 77)
+        flat[:4] = (0, 127, 128, n_bins - 1)
+    elif case == "empty":
+        flat = np.empty(0)
+    elif case == "out_of_range":  # counted nowhere: the step's drop slot
+        flat = rng.integers(0, n_bins, 2 * MXU_CHUNK)
+        flat[::3] = -1
+        flat[1::3] = n_bins
+        flat[2::7] = n_bins + 128
+    else:  # one bin past what a bf16 or a 16-bit count would hold
+        assert case == "heavy_bin"
+        flat = rng.integers(0, n_bins, (1 << 16) + 2 * MXU_CHUNK + 5)
+        flat[: (1 << 16) + 3] = n_bins - 2
+    return flat.astype(np.int32)
+
+
+class TestBincountMxu:
+    @pytest.mark.parametrize(
+        "case", ["unaligned", "empty", "out_of_range", "heavy_bin"]
+    )
+    @pytest.mark.parametrize("n_bins", [129, 4_800, 10_000, 34_000, 65_536])
+    def test_parity_with_numpy(self, n_bins, case):
+        flat = _mxu_case(case, n_bins)
+        counts = np.asarray(bincount_mxu(flat, n_bins))
+        assert counts.shape == (n_bins,) and counts.dtype == np.float32
+        valid = flat[(flat >= 0) & (flat < n_bins)]
+        want = np.bincount(valid, minlength=n_bins)
+        np.testing.assert_array_equal(counts, want)
+        if case == "heavy_bin":
+            assert want.max() > 1 << 16
+
+    def test_bin_bound_enforced(self):
+        assert MAX_MXU_BINS == 65_536
+        with pytest.raises(ValueError, match="VMEM"):
+            bincount_mxu(np.zeros(4, np.int32), MAX_MXU_BINS + 1)
+
+
+def _q_histogrammer(n_q: int, method: str, backend: str | None = None):
+    from esslivedata_tpu.ops.qhistogram import PixelBinMap, QHistogrammer
+
+    table = np.arange(40, dtype=np.int32).reshape(4, 10) % n_q
+    kw = dict(
+        qmap=PixelBinMap(table=table, id_base=0),
+        toa_edges=np.linspace(0, 1e6, 11),
+        n_q=n_q,
+        method=method,
+    )
+    if backend is None:
+        return QHistogrammer(**kw)
+    # the backend is asked at construction alone; the step itself
+    # traces for the CPU (interpret mode)
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax, "default_backend", lambda: backend)
+        return QHistogrammer(**kw)
+
+
+class TestQHistogrammerBincountChoice:
+    @pytest.mark.parametrize(
+        "n_q, method",
+        [
+            (100, "pallas"),  # LOKI's I(Q): one lane group
+            (128 * (MXU_LANE_GROUPS - 1), "pallas"),
+            (128 * (MXU_LANE_GROUPS - 1) + 1, "mxu"),
+            (4_800, "mxu"),  # BIFROST's S(Q, E)
+            (10_000, "mxu"),  # BIFROST's elastic map
+            (34_000, "mxu"),  # DREAM's I(d, 2-theta)
+            (MAX_MXU_BINS, "mxu"),
+            (70_000, "scatter"),
+        ],
+    )
+    def test_auto_on_a_tpu_goes_by_the_bin_space(self, n_q, method):
+        assert _q_histogrammer(n_q, "auto", "tpu")._method == method
+
+    @pytest.mark.parametrize("n_q", [100, 4_800, 10_000, 34_000, 70_000])
+    def test_auto_on_the_cpu_is_the_scatter(self, n_q):
+        assert _q_histogrammer(n_q, "auto")._method == "scatter"
+        assert _q_histogrammer(n_q, "auto", "gpu")._method == "scatter"
+
+    def test_the_flat_kernels_bound_stands(self):
+        assert MAX_PALLAS_BINS == 8192 and MXU_LANE_GROUPS * 128 < MAX_PALLAS_BINS
+
+    def test_mxu_bin_bound_enforced(self):
+        with pytest.raises(ValueError, match="mxu"):
+            _q_histogrammer(MAX_MXU_BINS + 1, "mxu")
+
+    def test_every_label_has_a_sample_after_construction(self):
+        _q_histogrammer(100, "scatter")
+        labels = {labels["method"] for labels, _ in Q_BINCOUNT_STEPS.items()}
+        assert {"scatter", "onehot", "mxu"} <= labels
+
+    @pytest.mark.parametrize(
+        "method, label",
+        [("scatter", "scatter"), ("pallas", "onehot"), ("mxu", "mxu")],
+    )
+    def test_a_step_counts_under_the_label_it_was_traced_with(self, method, label):
+        hist = _q_histogrammer(300, method)
+        before = {
+            kind: Q_BINCOUNT_STEPS.value(method=kind)
+            for kind in ("scatter", "onehot", "mxu")
+        }
+        batch = EventBatch.from_arrays(
+            np.array([0, 1, 2, 3, 9], np.int64),
+            np.array([1e5, 2e5, 3e5, 9.5e5, 1e5], np.float32),
+        )
+        state = hist.step(hist.init_state(), batch)
+        assert float(state.window.sum()) == 4.0
+        for kind, was in before.items():
+            assert Q_BINCOUNT_STEPS.value(method=kind) == was + (kind == label)
 
 
 class TestHistogrammerPallasMethod:
@@ -102,7 +226,8 @@ class TestHistogrammerPallasMethod:
 
 
 class TestQHistogrammerPallasMethod:
-    def test_parity_with_scatter(self):
+    @pytest.mark.parametrize("method", ["pallas", "mxu"])
+    def test_parity_with_scatter(self, method):
         from esslivedata_tpu.ops.qhistogram import (
             QHistogrammer,
             build_dspacing_map,
@@ -119,7 +244,7 @@ class TestQHistogrammerPallasMethod:
         )
         kw = dict(qmap=dmap, toa_edges=np.linspace(0.0, 7.1e7, 41), n_q=32)
         ref = QHistogrammer(method="scatter", **kw)
-        pal = QHistogrammer(method="pallas", **kw)
+        pal = QHistogrammer(method=method, **kw)
         s_ref, s_pal = ref.init_state(), pal.init_state()
         for seed in range(3):
             r = np.random.default_rng(seed)

@@ -1,13 +1,17 @@
-"""The windowed lookup compiles for a v5e at LOKI's widths (one byte
-plane) and at DREAM's powder widths (two), the step's other side
-(XLA's gather and scatter) at DREAM's powder widths, and both whole
-steps of BIFROST's merged stream (a tiny two-plane table in front of
-the one-hot bincount and in front of the scatter).
+"""The Q step's kernels compile for a v5e at the cells' widths: the
+windowed lookup at LOKI's (one byte plane) and at DREAM's powder widths
+(two), the factorised one-hot bincount on the MXU (``bincount_mxu``)
+alone at DREAM's powder and at BIFROST's widths, the step's other side
+(XLA's gather and scatter) at DREAM's powder widths, and the whole step
+in each of the three ways of counting bins: powder's (two planes in
+front of 34 000 bins) and both of BIFROST's merged stream (a tiny
+two-plane table in front of 4 800 and of 10 000 bins).
 
 Interpret mode cannot show what Mosaic refuses (a misaligned slice, too
 much VMEM). libtpu is installed here, so the kernel is compiled for a
 chip that is described and not attached: lowering, not results (those
-are ``scripts/tpu_kernel_check.py --lookup``'s, on the chip). The
+are ``scripts/tpu_kernel_check.py --lookup``'s and ``--bincount``'s, on
+the chip). The
 topology is described inside a fixture of this one file: only the xdist
 worker that is given the file loads the TPU's library.
 """
@@ -17,7 +21,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from esslivedata_tpu.ops import pallas_lookup
+from esslivedata_tpu.ops import pallas_hist, pallas_lookup
 
 
 @pytest.fixture(scope="module")
@@ -128,36 +132,85 @@ def test_the_gather_side_compiles_at_dreams_powder_widths(one_chip, n_pix):
 
 
 @pytest.mark.parametrize(
-    "n_bins, method, kernels",
-    [(80 * 60, "pallas", 2), (100 * 100, "scatter", 1)],
-    ids=["qe_map", "elastic_qmap"],
+    "n, n_bins",
+    [(1 << 22, 34_000), (1 << 24, 4_800), (1 << 24, 10_000), (1 << 22, 65_536)],
+    ids=["powder", "qe_map", "elastic_qmap", "one_tile"],
 )
-def test_both_whole_steps_compile_at_bifrosts_widths(one_chip, monkeypatch, n_bins, method, kernels):
-    """``bifrost_qe.paced14``: 13 500 pixels x 320 TOA bins in two byte
-    planes (106 table windows against 16 384 event blocks), the cell's
-    16 Mi merged batch; S(Q, E)'s 4 800 bins take the one-hot bincount (38
-    lane groups where LOKI's 100 bins are one), the elastic map's
-    10 000 are past ``MAX_PALLAS_BINS`` and take XLA's scatter. The
-    step asks ``jax.default_backend()`` whether to interpret its
-    kernels; here it is told the backend it is being compiled for."""
+def test_the_mxu_bincount_compiles_alone(one_chip, n, n_bins):
+    """The count tile and both one-hots live in VMEM: beside the bins
+    the kernel reads and the counts it writes, nothing on the HBM."""
+    bins = jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip)
+    compiled = (
+        jax.jit(lambda b: pallas_hist.bincount_mxu(b, n_bins, interpret=False))
+        .lower(bins)
+        .compile()
+    )
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1 and " scatter(" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 << 20
+
+
+def _whole_step(one_chip, *, n, table, packed_shape, n_bins, method):
+    """``table_scatter_delta`` behind a two-plane packed ``table``,
+    compiled for the described chip: its HLO and its memory."""
     import functools
 
     from esslivedata_tpu.ops.qhistogram import table_scatter_delta
 
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    n = 1 << 24
-    table = jax.ShapeDtypeStruct((13_500, 320), jnp.int16)
+    n_pix, n_toa = table.shape
     packed = jax.eval_shape(lambda t: pallas_lookup.pack_table(t, planes=2), table)
-    assert packed.shape == (2, 320, 13_568) and pallas_lookup.lookup_kind(n, packed.shape) == "windowed"
+    assert packed.shape == packed_shape and pallas_lookup.lookup_kind(n, packed.shape) == "windowed"
     step = functools.partial(
-        table_scatter_delta, id_base=1, lo=0.0, hi=1e9 / 14, inv_width=320 * 14 / 1e9,
-        n_bins=n_bins, dtype=jnp.float32, method=method, packed_shape=(13_500, 320),
+        table_scatter_delta, id_base=1, lo=0.0, hi=1e9 / 14, inv_width=n_toa * 14 / 1e9,
+        n_bins=n_bins, dtype=jnp.float32, method=method, packed_shape=(n_pix, n_toa),
     )
     packed = jax.ShapeDtypeStruct(packed.shape, packed.dtype, sharding=one_chip)
     ids = jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip)
     toa = jax.ShapeDtypeStruct((n,), jnp.float32, sharding=one_chip)
     compiled = jax.jit(step).lower(packed, ids, toa).compile()
-    text = compiled.as_text()
+    return compiled.as_text(), compiled.memory_analysis()
+
+
+@pytest.mark.parametrize("n_pix", [491_520, 30_720])
+def test_powders_whole_step_compiles_with_the_mxu_bincount(one_chip, monkeypatch, n_pix):
+    """``dream_powder.paced14`` on a TPU: two byte planes in front of
+    34 000 bins, a 4 Mi batch: the lookup's kernel, then the
+    bincount's, and no scatter."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text, stats = _whole_step(
+        one_chip, n=1 << 22, table=jax.ShapeDtypeStruct((n_pix, 500), jnp.int32),
+        packed_shape=(2, 512, n_pix), n_bins=34_000, method="mxu",
+    )
+    assert text.count("tpu_custom_call") == 2 and " scatter(" not in text
+    assert stats.temp_size_in_bytes < 1 << 30  # a few 4 Mi temporaries, no copy of the table
+
+
+@pytest.mark.parametrize(
+    "n_bins, method, kernels",
+    [
+        (80 * 60, "pallas", 2),
+        (100 * 100, "scatter", 1),
+        (80 * 60, "mxu", 2),
+        (100 * 100, "mxu", 2),
+    ],
+    ids=["qe_map", "elastic_qmap", "qe_map_mxu", "elastic_qmap_mxu"],
+)
+def test_both_whole_steps_compile_at_bifrosts_widths(one_chip, monkeypatch, n_bins, method, kernels):
+    """``bifrost_qe.paced14``: 13 500 pixels x 320 TOA bins in two byte
+    planes (106 table windows against 16 384 event blocks), the cell's
+    16 Mi merged batch. Each bin space in each way it can be counted:
+    S(Q, E)'s 4 800 bins by the flat one-hot (38 lane groups where
+    LOKI's 100 bins are one) and by the factorised one on the MXU, which
+    ``method="auto"`` takes there on a TPU; the elastic map's 10 000
+    (past ``MAX_PALLAS_BINS``) by XLA's scatter and on the MXU, one
+    more kernel than the lookup's and no scatter. The step asks
+    ``jax.default_backend()`` whether to interpret its kernels; here it
+    is told the backend it is being compiled for."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text, stats = _whole_step(
+        one_chip, n=1 << 24, table=jax.ShapeDtypeStruct((13_500, 320), jnp.int16),
+        packed_shape=(2, 320, 13_568), n_bins=n_bins, method=method,
+    )
     assert text.count("tpu_custom_call") == kernels and (" scatter(" in text) == (method == "scatter")
     # the wire, the table and a few 16 Mi temporaries: nothing near the chip's 16 GB
-    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+    assert stats.temp_size_in_bytes < 1 << 30
