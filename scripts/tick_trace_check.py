@@ -17,7 +17,13 @@ section 6 quotes:
   planes, counted by name;
 - with a dump, the **clock check**: every ring span, shifted by the dump's
   own ``epoch_minus_clock_ns``, against its twin; the largest difference
-  over every tick. And ``hold_us`` of the ``decode`` spans, tick by tick.
+  over every tick. And ``hold_us`` of the ``decode`` spans, tick by tick;
+- with a dump, the **pool phase** by thread (``pool_phase``): the ``h2d``
+  and ``q_step`` spans that a tick's job threads recorded (every thread
+  but the one of the tick's ``decode``), tick by tick: each thread's
+  seconds of either, its first start and last end, which thread finished
+  last, and the median over ticks of (last end - first start) beside the
+  median of the ``accumulate_wait`` twin, the loop thread's wait for them.
 
 What it does not find it leaves out.
 """
@@ -26,6 +32,7 @@ from __future__ import annotations
 
 import json
 import re
+import statistics
 import sys
 import time
 from pathlib import Path
@@ -178,6 +185,62 @@ def clock_check(ring, twins, start_ns: int, offset_ns: int) -> dict:
     }
 
 
+POOL_SPANS = ("h2d", "q_step")
+
+
+def pool_phase(events, waits_ns: dict[int, int] | None = None) -> dict | None:
+    """The accumulate phase by job thread, from a dump's events (Chrome
+    ``X`` events: ``ts`` / ``dur`` in us, ``tid`` the thread's name).
+    ``waits_ns``: the ``accumulate_wait`` twin's duration by trace id,
+    where a profiler trace gave one."""
+    loop_thread = {e["args"]["trace_id"]: e["tid"] for e in events if e["name"] == "decode"}
+    ticks: dict[int, dict[str, dict]] = {}
+    for event in events:
+        tick = event["args"]["trace_id"]
+        if event["name"] not in POOL_SPANS or event["tid"] == loop_thread.get(tick):
+            continue
+        row = ticks.setdefault(tick, {}).setdefault(
+            event["tid"], {"h2d_ms": 0.0, "q_step_ms": 0.0, "first": event["ts"], "last": 0.0}
+        )
+        row[event["name"] + "_ms"] += event["dur"] / 1e3
+        row["first"] = min(row["first"], event["ts"])
+        row["last"] = max(row["last"], event["ts"] + event["dur"])
+    if not ticks:
+        return None
+    by_tick, finished_last = [], {}
+    for tick, threads in sorted(ticks.items()):
+        first = min(row["first"] for row in threads.values())
+        last_thread = max(threads, key=lambda tid: threads[tid]["last"])
+        finished_last[last_thread] = finished_last.get(last_thread, 0) + 1
+        entry = {
+            "trace_id": tick,
+            "wall_ms": (threads[last_thread]["last"] - first) / 1e3,
+            "finished_last": last_thread,
+            "threads": {
+                tid: {
+                    "h2d_ms": row["h2d_ms"],
+                    "q_step_ms": row["q_step_ms"],
+                    "first_start_ms": (row["first"] - first) / 1e3,
+                    "last_end_ms": (row["last"] - first) / 1e3,
+                }
+                for tid, row in sorted(threads.items())
+            },
+        }
+        if waits_ns and tick in waits_ns:
+            entry["accumulate_wait_ms"] = waits_ns[tick] / 1e6
+        by_tick.append(entry)
+    out = {
+        "ticks": len(by_tick),
+        "wall_ms_median": statistics.median(e["wall_ms"] for e in by_tick),
+        "finished_last": dict(sorted(finished_last.items())),
+    }
+    waited = [e["accumulate_wait_ms"] for e in by_tick if "accumulate_wait_ms" in e]
+    if waited:
+        out["accumulate_wait_ms_median"] = statistics.median(waited)
+    out["by_tick"] = by_tick
+    return out
+
+
 def analyse(xplane: Path, dump: Path | None = None) -> dict:
     from jax.profiler import ProfileData
 
@@ -192,6 +255,8 @@ def analyse(xplane: Path, dump: Path | None = None) -> dict:
     found = find_xplane(xplane)
     if found is None:
         report["error"] = "no xplane"
+        if events and (pool := pool_phase(events)):
+            report["pool_phase"] = pool
         return report
     data = ProfileData.from_file(str(found))
     start = None
@@ -203,6 +268,7 @@ def analyse(xplane: Path, dump: Path | None = None) -> dict:
     # -- the annotation twins: host events that carry a trace id -------------
     twins: dict[tuple[str, int], list[int]] = {}
     twin_names: dict[str, int] = {}
+    waits_ns: dict[int, int] = {}  # the accumulate_wait twin, one a tick
     for plane in data.planes:
         if plane.name.startswith(DEVICE_PLANE):
             continue
@@ -214,7 +280,11 @@ def analyse(xplane: Path, dump: Path | None = None) -> dict:
                 key = (event.name, int(stats["trace_id"]))
                 twins.setdefault(key, []).append(int(event.start_ns))
                 twin_names[event.name] = twin_names.get(event.name, 0) + 1
+                if event.name == "accumulate_wait":
+                    waits_ns[key[1]] = int(event.duration_ns)
     report["twins_by_name"] = dict(sorted(twin_names.items()))
+    if events and (pool := pool_phase(events, waits_ns)):
+        report["pool_phase"] = pool
 
     offset = report.get("dump_offset_ns")
     lo, hi = float("-inf"), float("inf")

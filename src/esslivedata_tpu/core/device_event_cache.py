@@ -54,6 +54,8 @@ from typing import Any, Callable, Hashable
 
 import numpy as np
 
+from ..telemetry.trace import TRACER
+
 __all__ = [
     "DecodeArena",
     "DecodeArenaPool",
@@ -318,7 +320,11 @@ class StreamStageSlot:
                 _staged_nbytes(entry.value), time.perf_counter() - t0
             )
             return entry.value
-        entry.event.wait()
+        # What this reader waits for the thread that stages the entry
+        # (0 for an entry that was ready): with two jobs on one stream
+        # the second stands here for the first's ``h2d``.
+        with TRACER.aggregate("stage_wait"):
+            entry.event.wait()
         if entry.error is not None:
             raise entry.error
         self._cache._record_hit()

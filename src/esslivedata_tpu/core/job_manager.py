@@ -18,6 +18,7 @@ from __future__ import annotations
 import bisect
 import logging
 import threading
+import time
 import uuid
 from collections.abc import Callable, Mapping
 from concurrent.futures import ThreadPoolExecutor
@@ -1539,12 +1540,24 @@ class JobManager:
                 )
 
         trace_id = TRACER.current()
+        #: When the pool was handed the window's private jobs; None in
+        #: the serial branch, where no job waits for a thread.
+        fanned_out_at: float | None = None
 
         def run_accumulate(item: tuple[_JobRecord, dict[str, Any]]) -> None:
+            # Two aggregates a job a window, on whichever thread runs
+            # it: ``accumulate`` is this whole body; minus the job's
+            # ``h2d``, ``q_step`` and ``stage_wait`` it is what an add
+            # costs outside its spans. ``pool_queue`` is what the job
+            # waited for a free pool thread.
+            started = time.perf_counter()
             rec, job_data = item
             skip_streams = fused_streams.get(rec.job.job_id, frozenset())
             with TRACER.bind(trace_id):  # a pool thread has none of its own
+                if fanned_out_at is not None:
+                    TRACER.observe("pool_queue", started - fanned_out_at)
                 accumulate(rec, job_data, skip_streams)
+                TRACER.observe("accumulate", time.perf_counter() - started)
 
         def accumulate(
             rec: _JobRecord, job_data: dict[str, Any], skip_streams
@@ -1679,7 +1692,13 @@ class JobManager:
         work = [item for item in work if id(item[0]) not in ahead]
 
         if self._executor is not None and len(work) > 1:
-            list(self._executor.map(run_accumulate, work))
+            # The loop thread waits here under no span of its own (the
+            # job threads' ``h2d`` / ``q_step`` are theirs): the wait
+            # is an aggregate that counts toward this thread's
+            # coverage, so ``unspanned`` stays what no phase explains.
+            fanned_out_at = time.perf_counter()
+            with TRACER.aggregate("accumulate_wait", covers=True):
+                list(self._executor.map(run_accumulate, work))
         else:
             for item in work:
                 run_accumulate(item)

@@ -13,6 +13,7 @@ allocation on the host side either.
 
 from __future__ import annotations
 
+import time
 from dataclasses import dataclass
 
 import numpy as np
@@ -207,7 +208,13 @@ class StagingBuffer:
 _CPU_BACKEND: bool | None = None
 
 
-def dispatch_safe(x):
+def _copy_now(make_copy):
+    """The default ``copying`` of the two staging functions below: just
+    make the host copy. ``ship`` passes its own, which also times it."""
+    return make_copy()
+
+
+def dispatch_safe(x, copying=_copy_now):
     """Stage a host numpy array for an async jitted call.
 
     - CPU backend: copy. XLA's CPU client aliases suitably-aligned numpy
@@ -223,6 +230,9 @@ def dispatch_safe(x):
       zero-copy staging view released and overwritten by the next cycle
       could still be mid-transfer. A 16 MB memcpy is ~3 ms against the
       ~45 ms scatter it overlaps with.
+
+    ``copying`` is called with the function that makes that host copy
+    and returns its result: ``ship`` times the copy through it.
     """
     global _CPU_BACKEND
     if _CPU_BACKEND is None:
@@ -231,10 +241,10 @@ def dispatch_safe(x):
         _CPU_BACKEND = jax.default_backend() == "cpu"
     if isinstance(x, np.ndarray):
         if _CPU_BACKEND:
-            return x.copy()
+            return copying(x.copy)
         import jax
 
-        return jax.device_put(x.copy())
+        return jax.device_put(copying(x.copy))
     return x
 
 
@@ -247,13 +257,29 @@ def ship(batch: EventBatch, arrays: tuple, device=None) -> tuple:
     batch's wire, raw or flattened) through ``dispatch_safe`` or, placed,
     ``stage_for``. It times the host copy and the ENQUEUE of the
     asynchronous ``device_put``; the transfer itself completes under the
-    tick's ``fetch``. With it the count of what was shipped: the
+    tick's ``fetch``. Inside it the aggregate ``h2d_copy``: the host
+    copies alone, summed over the arrays (each array is still copied,
+    then put, before the next is copied), so that what is left of
+    ``h2d`` is the enqueue. With it the count of what was shipped: the
     bucket's slots, and those of them that are padding."""
+    copy_s = 0.0
+
+    def copying(make_copy):
+        nonlocal copy_s
+        start = time.perf_counter()
+        with TRACER.annotated("h2d_copy"):
+            copied = make_copy()
+        copy_s += time.perf_counter() - start
+        return copied
+
     with TRACER.span("h2d", args={"bytes": sum(a.nbytes for a in arrays)}):
         if device is None:
-            shipped = tuple(dispatch_safe(a) for a in arrays)
+            shipped = tuple(dispatch_safe(a, copying) for a in arrays)
         else:
-            shipped = tuple(stage_for(a, device) for a in arrays)
+            shipped = tuple(
+                stage_for(a, device, copying=copying) for a in arrays
+            )
+        TRACER.observe("h2d_copy", copy_s)
     _STAGED_SLOTS.inc(batch.padded_size)
     _PAD_SLOTS.inc(batch.padded_size - batch.n_valid)
     return shipped
@@ -325,7 +351,7 @@ def leaf_device_set(leaf, *, committed_only: bool = False):
         return None
 
 
-def stage_for(arr, sharding, *, dtype=None):
+def stage_for(arr, sharding, *, dtype=None, copying=_copy_now):
     """Stage a batch onto ``sharding`` in ONE placement hop.
 
     The sharded kernels' counterpart of ``dispatch_safe`` — same two
@@ -337,6 +363,7 @@ def stage_for(arr, sharding, *, dtype=None):
     device and pay a second device->device copy on the resharded
     placement. ``dtype`` optionally normalizes wire dtypes on the host
     (one pass, fused with the copy); device arrays cast on device.
+    ``copying`` as in ``dispatch_safe``.
     """
     import jax
 
@@ -344,7 +371,9 @@ def stage_for(arr, sharding, *, dtype=None):
         if dtype is not None and arr.dtype != np.dtype(dtype):
             arr = arr.astype(dtype)
         return jax.device_put(arr, sharding)
-    return jax.device_put(np.array(arr, dtype=dtype, copy=True), sharding)
+    return jax.device_put(
+        copying(lambda: np.array(arr, dtype=dtype, copy=True)), sharding
+    )
 
 
 def make_staging_buffer(min_bucket: int = MIN_BUCKET, prefer_native: bool = True):

@@ -28,6 +28,7 @@ __all__ = [
     "Q_LOOKUP_STEPS",
     "SINK_BYTES",
     "SINK_SECONDS",
+    "SINK_SERIALIZE_SECONDS",
     "STAGED_EVENTS",
     "TABLE_BUILD_SECONDS",
     "TABLE_BYTES",
@@ -128,6 +129,19 @@ SINK_SECONDS = REGISTRY.counter(
     "Cumulative seconds of publish_messages by phase "
     "(serialize = da00/f144/... encode, produce, flush)",
     labelnames=("phase",),
+)
+
+#: The ``serialize`` phase of a da00 message apart (``DefaultSerializer``,
+#: one clock read between the two), by ``step``: ``da00`` builds the message's
+#: variables from the job's result (``dataarray_to_da00``; a value still
+#: on the device would be pulled here), ``wire`` is the native encoder
+#: (``wire.encode_da00``). ``serialize`` above still holds both plus the
+#: publish loop's own overhead and every other message kind.
+SINK_SERIALIZE_SECONDS = REGISTRY.counter(
+    "livedata_sink_serialize_seconds_total",
+    "Cumulative seconds of da00 serialization by step "
+    "(da00 = build the variables, wire = encode them)",
+    labelnames=("step",),
 )
 
 #: What that time is paid for: the benchmark's ``sink_mb`` per tick, an

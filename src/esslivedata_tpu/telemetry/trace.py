@@ -43,18 +43,38 @@ every job's outside a tick group, come once more at the tick's end.
 The pipelined path adds ``prestage`` (its stage worker's flatten +
 H2D as one span). **The ring stays flat**: the spans one thread
 records never overlap, a span's parent is its tick (the trace id), and
-an enclosing or contained phase (``tick``, ``unspanned``, ``d2h``,
-``land``) is
-an aggregate on ``/metrics`` (:meth:`TickTracer.observe`), never a
+an enclosing or contained phase, or a wait for threads whose own spans
+are in the ring, is an aggregate on ``/metrics``
+(:meth:`TickTracer.observe`, :meth:`TickTracer.aggregate`), never a
 second ring entry. ``benchmark/harness/trace_reduce.py:name_gap`` adds
-the ring's spans up and relies on it.
+the ring's spans up and relies on it. The aggregates:
+
+- ``tick``, ``unspanned`` (:meth:`TickTracer.finish_tick`), ``d2h``
+  (inside ``fetch``), ``land`` (inside ``decode``);
+- ``accumulate_wait``: the loop thread's wait for the pool that runs a
+  window's private ``job.add`` calls (``JobManager.process_jobs``), the
+  pool branch alone. The job threads' ``h2d`` and ``q_step`` are in the
+  ring under their own thread, so this one **counts toward the loop
+  thread's coverage** instead (``covers=True``): ``unspanned`` is what
+  no phase explains, with or without a pool;
+- ``accumulate`` (one per job per window, on whichever thread runs it:
+  context delivery + ``job.add``) and ``pool_queue`` (the pool branch:
+  from the fan-out to the start of that job's turn on a pool thread);
+- ``stage_wait``: what a stage-once hit waited for the thread that
+  stages the entry (``StreamStageSlot.get_or_stage``; 0 for an entry
+  that was ready);
+- ``h2d_copy``: the host copies of one ``h2d`` alone, one observation
+  a ``ship`` call; the rest of ``h2d`` is ``device_put``'s enqueue.
 
 One clock: spans and tick totals read ``time.perf_counter()``; a dump
 names it and carries the offset to the epoch sampled in this process.
 While a ``jax.profiler`` session runs (``--profile``, ``POST
 /profile``) every :meth:`TickTracer.span` is mirrored into the
 profiler's own trace as a ``TraceAnnotation``, on the profiler's
-clock, next to the device ops.
+clock, next to the device ops; so are the aggregates that are timed
+regions (``accumulate_wait``, ``stage_wait``, ``h2d_copy``): the
+profiler's trace is per thread and nests, only the ring has to stay
+flat.
 
 Three consumers:
 
@@ -73,8 +93,11 @@ Three consumers:
 
 Hot-path cost: an enabled span is two ``perf_counter`` calls, one
 histogram observe, one deque append under the ring lock and, where jax
-is loaded, the profiler's is-a-session-running flag test; a disabled
-tracer (``LIVEDATA_TRACE=0``) costs one attribute read. Span recording
+is loaded, the profiler's is-a-session-running flag test; an aggregate
+is the same without the append (and without the flag test where the
+caller times it itself: ``accumulate``, ``pool_queue``); a disabled
+tracer (``LIVEDATA_TRACE=0``) costs one attribute read (the callers
+that time a region themselves keep their clock reads). Span recording
 must NEVER run inside jit-traced code — it would measure trace time,
 not execution (graftlint JGL018 polices this).
 
@@ -93,7 +116,7 @@ import sys
 import threading
 import time
 from collections import deque
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from dataclasses import dataclass
 
 from .registry import REGISTRY
@@ -108,7 +131,8 @@ _SPAN_SECONDS = REGISTRY.histogram(
     "livedata_tick_span_seconds",
     "Duration of per-tick phases (decode/flatten/h2d/prestage/"
     "tick_execute/fetch/finalize/sink; aggregate only: tick/unspanned/"
-    "d2h/land), labeled by span name",
+    "d2h/land/accumulate_wait/accumulate/pool_queue/stage_wait/"
+    "h2d_copy), labeled by span name",
     labelnames=("span",),
 )
 
@@ -235,25 +259,54 @@ class TickTracer:
             thread=threading.current_thread().name,
             args=args,
         )
-        # What this thread's spans cover of the trace, for
-        # finish_tick's ``unspanned``: one running sum per thread (a
-        # thread works on one tick at a time), never a ring scan.
-        local = self._local
-        if getattr(local, "covered_id", None) == trace_id:
-            local.covered_s += duration_s
-        else:
-            local.covered_id = trace_id
-            local.covered_s = duration_s
+        self._cover(trace_id, duration_s)
         with self._lock:
             self._spans.append(span)
 
-    def observe(self, name: str, seconds: float) -> None:
+    def _cover(self, trace_id: int, seconds: float) -> None:
+        """What this thread's phases cover of the trace, for
+        finish_tick's ``unspanned``: one running sum per thread (a
+        thread works on one tick at a time), never a ring scan."""
+        local = self._local
+        if getattr(local, "covered_id", None) == trace_id:
+            local.covered_s += seconds
+        else:
+            local.covered_id = trace_id
+            local.covered_s = seconds
+
+    def observe(
+        self, name: str, seconds: float, *, covers: bool = False
+    ) -> None:
         """An aggregate-only phase: into the span histogram, never the
-        ring. For a phase that encloses ring spans (``tick``) or lies
-        inside one (``d2h`` inside ``fetch``): a ring entry for it
-        would overlap them, and the ring stays flat."""
-        if self.enabled:
-            _SPAN_SECONDS.observe(seconds, span=name)
+        ring. For a phase that encloses ring spans (``tick``), lies
+        inside one (``d2h`` inside ``fetch``) or waits for threads
+        whose own spans are in the ring: a ring entry for it would
+        overlap them, and the ring stays flat. ``covers``: the calling
+        thread spent this time on its bound trace under no ring span of
+        its own (``accumulate_wait``), so it counts toward what
+        ``finish_tick(tiled=True)`` takes off ``unspanned``."""
+        if not self.enabled:
+            return
+        _SPAN_SECONDS.observe(seconds, span=name)
+        if covers:
+            trace_id = self.current()
+            if trace_id is not None:
+                self._cover(trace_id, seconds)
+
+    def annotated(
+        self, name: str, trace_id: int | None = None,
+        args: dict[str, int] | None = None,
+    ):
+        """A context manager: the region's ``TraceAnnotation`` in the
+        profiler's own trace while a session runs, nothing otherwise.
+        It records nothing here: :meth:`span` and :meth:`aggregate`
+        time the region around it, and a caller that sums several
+        regions into one aggregate (``h2d_copy``) times them itself."""
+        annotation = _session_annotation() if self.enabled else None
+        if annotation is None:
+            return nullcontext()
+        tick = self.current() if trace_id is None else trace_id
+        return annotation(name, trace_id=tick or 0, **(args or {}))
 
     @contextmanager
     def span(
@@ -268,19 +321,31 @@ class TickTracer:
         if not self.enabled:
             yield
             return
-        annotation = _session_annotation()
+        twin = self.annotated(name, trace_id, args)  # it starts at its enter
         start = time.perf_counter()
         try:
-            if annotation is None:
+            with twin:
                 yield
-            else:
-                tick = self.current() if trace_id is None else trace_id
-                with annotation(name, trace_id=tick or 0, **(args or {})):
-                    yield
         finally:
             self.record(
                 name, start, time.perf_counter() - start, trace_id, args
             )
+
+    @contextmanager
+    def aggregate(self, name: str, *, covers: bool = False):
+        """The wrapped region as one :meth:`observe` (histogram only:
+        the ring keeps its span names), with the profiler twin a span
+        has. JGL018 holds as for :meth:`span`."""
+        if not self.enabled:
+            yield
+            return
+        twin = self.annotated(name)
+        start = time.perf_counter()
+        try:
+            with twin:
+                yield
+        finally:
+            self.observe(name, time.perf_counter() - start, covers=covers)
 
     # -- watchdog ----------------------------------------------------------
     def finish_tick(

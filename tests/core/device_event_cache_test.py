@@ -4,6 +4,7 @@ the JobManager's fused stepping over it (ADR 0110)."""
 from __future__ import annotations
 
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -14,6 +15,7 @@ from esslivedata_tpu.core.job_manager import JobCommand, JobFactory, JobManager
 from esslivedata_tpu.core.timestamp import Timestamp
 from esslivedata_tpu.ops import EventBatch
 from esslivedata_tpu.preprocessors.event_data import StagedEvents
+from esslivedata_tpu.telemetry import REGISTRY, TRACER
 from esslivedata_tpu.workflows import WorkflowFactory
 from esslivedata_tpu.workflows.detector_view import (
     DetectorViewWorkflow,
@@ -102,6 +104,50 @@ class TestSlotSemantics:
             t.join()
         assert len(calls) == 1
         assert all(r is results[0] for r in results)
+
+
+class TestStageWait:
+    """``stage_wait``: what a stage-once hit waited for the thread that
+    stages the entry (BIFROST's second job, for the first's ``h2d``)."""
+
+    @staticmethod
+    def totals() -> tuple[float, int]:
+        family = REGISTRY.get("livedata_tick_span_seconds")
+        return family.sum(span="stage_wait"), family.count(span="stage_wait")
+
+    def test_a_hit_waits_out_the_owner_and_a_late_hit_waits_nothing(self, monkeypatch):
+        monkeypatch.setattr(TRACER, "enabled", True)
+        cache = DeviceEventCache()
+        cache.begin_window()
+        slot = cache.slot("det")
+        staging, finish = threading.Event(), threading.Event()
+
+        def stage():
+            staging.set()
+            assert finish.wait(30)
+            return "staged"
+
+        owner = threading.Thread(target=slot.get_or_stage, args=("k", stage))
+        owner.start()
+        assert staging.wait(30)
+        before = self.totals()
+        remaining = 0.05
+        releaser = threading.Timer(remaining, finish.set)
+        asked = time.perf_counter()
+        releaser.start()
+        assert slot.get_or_stage("k", lambda: "never") == "staged"
+        stood = time.perf_counter() - asked
+        owner.join(timeout=30)
+        assert not owner.is_alive()
+        waited, count = self.totals()
+        assert count == before[1] + 1  # the owner observes none: it is a miss
+        assert remaining * 0.9 <= waited - before[0] <= stood
+        # the entry is ready: one more hit, one more observation, of ~0
+        assert slot.get_or_stage("k", lambda: "never") == "staged"
+        late, count = self.totals()
+        assert count == before[1] + 2
+        assert 0.0 <= late - waited < 0.02
+        assert cache.stats()["hits"] == 2 and cache.stats()["misses"] == 1
 
 
 def _staged(pid: np.ndarray, toa: np.ndarray) -> StagedEvents:

@@ -8,6 +8,7 @@ from esslivedata_tpu.core.job_manager import JobCommand, JobFactory, JobManager
 from esslivedata_tpu.core.job import JobState
 from esslivedata_tpu.core.message import RunStart
 from esslivedata_tpu.core.timestamp import Timestamp
+from esslivedata_tpu.telemetry import REGISTRY, TRACER
 from esslivedata_tpu.utils import DataArray, Variable
 from esslivedata_tpu.workflows import WorkflowFactory
 
@@ -233,6 +234,78 @@ class TestThreadFanOut:
         )
         totals = sorted(float(r.outputs["total"].values) for r in results)
         assert totals == [1.0, 2.0]
+        manager.shutdown()
+
+
+def span_totals(name: str) -> tuple[float, int]:
+    family = REGISTRY.get("livedata_tick_span_seconds")
+    return family.sum(span=name), family.count(span=name)
+
+
+class TestAccumulatePhaseAggregates:
+    """The pool phase from inside (``telemetry/trace.py``): the loop
+    thread's wait, each job's turn and what it queued for a thread."""
+
+    NAMES = ("accumulate_wait", "accumulate", "pool_queue")
+
+    @pytest.fixture(autouse=True)
+    def tracer_on(self, monkeypatch):
+        monkeypatch.setattr(TRACER, "enabled", True)
+
+    def counts(self):
+        return [span_totals(name)[1] for name in self.NAMES]
+
+    def test_two_private_jobs_on_a_two_thread_pool(self, registry):
+        manager = JobManager(job_factory=JobFactory(registry), job_threads=2)
+        for source in ("bank0", "bank1"):
+            manager.schedule_job(start_config(registry, source=source))
+        trace_id = TRACER.new_trace()
+        ring_before = len(TRACER.spans())
+        for window in range(2):
+            before, sums = self.counts(), [span_totals(n)[0] for n in self.NAMES]
+            with TRACER.bind(trace_id):
+                manager.process_jobs(
+                    {"bank0": 1.0, "bank1": 2.0},
+                    start=T(10 * window),
+                    end=T(10 * window + 10),
+                )
+            wait, accumulate, queued = (
+                span_totals(n)[0] - was for n, was in zip(self.NAMES, sums)
+            )
+            assert [now - was for now, was in zip(self.counts(), before)] == [1, 2, 2]
+            # each job's turn, and its queueing, lie inside the loop thread's wait
+            assert 0.0 <= queued and 0.0 < accumulate <= 2 * wait
+        # the ring got the window's ``finalize`` spans and no new name
+        assert {s.name for s in TRACER.spans()[ring_before:]} == {"finalize"}
+        manager.shutdown()
+
+    def test_the_wait_counts_toward_the_loop_threads_coverage(self, registry):
+        manager = JobManager(job_factory=JobFactory(registry), job_threads=2)
+        for source in ("bank0", "bank1"):
+            manager.schedule_job(start_config(registry, source=source))
+        trace_id = TRACER.new_trace()
+        wait0, fin0, unspanned0 = (
+            span_totals(n)[0] for n in ("accumulate_wait", "finalize", "unspanned")
+        )
+        with TRACER.bind(trace_id):
+            manager.process_jobs({"bank0": 1.0, "bank1": 2.0}, start=T(0), end=T(10))
+        covered = (span_totals("accumulate_wait")[0] - wait0) + (
+            span_totals("finalize")[0] - fin0
+        )
+        TRACER.finish_tick(trace_id, covered + 0.003, tiled=True)
+        assert span_totals("unspanned")[0] - unspanned0 == pytest.approx(0.003, abs=1e-9)
+        manager.shutdown()
+
+    @pytest.mark.parametrize("threads, sources", [(2, ("bank0",)), (1, ("bank0", "bank1"))])
+    def test_the_serial_branch_has_no_wait_and_no_queue(self, registry, threads, sources):
+        """One job in the window, or no pool: the leaf spans are the
+        loop thread's own, and ``accumulate`` alone is observed."""
+        manager = JobManager(job_factory=JobFactory(registry), job_threads=threads)
+        for source in sources:
+            manager.schedule_job(start_config(registry, source=source))
+        before = self.counts()
+        manager.process_jobs({"bank0": 1.0, "bank1": 2.0}, start=T(0), end=T(10))
+        assert [now - was for now, was in zip(self.counts(), before)] == [0, len(sources), 0]
         manager.shutdown()
 
 

@@ -171,6 +171,48 @@ class TestKafkaSink:
             len(m.value) for m in producer.messages
         )
 
+    def test_serialize_is_split_into_da00_build_and_wire_encode(self, monkeypatch):
+        """``livedata_sink_serialize_seconds_total{step}``: both steps
+        are on the scrape at 0 from the serializer's construction; a
+        publish adds to both, and together they stay inside the
+        ``serialize`` phase of the same publish."""
+        from esslivedata_tpu.kafka import sink as sink_module
+        from esslivedata_tpu.telemetry import REGISTRY, Counter
+
+        assert REGISTRY.get("livedata_sink_serialize_seconds_total") is (
+            sink_module.SINK_SERIALIZE_SECONDS
+        )
+        parts = Counter("test_sink_serialize_seconds_total", "", labelnames=("step",))
+        monkeypatch.setattr(sink_module, "_DA00_S", parts.labels(step="da00"))
+        monkeypatch.setattr(sink_module, "_WIRE_S", parts.labels(step="wire"))
+        assert parts.items() == []
+        serializer = make_default_serializer(LivedataTopics.for_instrument("dummy"))
+        assert parts.items() == [({"step": "da00"}, 0.0), ({"step": "wire"}, 0.0)]
+
+        real_build, real_encode = sink_module.dataarray_to_da00, wire.encode_da00
+
+        def slow_build(value):
+            time.sleep(0.004)
+            return real_build(value)
+
+        def slow_encode(*args):
+            time.sleep(0.008)
+            return real_encode(*args)
+
+        monkeypatch.setattr(sink_module, "dataarray_to_da00", slow_build)
+        monkeypatch.setattr(wire, "encode_da00", slow_encode)
+        phases = REGISTRY.get("livedata_sink_seconds_total")
+        serialize0 = phases.value(phase="serialize")
+        producer = FakeProducer()
+        KafkaSink(producer, serializer).publish_messages(
+            [hist_message(), hist_message("bank0/b")]
+        )
+        serialize = phases.value(phase="serialize") - serialize0
+        da00, encode = parts.value(step="da00"), parts.value(step="wire")
+        assert 0.008 <= da00 and 0.016 <= encode  # each step holds its own sleeps
+        assert da00 + encode <= serialize
+        assert [wire.get_schema(m.value) for m in producer.messages] == ["da00", "da00"]
+
     def test_serialize_error_contained(self):
         producer = FakeProducer()
         topics = LivedataTopics.for_instrument("dummy")

@@ -14,7 +14,7 @@ import pytest
 
 from esslivedata_tpu.core.device_event_cache import DeviceEventCache
 from esslivedata_tpu.ops import EventBatch, EventHistogrammer
-from esslivedata_tpu.ops.event_batch import stage_raw
+from esslivedata_tpu.ops.event_batch import dispatch_safe, stage_for, stage_raw
 from esslivedata_tpu.telemetry import REGISTRY, TRACER
 
 N_EVENTS, BUCKET = 1000, 4096
@@ -49,6 +49,7 @@ def observed() -> dict[str, int]:
     return {
         "flatten": spans.count(span="flatten"),
         "h2d": spans.count(span="h2d"),
+        "h2d_copy": spans.count(span="h2d_copy"),
         "staged": staged.value(kind="staged"),
         "pad": staged.value(kind="pad"),
     }
@@ -75,7 +76,7 @@ class TestMissRecordsHitDoesNot:
             again = hist.tick_staging(batch, slot)
         assert all(a is b for a, b in zip(first, again, strict=True))
         assert added(before) == {
-            "flatten": 1, "h2d": 1,
+            "flatten": 1, "h2d": 1, "h2d_copy": 1,
             "staged": BUCKET, "pad": BUCKET - N_EVENTS,
         }
         flatten, h2d = tracer.spans(trace_id)
@@ -99,7 +100,7 @@ class TestMissRecordsHitDoesNot:
             make_hist().tick_staging(batch, slot)
             coarse.tick_staging(batch, slot)
         assert added(before) == {
-            "flatten": 2, "h2d": 2,
+            "flatten": 2, "h2d": 2, "h2d_copy": 2,
             "staged": 2 * BUCKET, "pad": 2 * (BUCKET - N_EVENTS),
         }
 
@@ -111,11 +112,48 @@ class TestMissRecordsHitDoesNot:
             stage_raw(batch, slot)
             stage_raw(batch, slot)
         assert added(before) == {
-            "flatten": 0, "h2d": 1,
+            "flatten": 0, "h2d": 1, "h2d_copy": 1,
             "staged": BUCKET, "pad": BUCKET - N_EVENTS,
         }
         (h2d,) = tracer.spans(trace_id)
         assert h2d.args == {"bytes": batch.pixel_id.nbytes + batch.toa.nbytes}
+
+
+class TestHostCopyInsideH2d:
+    """``h2d_copy``: the host copies of one ``ship`` call alone, an
+    aggregate inside ``h2d``; what is left of ``h2d`` is the enqueue."""
+
+    @staticmethod
+    def seconds(name: str) -> float:
+        return REGISTRY.get("livedata_tick_span_seconds").sum(span=name)
+
+    @pytest.mark.parametrize("placed", [False, True])
+    def test_one_observation_a_call_and_no_more_than_h2d(self, tracer, placed):
+        import jax
+
+        batch = make_batch()  # two arrays: two copies, one observation
+        device = jax.devices()[0] if placed else None
+        trace_id = tracer.new_trace()
+        before = observed()
+        copy0, h2d0 = self.seconds("h2d_copy"), self.seconds("h2d")
+        with tracer.bind(trace_id):
+            pid, toa = stage_raw(batch, device=device)
+        assert (added(before)["h2d"], added(before)["h2d_copy"]) == (1, 1)
+        copied, shipped = self.seconds("h2d_copy") - copy0, self.seconds("h2d") - h2d0
+        assert 0.0 < copied <= shipped
+        assert [s.name for s in tracer.spans(trace_id)] == ["h2d"]  # off the ring
+        np.testing.assert_array_equal(np.asarray(pid), batch.pixel_id)
+        np.testing.assert_array_equal(np.asarray(toa), batch.toa)
+
+    def test_a_copy_outside_ship_is_not_observed(self, tracer):
+        import jax
+
+        batch = make_batch()
+        before = observed()
+        with tracer.bind(tracer.new_trace()):
+            dispatch_safe(batch.pixel_id)
+            stage_for(batch.toa, jax.devices()[0])
+        assert added(before)["h2d_copy"] == 0
 
 
 class TestUnboundThreadReachesTheHistogramOnly:

@@ -170,6 +170,50 @@ def test_a_cpu_trace_beside_its_dump(check, tmp_path):
     assert "clock_check" not in alone
 
 
+def test_the_pool_phase_by_thread_from_a_hand_made_dump(check, tmp_path, capsys):
+    """Two ticks of three jobs on two job threads (times in us): which
+    thread staged what, when each started and ended, which finished
+    last, and the phase's wall time beside the loop thread's wait."""
+
+    def span(name, tick, tid, ts, dur):
+        return {"name": name, "ph": "X", "ts": ts, "dur": dur, "pid": tick, "tid": tid,
+                "args": {"trace_id": tick}}
+
+    events = [
+        span("decode", 1, "loop", 0, 1_000),
+        span("h2d", 1, "job_0", 1_100, 40_000), span("q_step", 1, "job_0", 41_100, 1_000),
+        span("h2d", 1, "job_1", 1_200, 30_000), span("q_step", 1, "job_1", 31_200, 900),
+        span("h2d", 1, "job_1", 32_200, 35_000), span("q_step", 1, "job_1", 67_200, 800),
+        span("fetch", 1, "loop", 68_100, 20_000),
+        span("decode", 2, "loop", 1_000_000, 1_000),
+        span("h2d", 2, "loop", 1_001_000, 5_000),  # the loop thread's own staging is not the pool's
+        span("h2d", 2, "job_0", 1_006_100, 50_000), span("q_step", 2, "job_0", 1_056_100, 1_000),
+        span("h2d", 2, "job_1", 1_006_300, 20_000), span("q_step", 2, "job_1", 1_026_300, 700),
+    ]
+    pool = check.pool_phase(events, {1: 67_500_000})
+    assert (pool["ticks"], pool["finished_last"]) == (2, {"job_0": 1, "job_1": 1})
+    first, second = pool["by_tick"]
+    assert (first["trace_id"], first["finished_last"]) == (1, "job_1")
+    assert first["wall_ms"] == pytest.approx(66.9) and first["accumulate_wait_ms"] == 67.5
+    assert first["threads"] == {
+        "job_0": {"h2d_ms": 40.0, "q_step_ms": 1.0, "first_start_ms": 0.0, "last_end_ms": pytest.approx(41.0)},
+        "job_1": {"h2d_ms": 65.0, "q_step_ms": pytest.approx(1.7), "first_start_ms": pytest.approx(0.1),
+                  "last_end_ms": pytest.approx(66.9)},
+    }
+    assert (second["finished_last"], second["wall_ms"]) == ("job_0", pytest.approx(51.0))
+    assert set(second["threads"]) == {"job_0", "job_1"} and "accumulate_wait_ms" not in second
+    assert pool["wall_ms_median"] == pytest.approx((66.9 + 51.0) / 2)
+    assert pool["accumulate_wait_ms_median"] == 67.5
+    assert check.pool_phase([e for e in events if e["tid"] == "loop"]) is None  # a service without a pool
+    # from the command line, a dump beside a directory without a trace still gives the table
+    dump = tmp_path / "ticks.json"
+    dump.write_text(json.dumps({"clock": "perf_counter", "epoch_minus_clock_ns": 0, "traceEvents": events}))
+    assert check.main([str(tmp_path), str(dump)]) == 1
+    report = json.loads(capsys.readouterr().out)
+    assert report["error"] == "no xplane" and report["pool_phase"]["ticks"] == 2
+    assert "accumulate_wait_ms_median" not in report["pool_phase"]
+
+
 def test_the_command_line(check, tmp_path, capsys):
     assert check.main([]) == 2
     assert check.main(["--workload", "x"]) == 2

@@ -157,6 +157,75 @@ class TestAggregatesAndArgs:
         assert after[1] == before[1] + 1
         assert abs(after[0] - before[0] - (0.035 - 0.040)) < 1e-12
 
+    def test_a_covering_aggregate_lowers_unspanned_by_its_duration(self):
+        """``accumulate_wait``: the loop thread waits for the pool under
+        no ring span of its own, and the wait still explains that part
+        of the tick. The ring's length does not change."""
+        tracer = make_tracer(slow_tick_s=10.0)
+        trace_id = tracer.new_trace()
+        tracer.record("decode", 0.0, 0.010, trace_id)
+        wait0 = span_totals("accumulate_wait")
+        with tracer.bind(trace_id):
+            tracer.observe("accumulate_wait", 0.025, covers=True)
+            tracer.observe("pool_queue", 0.5)  # a plain aggregate covers nothing
+        assert len(tracer.spans()) == 1
+        wait1 = span_totals("accumulate_wait")
+        assert (wait1[1], wait1[0] - wait0[0]) == (wait0[1] + 1, 0.025)
+        before = span_totals("unspanned")
+        tracer.finish_tick(trace_id, 0.040, tiled=True)
+        after = span_totals("unspanned")
+        assert after[1] == before[1] + 1
+        assert abs(after[0] - before[0] - (0.040 - 0.010 - 0.025)) < 1e-12
+
+    def test_an_overlapping_covering_aggregate_drives_unspanned_negative(self):
+        """A covering aggregate laid over a ring span of the same thread
+        covers that time twice: the remainder stays signed."""
+        tracer = make_tracer(slow_tick_s=10.0)
+        trace_id = tracer.new_trace()
+        with tracer.bind(trace_id):
+            tracer.record("fetch", 0.0, 0.030)
+            tracer.observe("accumulate_wait", 0.020, covers=True)  # inside fetch
+        before = span_totals("unspanned")
+        tracer.finish_tick(trace_id, 0.035, tiled=True)
+        assert abs(
+            span_totals("unspanned")[0] - before[0] - (0.035 - 0.050)
+        ) < 1e-12
+
+    def test_the_aggregate_context_times_its_region_off_the_ring(self):
+        tracer = make_tracer(slow_tick_s=10.0)
+        trace_id = tracer.new_trace()
+        release = threading.Event()
+        before = span_totals("accumulate_wait")
+        with tracer.bind(trace_id):
+            with tracer.aggregate("accumulate_wait", covers=True):
+                release.wait(0.02)
+        elapsed = span_totals("accumulate_wait")[0] - before[0]
+        assert span_totals("accumulate_wait")[1] == before[1] + 1
+        assert 0.015 < elapsed < 5.0
+        assert tracer.spans() == []
+        unspanned0 = span_totals("unspanned")
+        tracer.finish_tick(trace_id, elapsed + 0.004, tiled=True)
+        assert abs(span_totals("unspanned")[0] - unspanned0[0] - 0.004) < 1e-9
+        # Unbound, a covering aggregate has no tick to cover: histogram only.
+        with tracer.aggregate("accumulate_wait", covers=True):
+            pass
+        assert span_totals("accumulate_wait")[1] == before[1] + 2
+
+    def test_a_disabled_tracer_records_no_aggregate(self):
+        tracer = TickTracer(enabled=False)
+        trace_id = tracer.new_trace()
+        names = ("accumulate_wait", "stage_wait", "unspanned", "tick")
+        before = [span_totals(name) for name in names]
+        with tracer.bind(trace_id):
+            tracer.observe("accumulate_wait", 1.0, covers=True)
+            with tracer.aggregate("stage_wait"):
+                pass
+            with tracer.annotated("h2d_copy"):
+                pass
+        tracer.finish_tick(trace_id, 2.0, tiled=True)
+        assert [span_totals(name) for name in names] == before
+        assert tracer.spans() == []
+
     def test_covered_sum_does_not_leak_into_the_next_tick(self):
         tracer = make_tracer(slow_tick_s=10.0)
         first, second = tracer.new_trace(), tracer.new_trace()

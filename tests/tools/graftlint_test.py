@@ -1167,6 +1167,23 @@ class H:
     assert any(f.rule == "JGL001" for f in run_source(src))
 
 
+@pytest.mark.parametrize("method", ["span", "aggregate", "annotated"])
+def test_jgl018_covers_every_timed_region_of_the_tracer(method):
+    # The tracer's context managers all time (or annotate) a host
+    # region: inside a traced body each would fire once per TRACE.
+    src = f'''
+import jax
+
+from esslivedata_tpu.telemetry.trace import TRACER
+
+@jax.jit
+def step(state, batch):
+    with TRACER.{method}("h2d_copy"):
+        return state + batch
+'''
+    assert any(f.rule == "JGL018" for f in run_source(src))
+
+
 # -- the acceptance gate ---------------------------------------------------
 
 def test_src_tree_is_clean():

@@ -903,7 +903,10 @@ _TIMING_QUALNAMES = frozenset(
 #: Telemetry mutation methods; gated on a telemetry-ish receiver below
 #: (``.set`` alone is far too common to flag bare).
 _TELEMETRY_METHODS = frozenset(
-    {"inc", "dec", "observe", "record", "set", "span", "stage"}
+    {
+        "inc", "dec", "observe", "record", "set", "span", "stage",
+        "aggregate", "annotated",
+    }
 )
 
 #: Receiver-name tokens that mark a telemetry/timing object: the
