@@ -960,6 +960,13 @@ class OrchestratingProcessor:
                 extra["producer_lag_level"] = lag_report.worst_level
         if stages := self.stage_timer.drain():
             extra["stages"] = stages
+        # A stream that stopped stages nothing, so nothing sweeps the
+        # staging pool for it: done here, it lets go of the last
+        # window's device arrays and, a minute on, of its host buffers
+        # (ADR 0130).
+        from ..ops.staging_pool import POOL
+
+        POOL.sweep()
         if self._pipeline is not None:
             extra["pipeline"] = self._pipeline.stats()
         # Device dispatch decomposition (ADR 0113/0114): publish/tick

@@ -18,8 +18,9 @@ from dataclasses import dataclass
 
 import numpy as np
 
-from ..telemetry.instruments import STAGED_EVENTS
+from ..telemetry.instruments import STAGED_EVENTS, STAGING_COPIES
 from ..telemetry.trace import TRACER
+from .staging_pool import POOL
 
 __all__ = [
     "EventBatch",
@@ -208,60 +209,86 @@ class StagingBuffer:
 _CPU_BACKEND: bool | None = None
 
 
+def _cpu_backend() -> bool:
+    global _CPU_BACKEND
+    if _CPU_BACKEND is None:
+        import jax
+
+        _CPU_BACKEND = jax.default_backend() == "cpu"
+    return _CPU_BACKEND
+
+
 def _copy_now(make_copy):
     """The default ``copying`` of the two staging functions below: just
     make the host copy. ``ship`` passes its own, which also times it."""
     return make_copy()
 
 
-def dispatch_safe(x, copying=_copy_now):
+_FRESH_COPIES = STAGING_COPIES.labels(kind="fresh")
+
+
+def dispatch_safe(x, copying=_copy_now, kept=True):
     """Stage a host numpy array for an async jitted call.
 
-    - CPU backend: copy. XLA's CPU client aliases suitably-aligned numpy
-      buffers into device arrays zero-copy, and dispatch is asynchronous —
-      so a staging buffer reused (overwritten) after ``release()`` could
-      still be read by the in-flight step, corrupting the histogram.
-    - Accelerators: host copy + explicit async ``jax.device_put``. Passing
-      raw numpy into a jitted call transfers during dispatch on the
-      caller's thread; an explicit async device_put instead lets the
-      transfer of batch i+1 overlap the kernel of batch i (measured ~1.5x
-      end-to-end on the TPU ingest loop). The copy is required for
-      correctness, not just on CPU: device_put is asynchronous, so a
-      zero-copy staging view released and overwritten by the next cycle
-      could still be mid-transfer. A 16 MB memcpy is ~3 ms against the
-      ~45 ms scatter it overlaps with.
+    ``x`` may be a view of memory that is reused (a staging buffer's
+    ``take()``, a decode arena), so it is copied before the call
+    returns: ``device_put`` is asynchronous, and a view released and
+    overwritten by the next cycle could still be mid-transfer (ADR
+    0130).
+
+    - Accelerators: the copy goes into a kept host buffer of
+      ``staging_pool.POOL``, reused once the device array put from it
+      reads ready, and an explicit async ``jax.device_put`` follows, so
+      that the transfer of batch i+1 overlaps the kernel of batch i
+      (passing raw numpy into a jitted call would transfer during
+      dispatch on the caller's thread). A copy into kept memory runs at
+      memcpy speed (3.4 ms for 64 MiB on the chip's host); a fresh
+      array of 32 MiB or more is a new mapping faulted in page by page,
+      79 ms for the same bytes (``scripts/host_copy_probe.py``).
+    - The CPU backend keeps a fresh copy per call: XLA's CPU client
+      aliases suitably-aligned numpy buffers into device arrays
+      zero-copy, so a kept buffer would be overwritten under a live
+      array. It returns numpy, uncommitted.
+    - ``kept=False`` asks for the fresh copy on an accelerator too. The
+      detector views' flat wires do: a flatten's output is itself a
+      fresh array a window, and copying it into a kept buffer and
+      freeing it at once left glibc trimming and re-faulting it window
+      after window in most runs of ``dream_banks.paced14`` (the median
+      picture a quarter older in 4 runs of 5; ``PERF.md`` §6, PR 36).
 
     ``copying`` is called with the function that makes that host copy
     and returns its result: ``ship`` times the copy through it.
     """
-    global _CPU_BACKEND
-    if _CPU_BACKEND is None:
-        import jax
+    if not isinstance(x, np.ndarray):
+        return x
+    if _cpu_backend():
+        _FRESH_COPIES.inc()
+        return copying(x.copy)
+    import jax
 
-        _CPU_BACKEND = jax.default_backend() == "cpu"
-    if isinstance(x, np.ndarray):
-        if _CPU_BACKEND:
-            return copying(x.copy)
-        import jax
-
+    if not kept:
+        _FRESH_COPIES.inc()
         return jax.device_put(copying(x.copy))
-    return x
+    return POOL.stage(x, jax.device_put, copying=copying)
 
 
 _STAGED_SLOTS = STAGED_EVENTS.labels(kind="staged")
 _PAD_SLOTS = STAGED_EVENTS.labels(kind="pad")
 
 
-def ship(batch: EventBatch, arrays: tuple, device=None) -> tuple:
+def ship(
+    batch: EventBatch, arrays: tuple, device=None, *, kept=True
+) -> tuple:
     """The ``h2d`` leaf span of a stage-cache miss: ``arrays`` (the
     batch's wire, raw or flattened) through ``dispatch_safe`` or, placed,
-    ``stage_for``. It times the host copy and the ENQUEUE of the
-    asynchronous ``device_put``; the transfer itself completes under the
-    tick's ``fetch``. Inside it the aggregate ``h2d_copy``: the host
-    copies alone, summed over the arrays (each array is still copied,
-    then put, before the next is copied), so that what is left of
-    ``h2d`` is the enqueue. With it the count of what was shipped: the
-    bucket's slots, and those of them that are padding."""
+    ``stage_for``, ``kept`` as theirs. It times the host copy and the
+    ENQUEUE of the asynchronous ``device_put``; the transfer itself
+    completes under the tick's ``fetch``. Inside it the aggregate
+    ``h2d_copy``: the host copies alone, summed over the arrays (each
+    array is still copied, then put, before the next is copied), so
+    that what is left of ``h2d`` is the enqueue. With it the count of
+    what was shipped: the bucket's slots, and those of them that are
+    padding."""
     copy_s = 0.0
 
     def copying(make_copy):
@@ -274,10 +301,11 @@ def ship(batch: EventBatch, arrays: tuple, device=None) -> tuple:
 
     with TRACER.span("h2d", args={"bytes": sum(a.nbytes for a in arrays)}):
         if device is None:
-            shipped = tuple(dispatch_safe(a, copying) for a in arrays)
+            shipped = tuple(dispatch_safe(a, copying, kept) for a in arrays)
         else:
             shipped = tuple(
-                stage_for(a, device, copying=copying) for a in arrays
+                stage_for(a, device, copying=copying, kept=kept)
+                for a in arrays
             )
         TRACER.observe("h2d_copy", copy_s)
     _STAGED_SLOTS.inc(batch.padded_size)
@@ -351,19 +379,34 @@ def leaf_device_set(leaf, *, committed_only: bool = False):
         return None
 
 
-def stage_for(arr, sharding, *, dtype=None, copying=_copy_now):
+def _places_on_cpu(target) -> bool:
+    """Whether ``target`` (a sharding or a device) places on a CPU
+    device, whose client aliases host memory. Read from the target and
+    not from the default backend: a CPU sharding in a process whose
+    default backend is the chip still aliases."""
+    devices = getattr(target, "device_set", None)
+    if devices is None:
+        devices = (target,) if hasattr(target, "platform") else ()
+    if not devices:
+        return _cpu_backend()
+    return any(d.platform == "cpu" for d in devices)
+
+
+def stage_for(arr, sharding, *, dtype=None, copying=_copy_now, kept=True):
     """Stage a batch onto ``sharding`` in ONE placement hop.
 
-    The sharded kernels' counterpart of ``dispatch_safe`` — same two
-    guarantees (a defensive host copy so the async transfer never reads
-    a staging buffer the caller has already reused, and an asynchronous
-    ``device_put`` so batch i+1's transfer overlaps batch i's kernel),
-    but placed directly onto the target sharding: routing a host array
-    through ``dispatch_safe`` first would commit it to the DEFAULT
-    device and pay a second device->device copy on the resharded
-    placement. ``dtype`` optionally normalizes wire dtypes on the host
-    (one pass, fused with the copy); device arrays cast on device.
-    ``copying`` as in ``dispatch_safe``.
+    The sharded kernels' counterpart of ``dispatch_safe``: the same
+    defensive host copy (into a kept buffer of the staging pool, or a
+    fresh array where ``sharding`` places on a CPU device or ``kept``
+    is False, so the async transfer never reads memory the caller has
+    already reused) and the
+    same asynchronous ``device_put``, so batch i+1's transfer overlaps
+    batch i's kernel, but placed directly onto the target sharding:
+    routing a host array through ``dispatch_safe`` first would commit
+    it to the DEFAULT device and pay a second device->device copy on
+    the resharded placement. ``dtype`` optionally normalizes wire
+    dtypes on the host (one pass: the cast is the copy); device arrays
+    cast on device. ``copying`` as in ``dispatch_safe``.
     """
     import jax
 
@@ -371,8 +414,16 @@ def stage_for(arr, sharding, *, dtype=None, copying=_copy_now):
         if dtype is not None and arr.dtype != np.dtype(dtype):
             arr = arr.astype(dtype)
         return jax.device_put(arr, sharding)
-    return jax.device_put(
-        copying(lambda: np.array(arr, dtype=dtype, copy=True)), sharding
+    if not kept or _places_on_cpu(sharding):
+        _FRESH_COPIES.inc()
+        return jax.device_put(
+            copying(lambda: np.array(arr, dtype=dtype, copy=True)), sharding
+        )
+    return POOL.stage(
+        arr,
+        lambda staged: jax.device_put(staged, sharding),
+        dtype=dtype,
+        copying=copying,
     )
 
 

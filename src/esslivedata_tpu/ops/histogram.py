@@ -864,7 +864,7 @@ class EventHistogrammer:
                     )
                 else:
                     flat = self.flatten_host(batch.pixel_id, batch.toa)
-            return ship(batch, (flat,), device)[0]
+            return ship(batch, (flat,), device, kept=False)[0]
 
         if cache is None:
             return stage()
@@ -882,7 +882,7 @@ class EventHistogrammer:
         def stage():
             with TRACER.span("flatten", args=_flatten_args(batch)):
                 wire = self.flatten_partition_host(batch.pixel_id, batch.toa)
-            return ship(batch, wire, device)
+            return ship(batch, wire, device, kept=False)
 
         if cache is None:
             return stage()

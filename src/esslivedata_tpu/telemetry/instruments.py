@@ -30,6 +30,8 @@ __all__ = [
     "SINK_SECONDS",
     "SINK_SERIALIZE_SECONDS",
     "STAGED_EVENTS",
+    "STAGING_COPIES",
+    "STAGING_POOL_BYTES",
     "TABLE_BUILD_SECONDS",
     "TABLE_BYTES",
     "TICK_GROUPS",
@@ -121,6 +123,34 @@ STAGED_EVENTS = REGISTRY.counter(
     "that is bucket padding",
     labelnames=("kind",),
 )
+
+#: How each host array shipped to the device got its staging copy
+#: (``ops/event_batch.py`` over ``ops/staging_pool.py``, ADR 0130), one
+#: count per array: ``kept`` = copied into a kept host buffer whose last
+#: transfer was done, ``fresh`` = a buffer had to be allocated (first
+#: use, a second generation in flight, a new bucket; every copy on the
+#: CPU backend, which aliases host memory and so keeps none; and the
+#: detector views' flat wires, ``kept=False``). Both have a sample from
+#: import on. ``kept`` over both is the benchmark's
+#: ``staging_kept_share``.
+STAGING_COPIES = REGISTRY.counter(
+    "livedata_staging_copies_total",
+    "Host arrays shipped to the device, by how the staging copy was "
+    "made (kept buffer reused, or fresh allocation)",
+    labelnames=("kind",),
+)
+for _kind in ("kept", "fresh"):
+    STAGING_COPIES.inc(0.0, kind=_kind)
+
+#: Host bytes the process's staging pool holds (``ops/staging_pool.py``):
+#: one generation of a window's staged arrays in the serial loop, two
+#: where windows overlap; falls when a bucket is left behind or the
+#: stream stops.
+STAGING_POOL_BYTES = REGISTRY.gauge(
+    "livedata_staging_pool_bytes",
+    "Host bytes held by the staging pool's kept buffers",
+)
+STAGING_POOL_BYTES.set(0.0)
 
 #: Where the ``sink`` span's time goes (``kafka/sink.py``): summed over
 #: a publish's messages, two clock reads a message, not a span each.

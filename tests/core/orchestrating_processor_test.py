@@ -194,6 +194,26 @@ class TestProcessorCycle:
         processor.process()  # past 2 s: heartbeat again
         assert len(sink.messages) > n0
 
+    def test_an_idle_loop_still_sweeps_the_staging_pool(self, monkeypatch):
+        """Beam off: no staging looks at the pool any more, so the 30 s
+        metrics line does (ADR 0130)."""
+        from esslivedata_tpu.ops import staging_pool
+
+        swept = []
+        monkeypatch.setattr(
+            staging_pool.POOL, "sweep", lambda: swept.append(now["t"])
+        )
+        now = {"t": 0.0}
+        processor, _ = make_processor(
+            source=FakeMessageSource([[], [], []]), clock=lambda: now["t"]
+        )
+        processor.process()
+        now["t"] = 10.0
+        processor.process()
+        now["t"] = 31.0
+        processor.process()
+        assert swept == [31.0]
+
     def test_data_batch_reaches_accumulator_and_buffers_release(self):
         acc = RecordingAccumulator()
         source = FakeMessageSource([[msg("a", 5.0)]])

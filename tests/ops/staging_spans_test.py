@@ -156,6 +156,40 @@ class TestHostCopyInsideH2d:
         assert added(before)["h2d_copy"] == 0
 
 
+class TestEveryWireIsCopied:
+    """ADR 0130: a flattened wire and the raw one are both copied, one
+    count an array (``fresh`` on the CPU backend, which keeps no
+    buffer) and one ``h2d_copy`` observation a ``ship``."""
+
+    @staticmethod
+    def copies() -> dict[str, float]:
+        counter = REGISTRY.get("livedata_staging_copies_total")
+        return {k: counter.value(kind=k) for k in ("kept", "fresh")}
+
+    @pytest.mark.parametrize(
+        ("method", "arrays"), [("scatter", 1), ("pallas2d", 2)]
+    )
+    def test_a_flattened_wire_is_copied(self, tracer, method, arrays):
+        hist, batch, slot = make_hist(method), make_batch(), fresh_slot()
+        before, seen = self.copies(), observed()
+        hist.tick_staging(batch, slot)
+        after = self.copies()
+        assert {k: after[k] - before[k] for k in after} == {
+            "kept": 0, "fresh": arrays,
+        }
+        assert added(seen)["h2d_copy"] == 1
+
+    def test_the_raw_wire_is_copied(self, tracer):
+        batch = make_batch()
+        before = self.copies()
+        pid, _ = stage_raw(batch, fresh_slot())
+        after = self.copies()
+        assert {k: after[k] - before[k] for k in after} == {
+            "kept": 0, "fresh": 2,
+        }
+        assert not np.shares_memory(np.asarray(pid), batch.pixel_id)
+
+
 class TestUnboundThreadReachesTheHistogramOnly:
     def test_worker_without_a_trace_skips_the_ring(self, tracer):
         """The pipelined stage worker and pool threads carry no bound
