@@ -10,7 +10,9 @@ of any service will do. The report, JSON on stdout, is what PERF.md
 section 6 quotes:
 
 - **scopes**: device time by ``jax.named_scope`` (``scatter`` / ``fold`` /
-  ``publish_reduce`` / ``pack``; ``qmap_gather`` / ``q_bincount`` of a Q
+  ``publish_reduce`` / ``pack``; ``scatter/replica_gather``, the LUT
+  gather of a view that projects on the device, listed apart from the
+  rest of its ``scatter``; ``qmap_gather`` / ``q_bincount`` of a Q
   step) and by jitted program, with the heaviest
   ops of each scope (cut to the dump's steady ticks where there is a dump);
 - **twins**: the ``TraceAnnotation`` twins of the tick spans in the host
@@ -39,6 +41,7 @@ from pathlib import Path
 
 SCOPES = (
     "scatter",
+    "replica_gather",
     "fold",
     "publish_reduce",
     "pack",

@@ -17,6 +17,7 @@ from .specs import (
     SANS_IQ_HANDLE,
     TIMESERIES_HANDLE,
     WAVELENGTH_SPECTRUM_HANDLE,
+    XY_PROJECTION_HANDLE,
 )
 
 
@@ -33,6 +34,7 @@ def _projection_for(detector_name: str) -> ProjectionTable:
     )
 
 
+@XY_PROJECTION_HANDLE.attach_factory
 @DETECTOR_VIEW_HANDLE.attach_factory
 def make_detector_view(*, source_name: str, params) -> DetectorViewWorkflow:
     return DetectorViewWorkflow(

@@ -50,6 +50,11 @@ INSTRUMENT.add_detector(
 )
 # The nine straw-tube banks as deployed (3 211 264 pixels), beside the
 # toy plane: positions and ids are read when a job on a bank starts.
+# Their 2-D view is the xy-plane projection with position noise: a
+# straw pixel is 2 mm x ~8 mm against screen bins of ~4-6 mm, so each
+# pixel is antialiased by four replicas of sigma 4 mm (upstream's pixel
+# noise as remembered: no network; benchmark/configs/loki_banks.json
+# lists it under ``assumed``).
 for _bank in BANK_PIXELS:
     INSTRUMENT.add_detector(
         DetectorConfig(
@@ -58,6 +63,8 @@ for _bank in BANK_PIXELS:
             geometry_loader=partial(bank_geometry, _bank),
             projection="xy_plane",
             resolution=(256, 256),
+            noise_sigma=0.004,
+            n_replica=4,
         )
     )
 INSTRUMENT.add_monitor(MonitorConfig(name="monitor_1", source_name="loki_mon_1"))
@@ -82,6 +89,20 @@ DETECTOR_VIEW_HANDLE = workflow_registry.register_spec(
                 title="ROI spectra (since start)", view="since_start"
             ),
         },
+    )
+)
+
+# The deployed view: one job per bank, every bank on its own xy plane
+# (``rear_view`` above stays on the toy plane for the package's tests).
+XY_PROJECTION_HANDLE = workflow_registry.register_spec(
+    WorkflowSpec(
+        instrument="loki",
+        namespace="detector_view",
+        name="xy_projection",
+        title="Bank 2-D view (xy plane, pixel noise)",
+        source_names=list(BANK_PIXELS),
+        params_model=DetectorViewParams,
+        outputs=detector_view_outputs(),  # incl. the ROI readbacks
     )
 )
 

@@ -313,7 +313,9 @@ def ship(
     return shipped
 
 
-def stage_raw(batch: EventBatch, cache=None, tag: str = "", device=None):
+def stage_raw(
+    batch: EventBatch, cache=None, tag: str = "", device=None, *, on_miss=None
+):
     """Stage a batch's raw ``(pixel_id, toa)`` pair for the device path.
 
     With a window's stream cache (``core/device_event_cache.py``) the
@@ -336,9 +338,14 @@ def stage_raw(batch: EventBatch, cache=None, tag: str = "", device=None):
     VALUE is what downstream kernels consume either way, and the
     prologue's canonicalization (out-of-range → -1) is exactly what
     every kernel already treats as the drop marker.
+
+    ``on_miss`` is called where the pair is really shipped (no cache,
+    or a miss of it): the caller's own count of the wires it staged.
     """
 
     def stage():
+        if on_miss is not None:
+            on_miss()
         pid, toa = ship(batch, (batch.pixel_id, batch.toa), device)
         if getattr(batch, "prologue", False):
             from .decode_prologue import decode_prologue

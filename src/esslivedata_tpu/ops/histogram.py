@@ -54,6 +54,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..telemetry.instruments import SCATTER_UPDATES, VIEW_WIRES
 from ..telemetry.trace import TRACER
 from .event_batch import (
     EventBatch,
@@ -68,6 +69,10 @@ from .event_batch import (
 __all__ = ["EventHistogrammer", "EventProjection", "HistogramState"]
 
 logger = logging.getLogger(__name__)
+
+
+_FLAT_WIRES = VIEW_WIRES.labels(staging="flat")
+_RAW_WIRES = VIEW_WIRES.labels(staging="raw")
 
 
 def _flatten_args(batch: EventBatch) -> dict[str, int]:
@@ -222,7 +227,8 @@ class EventProjection:
             n_rep, n_pix = lut.shape
             p_ok = (pixel_id >= 0) & (pixel_id < n_pix)
             pid = jnp.clip(pixel_id, 0, n_pix - 1)
-            screen = lut[:, pid]  # [R, N]
+            with jax.named_scope("replica_gather"):
+                screen = lut[:, pid]  # [R, N]
             local_row = screen - row0
             ok = (
                 p_ok[None, :]
@@ -371,6 +377,10 @@ class EventHistogrammer:
                 )
                 else "scatter"
             )
+        # Both wire kinds have a sample from the first histogrammer on,
+        # so that a share of either reads 0, not "no sample".
+        _FLAT_WIRES.inc(0.0)
+        _RAW_WIRES.inc(0.0)
         self._edges = self._proj.edges
         self._edges_f32 = self._edges.astype(np.float32)
         # graft: key-derived=_n_toa,_n_screen,_n_bins pure functions of
@@ -857,6 +867,7 @@ class EventHistogrammer:
         spans (ADR 0116); a hit records nothing."""
 
         def stage():
+            _FLAT_WIRES.inc()
             with TRACER.span("flatten", args=_flatten_args(batch)):
                 if pool is not None:
                     flat = self.flatten_host_chunked(
@@ -880,6 +891,7 @@ class EventHistogrammer:
         slice); the same two leaf spans on a miss."""
 
         def stage():
+            _FLAT_WIRES.inc()
             with TRACER.span("flatten", args=_flatten_args(batch)):
                 wire = self.flatten_partition_host(batch.pixel_id, batch.toa)
             return ship(batch, wire, device, kept=False)
@@ -889,6 +901,21 @@ class EventHistogrammer:
         return cache.get_or_stage(
             (tag,) + self.partition_key + (device_token(device),), stage
         )
+
+    @staticmethod
+    def _staged_raw(batch: EventBatch, cache, tag: str, device=None):
+        """The raw (pixel id, TOA) pair staged for the device path
+        (``stage_raw``: 8 B an event, shared by (stream, tag) whatever
+        the layout), a miss counted as this family's ``raw`` wire."""
+        return stage_raw(
+            batch, cache, tag, device=device, on_miss=_RAW_WIRES.inc
+        )
+
+    def _count_scatter(self, slots: int) -> None:
+        """One step dispatch: ``slots`` staged slots, each scattered
+        once per LUT replica."""
+        lut = self._proj.lut_host
+        SCATTER_UPDATES.inc(slots * (1 if lut is None else lut.shape[0]))
 
     def stage_events(
         self,
@@ -923,7 +950,7 @@ class EventHistogrammer:
                 batch, cache, batch_tag, pool=pool, device=device
             )
         else:
-            stage_raw(batch, cache, batch_tag, device=device)
+            self._staged_raw(batch, cache, batch_tag, device=device)
 
     #: Below this many events per chunk the pool dispatch overhead beats
     #: the parallel flatten; chunks are sized to keep every worker fed.
@@ -962,6 +989,7 @@ class EventHistogrammer:
     def step(self, state: HistogramState, batch: EventBatch) -> HistogramState:
         """Accumulate one padded batch. Donates ``state``: the caller's
         handle is invalidated, use the returned state."""
+        self._count_scatter(batch.padded_size)
         return self._step(
             state,
             self._proj.lut,
@@ -977,6 +1005,7 @@ class EventHistogrammer:
             # Host arrays may carry wire dtypes (int64 ev44 ids); device
             # arrays are already int32 by construction.
             pixel_id = sanitize_pixel_id(pixel_id)
+        self._count_scatter(int(pixel_id.shape[0]))
         return self._step(
             state,
             self._proj.lut,
@@ -1039,6 +1068,7 @@ class EventHistogrammer:
         slice-keyed cache entry the tick path uses."""
         if device is None:
             device = self._state_slice_device(state)
+        self._count_scatter(batch.padded_size)
         if self._method == "pallas2d":
             events, chunk_map = self._staged_partition(
                 batch, cache, batch_tag, device=device
@@ -1049,7 +1079,7 @@ class EventHistogrammer:
                 state,
                 self._staged_flat(batch, cache, batch_tag, device=device),
             )
-        pid, toa = stage_raw(batch, cache, batch_tag, device=device)
+        pid, toa = self._staged_raw(batch, cache, batch_tag, device=device)
         return self._step(state, self._proj.lut, pid, toa)
 
     def step_many(
@@ -1076,6 +1106,7 @@ class EventHistogrammer:
             return ()
         if device is None:
             device = self._state_slice_device(states[0])
+        self._count_scatter(batch.padded_size)
         if self._method == "pallas2d":
             events, chunk_map = self._staged_partition(
                 batch, cache, batch_tag, device=device
@@ -1089,7 +1120,7 @@ class EventHistogrammer:
                 states,
                 self._staged_flat(batch, cache, batch_tag, device=device),
             )
-        pid, toa = stage_raw(batch, cache, batch_tag, device=device)
+        pid, toa = self._staged_raw(batch, cache, batch_tag, device=device)
         return self._dispatch_fused(
             self._step_fused, states, self._proj.lut, pid, toa
         )
@@ -1151,7 +1182,10 @@ class EventHistogrammer:
         time) and any other same-layout consumer shares the arrays by
         reference. The device-path tuple leads with the LUT so a live
         swap stays an argument change (ADR 0105), never a retrace of the
-        step body itself."""
+        step body itself. The tick program that takes the tuple is this
+        window's one dispatch of the group, so its scatter's updates
+        are counted here."""
+        self._count_scatter(batch.padded_size)
         if self._method == "pallas2d":
             return self._staged_partition(
                 batch, cache, batch_tag, device=device
@@ -1162,7 +1196,7 @@ class EventHistogrammer:
                     batch, cache, batch_tag, pool=pool, device=device
                 ),
             )
-        pid, toa = stage_raw(batch, cache, batch_tag, device=device)
+        pid, toa = self._staged_raw(batch, cache, batch_tag, device=device)
         return (self._proj.lut, pid, toa)
 
     def tick_step(self, states, *staged):
@@ -1240,6 +1274,7 @@ class EventHistogrammer:
         With ``method='pallas2d'`` the indices are partitioned by bin
         block on the host (native ``ld_partition`` when available) and
         fed to the MXU-tiled kernel instead of the serial scatter."""
+        self._count_scatter(int(np.shape(flat)[0]))
         if self._method == "pallas2d":
             from .pallas_hist2d import partition_events_host
 

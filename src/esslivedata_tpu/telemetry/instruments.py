@@ -26,6 +26,7 @@ __all__ = [
     "JOB_WINDOWS",
     "Q_BINCOUNT_STEPS",
     "Q_LOOKUP_STEPS",
+    "SCATTER_UPDATES",
     "SINK_BYTES",
     "SINK_SECONDS",
     "SINK_SERIALIZE_SECONDS",
@@ -35,6 +36,7 @@ __all__ = [
     "TABLE_BUILD_SECONDS",
     "TABLE_BYTES",
     "TICK_GROUPS",
+    "VIEW_WIRES",
 ]
 
 #: Calibration-plane swaps (workloads/calibration.py, ADR 0122): every
@@ -122,6 +124,35 @@ STAGED_EVENTS = REGISTRY.counter(
     "Event slots staged for the device; kind=pad is the share of them "
     "that is bucket padding",
     labelnames=("kind",),
+)
+
+#: Which wire each stage-cache miss of an event histogrammer
+#: (``ops/histogram.EventHistogrammer``: the detector views, the monitor
+#: histograms) shipped, one count per miss beside the ``h2d`` span:
+#: ``flat`` = indices flattened on the host (4 B an event; the
+#: partitioned wire of ``method="pallas2d"`` too), ``raw`` = the
+#: (pixel id, TOA) pair (8 B an event) of a view that projects on the
+#: device: a replica LUT or per-pixel weights. Both have a sample from
+#: the first histogrammer on; a service that builds none shows none.
+#: raw / both is the benchmark's ``view_raw_staging_share``.
+VIEW_WIRES = REGISTRY.counter(
+    "livedata_view_wires_total",
+    "Wires an event histogrammer staged for the device, by kind "
+    "(flat = host-flattened indices, raw = pixel id and TOA)",
+    labelnames=("staging",),
+)
+
+#: What the histogrammers' scatters were handed: the staged bucket's
+#: slots times the LUT's replicas, one count per dispatch of a step
+#: (a tick program, a fused or a private step; the members of one group
+#: share the dispatch and count once). Over
+#: ``livedata_staged_events_total{kind="staged"}`` it is the benchmark's
+#: ``scatter_updates_per_event``: 1 where every wire is scattered once,
+#: R under R position-noise replicas.
+SCATTER_UPDATES = REGISTRY.counter(
+    "livedata_scatter_updates_total",
+    "Updates handed to the histogram scatter: staged slots times LUT "
+    "replicas, per step dispatch",
 )
 
 #: How each host array shipped to the device got its staging copy
@@ -236,7 +267,11 @@ JOB_WINDOWS = REGISTRY.counter(
 #: further), summed over live tables. Set-up has no span, so
 #: what building them cost is the counter below: both change at
 #: construction and at ``swap_table`` only, and neither can be read by a
-#: windowed metric.
+#: windowed metric. Family ``projection`` is the detector views'
+#: pixel -> screen-bin LUT (``workflows/detector_view/projectors.py``):
+#: int32 ``[replicas, id space]`` as built at job start, read on the
+#: device by a view that projects there (replicas, weights) and on the
+#: host by one that flattens there.
 TABLE_BYTES = REGISTRY.gauge(
     "livedata_table_bytes",
     "Bytes of precompiled event->bin tables resident on the device "

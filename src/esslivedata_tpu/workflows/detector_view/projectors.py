@@ -16,10 +16,13 @@ parts" item 5).
 
 from __future__ import annotations
 
+import time
+import weakref
 from dataclasses import dataclass
 
 import numpy as np
 
+from ...telemetry.instruments import TABLE_BUILD_SECONDS, TABLE_BYTES
 from ...utils.labeled import Variable
 
 __all__ = [
@@ -51,6 +54,18 @@ class ProjectionTable:
     @property
     def n_replica(self) -> int:
         return int(self.lut.shape[0])
+
+
+def _built(table: ProjectionTable, began: float) -> ProjectionTable:
+    """``table``, counted: set-up has no span, so what the LUT cost to
+    build (from ``began``, a ``perf_counter`` reading) and the bytes it
+    holds while it lives go under the table instruments' family
+    ``projection``."""
+    TABLE_BUILD_SECONDS.inc(time.perf_counter() - began, family="projection")
+    nbytes = table.lut.nbytes
+    TABLE_BYTES.inc(nbytes, family="projection")
+    weakref.finalize(table, TABLE_BYTES.dec, nbytes, family="projection")
+    return table
 
 
 def _bin_2d(
@@ -102,6 +117,7 @@ def project_geometric(
         Optional (x_min, x_max, y_min, y_max) screen bounds; default = data
         bounds of the *unjittered* projection.
     """
+    began = time.perf_counter()
     positions = np.asarray(positions, dtype=np.float64)
     pixel_ids = np.asarray(pixel_ids)
     if positions.ndim != 2 or positions.shape[1] != 3:
@@ -146,12 +162,15 @@ def project_geometric(
 
     lut = np.full((flat_rep.shape[0], n_id_space), -1, dtype=np.int32)
     lut[:, pixel_ids] = flat_rep
-    return ProjectionTable(
-        lut=lut,
-        ny=ny,
-        nx=nx,
-        y_edges=Variable(y_edges, ("y",), unit),
-        x_edges=Variable(x_edges, ("x",), unit),
+    return _built(
+        ProjectionTable(
+            lut=lut,
+            ny=ny,
+            nx=nx,
+            y_edges=Variable(y_edges, ("y",), unit),
+            x_edges=Variable(x_edges, ("x",), unit),
+        ),
+        began,
     )
 
 
@@ -196,6 +215,7 @@ def project_logical_nd(
     ``detector_numbers`` is flat (C-order over ``view.sizes``) or already
     shaped to those sizes.
     """
+    began = time.perf_counter()
     shape = tuple(view.sizes.values())
     det = np.asarray(detector_numbers).reshape(shape)
     dims = list(view.sizes)
@@ -221,12 +241,15 @@ def project_logical_nd(
     n_id_space = int(det.max()) + 1
     lut = np.full((1, n_id_space), -1, dtype=np.int32)
     lut[0, det.reshape(-1)] = screen.reshape(-1)
-    return ProjectionTable(
-        lut=lut,
-        ny=ny,
-        nx=nx,
-        y_edges=Variable(np.arange(ny + 1, dtype=np.float64) - 0.5, ("y",), ""),
-        x_edges=Variable(np.arange(nx + 1, dtype=np.float64) - 0.5, ("x",), ""),
+    return _built(
+        ProjectionTable(
+            lut=lut,
+            ny=ny,
+            nx=nx,
+            y_edges=Variable(np.arange(ny + 1, dtype=np.float64) - 0.5, ("y",), ""),
+            x_edges=Variable(np.arange(nx + 1, dtype=np.float64) - 0.5, ("x",), ""),
+        ),
+        began,
     )
 
 
@@ -252,6 +275,7 @@ def project_logical(
     the identity-layout fast path the reference implements as fold/slice
     transforms.
     """
+    began = time.perf_counter()
     det = np.asarray(detector_numbers)
     if det.ndim == 1:
         if view is None:
@@ -269,10 +293,13 @@ def project_logical(
     lut = np.full((1, n_id_space), -1, dtype=np.int32)
     yy, xx = np.meshgrid(np.arange(ny), np.arange(nx), indexing="ij")
     lut[0, det.reshape(-1)] = (yy * nx + xx).reshape(-1).astype(np.int32)
-    return ProjectionTable(
-        lut=lut,
-        ny=ny,
-        nx=nx,
-        y_edges=Variable(np.arange(ny + 1, dtype=np.float64) - 0.5, ("y",), ""),
-        x_edges=Variable(np.arange(nx + 1, dtype=np.float64) - 0.5, ("x",), ""),
+    return _built(
+        ProjectionTable(
+            lut=lut,
+            ny=ny,
+            nx=nx,
+            y_edges=Variable(np.arange(ny + 1, dtype=np.float64) - 0.5, ("y",), ""),
+            x_edges=Variable(np.arange(nx + 1, dtype=np.float64) - 0.5, ("x",), ""),
+        ),
+        began,
     )
