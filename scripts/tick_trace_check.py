@@ -12,8 +12,9 @@ section 6 quotes:
 - **scopes**: device time by ``jax.named_scope`` (``scatter`` / ``fold`` /
   ``publish_reduce`` / ``pack``; ``scatter/replica_gather``, the LUT
   gather of a view that projects on the device, listed apart from the
-  rest of its ``scatter``; ``qmap_gather`` / ``q_bincount`` of a Q
-  step) and by jitted program, with the heaviest
+  rest of its ``scatter``, and ``scatter/count_sort``, the key sort in
+  front of the MXU count, ADR 0131; ``qmap_gather`` / ``q_bincount`` of
+  a Q step) and by jitted program, with the heaviest
   ops of each scope (cut to the dump's steady ticks where there is a dump);
 - **twins**: the ``TraceAnnotation`` twins of the tick spans in the host
   planes, counted by name;
@@ -42,6 +43,7 @@ from pathlib import Path
 SCOPES = (
     "scatter",
     "replica_gather",
+    "count_sort",
     "fold",
     "publish_reduce",
     "pack",

@@ -27,6 +27,19 @@ Sections:
        and a 16 Mi batch with the cells' 23 % of padding routed to the
        drop slot: each exact against ``np.bincount``, ns an event, and
        what ``method="auto"`` takes (``MXU_LANE_GROUPS``).
+  view (``--view`` runs this section alone; about 320 s through the
+       builder's tool): the detector view's count of 4 Mi slots, 23.4 %
+       of them dropped, into 6 553 601, 163 840 001, 15 769 601 and
+       25 601 bins (LOKI's bank view, NMX's panel, DREAM's largest and
+       smallest): XLA's scatter weighted and unit against the
+       device-partitioned count of ops/pallas_hist2d.py (key sort on the
+       chip, (chunk, block) work items, the factorised one-hot on the
+       MXU in place) at blocks of 16 Ki and 64 Ki bins and chunks of
+       512, 2 048 and 8 192 keys, updates 1 and 1/4, each exact against
+       ``np.bincount``, ms and ns a slot; the table is in that module's
+       docstring. ``view_section(n=16384, bin_spaces=(100_001, 3_001,
+       25_601), bpbs=(2048, 16384), chunks=(512, 2048))`` is the
+       rehearsal on the CPU (interpret mode: no timing).
 """
 
 import functools
@@ -178,7 +191,12 @@ def lookup_section(
                 packed, keys_sorted,
             ),
             "work items alone": (
-                lambda k: pallas_lookup._work_items(k, n_windows, shift),
+                lambda k: pallas_lookup.work_items(
+                    k,
+                    group=pallas_lookup.BLOCK,
+                    span=pallas_lookup.WINDOW << shift,
+                    n_targets=n_windows,
+                ),
                 keys_sorted,
             ),
         }
@@ -192,7 +210,12 @@ def lookup_section(
                 f"{_ms(jax.jit(fn), *args):.2f} ms",
                 flush=True,
             )
-        items = pallas_lookup._work_items(keys_sorted, n_windows, shift)
+        items = pallas_lookup.work_items(
+            keys_sorted,
+            group=pallas_lookup.BLOCK,
+            span=pallas_lookup.WINDOW << shift,
+            n_targets=n_windows,
+        )
         print(
             f"lookup {tag}: {int(items[2][0])} work items of "
             f"{items[0].shape[0]} grid steps",
@@ -270,11 +293,154 @@ def bincount_section(
             )
 
 
+#: ``view_section``'s bin spaces (dump slot included): LOKI's bank view
+#: (256 x 256 x 100), NMX's panel (1280 x 1280 x 100), DREAM's largest
+#: and smallest views.
+VIEW_BINS = (6_553_601, 163_840_001, 15_769_601, 25_601)
+
+
+def _ms_donated(fn, state, *args, repeats: int = 10) -> tuple[float, object]:
+    """Milliseconds a call of the jitted ``fn(state, *args) -> state``
+    that donates its state, and the state after ``repeats + 1`` calls."""
+    import jax
+
+    state = jax.block_until_ready(fn(state, *args))
+    t0 = time.perf_counter()
+    for _ in range(repeats):
+        state = fn(state, *args)
+    jax.block_until_ready(state)
+    return (time.perf_counter() - t0) / repeats * 1e3, state
+
+
+def view_section(
+    n: int = 1 << 22,
+    bin_spaces: tuple[int, ...] = VIEW_BINS,
+    pad_share: float = 0.234,
+    bpbs: tuple[int, ...] = (16_384, 65_536),
+    chunks: tuple[int, ...] = (512, 2_048, 8_192),
+) -> None:
+    """The detector view's count of ``n`` slots (``pad_share`` of them
+    routed to the dump slot, as a bucket's padding is): XLA's scatter,
+    weighted (a float array of 1/4 a slot, LOKI's replicas before PR
+    38) and unit, against the device-partitioned count of
+    ops/pallas_hist2d.py at every (block, chunk) of the sweep, with the
+    update 1 and 1/4; each exact against ``np.bincount`` (a touched bin
+    reads its count, and the total leaves none for any other), ns a
+    slot. A rehearsal on the CPU passes small sizes; raises on a
+    mismatch."""
+    import jax
+    import jax.numpy as jnp
+
+    from esslivedata_tpu.ops import pallas_hist2d, pallas_lookup
+
+    rng = np.random.default_rng(38)
+    kept = pallas_hist2d.COUNT_BPB, pallas_hist2d.COUNT_CHUNK
+    for n_incl_dump in bin_spaces:
+        n_bins = n_incl_dump - 1
+        flat = rng.integers(0, n_bins, n).astype(np.int32)
+        flat[rng.random(n) < pad_share] = n_bins
+        bins, counts = np.unique(flat[flat < n_bins], return_counts=True)
+        dev = jax.device_put(flat)
+        dev_bins = jax.device_put(bins.astype(np.int32))
+
+        def exact(window, upd, rounds, dev_bins=dev_bins, counts=counts):
+            got = np.asarray(window[dev_bins])
+            np.testing.assert_array_equal(got, counts * upd * rounds)
+            # in quarters, as integers: a float32 sum rounds past 2**24
+            total = int(jnp.sum((window * 4).astype(jnp.int32)))
+            assert total == int(counts.sum() * 4 * upd * rounds), total
+
+        tag = f"view bins={n_incl_dump} n={n}"
+        scatters = {
+            "scatter weighted": (
+                lambda w, f: w.at[f].add(
+                    jnp.full(f.shape, 0.25, jnp.float32), mode="drop"
+                ),
+                0.25,
+            ),
+            "scatter unit": (lambda w, f: w.at[f].add(1.0, mode="drop"), 1.0),
+        }
+        for name, (fn, upd) in scatters.items():
+            window = jnp.zeros((n_incl_dump,), jnp.float32)
+            took, window = _ms_donated(
+                jax.jit(fn, donate_argnums=(0,)), window, dev
+            )
+            window = window.at[n_bins].set(0.0)  # the scatter counts the dump
+            exact(window, upd, 11)
+            print(
+                f"{tag}: {name} {took:.3f} ms = {took * 1e6 / n:.3f} ns a slot, "
+                "exact",
+                flush=True,
+            )
+            del window
+        for bpb in bpbs:
+            for chunk in chunks:
+                pallas_hist2d.COUNT_BPB = bpb
+                pallas_hist2d.COUNT_CHUNK = chunk
+                blk, n_state = pallas_hist2d.count_layout(n_bins)
+                line = []
+                for upd in (1.0, 0.25):
+
+                    def count(w, f, upd=upd, blk=blk, n_bins=n_bins):
+                        part = pallas_hist2d.partition_on_device(
+                            f, n_bins, bpb=blk
+                        )
+                        return pallas_hist2d.count_partitioned(
+                            w, *part, bpb=blk, upd=upd
+                        )
+
+                    window = jnp.zeros((n_state,), jnp.float32)
+                    took, window = _ms_donated(
+                        jax.jit(count, donate_argnums=(0,)), window, dev
+                    )
+                    exact(window, upd, 11)
+                    line.append(
+                        f"upd {upd}: {took:.3f} ms = {took * 1e6 / n:.3f} ns"
+                    )
+                    del window
+                part = jax.jit(
+                    lambda f, blk=blk, n_bins=n_bins: (
+                        pallas_hist2d.partition_on_device(f, n_bins, bpb=blk)
+                    )
+                )(dev)
+                items = int(part[3][0])
+                print(
+                    f"{tag}: mxu bpb={bpb} chunk={chunk} ({n_state // blk} "
+                    f"blocks of {blk}; {items} items of "
+                    f"{part[2].shape[0]} steps), exact: " + "; ".join(line),
+                    flush=True,
+                )
+        # the count's parts at the constants kept: key sort, work items
+        pallas_hist2d.COUNT_BPB, pallas_hist2d.COUNT_CHUNK = kept
+        blk, n_state = pallas_hist2d.count_layout(n_bins)
+        keys = jnp.where(dev < n_bins, dev, np.iinfo(np.int32).max)
+        keys_sorted = jax.lax.sort(keys, is_stable=False)
+        parts = {
+            "key sort": (lambda k: jax.lax.sort(k, is_stable=False), keys),
+            "work items": (
+                lambda k, blk=blk, n_state=n_state: pallas_lookup.work_items(
+                    k, group=kept[1], span=blk, n_targets=n_state // blk
+                ),
+                keys_sorted,
+            ),
+        }
+        for name, (fn, *args) in parts.items():
+            print(
+                f"{tag}: {name} at bpb={kept[0]} chunk={kept[1]}: "
+                f"{_ms(jax.jit(fn), *args):.3f} ms",
+                flush=True,
+            )
+    pallas_hist2d.COUNT_BPB, pallas_hist2d.COUNT_CHUNK = kept
+
+
 def main() -> None:
     import jax
     import jax.numpy as jnp
 
     print("device:", jax.devices()[0], flush=True)
+    if "--view" in sys.argv[1:]:
+        view_section()
+        return
     if "--bincount" in sys.argv[1:]:
         bincount_section()
         return
@@ -390,6 +556,7 @@ def main() -> None:
     lookup_section()
     lookup_section(**DREAM_POWDER)
     bincount_section()
+    view_section()
 
 
 if __name__ == "__main__":

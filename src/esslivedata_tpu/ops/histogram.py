@@ -15,9 +15,12 @@ Key properties:
   place — the rolling histogram never round-trips to host (the reference's
   NoCopyAccumulator exists to avoid a 30 ms deepcopy of a 500 MB histogram,
   accumulators.py:96; here the histogram is never copied).
-- **One scatter per step.** XLA's TPU scatter is serial (~11 ns/event
-  measured on v5e at LOKI scale), so it is the whole cost of a step.
-  Events are scattered *only* into ``window``; ``clear_window`` folds the
+- **One count per step.** XLA's TPU scatter is serial (~9.7 ns a slot
+  on a v5e whatever the bin space, padding included), so on a TPU a
+  step whose update is a scalar counts on the MXU instead
+  (``method="auto"`` -> ``"mxu"``, ADR 0131: the flat indices sorted on
+  the chip, then counted block by block in place; 0.8-2.2 ns a slot).
+  Events are counted *only* into ``window``; ``clear_window`` folds the
   window into ``folded`` with a dense add (~1.5 ms at LOKI scale, paid at
   the ~1 Hz publish rate, not per batch). The cumulative view is
   ``folded + window``, fused into whatever jitted read consumes it. This
@@ -41,7 +44,10 @@ Measured on TPU v5e (1.5M pixels x 100 TOA bins, 4M-event batches):
 two-scatter design 26.8M ev/s -> single-scatter flat design 93M ev/s
 device-resident; sort/``indices_are_sorted``/``unique_indices``/dtype
 make no measurable difference (the scatter is scalar-core serial either
-way), so the simple unsorted scatter is used.
+way), so where the scatter is used it is the simple unsorted one; the
+sort that pays is the key sort in front of the MXU count (4 Mi slots
+into 6.55 M bins: 3.28 ms against the scatter's 40.56; my chip run,
+PR 38, ``ops/pallas_hist2d.py``).
 """
 
 from __future__ import annotations
@@ -54,7 +60,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..telemetry.instruments import SCATTER_UPDATES, VIEW_WIRES
+from ..telemetry.instruments import SCATTER_UPDATES, VIEW_STEPS, VIEW_WIRES
 from ..telemetry.trace import TRACER
 from .event_batch import (
     EventBatch,
@@ -73,6 +79,7 @@ logger = logging.getLogger(__name__)
 
 _FLAT_WIRES = VIEW_WIRES.labels(staging="flat")
 _RAW_WIRES = VIEW_WIRES.labels(staging="raw")
+_METHODS = ("auto", "scatter", "sort", "mxu", "pallas2d")
 
 
 def _flatten_args(batch: EventBatch) -> dict[str, int]:
@@ -204,7 +211,9 @@ class EventProjection:
         lut=None,
     ) -> tuple[jax.Array, jax.Array | None]:
         """Flat local bin index per event (dump = n_rows*n_toa = dropped)
-        and the event weight (None = unit weights); replicas folded in.
+        and the event weight (None = unit weights); replicas folded in:
+        without per-pixel weights a replica LUT's weight is the scalar
+        1/R, with them the per-slot array of weight / R.
 
         ``lut`` optionally overrides the captured device LUT so callers
         can thread it through jit as an ARGUMENT (ADR 0105: live
@@ -241,7 +250,9 @@ class EventProjection:
                 ok, local_row * self.n_toa + tb[None, :], n_local
             ).reshape(-1)
             if w is None and n_rep > 1:
-                w = jnp.full(flat.shape, 1.0 / n_rep, dtype=jnp.float32)
+                # one weight for every slot: a scalar, so that the count
+                # scales it once and no per-slot float array is built
+                w = jnp.asarray(1.0 / n_rep, dtype=jnp.float32)
             elif w is not None:
                 w = jnp.broadcast_to(w[None, :] / n_rep, screen.shape).reshape(-1)
         else:
@@ -311,25 +322,31 @@ class EventHistogrammer:
         reflects the decayed window (the decayed EMA is the product; a
         raw-count cumulative alongside it would need a second scatter).
     method:
-        'auto' resolves at construction: 'pallas' for VMEM-sized,
-        unit-weight bin spaces on a TPU backend, else 'scatter'.
-        'scatter' (default) or 'sort' (argsort + sorted scatter-add).
-        Measured equal on TPU v5e; kept for hardware where they differ.
-        'pallas' replaces the serial scatter with the vectorized
-        one-hot-reduction kernel (ops/pallas_hist.py) — only for bin
-        spaces that fit VMEM (monitor spectra, Q-family sizes; bound
-        enforced at construction) and unit/scalar event weights
-        (per-event weight arrays fall back to the scatter).
-        'pallas2d' tiles arbitrarily large bin spaces over VMEM-sized
-        blocks with MXU accumulation (ops/pallas_hist2d.py): the host
-        ingest partitions events by bin block (native ``ld_partition``
-        or numpy), and the flat-index fast path (``step_flat`` /
-        ``step_batch``) feeds the tiled kernel; the (pixel_id, toa)
-        device path keeps the scatter (its indices are device-resident,
-        and the partition is a host pass). Requires a host-flattenable
-        configuration (no per-pixel weights, no replica LUTs). State
-        arrays are padded to whole blocks; all views slice the padding
-        (and the dump bin) away.
+        How a batch's flat bin indices are counted into the window.
+        'auto' resolves at construction from what the histogrammer
+        observes: 'mxu' on a TPU backend for every configuration whose
+        update is a scalar (counts, a replica LUT's 1/R, the decay's
+        1/scale), whatever the bin space; 'scatter' for per-pixel
+        weights (a float per event) and on other backends.
+        'mxu' counts on the chip without XLA's serial scatter
+        (ops/pallas_hist2d.py, ADR 0131): the indices are sorted by key
+        alone, listed as (chunk, bin block) work items, and each item's
+        counts are the factorised one-hot on the MXU, scaled once by
+        the update and added in place; dropped slots sort past every
+        block and cost no item. A one-block bin space skips the sort.
+        The dump slot is never counted. Per-event weights fall back to
+        the scatter.
+        'scatter' (the default) is XLA's scatter-add: serial on a TPU's
+        scalar core (6-10 ns a slot, padding included, whatever the bin
+        space; PERF.md section 6), and the only kernel per-event weights
+        take. 'sort' sorts before it (no faster on a v5e).
+        'pallas2d' is 'mxu''s kernel over a partition made on the host
+        (native ``ld_partition`` or numpy) and shipped as the wire: the
+        flat-index fast path (``step_flat`` / ``step_batch``) feeds it;
+        it requires a host-flattenable configuration (no per-pixel
+        weights, no replica LUTs). 'mxu' and 'pallas2d' pad the state
+        arrays to whole blocks; all views slice the padding (and the
+        dump bin) away.
     """
 
     def __init__(
@@ -346,7 +363,7 @@ class EventHistogrammer:
         pallas2d_chunk: int | None = None,
         pallas2d_precision: str = "bf16",
     ) -> None:
-        if method not in ("auto", "scatter", "sort", "pallas", "pallas2d"):
+        if method not in _METHODS:
             raise ValueError(f"Unknown method {method!r}")
         self._proj = EventProjection(
             toa_edges=toa_edges,
@@ -355,32 +372,35 @@ class EventHistogrammer:
             n_screen=n_screen,
         )
         if method == "auto":
-            # Resolve at construction: on TPU a VMEM-sized bin space takes
-            # the one-hot reduction kernel (measured 6.3e8 vs 1.05e8 ev/s
-            # device-resident against the scalar-core scatter, v5e r5);
-            # everything else — big spaces, per-pixel weights, non-TPU
-            # backends (where the kernel would run in interpret mode) —
-            # stays on the XLA scatter.
-            from .pallas_hist import MAX_PALLAS_BINS
+            # Resolve at construction, from what is observed: the MXU
+            # count wherever the update is a scalar on a TPU (and the
+            # padded state stays inside int32 keys); per-pixel weights
+            # and other backends, where the kernel would run in
+            # interpret mode, keep XLA's scatter.
+            from .pallas_hist2d import count_layout
 
-            n_bins_auto = self._proj.n_screen * self._proj.n_toa
-            lut_auto = self._proj.lut_host
             method = (
-                "pallas"
+                "mxu"
                 if (
-                    n_bins_auto + 1 <= MAX_PALLAS_BINS
-                    and pixel_weights is None
-                    # Replica LUTs carry per-event 1/n_rep weights, which
-                    # the pallas path hands back to the scatter anyway.
-                    and (lut_auto is None or lut_auto.shape[0] == 1)
+                    pixel_weights is None
+                    and count_layout(self._proj.n_screen * self._proj.n_toa)[1]
+                    < np.iinfo(np.int32).max
                     and jax.default_backend() == "tpu"
                 )
                 else "scatter"
             )
-        # Both wire kinds have a sample from the first histogrammer on,
-        # so that a share of either reads 0, not "no sample".
+        # Both wire kinds and every kernel have a sample from the first
+        # histogrammer on, so that a share of any reads 0, not "no
+        # sample".
         _FLAT_WIRES.inc(0.0)
         _RAW_WIRES.inc(0.0)
+        for kind in ("mxu", "scatter"):
+            VIEW_STEPS.inc(0.0, kernel=kind)
+        # The kernel of the unpartitioned paths (``_counter``); a batch
+        # that pallas2d partitions on the host counts on the MXU.
+        self._kernel = (
+            "mxu" if method == "mxu" and pixel_weights is None else "scatter"
+        )
         self._edges = self._proj.edges
         self._edges_f32 = self._edges.astype(np.float32)
         # graft: key-derived=_n_toa,_n_screen,_n_bins pure functions of
@@ -393,17 +413,12 @@ class EventHistogrammer:
         self._dtype = dtype
         self._method = method
         self._decay = decay
-        if method == "pallas":
-            from .pallas_hist import MAX_PALLAS_BINS
-
-            if self._n_bins + 1 > MAX_PALLAS_BINS:
-                raise ValueError(
-                    f"method='pallas' supports at most "
-                    f"{MAX_PALLAS_BINS - 1} bins (VMEM bound); this "
-                    f"configuration has {self._n_bins}"
-                )
         self._n_state = self._n_bins + 1
         self._ppb_shift = None
+        if method == "mxu":
+            from .pallas_hist2d import count_layout
+
+            self._bpb, self._n_state = count_layout(self._n_bins)
         if method == "pallas2d":
             from .pallas_hist2d import DEFAULT_BPB, padded_bins
 
@@ -519,14 +534,6 @@ class EventHistogrammer:
     def _scatter_into(
         self, window: jax.Array, flat: jax.Array, updates
     ) -> jax.Array:
-        scalar_updates = not (
-            isinstance(updates, jax.Array) and updates.ndim
-        )
-        if self._method == "pallas" and scalar_updates:
-            from .pallas_hist import bincount_pallas
-
-            counts = bincount_pallas(flat, window.shape[0])
-            return window + counts.astype(window.dtype) * updates
         sorted_ = self._method == "sort"
         if sorted_:
             if isinstance(updates, jax.Array) and updates.ndim:
@@ -542,13 +549,25 @@ class EventHistogrammer:
             updates, mode="drop", indices_are_sorted=sorted_
         )
 
+    def _counter(self, flat: jax.Array):
+        """``(window, updates) -> window`` for one batch of flat indices.
+        The MXU count partitions the batch on the chip here, once, so
+        that the K states of a fused step share its sort and work
+        items."""
+        if self._method == "mxu" and self._proj.weights is None:
+            from .pallas_hist2d import count_partitioned, partition_on_device
+
+            part = partition_on_device(flat, self._n_bins, bpb=self._bpb)
+            return lambda win, upd: count_partitioned(
+                win, *part, bpb=self._bpb, upd=upd
+            )
+        return lambda win, upd: self._scatter_into(win, flat, upd)
+
     def _advance(
         self, state: HistogramState, flat: jax.Array, w
     ) -> HistogramState:
-        """One scatter into the window; decay handled via the lazy scale."""
-        return self._advance_core(
-            state, lambda win, upd: self._scatter_into(win, flat, upd), w
-        )
+        """One count into the window; decay handled via the lazy scale."""
+        return self._advance_core(state, self._counter(flat), w)
 
     def _advance_core(
         self, state: HistogramState, apply_updates, w
@@ -635,13 +654,15 @@ class EventHistogrammer:
     # results are bit-identical to K private steps.
     def _step_fused_impl(self, states, lut, pixel_id, toa):
         flat, w = self._proj.flat_and_weights(pixel_id, toa, lut=lut)
-        return tuple(self._advance(s, flat, w) for s in states)
+        count = self._counter(flat)
+        return tuple(self._advance_core(s, count, w) for s in states)
 
     def _step_flat_fused_impl(self, states, flat):
         flat = jnp.where(
             (flat < 0) | (flat > self._n_bins), self._n_bins, flat
         )
-        return tuple(self._advance(s, flat, None) for s in states)
+        count = self._counter(flat)
+        return tuple(self._advance_core(s, count, None) for s in states)
 
     def _step_part_fused_impl(self, states, events, chunk_map):
         from .pallas_hist2d import scatter_add_pallas2d
@@ -911,11 +932,16 @@ class EventHistogrammer:
             batch, cache, tag, device=device, on_miss=_RAW_WIRES.inc
         )
 
-    def _count_scatter(self, slots: int) -> None:
-        """One step dispatch: ``slots`` staged slots, each scattered
-        once per LUT replica."""
+    def _count_scatter(
+        self, slots: int, *, partitioned: bool = False
+    ) -> None:
+        """One step dispatch: ``slots`` staged slots, each counted
+        once per LUT replica, and one step of the kernel it runs: the
+        MXU's for a batch partitioned on the host, else that of the
+        unpartitioned path."""
         lut = self._proj.lut_host
         SCATTER_UPDATES.inc(slots * (1 if lut is None else lut.shape[0]))
+        VIEW_STEPS.inc(kernel="mxu" if partitioned else self._kernel)
 
     def stage_events(
         self,
@@ -1068,7 +1094,9 @@ class EventHistogrammer:
         slice-keyed cache entry the tick path uses."""
         if device is None:
             device = self._state_slice_device(state)
-        self._count_scatter(batch.padded_size)
+        self._count_scatter(
+            batch.padded_size, partitioned=self._method == "pallas2d"
+        )
         if self._method == "pallas2d":
             events, chunk_map = self._staged_partition(
                 batch, cache, batch_tag, device=device
@@ -1106,7 +1134,9 @@ class EventHistogrammer:
             return ()
         if device is None:
             device = self._state_slice_device(states[0])
-        self._count_scatter(batch.padded_size)
+        self._count_scatter(
+            batch.padded_size, partitioned=self._method == "pallas2d"
+        )
         if self._method == "pallas2d":
             events, chunk_map = self._staged_partition(
                 batch, cache, batch_tag, device=device
@@ -1185,7 +1215,9 @@ class EventHistogrammer:
         step body itself. The tick program that takes the tuple is this
         window's one dispatch of the group, so its scatter's updates
         are counted here."""
-        self._count_scatter(batch.padded_size)
+        self._count_scatter(
+            batch.padded_size, partitioned=self._method == "pallas2d"
+        )
         if self._method == "pallas2d":
             return self._staged_partition(
                 batch, cache, batch_tag, device=device
@@ -1274,7 +1306,9 @@ class EventHistogrammer:
         With ``method='pallas2d'`` the indices are partitioned by bin
         block on the host (native ``ld_partition`` when available) and
         fed to the MXU-tiled kernel instead of the serial scatter."""
-        self._count_scatter(int(np.shape(flat)[0]))
+        self._count_scatter(
+            int(np.shape(flat)[0]), partitioned=self._method == "pallas2d"
+        )
         if self._method == "pallas2d":
             from .pallas_hist2d import partition_events_host
 

@@ -27,13 +27,12 @@ column and are dropped for free, the same semantics as scatter's
 ``mode='drop'`` with negatives pre-routed.
 
 The big 2-D pixel x TOF spaces (1.5M x 100 bins) do NOT fit VMEM; those
-stay on the XLA scatter (``EventHistogrammer`` enforces the bound) or
-take ``pallas_hist2d``.
+take ``pallas_hist2d`` (``EventHistogrammer``'s counts of every size,
+ADR 0131).
 
 On non-TPU backends the kernels run in interpret mode (slow, for
-tests); ``EventHistogrammer(method='pallas')`` is the flat kernel's
-integration point, and ``QHistogrammer(method='auto')`` takes one of the
-two on a TPU by the bin count (``MXU_LANE_GROUPS``).
+tests); ``QHistogrammer(method='auto')`` takes one of the two on a TPU
+by the bin count (``MXU_LANE_GROUPS``).
 
 Readings on a v5e (``scripts/tpu_kernel_check.py --bincount``; my chip
 run, PR 34, PERF.md section 6; ms for 4 Mi / 16 Mi event slots, 23 % of

@@ -43,6 +43,38 @@ Out-of-range/padded events (``flat = -1`` after block-local shift) have a
 negative ``hi`` and match no one-hot row, so they are dropped for free —
 the same semantics as the scatter path's dump-bin routing.
 
+The partition on the chip (``partition_on_device`` +
+``count_partitioned``; ``EventHistogrammer(method="mxu")``, ADR 0131)
+------------------------------------------------------------------------
+The detector views' indices are made on the device (a replica LUT's
+projection) or arrive unsorted on the flat wire, so the same kernel takes
+a partition the chip makes: dropped slots keyed ``INT32_MAX``, the keys
+sorted alone, cut into chunks of ``COUNT_CHUNK``, and the ``(chunk,
+block)`` work items listed by ``pallas_lookup.work_items``; the grid
+walks the items with both indices scalar-prefetched and skips the steps
+past the last. A state of one block (up to ``MAX_MXU_BINS``) needs no
+sort. 4 Mi slots, 23.4 % of them dropped, every count exact against
+``np.bincount`` at updates of 1 and 1/4 (``scripts/tpu_kernel_check.py
+--view``; my chip run, PR 38), ms (ns a slot):
+
+============ ============ ===================== ================ ================
+ bins         XLA scatter  blocks of 16 Ki,      blocks of 64 Ki   one block
+ (dump incl.) weighted /   chunk 512 / 2 048 /   chunk 512 /       (up to 65 536)
+              unit         8 192                 2 048 / 8 192
+============ ============ ===================== ================ ================
+  6 553 601  40.56 / 40.57 4.68 / **3.28** / 3.51  7.82 / 4.85 / 5.06        -
+163 840 001  41.77 / 41.79 9.88 / 9.08 / 18.69   10.39 / **8.77** / 18.73    -
+ 15 769 601  40.64 / 40.63 4.93 / **3.63** / 4.44  8.00 / 5.13 / 5.91        -
+     25 601  25.59 / 25.61 4.48 / 3.05 / 2.83          -             3.73 / **1.59** / 1.34
+============ ============ ===================== ================ ================
+
+(the 6.55 M row is 0.78 ns a slot against the scatter's 9.67; of it the
+key sort alone reads 3.4 ms and the work items 0.5 ms at 4 Mi keys.) So
+``COUNT_BPB`` 16 Ki and ``COUNT_CHUNK`` 2 048: the fastest at 6.55 M and
+15.8 M, 4 % over 64 Ki blocks at NMX's 10 001 blocks, and one block for
+DREAM's 25 601 bins at 1.59 (8 192 keys would give 1.34 there and 18.7
+at 164 M).
+
 The state arrays for ``method='pallas2d'`` are padded to ``n_blocks*bpb``
 (the dump bin and the padding tail are excluded from all views, exactly
 like the existing dump-bin slot).
@@ -61,13 +93,18 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .pallas_hist import factorised_counts
+from .pallas_hist import MAX_MXU_BINS, factorised_counts
 
 __all__ = [
+    "COUNT_BPB",
+    "COUNT_CHUNK",
     "DEFAULT_BPB",
     "DEFAULT_CHUNK",
     "bucketed_chunks",
     "chunk_capacity",
+    "count_layout",
+    "count_partitioned",
+    "partition_on_device",
     "partition_events_host",
     "scatter_add_pallas2d",
     "padded_bins",
@@ -77,17 +114,131 @@ __all__ = [
 DEFAULT_BPB = 65536
 #: Default events per grid step (chunk).
 DEFAULT_CHUNK = 512
+#: The device-partitioned count's block past one tile (``count_layout``)
+#: and the keys of one of its work items (``partition_on_device``): the
+#: chip sweep's pick (``scripts/tpu_kernel_check.py --view``; the table
+#: is in the module docstring): 16 Ki bins and 2 048 keys are the
+#: fastest or within 4 % of it at every bin space measured.
+COUNT_BPB = 16384
+COUNT_CHUNK = 2048
 #: Chunk-count bucket: the padded chunk count rounds up to a multiple of
 #: this so the jit cache sees a handful of shapes, not one per batch.
 _CHUNK_BUCKET = 512
 
 _LANES = 128
+_SENTINEL = np.iinfo(np.int32).max
 
 
 def padded_bins(n_bins_incl_dump: int, bpb: int = DEFAULT_BPB) -> int:
     """State size for pallas2d: bins (incl. dump) padded to whole blocks."""
     n_blocks = -(-n_bins_incl_dump // bpb)
     return n_blocks * bpb
+
+
+def count_layout(n_bins: int) -> tuple[int, int]:
+    """``(bpb, n_state)`` of the device-partitioned count over
+    ``n_bins`` bins and the dump slot behind them: one block of whole
+    (8, 128) float32 tiles up to ``MAX_MXU_BINS`` (``bincount_mxu``'s
+    one VMEM tile: no sort), else whole blocks of ``COUNT_BPB``."""
+    n = n_bins + 1
+    if n <= max(MAX_MXU_BINS, COUNT_BPB):
+        bpb = -(-n // (8 * _LANES)) * 8 * _LANES
+        return bpb, bpb
+    return COUNT_BPB, padded_bins(n, COUNT_BPB)
+
+
+def partition_on_device(flat: jax.Array, n_bins: int, *, bpb: int):
+    """The chip's twin of ``partition_events_host``, traceable: the
+    batch's keys and its ``(chunk, block)`` work items, the trailing
+    arguments of ``count_partitioned``. ``flat`` is int32 ``[n]``; an
+    index outside ``[0, n_bins)`` (the dump slot, a bucket's padding) is
+    keyed ``INT32_MAX``, so that it sorts past every block and makes no
+    item: a dropped slot costs its share of the sort and nothing else,
+    and the dump slot is never counted. The keys are sorted alone
+    (unstable: equal keys are one bin, and nothing rides along) and cut
+    into chunks of ``COUNT_CHUNK``; ``pallas_lookup.work_items`` lists
+    the blocks each chunk's keys fall in. A state of one block skips the
+    sort: every chunk is an item of that block, up to the last one that
+    holds a key."""
+    return _lowered_once(
+        _partition, (flat,), n_bins=n_bins, bpb=bpb, chunk=COUNT_CHUNK
+    )
+
+
+def _partition(flat, *, n_bins: int, bpb: int, chunk: int):
+    from .pallas_lookup import work_items
+
+    keys = jnp.where((flat >= 0) & (flat < n_bins), flat, _SENTINEL)
+    pad = max(-(-keys.shape[0] // chunk), 1) * chunk - keys.shape[0]
+    if pad:
+        keys = jnp.concatenate([keys, jnp.full((pad,), _SENTINEL, jnp.int32)])
+    n_chunks = keys.shape[0] // chunk
+    n_blocks = -(-(n_bins + 1) // bpb)
+    if n_blocks == 1:
+        held = jnp.any(keys.reshape(n_chunks, chunk) != _SENTINEL, axis=1)
+        steps = jnp.arange(n_chunks, dtype=jnp.int32)
+        n_items = jnp.max(jnp.where(held, steps + 1, 0))
+        return (
+            keys,
+            jnp.minimum(steps, jnp.maximum(n_items - 1, 0)),
+            jnp.zeros((n_chunks,), jnp.int32),
+            n_items.reshape(1),
+        )
+    with jax.named_scope("count_sort"):
+        keys = jax.lax.sort(keys, is_stable=False)
+    chunk_of, block_of, n_items = work_items(
+        keys, group=chunk, span=bpb, n_targets=n_blocks
+    )
+    return keys, chunk_of, block_of, n_items
+
+
+def count_partitioned(
+    window: jax.Array,
+    keys: jax.Array,
+    chunk_of: jax.Array,
+    block_of: jax.Array,
+    n_items: jax.Array,
+    *,
+    bpb: int,
+    upd=1.0,
+    interpret: bool | None = None,
+) -> jax.Array:
+    """Count ``partition_on_device``'s keys into the block-padded flat
+    ``window`` in place (``count_layout``'s ``n_state`` elements),
+    every count times the scalar ``upd``. Traceable."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    return _lowered_once(
+        _pallas2d_call,
+        (window, keys, chunk_of, block_of, n_items, jnp.asarray(upd, jnp.float32)),
+        bpb=bpb,
+        chunk=COUNT_CHUNK,
+        interpret=bool(interpret),
+    )
+
+
+def _lowered_once(fn, args: tuple, **static):
+    """``fn(*args, **static)`` inside the caller's trace, lowered once a
+    process per shape and kept as a ``jax.export`` module, which a
+    program that calls it again inlines without lowering the Mosaic
+    kernel again. A view's two tick programs (the first tick's, with its
+    statics, and the steady one) and every view of one shape then pay
+    the kernel's lowering once: lowering it is ~0.13 s of Python on the
+    chip's host, where the whole scatter program took ~0.013 (my chip
+    run, PR 38), and a compile round pays it per program."""
+    avals = tuple(jax.ShapeDtypeStruct(a.shape, a.dtype) for a in args)
+    exported = _export(
+        fn, avals, tuple(sorted(static.items())), jax.default_backend()
+    )
+    return exported.call(*args)
+
+
+@functools.lru_cache(maxsize=64)
+def _export(fn, avals: tuple, static: tuple, platform: str):
+    from jax import export
+
+    jitted = jax.jit(functools.partial(fn, **dict(static)))
+    return export.export(jitted, platforms=[platform])(*avals)
 
 
 def chunk_capacity(
@@ -247,15 +398,18 @@ def partition_events_host(
 
 
 @functools.partial(
-    jax.jit, static_argnums=(4, 5, 6, 7), donate_argnums=(0,)
+    jax.jit, static_argnums=(6, 7, 8, 9, 10), donate_argnums=(0,)
 )
 def _pallas2d_call(
     window: jax.Array,  # [n_blocks * bpb] float32, donated
-    events: jax.Array,  # [n_chunks * chunk]: int32 flat (-1 padded) or
-    #                     uint16 block-local (0xFFFF padded, `local`)
-    chunk_map: jax.Array,  # [n_chunks] int32, non-decreasing
-    upd,  # traced float32 scalar (1.0 for counts; 1/scale for decay)
+    events: jax.Array,  # [n_chunks * chunk]: int32 flat (-1 or INT32_MAX
+    #                     padded) or uint16 block-local (0xFFFF, `local`)
+    chunk_of,  # [n_steps] int32: each work item's chunk (None: step's)
+    block_of: jax.Array,  # [n_steps] int32, non-decreasing: its block
+    n_items,  # [1] int32: steps from here on are skipped (None: none)
+    upd,  # traced float32 scalar (1.0 for counts; 1/R; 1/scale for decay)
     bpb: int,
+    chunk: int,
     interpret: bool,
     precision: str = "bf16",
     local: bool = False,
@@ -263,9 +417,11 @@ def _pallas2d_call(
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    n_chunks = chunk_map.shape[0]
-    chunk = events.shape[0] // n_chunks
+    n_chunks = events.shape[0] // chunk
     n_blocks = window.shape[0] // bpb
+    if chunk_of is None:
+        chunk_of = jnp.arange(block_of.shape[0], dtype=jnp.int32)
+        n_items = jnp.full((1,), block_of.shape[0], jnp.int32)
     h = bpb // _LANES
     win3 = window.reshape(n_blocks, h, _LANES)
     if local:
@@ -288,40 +444,51 @@ def _pallas2d_call(
     # inside int32).
     oh_dtype = jnp.int8 if precision == "int8" else jnp.bfloat16
 
-    def kernel(map_ref, upd_ref, win_ref, rows_ref, out_ref):
+    def kernel(chunk_ref, block_ref, n_ref, upd_ref, win_ref, rows_ref, out_ref):
         j = pl.program_id(0)
-        blk = map_ref[j]
-        prev = map_ref[jnp.maximum(j - 1, 0)]
-        first = (j == 0) | (blk != prev)
+        blk = block_ref[j]
 
-        @pl.when(first)
+        # A block's first visit loads it, counted or not: the output
+        # tile is written back whatever the step did, so a skipped
+        # first step (an empty batch) must still hold the window.
+        @pl.when((j == 0) | (blk != block_ref[jnp.maximum(j - 1, 0)]))
         def _load():
             out_ref[...] = win_ref[...]
 
-        # `local` events arrive block-local already; flat events
-        # subtract the block base (padding/-1 stays negative).
-        contrib = factorised_counts(
-            rows_ref, h, base=None if local else blk * bpb, oh_dtype=oh_dtype
-        )
-        out_ref[0, :, :] += contrib.astype(jnp.float32) * upd_ref[0]
+        @pl.when(j < n_ref[0])
+        def _count():
+            # `local` events arrive block-local already; flat events
+            # subtract the block base (padding, -1 or INT32_MAX, and
+            # another block's bins land outside the tile's rows).
+            contrib = factorised_counts(
+                rows_ref,
+                h,
+                base=None if local else blk * bpb,
+                oh_dtype=oh_dtype,
+            )
+            # the update scales the item's counts once, not each event
+            out_ref[0, :, :] += contrib.astype(jnp.float32) * upd_ref[0]
+
+    def at_block(j, c, b, n, u):
+        return (b[j], 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(n_chunks,),
+        num_scalar_prefetch=4,
+        grid=(block_of.shape[0],),
         in_specs=[
-            pl.BlockSpec((1, h, _LANES), lambda j, m, u: (m[j], 0, 0)),
-            pl.BlockSpec((1, 8, cw), lambda j, m, u: (j, 0, 0)),
+            pl.BlockSpec((1, h, _LANES), at_block),
+            pl.BlockSpec((1, 8, cw), lambda j, c, b, n, u: (c[j], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, h, _LANES), lambda j, m, u: (m[j], 0, 0)),
+        out_specs=pl.BlockSpec((1, h, _LANES), at_block),
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(win3.shape, jnp.float32),
-        input_output_aliases={2: 0},  # window (after the 2 scalar args)
+        input_output_aliases={4: 0},  # window (after the 4 scalar args)
         interpret=interpret,
-        name="scatter_add_pallas2d",
-    )(chunk_map, upd_arr, win3, rows)
+        name="count_blocks_mxu",
+    )(chunk_of, block_of, n_items, upd_arr, win3, rows)
     return out.reshape(n_blocks * bpb)
 
 
@@ -365,9 +532,12 @@ def scatter_add_pallas2d(
     return _pallas2d_call(
         window,
         jnp.asarray(events) if local else jnp.asarray(events, jnp.int32),
+        None,  # chunk j is step j's, and every step counts
         jnp.asarray(chunk_map, jnp.int32),
+        None,
         jnp.asarray(upd, jnp.float32),
         bpb,
+        events.shape[0] // max(n_chunks, 1),
         bool(interpret),
         precision,
         local,

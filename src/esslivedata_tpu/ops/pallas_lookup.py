@@ -60,6 +60,7 @@ __all__ = [
     "lookup_kind",
     "pack_table",
     "packable",
+    "work_items",
 ]
 
 #: Events per block: 8 sublane rows of BLOCK / 8 lanes, so that the
@@ -172,36 +173,42 @@ def lookup_kind(n_events: int, packed_shape: tuple[int, int, int]) -> str:
     return "windowed" if dense else "gather"
 
 
-def _work_items(keys: jax.Array, n_windows: int, shift: int):
-    """``(block, window, n_items)`` of the sorted ``keys``: per item its
-    event block and table window, int32 ``[n / BLOCK + n_windows]``;
-    entries from ``n_items`` on repeat the last item, so that a skipped
-    grid step moves no block. Dense ops only: a gather here would pay
-    the per-entry price the kernel exists to avoid."""
-    n_blocks = keys.shape[0] // BLOCK
-    by_block = keys.reshape(n_blocks, BLOCK)
-    first = by_block[:, 0]
-    last = jnp.max(jnp.where(by_block == _SENTINEL, -1, by_block), axis=1)
-    w_lo = (first >> shift) // WINDOW
-    w_hi = (last >> shift) // WINDOW
-    count = jnp.where(last >= 0, w_hi - w_lo + 1, 0)
+def work_items(keys: jax.Array, *, group: int, span: int, n_targets: int):
+    """``(group, target, n_items)`` of the sorted ``keys``, cut into
+    groups of ``group`` keys, the key space into targets of ``span``
+    keys (target = key // span): per item a group and a target that
+    holds one of its keys, int32 ``[n / group + n_targets]``, groups and
+    targets both non-decreasing; entries from ``n_items`` on repeat the
+    last item, so that a skipped grid step moves no block. A dropped key
+    (``INT32_MAX``) sorts to the end and makes no item. Dense ops only:
+    a gather here would pay the per-entry price the kernels exist to
+    avoid. The lookup's items are (event block, table window); the
+    device-partitioned count's (``pallas_hist2d.partition_on_device``)
+    are (event chunk, bin block)."""
+    n_groups = keys.shape[0] // group
+    by_group = keys.reshape(n_groups, group)
+    first = by_group[:, 0]
+    last = jnp.max(jnp.where(by_group == _SENTINEL, -1, by_group), axis=1)
+    t_lo = first // span
+    t_hi = last // span
+    count = jnp.where(last >= 0, t_hi - t_lo + 1, 0)
     ends = jnp.cumsum(count)
     n_items = ends[-1]
     j = jnp.minimum(
-        jnp.arange(n_blocks + n_windows, dtype=jnp.int32),
+        jnp.arange(n_groups + n_targets, dtype=jnp.int32),
         jnp.maximum(n_items - 1, 0),
     )
-    # block(j) = how many blocks end at or before item j; window(j) =
-    # j + g(block(j)) with g(b) = w_lo[b] - starts[b], summed from its
+    # group(j) = how many groups end at or before item j; target(j) =
+    # j + g(group(j)) with g(b) = t_lo[b] - starts[b], summed from its
     # differences under the same comparison
     before = ends[None, :] <= j[:, None]
-    g = w_lo - (ends - count)
+    g = t_lo - (ends - count)
     dg = jnp.diff(g, append=g[-1:])
-    block = jnp.sum(before, axis=1, dtype=jnp.int32)
-    window = j + g[0] + jnp.sum(jnp.where(before, dg[None, :], 0), axis=1)
+    of_group = jnp.sum(before, axis=1, dtype=jnp.int32)
+    target = j + g[0] + jnp.sum(jnp.where(before, dg[None, :], 0), axis=1)
     return (
-        jnp.minimum(block, n_blocks - 1),
-        jnp.clip(window, 0, n_windows - 1).astype(jnp.int32),
+        jnp.minimum(of_group, n_groups - 1),
+        jnp.clip(target, 0, n_targets - 1).astype(jnp.int32),
         n_items.astype(jnp.int32).reshape(1),
     )
 
@@ -215,7 +222,9 @@ def _lookup_sorted(packed, keys, shift: int, interpret: bool):
     planes, n_toa_p, n_pix_p = packed.shape
     n_blocks = keys.shape[0] // BLOCK
     lanes = BLOCK // _SUBLANES
-    block, window, n_items = _work_items(keys, n_pix_p // WINDOW, shift)
+    block, window, n_items = work_items(
+        keys, group=BLOCK, span=WINDOW << shift, n_targets=n_pix_p // WINDOW
+    )
     toa_mask = (1 << shift) - 1
 
     def kernel(block_ref, window_ref, n_ref, keys_ref, table_ref, out_ref):

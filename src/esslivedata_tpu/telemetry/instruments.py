@@ -36,6 +36,7 @@ __all__ = [
     "TABLE_BUILD_SECONDS",
     "TABLE_BYTES",
     "TICK_GROUPS",
+    "VIEW_STEPS",
     "VIEW_WIRES",
 ]
 
@@ -153,6 +154,25 @@ SCATTER_UPDATES = REGISTRY.counter(
     "livedata_scatter_updates_total",
     "Updates handed to the histogram scatter: staged slots times LUT "
     "replicas, per step dispatch",
+)
+
+#: Steps of the histogrammers (``ops/histogram.EventHistogrammer``: the
+#: detector views, the monitor histograms), one count per dispatch (the
+#: members of one group share it and count once, where
+#: ``livedata_scatter_updates_total`` counts), by the kernel the step's
+#: program was traced with: ``mxu`` = the factorised one-hot on the MXU
+#: over a partition by bin block (``method="mxu"``, which
+#: ``method="auto"`` takes on a TPU wherever the update is a scalar: the
+#: chip sorts the indices; and ``"pallas2d"``, whose partition the host
+#: makes), ``scatter`` = XLA's scatter-add (per-pixel weights, other
+#: backends, ``"scatter"`` / ``"sort"``, and pallas2d's (pixel_id, toa)
+#: device path). Both labels have a sample from the first histogrammer
+#: on. mxu / all is the benchmark's ``view_mxu_share``.
+VIEW_STEPS = REGISTRY.counter(
+    "livedata_view_steps_total",
+    "Event-histogrammer step dispatches, by the kernel that counts the "
+    "bins (mxu or scatter)",
+    labelnames=("kernel",),
 )
 
 #: How each host array shipped to the device got its staging copy

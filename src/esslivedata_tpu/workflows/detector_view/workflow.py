@@ -46,11 +46,15 @@ class DetectorViewParams(BaseModel):
     # spectrum keeps the full axis. Bin edges are static under jit, so
     # the slice compiles to a static index range — zero runtime cost.
     image_toa_slice: TOARange | None = None
-    # Histogram kernel selection (ops/histogram.py): 'scatter' (XLA
-    # scatter-add, the safe default), or 'pallas2d' (MXU-tiled kernel,
-    # ops/pallas_hist2d.py) for host-flattenable configurations — falls
-    # back to 'scatter' when the configuration can't take it
-    # (pixel weighting, replica LUTs).
+    # How the view's bin indices reach the count (ops/histogram.py).
+    # 'scatter' (the default): unpartitioned, and the histogrammer picks
+    # the kernel from what it observes (``EventHistogrammer(method=
+    # "auto")``, ADR 0131): on a TPU the chip sorts the indices and
+    # counts them block by block on the MXU wherever the update is a
+    # scalar, replica LUTs included; XLA's scatter-add for pixel
+    # weighting and off the TPU. 'pallas2d': partitioned on the host for
+    # the same MXU kernel, host-flattenable configurations only; the
+    # others (pixel weighting, replica LUTs) count as 'scatter' does.
     histogram_method: Literal["scatter", "pallas2d"] = "scatter"
 
 
@@ -97,14 +101,15 @@ class DetectorViewWorkflow:
         weights = (
             _density_weights(projection.lut) if params.pixel_weighting else None
         )
-        method = params.histogram_method
-        if method == "pallas2d" and (
+        # pallas2d consumes host-partitioned flat indices; weighted and
+        # replica configurations, like 'scatter', leave the kernel to the
+        # histogrammer.
+        method = "auto"
+        if params.histogram_method == "pallas2d" and not (
             weights is not None
             or (projection.lut is not None and projection.lut.shape[0] > 1)
         ):
-            # pallas2d consumes host-partitioned flat indices; weighted
-            # and replica configurations stay on the scatter.
-            method = "scatter"
+            method = "pallas2d"
         self._hist = EventHistogrammer(
             toa_edges=edges,
             n_screen=projection.n_screen,

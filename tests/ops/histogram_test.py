@@ -437,3 +437,188 @@ def test_swap_projection_device_path_never_retraces():
     assert img.sum() == 16.0
     row_counts = np.asarray(img).sum(axis=1)
     np.testing.assert_array_equal(row_counts, [10.0, 2.0, 2.0, 2.0])
+
+
+@pytest.fixture
+def small_blocks(monkeypatch):
+    """``method="mxu"`` at test size: blocks of 2 048 bins, work items
+    of 256 keys (interpret mode on the CPU)."""
+    from esslivedata_tpu.ops import pallas_hist2d
+
+    monkeypatch.setattr(pallas_hist2d, "COUNT_BPB", 2048)
+    monkeypatch.setattr(pallas_hist2d, "COUNT_CHUNK", 256)
+    monkeypatch.setattr(pallas_hist2d, "MAX_MXU_BINS", 2048)
+
+
+def _mxu_batches(n_pix, n=3000, k=3, seed=38):
+    rng = np.random.default_rng(seed)
+    return [
+        EventBatch.from_arrays(
+            rng.integers(-2, n_pix + 2, n).astype(np.int32),
+            rng.uniform(-1.0, 73.0, n).astype(np.float32),
+        )
+        for _ in range(k)
+    ]
+
+
+class TestMxuMethod:
+    """The device-partitioned count (ADR 0131) against XLA's scatter and
+    numpy, through every step path of the histogrammer."""
+
+    EDGES = np.linspace(0.0, 71.0, 51)
+
+    def _pair(self, **kw):
+        return [
+            EventHistogrammer(toa_edges=self.EDGES, method=m, **kw)
+            for m in ("scatter", "mxu")
+        ]
+
+    @pytest.mark.parametrize(
+        "n_screen", [8, 300], ids=["one_block", "many_blocks"]
+    )
+    @pytest.mark.parametrize("path", ["step", "step_batch", "step_many"])
+    def test_unit_weights_match_the_scatter(self, small_blocks, n_screen, path):
+        batches = _mxu_batches(n_screen)
+        views = []
+        for h in self._pair(n_screen=n_screen):
+            states = (h.init_state(), h.init_state())
+            for b in batches:
+                if path == "step_many":
+                    states = h.step_many(states, b)
+                else:
+                    states = tuple(getattr(h, path)(s, b) for s in states)
+            views.append([h.read(s) for s in states])
+        (scatter, _), (mxu, mxu_twin) = views
+        for a, b in zip(scatter, mxu):
+            np.testing.assert_array_equal(a, b)
+        for a, b in zip(mxu, mxu_twin):  # fused K states: each as a private one
+            np.testing.assert_array_equal(a, b)
+
+    def test_replica_lut_with_entries_off_screen(self, small_blocks):
+        """R = 4, a fifth of the entries -1: every bin a multiple of 1/4,
+        exact against the scatter and against numpy."""
+        rng = np.random.default_rng(4)
+        n_screen, n_pix = 300, 500
+        lut = rng.integers(0, n_screen, (4, n_pix)).astype(np.int32)
+        lut[rng.random(lut.shape) < 0.2] = -1
+        batches = _mxu_batches(n_pix)
+        out = []
+        for h in self._pair(n_screen=n_screen, pixel_lut=lut):
+            s = h.init_state()
+            for b in batches:
+                s = h.step_batch(s, b)
+            out.append(h.read(s)[1])
+        np.testing.assert_array_equal(out[0], out[1])
+        pid = np.concatenate([np.asarray(b.pixel_id)[: b.n_valid] for b in batches])
+        toa = np.concatenate([np.asarray(b.toa)[: b.n_valid] for b in batches])
+        ref = np_hist2d(pid, toa, n_screen, self.EDGES, lut=lut)
+        assert np.abs(out[1] - ref).sum() <= 1.0  # float32 TOA binning at edges
+        assert (out[1] * 4 == np.round(out[1] * 4)).all()
+
+    def test_the_replica_weight_is_a_scalar(self):
+        import jax.numpy as jnp
+
+        lut = np.array([[0, 1], [1, -1]], np.int32)
+        pid, toa = jnp.array([0, 1], jnp.int32), jnp.array([5.0, 5.0], jnp.float32)
+        h = EventHistogrammer(toa_edges=self.EDGES, n_screen=2, pixel_lut=lut)
+        flat, w = h._proj.flat_and_weights(pid, toa)
+        assert flat.shape == (4,) and w.ndim == 0 and float(w) == 0.5
+        weighted = EventHistogrammer(
+            toa_edges=self.EDGES, n_screen=2, pixel_lut=lut,
+            pixel_weights=np.array([2.0, 1.0], np.float32),
+        )
+        _, w = weighted._proj.flat_and_weights(pid, toa)
+        np.testing.assert_array_equal(np.asarray(w), [1.0, 0.5, 1.0, 0.5])
+
+    def test_decay_against_float64(self, small_blocks):
+        """``counts * (1/scale)`` a work item where the scatter added
+        ``1/scale`` a slot: not bit-identical, so held to a float64
+        rolling window within float32 rounding."""
+        decay, n_screen = 0.8, 300
+        batches = _mxu_batches(n_screen, k=5)
+        h = EventHistogrammer(
+            toa_edges=self.EDGES, n_screen=n_screen, method="mxu", decay=decay
+        )
+        s = h.init_state()
+        want = np.zeros((n_screen, 50))
+        for b in batches:
+            s = h.step_batch(s, b)
+            pid = np.asarray(b.pixel_id)[: b.n_valid]
+            toa = np.asarray(b.toa)[: b.n_valid]
+            want = want * decay + np_hist2d(pid, toa, n_screen, self.EDGES)
+        got = h.read(s)[1]
+        assert np.abs(got - want).sum() <= 2.0  # a boundary TOA or two
+        close = np.isclose(got, want, rtol=1e-6, atol=0)
+        assert close.mean() > 0.999
+
+    def test_the_dump_slot_and_the_padding_stay_empty(self, small_blocks):
+        h = EventHistogrammer(toa_edges=self.EDGES, n_screen=300, method="mxu")
+        s = h.init_state()
+        for b in _mxu_batches(300):
+            s = h.step_batch(s, b)
+        window = np.asarray(s.window)
+        assert window.shape[0] % 2048 == 0 and window[:15_000].sum() > 0
+        assert not window[15_000:].any()  # dump slot and block padding
+
+    @pytest.mark.parametrize(
+        ("backend", "kw", "want"),
+        [
+            ("tpu", {}, "mxu"),
+            ("tpu", {"lut": 4}, "mxu"),
+            ("tpu", {"weights": True}, "scatter"),
+            ("tpu", {"lut": 4, "weights": True}, "scatter"),
+            ("cpu", {}, "scatter"),
+            ("cpu", {"lut": 4}, "scatter"),
+        ],
+        ids=["tpu_unit", "tpu_replicas", "tpu_weights", "tpu_replicas_weights",
+             "cpu_unit", "cpu_replicas"],
+    )
+    def test_auto_resolves_by_what_it_observes(self, monkeypatch, backend, kw, want):
+        import jax
+
+        from esslivedata_tpu.telemetry.instruments import VIEW_STEPS
+
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+        n_pix = 64
+        lut = None
+        if kw.get("lut"):
+            lut = np.random.default_rng(0).integers(-1, 16, (kw["lut"], n_pix)).astype(np.int32)
+        weights = np.ones(n_pix, np.float32) if kw.get("weights") else None
+        h = EventHistogrammer(
+            toa_edges=self.EDGES, n_screen=16 if lut is not None else n_pix,
+            pixel_lut=lut, pixel_weights=weights, method="auto",
+        )
+        assert h.fuse_key[1] == want
+        before = VIEW_STEPS.value(kernel=want)
+        h._count_scatter(1024)
+        assert VIEW_STEPS.value(kernel=want) == before + 1
+
+    def test_the_counter_has_every_label_from_construction(self):
+        from esslivedata_tpu.telemetry.instruments import VIEW_STEPS
+
+        EventHistogrammer(toa_edges=self.EDGES, n_screen=4)
+        labels = {d["kernel"] for d, _ in VIEW_STEPS.items()}
+        assert labels == {"scatter", "mxu"}
+
+    @pytest.mark.parametrize(
+        ("path", "want"),
+        [("step", "scatter"), ("step_batch", "mxu"), ("step_flat", "mxu")],
+    )
+    def test_pallas2d_counts_each_step_by_the_kernel_it_ran(self, path, want):
+        """pallas2d's (pixel_id, toa) device path runs XLA's scatter; the
+        batches it partitions on the host run the MXU kernel."""
+        from esslivedata_tpu.telemetry.instruments import VIEW_STEPS
+
+        h = EventHistogrammer(toa_edges=self.EDGES, n_screen=8, method="pallas2d")
+        batch = _mxu_batches(8, n=500, k=1)[0]
+        arg = batch
+        if path == "step_flat":
+            n = batch.n_valid
+            arg = h.flatten_host(
+                np.asarray(batch.pixel_id)[:n], np.asarray(batch.toa)[:n]
+            )
+        before = {k: VIEW_STEPS.value(kernel=k) for k in ("scatter", "mxu")}
+        state = getattr(h, path)(h.init_state(), arg)
+        assert float(np.asarray(state.window).sum()) > 0
+        for kind, was in before.items():
+            assert VIEW_STEPS.value(kernel=kind) == was + (kind == want)

@@ -234,6 +234,125 @@ class TestKernel:
         assert out.sum() == 10.0
 
 
+@pytest.fixture
+def small_blocks(monkeypatch):
+    """The device-partitioned count at test size: blocks of 2 048 bins,
+    one block up to 2 048, work items of 256 keys (the kernel's shapes,
+    in interpret mode)."""
+    monkeypatch.setattr(p2, "COUNT_BPB", 2048)
+    monkeypatch.setattr(p2, "COUNT_CHUNK", 256)
+    monkeypatch.setattr(p2, "MAX_MXU_BINS", 2048)
+    return 2048
+
+
+def _count_on_device(window, flat, n_bins, upd=1.0):
+    import jax.numpy as jnp
+
+    bpb = p2.count_layout(n_bins)[0]
+    part = p2.partition_on_device(jnp.asarray(flat, jnp.int32), n_bins, bpb=bpb)
+    return np.asarray(
+        p2.count_partitioned(jnp.asarray(window), *part, bpb=bpb, upd=upd)
+    )
+
+
+class TestDevicePartitionedCount:
+    """The chip's own partition (key sort + work items) in front of the
+    block kernel: what ``EventHistogrammer(method="mxu")`` runs."""
+
+    @pytest.mark.parametrize(
+        ("n_bins", "blocks"),
+        [(1000, 1), (2047, 1), (2048, 2), (20_000, 10)],
+        ids=["one_block", "one_block_full", "dump_slot_past_the_block", "many_blocks"],
+    )
+    def test_layout(self, small_blocks, n_bins, blocks):
+        bpb, n_state = p2.count_layout(n_bins)
+        assert n_state == blocks * bpb >= n_bins + 1
+        assert bpb % 1024 == 0 and bpb <= small_blocks
+
+    @pytest.mark.parametrize("n_bins", [1000, 20_000], ids=["one_block", "many_blocks"])
+    @pytest.mark.parametrize("upd", [1.0, 0.25])
+    def test_counts_match_bincount(self, small_blocks, n_bins, upd):
+        """Dropped slots (the dump slot, negatives, past the bins) count
+        nowhere, the dump slot and the padding tail included; a touched
+        bin reads its count times the update, exactly."""
+        rng = np.random.default_rng(n_bins)
+        flat = rng.integers(-5, n_bins + 5, 5000).astype(np.int32)
+        flat[rng.random(5000) < 0.234] = n_bins
+        n_state = p2.count_layout(n_bins)[1]
+        out = _count_on_device(np.zeros(n_state, np.float32), flat, n_bins, upd)
+        ok = flat[(flat >= 0) & (flat < n_bins)]
+        np.testing.assert_array_equal(
+            out[:n_bins], np.bincount(ok, minlength=n_bins) * upd
+        )
+        assert not out[n_bins:].any()
+
+    def test_matches_the_scatter_on_block_edges(self, small_blocks):
+        """Keys on both sides of every block edge, and the first and the
+        last bin, against XLA's scatter into the same window."""
+        import jax.numpy as jnp
+
+        n_bins = 20_000
+        bpb, n_state = p2.count_layout(n_bins)
+        edges = np.arange(bpb, n_bins, bpb)
+        flat = np.concatenate(
+            [edges - 1, edges, edges, [0, 0, n_bins - 1, n_bins]]
+        ).astype(np.int32)
+        base = np.random.default_rng(1).integers(0, 9, n_state).astype(np.float32)
+        out = _count_on_device(base, flat, n_bins)
+        want = np.array(
+            jnp.asarray(base).at[jnp.asarray(flat)].add(1.0, mode="drop")
+        )
+        want[n_bins] = base[n_bins]  # the scatter counts the dump slot
+        np.testing.assert_array_equal(out, want)
+
+    @pytest.mark.parametrize("n", [0, 3000], ids=["empty", "all_padding"])
+    @pytest.mark.parametrize("n_bins", [1000, 20_000], ids=["one_block", "many_blocks"])
+    def test_a_batch_with_nothing_to_count_leaves_the_window(self, small_blocks, n, n_bins):
+        """No work item: every block the grid visits is loaded and
+        written back as it was (in place), none is counted into."""
+        n_state = p2.count_layout(n_bins)[1]
+        base = np.random.default_rng(2).random(n_state).astype(np.float32)
+        flat = np.full(n, n_bins, np.int32)
+        np.testing.assert_array_equal(_count_on_device(base, flat, n_bins), base)
+
+    def test_untouched_blocks_are_not_rewritten(self, small_blocks):
+        n_bins = 20_000
+        n_state = p2.count_layout(n_bins)[1]
+        base = np.random.default_rng(3).integers(0, 50, n_state).astype(np.float32)
+        flat = np.random.default_rng(4).integers(3 * 2048, 5 * 2048, 4000).astype(np.int32)
+        out = _count_on_device(base, flat, n_bins)
+        np.testing.assert_array_equal(
+            out, base + np.bincount(flat, minlength=n_state)
+        )
+
+    @pytest.mark.parametrize("n_bins", [1000, 20_000], ids=["one_block", "many_blocks"])
+    def test_work_items_cover_each_chunk_and_block_once(self, small_blocks, n_bins):
+        """Per item a (chunk, block) that one of the chunk's keys lies
+        in, each once, blocks in order (the output's revisiting rule);
+        dropped keys at the end make no item, and skipped steps repeat
+        the last item. One block: no sort, every chunk that holds a key
+        is an item."""
+        import jax.numpy as jnp
+
+        rng = np.random.default_rng(5)
+        flat = rng.integers(0, n_bins, 3000).astype(np.int32)
+        flat[2500:] = n_bins  # a bucket's padding: at its tail
+        bpb = p2.count_layout(n_bins)[0]
+        keys, chunk_of, block_of, n_items = map(
+            np.asarray, p2.partition_on_device(jnp.asarray(flat), n_bins, bpb=bpb)
+        )
+        n_items = int(n_items[0])
+        items = set(zip(chunk_of[:n_items], block_of[:n_items]))
+        assert len(items) == n_items
+        valid = keys != np.iinfo(np.int32).max
+        wanted = set(zip((np.flatnonzero(valid) // 256), keys[valid] // bpb))
+        assert wanted == items
+        assert (np.diff(block_of) >= 0).all()
+        assert (chunk_of[n_items:] == chunk_of[n_items - 1]).all()
+        assert (block_of[n_items:] == block_of[n_items - 1]).all()
+        assert sorted(keys[valid]) == sorted(flat[:2500])
+
+
 class TestHistogrammerPallas2d:
     def _run(self, method, batches, toa_edges=None, **kw):
         if toa_edges is None:

@@ -159,7 +159,10 @@ class TestQHistogrammerBincountChoice:
             assert Q_BINCOUNT_STEPS.value(method=kind) == was + (kind == label)
 
 
-class TestHistogrammerPallasMethod:
+class TestHistogrammerMxuMethod:
+    """EventHistogrammer's one-block MXU count (ADR 0131), which took the
+    place of its flat one-hot method, at monitor-sized bin spaces."""
+
     def _batches(self, n_batches=3, n=3000, n_pixel=8):
         rng = np.random.default_rng(5)
         return [
@@ -175,29 +178,29 @@ class TestHistogrammerPallasMethod:
         edges = np.linspace(0.0, 7.1e7, 101)
         kw = dict(toa_edges=edges, n_screen=8, decay=decay)
         ref = EventHistogrammer(method="scatter", **kw)
-        pal = EventHistogrammer(method="pallas", **kw)
-        s_ref, s_pal = ref.init_state(), pal.init_state()
+        mxu = EventHistogrammer(method="mxu", **kw)
+        s_ref, s_mxu = ref.init_state(), mxu.init_state()
         for batch in self._batches():
             s_ref = ref.step(s_ref, batch)
-            s_pal = pal.step(s_pal, batch)
+            s_mxu = mxu.step(s_mxu, batch)
         cum_ref, win_ref = ref.read(s_ref)
-        cum_pal, win_pal = pal.read(s_pal)
-        np.testing.assert_allclose(win_pal, win_ref, rtol=1e-6)
-        np.testing.assert_allclose(cum_pal, cum_ref, rtol=1e-6)
+        cum_mxu, win_mxu = mxu.read(s_mxu)
+        np.testing.assert_allclose(win_mxu, win_ref, rtol=1e-6)
+        np.testing.assert_allclose(cum_mxu, cum_ref, rtol=1e-6)
 
     def test_step_flat_parity(self):
         edges = np.linspace(0.0, 7.1e7, 1001)
         ref = EventHistogrammer(toa_edges=edges, method="scatter")
-        pal = EventHistogrammer(toa_edges=edges, method="pallas")
+        mxu = EventHistogrammer(toa_edges=edges, method="mxu")
         rng = np.random.default_rng(2)
         pid = rng.integers(0, 1, 5000).astype(np.int32)
         toa = rng.uniform(0, 7.1e7, 5000).astype(np.float32)
         flat = ref.flatten_host(pid, toa)
         s_ref = ref.step_flat(ref.init_state(), flat)
-        s_pal = pal.step_flat(pal.init_state(), flat)
-        np.testing.assert_array_equal(
-            np.asarray(s_ref.window), np.asarray(s_pal.window)
-        )
+        s_mxu = mxu.step_flat(mxu.init_state(), flat)
+        # the views: the MXU state is block-padded and leaves the dump empty
+        for a, b in zip(ref.read(s_ref), mxu.read(s_mxu), strict=True):
+            np.testing.assert_array_equal(a, b)
 
     def test_weighted_config_falls_back_to_scatter(self):
         # Per-event weight arrays are outside the kernel's contract; the
@@ -210,18 +213,16 @@ class TestHistogrammerPallasMethod:
             pixel_weights=weights,
         )
         ref = EventHistogrammer(method="scatter", **kw)
-        pal = EventHistogrammer(method="pallas", **kw)
+        mxu = EventHistogrammer(method="mxu", **kw)
         batch = self._batches(1, n=2000, n_pixel=16)[0]
         w_ref = ref.read(ref.step(ref.init_state(), batch))[1]
-        w_pal = pal.read(pal.step(pal.init_state(), batch))[1]
-        np.testing.assert_allclose(w_pal, w_ref, rtol=1e-6)
+        w_mxu = mxu.read(mxu.step(mxu.init_state(), batch))[1]
+        np.testing.assert_allclose(w_mxu, w_ref, rtol=1e-6)
 
-    def test_too_many_bins_rejected_at_construction(self):
+    def test_the_flat_one_hot_is_no_method_of_the_view(self):
         with pytest.raises(ValueError, match="pallas"):
             EventHistogrammer(
-                toa_edges=np.linspace(0, 7.1e7, 101),
-                n_screen=1000,  # 100k bins: far beyond VMEM
-                method="pallas",
+                toa_edges=np.linspace(0, 7.1e7, 101), n_screen=8, method="pallas"
             )
 
 

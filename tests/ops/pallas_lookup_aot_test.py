@@ -214,3 +214,49 @@ def test_both_whole_steps_compile_at_bifrosts_widths(one_chip, monkeypatch, n_bi
     assert text.count("tpu_custom_call") == kernels and (" scatter(" in text) == (method == "scatter")
     # the wire, the table and a few 16 Mi temporaries: nothing near the chip's 16 GB
     assert stats.temp_size_in_bytes < 1 << 30
+
+
+@pytest.mark.parametrize(
+    "n_screen, n_pix, replicas, n, sorts",
+    [
+        (256 * 256, 802_816, 4, 1 << 20, 1),
+        (1280 * 1280, None, None, 1 << 22, 1),
+        (256, None, None, 1 << 22, 0),
+    ],
+    ids=["loki_bank_view", "nmx_panel", "dream_one_block"],
+)
+def test_the_detector_views_mxu_count_compiles_at_the_cells_widths(
+    one_chip, monkeypatch, n_screen, n_pix, replicas, n, sorts
+):
+    """``EventHistogrammer(method="mxu")`` (ADR 0131), its whole fused
+    step at the widths of the three detector cells, 100 TOA bins: LOKI's
+    bank view (a 4-replica LUT gathered on the chip, 4 Mi slots into
+    6.55 M bins), NMX's panel (the host's flat wire into 164 M bins) and
+    DREAM's smallest view (one block: no sort). One Mosaic kernel, no
+    XLA scatter, the key sort where there is more than one block."""
+    import numpy as np
+
+    from esslivedata_tpu.ops.histogram import EventHistogrammer, HistogramState
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    lut = None if replicas is None else np.zeros((replicas, n_pix), np.int32)
+    hist = EventHistogrammer(
+        toa_edges=np.linspace(0.0, 1e9 / 14, 101), n_screen=n_screen,
+        pixel_lut=lut, method="auto",
+    )
+    assert hist.fuse_key[1] == "mxu"
+    window = jax.ShapeDtypeStruct((hist._n_state,), jnp.float32, sharding=one_chip)
+    states = (HistogramState(window, window, None),)
+    if lut is None:
+        step = hist._step_flat_fused_impl
+        wire = (jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip),)
+    else:
+        step = hist._step_fused_impl
+        wire = (
+            jax.ShapeDtypeStruct(lut.shape, jnp.int32, sharding=one_chip),
+            jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip),
+            jax.ShapeDtypeStruct((n,), jnp.float32, sharding=one_chip),
+        )
+    text = jax.jit(step, donate_argnums=(0,)).lower(states, *wire).compile().as_text()
+    assert text.count("tpu_custom_call") == 1 and " scatter(" not in text
+    assert text.count(" sort(") == sorts

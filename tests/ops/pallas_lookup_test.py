@@ -224,7 +224,13 @@ class TestWindowedLookup:
         keys = np.full(4096, SENTINEL, np.int32)
         keys[:3000] = pid << shift
         block, window, n_items = map(
-            np.asarray, pallas_lookup._work_items(jnp.asarray(keys), 8, shift)
+            np.asarray,
+            pallas_lookup.work_items(
+                jnp.asarray(keys),
+                group=pallas_lookup.BLOCK,
+                span=pallas_lookup.WINDOW << shift,
+                n_targets=8,
+            ),
         )
         n_items = int(n_items[0])
         assert block.shape == window.shape == (4 + 8,)

@@ -400,6 +400,65 @@ class TestWireFormats:
         ref.shutdown()
 
 
+class TestMxuCount:
+    @pytest.mark.parametrize("replicas", [1, 4], ids=["flat_wire", "replica_lut"])
+    def test_the_tick_program_counts_as_a_private_step_and_as_the_scatter(
+        self, monkeypatch, replicas
+    ):
+        """A view built where ``auto`` observes a TPU takes the MXU count
+        (ADR 0131): on the flat wire, and on a replica LUT's device path
+        (four replicas, entries off screen). Its tick program's da00
+        bytes equal the separate-dispatch path's, window by window, and
+        the scatter's views."""
+        import dataclasses
+        from unittest import mock
+
+        from esslivedata_tpu.ops import pallas_hist2d
+
+        monkeypatch.setattr(pallas_hist2d, "COUNT_BPB", 2048)
+        monkeypatch.setattr(pallas_hist2d, "COUNT_CHUNK", 256)
+        monkeypatch.setattr(pallas_hist2d, "MAX_MXU_BINS", 2048)
+        det = _det()
+        table = project_logical(det)
+        if replicas > 1:
+            rng = np.random.default_rng(60)
+            lut = np.stack(
+                [table.lut[0]] + [rng.permutation(table.lut[0]) for _ in range(3)]
+            )
+            lut[:, rng.random(lut.shape[1]) < 0.1] = -1
+            table = dataclasses.replace(table, lut=lut.astype(np.int32))
+
+        def make(backend):
+            def build():
+                # only the construction observes the TPU: the kernel's
+                # interpret mode is read at trace time, on the CPU
+                with mock.patch.object(jax, "default_backend", lambda: backend):
+                    return DetectorViewWorkflow(projection=table)
+
+            return build
+
+        tick, created_t = _make_manager([make("tpu")])
+        ref, created_r = _make_manager([make("tpu")], tick_program=False)
+        scat, created_s = _make_manager([make("cpu")])
+        for wf in (*created_t, *created_r):
+            assert wf.histogrammer.fuse_key[1] == "mxu"
+        assert created_s[0].histogrammer.fuse_key[1] == "scatter"
+        rng = np.random.default_rng(61)
+        METRICS.drain()
+        for w, (pid, toa) in enumerate(_windows(rng, 3, 1000, -3, 150)):
+            res = [
+                m.process_jobs({"det0": _staged(pid, toa)}, start=T(0), end=T(w + 1))
+                for m in (tick, ref, scat)
+            ]
+            assert all(len(r) == 1 for r in res)
+            wires = [_wire_bytes(r[0]) for r in res]
+            assert wires[0] == wires[1], f"window {w}: tick and private disagree"
+            assert wires[0] == wires[2], f"window {w}: mxu and scatter disagree"
+        assert METRICS.drain()["tick_publishes"] == 6
+        for m in (tick, ref, scat):
+            m.shutdown()
+
+
 class TestContainment:
     def test_state_lost_on_post_donation_dispatch_failure(self):
         """A dispatch that fails AFTER consuming the donated states
