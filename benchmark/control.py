@@ -5,10 +5,11 @@
 
 One sound run of the cell, and then the reference with one guarantee
 broken put in the program's place, once for each fault of
-``reference.break_guarantee`` and then, for the jobs of a view kind
-that ``references/<kind>.py`` answers, once for each of that kind's own
-``faults()`` (``<kind>.<name>``): the same publishes, judged against what
-the broken reference says. Prints the sound run's numbers and each
+``reference.break_guarantee`` (where the cell has a detector stream)
+and then, for the jobs of a view kind that ``references/<kind>.py``
+answers, once for each of that kind's own ``faults()``
+(``<kind>.<name>``): the same publishes, judged against what the broken
+reference says. Prints the sound run's numbers and each
 control's, and exits 0 only where the sound run is correct and every
 control is not. The benchmark's own runs do not run this; PERF.md's
 limits were set from its readings.
@@ -35,8 +36,7 @@ from harness.service import BenchFailure  # noqa: E402
 
 def controls_of(cell: manifest.Cell) -> tuple[str, ...]:
     """Every fault a cell's comparison has to catch."""
-    return (*reference.FAULTS,
-            *(f"{kind}.{name}" for kind, module in cell.kinds.items() for name in module.faults()))
+    return reference.controls(cell.config, cell.kinds)
 
 
 def main(argv=None) -> int:

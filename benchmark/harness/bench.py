@@ -40,9 +40,13 @@ def streams_with_topics(config: dict) -> list[dict]:
     return [{"topic": config["detector_topic"], **stream} for stream in config["streams"]]
 
 
-def events_per_pulse(config: dict, traffic) -> dict[str, int]:
-    """job -> events a pulse of its own stream carries."""
-    of_stream = {s["name"]: stream_events(s, traffic) for s in config["streams"]}
+def events_per_pulse(config: dict, traffic) -> dict[str, float]:
+    """job -> events a pulse of its own stream carries; frames, on
+    average, where that is a camera's."""
+    of_stream = {
+        s["name"]: traffic.frames_per_pulse() if s.get("kind") == "camera" else stream_events(s, traffic)
+        for s in config["streams"]
+    }
     return {job["name"]: of_stream[job["stream"]] for job in config["jobs"]}
 
 
@@ -125,9 +129,13 @@ class Run:
         return prom.value(self.child.scrape(), "livedata_jit_compiles") or 0.0
 
     def taken_pulses(self) -> int:
-        """Pulses the service has taken into batches, by its own counter."""
-        taken = prom.value(self.child.scrape(), "livedata_preprocessed_messages") or 0.0
-        return int(taken) // self.generator.hello["messages_per_pulse"]
+        """Pulses the service has taken into batches, by its own counter:
+        the longest run of pulses whose messages it has all taken (a
+        pool entry's count is the generator's; a camera may skip pulses)."""
+        taken = int(prom.value(self.child.scrape(), "livedata_preprocessed_messages") or 0.0)
+        per_entry = self.generator.hello["messages_per_entry"]
+        turns, rest = divmod(taken, sum(per_entry))
+        return turns * len(per_entry) + int(np.searchsorted(np.cumsum(per_entry), rest, "right"))
 
     def published(self) -> int:
         """Publishes every job has delivered so far."""
@@ -212,8 +220,9 @@ class Run:
     def measure(self) -> dict:
         self.warm_up()
         scrape_start = self.child.scrape()
-        self.generator.tell("run")
+        run_from = self.mark()
         t0 = time.monotonic_ns()
+        self.generator.tell(f"run {t0}")
         setup_s = time.monotonic() - self.started
         log(f"window opens after {setup_s:.1f} s of set-up")
         t1 = t0 + int(self.seconds * 1e9)
@@ -223,12 +232,13 @@ class Run:
             if self.child.proc.poll() is not None:
                 raise BenchFailure(f"service exited rc={self.child.proc.returncode} in the window")
         t1 = time.monotonic_ns()
-        # Drain: the generator keeps running until every pulse offered in
-        # the window is published (a window closes on a later pulse). The
-        # service is left alone meanwhile, as in the window: the client
-        # only reads, and a scrape (which the service answers between its
-        # own work) comes once a second.
-        offered = self.mark()
+        # Drain: the generator keeps running until every pulse due in the
+        # window is published (a window closes on a later pulse), however
+        # late it was sent. The service is left alone meanwhile, as in the
+        # window: the client only reads, and a scrape (which the service
+        # answers between its own work) comes once a second.
+        offered = run_from + self.cell.traffic.pulses_due(t1 - t0)
+        self.mark()
         drain_until = time.monotonic() + DRAIN_S
         taken_at = None  # publishes delivered when the last offered pulse was taken
         next_scrape = time.monotonic() + 1.0
@@ -248,7 +258,8 @@ class Run:
         # the scrape that closes the layer metrics' deltas: per-batch ratios,
         # taken once the window's last batch is out so that it delays none
         scrape_end = self.child.scrape()
-        self.sent = self.generator.ask("stop")["sent"]
+        stopped = self.generator.ask("stop")
+        self.sent = stopped["sent"]
         final = self.child.scrape()
         self.generator.close()
         self.generator = None
@@ -258,7 +269,7 @@ class Run:
         return {
             "t0": t0, "t1": t1, "drained_ns": drained_ns, "setup_s": setup_s,
             "scrape_start": scrape_start,
-            "scrape_end": scrape_end, "scrape_final": final,
+            "scrape_end": scrape_end, "scrape_final": final, "bytes_sent": stopped["bytes"],
         }
 
     def close(self) -> None:
@@ -306,9 +317,9 @@ def finish(run: Run, window: dict, pulse_log, trace_events, controls=()):
     sent = len(pulse_log)
     due_ns = pulse_log[:, 1]
     results.assign_prefixes(reader.publishes, run.refs, sent)
-    in_window = (pulse_log[:, 2] >= t0) & (pulse_log[:, 2] < t1)
+    in_window = (due_ns >= t0) & (due_ns < t1)  # a warm-up pulse is due when it is sent
     first_pulse = int(np.argmax(in_window)) if in_window.any() else sent
-    offered = first_pulse + int(in_window.sum())  # pulses sent before the window closed
+    offered = first_pulse + int(in_window.sum())  # pulses due before the window closed
 
     # End to end: every pair due in the window, whenever it arrived.
     pairs = results.freshness(
@@ -399,8 +410,9 @@ def finish(run: Run, window: dict, pulse_log, trace_events, controls=()):
         line["breakdown"] = breakdown
     line["seed"] = run.seed
     line["workload"] = cell.name
-    line["pulses"] = {"sent": sent, "first_in_window": first_pulse, "offered": offered,
-                      "window_s": window_s, "publishes": {j: len(i) for j, i in reader.publishes.items()}}
+    line["pulses"] = {"sent": sent, "bytes": window["bytes_sent"], "first_in_window": first_pulse,
+                      "offered": offered, "window_s": window_s,
+                      "publishes": {j: len(i) for j, i in reader.publishes.items()}}
     if in_window.any():  # where a stall sat: in the generator, or after it
         late_ns = pulse_log[in_window, 2] - pulse_log[in_window, 1]
         line["pulses"]["generator_late_max_ms"] = float(late_ns.max()) / 1e6

@@ -7,9 +7,10 @@ then obeys lines on stdin and answers each on stdout with one JSON line:
 
 ``send N``   N pulses back to back (warm-up).
 ``mark``     answered with the pulses sent so far (also while running).
-``run``      pulses on the ``pulse_hz`` schedule, each due at a fixed
-             time that does not slip when sending is late, until a
-             ``stop`` line.
+``run T``    pulses on the ``pulse_hz`` schedule until a ``stop``
+             line: pulse k of the run is due at T + ``Traffic.due_ns(k)``
+             (T: the client's window opening), a time that does not slip
+             when sending is late.
 ``quit``     writes the pulse log and exits.
 
 The pulse log is int64 rows (pulse, due_ns, sent_ns) on CLOCK_MONOTONIC,
@@ -28,7 +29,7 @@ import numpy as np
 
 from .broker import Producer
 from .traffic import Traffic, pulse_time_ns, stream_events, stream_pool
-from .wire import Ev44Template
+from .wire import Ad00Template, Ev44Template
 
 
 class Generator:
@@ -39,16 +40,26 @@ class Generator:
         self.templates = []  # [pool entry][message of the pulse]: (topic, template)
         for entry in range(self.traffic.pool_pulses):
             self.templates.append([])
+        cameras = []
         for index, stream in enumerate(spec["streams"]):
-            chunk = stream_events(stream, self.traffic) // m
             pool = stream_pool(spec["seed"], index, stream, self.traffic)
+            if stream.get("kind") == "camera":
+                cameras.append((stream, pool))
+                continue
+            chunk = stream_events(stream, self.traffic) // m
             for entry, (ids, toa) in enumerate(pool):
                 for part in range(m):
                     sel = slice(part * chunk, (part + 1) * chunk)
                     self.templates[entry].append(
                         (stream["topic"], Ev44Template(stream["wire_source"], toa[sel], ids[sel]))
                     )
-        self.messages_per_pulse = len(self.templates[0])
+        # a pulse's camera frames go after its ev44 messages, with its stamp
+        for stream, pool in cameras:
+            for entry in range(len(pool)):
+                self.templates[entry] += [
+                    (stream["topic"], Ad00Template(stream["wire_source"], frame))
+                    for frame in pool[entry]
+                ]
         self.base_index = int(time.time_ns() * 14 // 10**9)
         self.next_pulse = 0
         self.message_id = 0
@@ -75,12 +86,10 @@ class Generator:
             print(json.dumps({"mark": self.next_pulse}), flush=True)
         return word in ("stop", "")
 
-    def run_paced(self) -> None:
-        period_ns = 1e9 / self.traffic.pulse_hz
-        start = time.monotonic_ns()
+    def run_paced(self, start: int) -> None:
         k = 0
         while True:
-            due = start + int(k * period_ns)
+            due = start + self.traffic.due_ns(k)
             if self.stop_requested((due - time.monotonic_ns()) / 1e9):
                 return
             self.send_pulse(due)
@@ -95,7 +104,7 @@ def main(argv) -> int:
         print(json.dumps(doc), flush=True)
 
     say(ready=True, base_index=generator.base_index,
-        messages_per_pulse=generator.messages_per_pulse)
+        messages_per_entry=[len(messages) for messages in generator.templates])
     while line := sys.stdin.readline():
         words = line.split()
         if not words:
@@ -107,7 +116,7 @@ def main(argv) -> int:
             say(mark=generator.next_pulse)
             continue
         elif words[0] == "run":
-            generator.run_paced()
+            generator.run_paced(int(words[1]))
         elif words[0] == "quit":
             break
         say(sent=generator.next_pulse, bytes=generator.producer.bytes_written)

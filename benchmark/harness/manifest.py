@@ -19,7 +19,7 @@ from dataclasses import dataclass, field
 from pathlib import Path
 
 from . import reference
-from .traffic import STREAM_KINDS, Traffic, stream_events
+from .traffic import AD00_DTYPES, STREAM_KINDS, Traffic, stream_events
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
@@ -120,16 +120,23 @@ def plugs(bench: Path, config: dict, traffic: Traffic, limits: dict) -> tuple[di
     broken = []
     streams = {s["name"] for s in config["streams"]}
     for stream in config["streams"]:
+        if stream.get("kind", "detector") not in STREAM_KINDS:
+            broken.append(f"stream {stream['name']}: kind {stream['kind']!r}")
+        elif stream.get("kind") == "camera":
+            broken += camera_faults(stream)
+            continue
         try:
             stream_events(stream, traffic)
         except ValueError as err:
             broken.append(str(err))
-        if stream.get("kind", "detector") not in STREAM_KINDS:
-            broken.append(f"stream {stream['name']}: kind {stream['kind']!r}")
     kinds = {}
+    stream_kind = {s["name"]: s.get("kind", "detector") for s in config["streams"]}
     for job in config["jobs"]:
         if job["stream"] not in streams:
             broken.append(f"job {job['name']}: no stream {job['stream']!r}")
+        elif (job["view"]["kind"] == "frames") != (stream_kind[job["stream"]] == "camera"):
+            broken.append(f"job {job['name']}: a view of kind {job['view']['kind']!r} on the "
+                          f"{stream_kind[job['stream']]} stream {job['stream']!r}")
         for role, name in job.get("aux_source_names", {}).items():
             if name not in streams:
                 broken.append(f"job {job['name']}: aux {role!r} names no stream {name!r}")
@@ -148,6 +155,28 @@ def plugs(bench: Path, config: dict, traffic: Traffic, limits: dict) -> tuple[di
             if name not in limits:
                 broken.append(f"check {name} has no limit")
     return kinds, broken
+
+
+#: What a camera stream states, and what it does not: it sends frames, not events.
+CAMERA_KEYS = ("name", "kind", "wire_source", "topic", "frame_shape", "dtype")
+EVENT_KEYS = ("first_id", "n_pixels", "rate_share")
+
+
+def camera_faults(stream: dict) -> list[str]:
+    """A ``camera`` stream's breaches, as sentences."""
+    what = f"camera stream {stream['name']}"
+    faults = [f"{what} lacks {key!r}" for key in CAMERA_KEYS if key not in stream]
+    faults += [f"{what} states {key!r}, which is an event stream's" for key in EVENT_KEYS
+               if key in stream]
+    if "dtype" in stream and stream["dtype"] not in AD00_DTYPES:
+        faults.append(f"{what}: dtype {stream['dtype']!r} is no ad00 type")
+    shape = stream.get("frame_shape")
+    if shape is not None and not (
+        isinstance(shape, list) and len(shape) == 2
+        and all(isinstance(n, int) and n > 0 for n in shape)
+    ):
+        faults.append(f"{what}: frame_shape {shape!r} is not [ny, nx]")
+    return faults
 
 
 def check(root: Path) -> list[str]:

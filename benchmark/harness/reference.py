@@ -52,7 +52,7 @@ from typing import Callable, Protocol
 
 import numpy as np
 
-from .traffic import Traffic, pulse_period_ns, stream_pool
+from .traffic import FramePool, Traffic, pulse_period_ns, stream_pool
 
 #: The kinds answered in this module.
 VIEW_KINDS = ("grid", "nd")
@@ -284,6 +284,17 @@ def check_names(config: dict, kinds: dict[str, Kind]) -> list[str]:
 FAULTS = ("drop_event", "half_pulse", "clip_toa", "clip_pixel")
 
 
+def controls(config: dict, kinds: dict[str, Kind]) -> tuple[str, ...]:
+    """Every fault a configuration's comparison has to catch: the pools'
+    faults where it has a detector stream, then each fault of each of
+    its kinds' modules (``<kind>.<name>``)."""
+    events = any(s.get("kind", "detector") == "detector" for s in config["streams"])
+    return (
+        *(FAULTS if events else ()),
+        *(f"{kind}.{name}" for kind, module in kinds.items() for name in module.faults()),
+    )
+
+
 def break_guarantee(pools, fault: str):
     """The control: the same pools with one guarantee broken, as a later
     PR that trades exactness for speed would break it.
@@ -293,14 +304,17 @@ def break_guarantee(pools, fault: str):
     ``clip_toa``: out-of-range TOA is clipped into the frame, not dropped.
     ``clip_pixel``: out-of-range ids are clamped onto the edge pixels.
 
-    A stream with no ids (a monitor) is left as it is: these are the
-    detector streams' guarantees.
+    A stream with no ids (a monitor) and a camera's frames are left as
+    they are: these are the detector streams' guarantees.
     """
     if fault not in FAULTS:
         raise ValueError(f"unknown fault {fault!r}")
     period = pulse_period_ns()
     out = []
     for pool, (first_id, n_pixels) in pools:
+        if isinstance(pool, FramePool):
+            out.append((pool, (first_id, n_pixels)))
+            continue
         pulses = []
         for entry, (ids, toa) in enumerate(pool):
             ids, toa = ids.copy(), toa.copy()
@@ -329,7 +343,8 @@ def break_guarantee(pools, fault: str):
 
 def make_pools(config: dict, traffic: Traffic, seed: int):
     """[(pool, (first_id, n_pixels))] in the order of the configuration's
-    streams; (0, 0) for a stream with no pixels (a monitor)."""
+    streams; (0, 0) for a stream with no pixel ids (a monitor, a camera,
+    whose pool is a ``FramePool``)."""
     return [
         (
             stream_pool(seed, i, stream, traffic),

@@ -14,6 +14,11 @@ that wraps over the id space and whose centre swings ``0.5 +- 0.4`` of
 it, TOA uniform over the pulse period. The pool turns the producer's
 slow swing (``sin(pulse / 50)``) into one turn of the sine per turn of
 the pool, so that every second of a run holds the same mix.
+
+A ``camera`` stream sends ad00 frames instead (upstream's
+``services/fake_detectors.py:FakeAreaDetectorSource``): its pool is a
+``FramePool`` of seeded frames, ``camera_frames_per_pulse`` of them on
+every pulse or one on every ``camera_pulses_per_frame``-th.
 """
 
 from __future__ import annotations
@@ -23,6 +28,15 @@ from dataclasses import dataclass, replace
 import numpy as np
 
 PULSE_HZ_GRID = 14  # the data-time grid of the program (core/constants.py)
+#: ad00's ``DType`` (schemas/ad00_area_detector_array.fbs), by code:
+#: int8 = 0 .. float64 = 9. Not da00's order.
+AD00_DTYPES = (
+    "int8", "uint8", "int16", "uint16", "int32", "uint32", "int64", "uint64", "float32", "float64",
+)
+#: The beam spot of a camera frame: a disc of this share of the shorter
+#: side as its radius, at the frame's centre, this many times as bright.
+SPOT_RADIUS_SHARE = 0.2
+SPOT_GAIN = 8.0
 
 
 def pulse_time_ns(index: int) -> int:
@@ -38,7 +52,7 @@ def pulse_period_ns() -> float:
 class Traffic:
     """Parameters of one traffic mix (``benchmark/traffic/<name>.json``)."""
 
-    pulse_hz: float  # open loop: pulse k is due at start + k / pulse_hz
+    pulse_hz: float  # open loop: pulse k is due at start + due_ns(k)
     events_per_pulse: int  # per stream
     pixel_dist: str = "blob"  # or "uniform", "hotspot"
     blob_sigma_share: float = 0.125  # blob: sigma as a share of the id space
@@ -49,6 +63,9 @@ class Traffic:
     messages_per_pulse: int = 1
     pool_pulses: int = 14
     toa_bins: int = 100
+    camera_frames_per_pulse: int = 1  # ad00 frames each pulse, per camera stream
+    camera_pulses_per_frame: int = 1  # or one frame on every this-many pulses
+    camera_mean_counts: float = 100.0  # of a pixel of the flat field
 
     @classmethod
     def from_dict(cls, doc: dict) -> "Traffic":
@@ -67,10 +84,34 @@ class Traffic:
             raise ValueError("messages_per_pulse must divide events_per_pulse")
         if not 0 <= 2 * traffic.out_of_range_probes <= traffic.events_per_pulse:
             raise ValueError("out_of_range_probes does not fit in a pulse")
+        per_pulse, per_frame = traffic.camera_frames_per_pulse, traffic.camera_pulses_per_frame
+        if not (isinstance(per_pulse, int) and isinstance(per_frame, int)
+                and per_pulse >= 1 and per_frame >= 1 and min(per_pulse, per_frame) == 1):
+            raise ValueError("camera_frames_per_pulse and camera_pulses_per_frame are whole "
+                             "numbers of at least 1, and at most one of them is above 1")
+        if traffic.pool_pulses % per_frame:
+            raise ValueError("camera_pulses_per_frame must divide pool_pulses")
+        if not traffic.camera_mean_counts > 0:
+            raise ValueError("camera_mean_counts must be above 0")
         return traffic
 
+    def frames_per_pulse(self) -> float:
+        """ad00 frames a camera stream sends a pulse, on average."""
+        return self.camera_frames_per_pulse / self.camera_pulses_per_frame
 
-STREAM_KINDS = ("detector", "monitor")
+    def due_ns(self, k: int) -> int:
+        """How long after a paced run's start its pulse ``k`` is due."""
+        return int(k * (1e9 / self.pulse_hz))
+
+    def pulses_due(self, span_ns: int) -> int:
+        """How many pulses of a paced run are due in its first ``span_ns``."""
+        k = 0
+        while self.due_ns(k) < span_ns:
+            k += 1
+        return k
+
+
+STREAM_KINDS = ("detector", "monitor", "camera")
 
 
 def stream_events(stream: dict, traffic: Traffic) -> int:
@@ -99,10 +140,14 @@ def stream_pool(seed: int, stream_index: int, stream: dict, traffic: Traffic):
     """``make_pool`` for one stream of a configuration, at the stream's
     own size. A ``monitor`` stream is ev44 with TOA only (as
     ``services/fake_sources.py:FakeMonitorStream`` sends one): the same
-    draws in the same order, the ids left out."""
+    draws in the same order, the ids left out. A ``camera`` stream's
+    pool is ``make_frame_pool``'s."""
     kind = stream.get("kind", "detector")
     if kind not in STREAM_KINDS:
         raise ValueError(f"stream {stream['name']}: kind {kind!r}")
+    if kind == "camera":
+        return make_frame_pool(seed, stream_index, tuple(stream["frame_shape"]), stream["dtype"],
+                               traffic)
     own = replace(traffic, events_per_pulse=stream_events(stream, traffic))
     pool = make_pool(
         seed, stream_index, stream.get("first_id", 0), stream.get("n_pixels", 1), own
@@ -155,3 +200,58 @@ def make_pool(seed: int, stream_index: int, first_id: int, n_pixels: int, traffi
             )
         pool.append((ids.astype(np.int32), toa.astype(np.int32)))
     return pool
+
+
+@dataclass(frozen=True)
+class FramePool:
+    """A camera stream's pool: ``frames`` [n, ny, nx] in the stream's
+    ad00 type, and for each pool entry (a pulse) the frames it sends, in
+    order. Entry ``e`` as a list of frames is ``pool[e]``."""
+
+    frames: np.ndarray
+    entries: tuple[tuple[int, ...], ...]
+
+    def __len__(self) -> int:
+        return len(self.entries)
+
+    def __getitem__(self, entry: int) -> list[np.ndarray]:
+        return [self.frames[i] for i in self.entries[entry]]
+
+
+def make_frame_pool(seed: int, stream_index: int, shape: tuple[int, int], dtype: str,
+                    traffic: Traffic) -> FramePool:
+    """The frames of one camera stream: Poisson counts of mean
+    ``camera_mean_counts`` over a flat field, ``SPOT_GAIN`` times that on
+    a disc at the centre (the beam spot), clipped to ``dtype``'s range.
+    Every frame's total differs from every other's (a pixel of the
+    corner is moved one count at a time, away from the type's top, while
+    it ties), so that a prefix of a cycled pool can be told from its
+    sum. Drawn from a key of their own: no event stream's draws change."""
+    if dtype not in AD00_DTYPES:
+        raise ValueError(f"camera dtype {dtype!r} is no ad00 type")
+    rng = np.random.default_rng([int(seed), int(stream_index), 0x63616D65])
+    ny, nx = shape
+    y, x = np.ogrid[:ny, :nx]
+    spot = np.hypot(y - (ny - 1) / 2, x - (nx - 1) / 2) <= SPOT_RADIUS_SHARE * min(ny, nx)
+    mean = traffic.camera_mean_counts * np.where(spot, SPOT_GAIN, 1.0)
+    per_pulse, per_frame = traffic.camera_frames_per_pulse, traffic.camera_pulses_per_frame
+    entries = tuple(
+        tuple(range(e * per_pulse, (e + 1) * per_pulse)) if per_frame == 1
+        else ((e // per_frame,) if e % per_frame == 0 else ())
+        for e in range(traffic.pool_pulses)
+    )
+    n = sum(map(len, entries))
+    kind = np.dtype(dtype)
+    top = np.iinfo(kind).max if kind.kind in "iu" else np.inf
+    frames = np.empty((n, ny, nx), kind)
+    seen = set()
+    for k in range(n):
+        frame = np.minimum(rng.poisson(mean), top)
+        total = int(frame.sum())
+        step = 1 if frame[0, 0] + n < top else -1
+        while total in seen:
+            frame[0, 0] += step
+            total += step
+        seen.add(total)
+        frames[k] = frame
+    return FramePool(frames, entries)

@@ -1,4 +1,4 @@
-"""The two schemas the client side speaks: ev44 out, da00 in.
+"""The schemas the client side speaks: ev44 and ad00 out, da00 in.
 
 Written against the ESS streaming-data-types schemas with the
 ``flatbuffers`` runtime only, so that a change to the program's own
@@ -13,6 +13,8 @@ import flatbuffers
 import numpy as np
 from flatbuffers import number_types as N
 from flatbuffers.table import Table
+
+from .traffic import AD00_DTYPES
 
 #: da00_dtype (da00_dataarray.fbs): none=0, int8..float64, c_string=11.
 _DA00_DTYPES = (
@@ -46,18 +48,39 @@ def encode_ev44(
     return bytes(b.Output())
 
 
-class Ev44Template:
-    """A pre-encoded ev44 message whose id and reference time are
-    patched in place: the pool is encoded once in set-up and cycled
-    with fresh timestamps, so sending a pulse costs one write."""
+def encode_ad00(
+    source_name: str, frame_id: int, timestamp_ns: int, frame: np.ndarray
+) -> bytes:
+    """One ad00 message carrying one frame
+    (``schemas/ad00_area_detector_array.fbs``: source_name, id,
+    timestamp, data_type, dimensions, data). ``data_type`` is ad00's own
+    ``DType`` code (uint16 = 3), not da00's."""
+    frame = np.ascontiguousarray(frame)
+    b = flatbuffers.Builder(frame.nbytes + 1024)
+    data = b.CreateNumpyVector(frame.reshape(-1).view(np.uint8))
+    dims = b.CreateNumpyVector(np.asarray(frame.shape, np.int64))
+    src = b.CreateString(source_name)
+    b.StartObject(6)
+    b.PrependUOffsetTRelativeSlot(0, src, 0)
+    b.PrependInt64Slot(1, frame_id, 0)
+    b.PrependInt64Slot(2, timestamp_ns, 0)
+    b.PrependInt8Slot(3, AD00_DTYPES.index(frame.dtype.name), 0)
+    b.PrependUOffsetTRelativeSlot(4, dims, 0)
+    b.PrependUOffsetTRelativeSlot(5, data, 0)
+    b.Finish(b.EndObject(), file_identifier=b"ad00")
+    return bytes(b.Output())
+
+
+class _Template:
+    """A pre-encoded message whose id and time are patched in place: the
+    pool is encoded once in set-up and cycled with fresh timestamps, so
+    sending a message costs one write."""
 
     _ID_MARK = 0x1122334455667788
     _TIME_MARK = 0x0A0B0C0D0E0F1011
 
-    def __init__(self, source_name: str, toa: np.ndarray, ids: np.ndarray) -> None:
-        self.buf = bytearray(
-            encode_ev44(source_name, self._ID_MARK, self._TIME_MARK, toa, ids)
-        )
+    def __init__(self, encoded: bytes) -> None:
+        self.buf = bytearray(encoded)
         self._id_at = self._find(self._ID_MARK)
         self._time_at = self._find(self._TIME_MARK)
 
@@ -65,13 +88,29 @@ class Ev44Template:
         raw = struct.pack("<q", mark)
         at = self.buf.find(raw)
         if at < 0 or self.buf.find(raw, at + 1) >= 0:
-            raise ValueError("ev44 template: patch point is not unique")
+            raise ValueError(f"{type(self).__name__}: patch point is not unique")
         return at
 
-    def stamp(self, message_id: int, reference_time_ns: int) -> bytearray:
+    def stamp(self, message_id: int, time_ns: int) -> bytearray:
         struct.pack_into("<q", self.buf, self._id_at, message_id)
-        struct.pack_into("<q", self.buf, self._time_at, reference_time_ns)
+        struct.pack_into("<q", self.buf, self._time_at, time_ns)
         return self.buf
+
+
+class Ev44Template(_Template):
+    """An ev44 message of one pulse; ``stamp`` patches its message id and
+    reference time."""
+
+    def __init__(self, source_name: str, toa: np.ndarray, ids: np.ndarray) -> None:
+        super().__init__(encode_ev44(source_name, self._ID_MARK, self._TIME_MARK, toa, ids))
+
+
+class Ad00Template(_Template):
+    """An ad00 message of one frame; ``stamp`` patches its id and
+    timestamp."""
+
+    def __init__(self, source_name: str, frame: np.ndarray) -> None:
+        super().__init__(encode_ad00(source_name, self._ID_MARK, self._TIME_MARK, frame))
 
 
 def _string(tab: Table, slot: int) -> str:

@@ -137,7 +137,7 @@ def test_check_names_each_breach(tmp_path, breach):
 
 
 def test_fixture_adds_files_and_entries_and_edits_nothing(toy_root):
-    """Two configurations, three cells with their limits, three traffic
+    """Three configurations, four cells with their limits, four traffic
     mixes, a prometheus metric and a reference kind arrive as new files
     and new manifest entries; the harness finds them by name with no
     edit to any file that was there."""
@@ -185,6 +185,42 @@ def test_fixture_plugs_a_kind_a_stream_and_outputs_of_its_own(toy_root):
         assert loaded.kinds == {}
         assert reference.check_names(loaded.config, {}) == list(loaded.limits) == [
             "spectrum_bins_wrong", "image_bins_wrong", "prefix_off_pulses"]
+
+
+def test_fixture_plugs_a_camera_stream_and_the_frames_kind(toy_root):
+    """The toy ODIN: the package's ``camera_view`` on the detector
+    service, a camera stream of 64 x 64 uint16 ad00 frames on its own
+    topic, answered by the kind ``frames`` (``references/frames.py``,
+    the benchmark's own, not the fixture's), its checks the kind's own."""
+    cell = manifest.load_cell(toy_root, "toy_odin.toy_camera")
+    assert cell.config["service"] == "detector_data" and cell.config["instrument"] == "odin"
+    (camera,) = cell.config["streams"]
+    assert (camera["kind"], camera["topic"], camera["wire_source"]) == ("camera", "odin_camera", "odin_orca")
+    assert camera["frame_shape"] == [64, 64] and camera["dtype"] == "uint16"
+    assert not {"first_id", "n_pixels", "rate_share"} & set(camera)
+    (job,) = cell.config["jobs"]
+    assert job["workflow"] == ["detector_view", "camera_view"] and job["view"] == {"kind": "frames"}
+    assert list(cell.kinds) == ["frames"] and not (FIXTURE / KINDS / "frames.py").exists()
+    assert reference.check_names(cell.config, cell.kinds) == list(cell.limits) == [
+        "frame_bins_wrong", "prefix_off_pulses"]
+    assert reference.controls(cell.config, cell.kinds) == tuple(
+        f"frames.{name}" for name in ("frame_dropped", "frame_twice", "frame_transposed", "frame_uint8"))
+    assert (cell.traffic.camera_frames_per_pulse, cell.traffic.camera_pulses_per_frame) == (1, 1)
+
+
+def test_the_package_declares_the_fixtures_camera_as_the_configuration_states():
+    import esslivedata_tpu.config.instruments  # noqa: F401 - registers
+    from esslivedata_tpu.config.instrument import instrument_registry
+    from esslivedata_tpu.config.streams import get_stream_mapping
+    from esslivedata_tpu.workflows.area_detector_view import AreaDetectorParams
+
+    config = json.loads((FIXTURE / "configs" / "toy_odin.json").read_text())
+    mapping = get_stream_mapping(instrument_registry["odin"], False)
+    wire = {stream: (key.topic, key.source_name) for key, stream in mapping.area_detectors.items()}
+    for stream in config["streams"]:
+        assert wire[stream["name"]] == (stream["topic"], stream["wire_source"])
+    for job in config["jobs"]:
+        assert set(job["params"]) <= set(AreaDetectorParams.model_fields)
 
 
 def test_unknown_traffic_keys_and_modes_are_refused():

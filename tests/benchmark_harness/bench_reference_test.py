@@ -16,8 +16,8 @@ import tempfile
 import numpy as np
 import pytest
 from bench_support import FIXTURE, KINDS, REPO
-from harness import generator, reference, results, roofline
-from harness.traffic import Traffic, pulse_time_ns
+from harness import bench, generator, manifest, reference, results, roofline
+from harness.traffic import FramePool, Traffic, pulse_time_ns
 
 BENCH = REPO / "benchmark"
 LIMITS = json.loads((BENCH / "limits" / "nmx_panels.paced14.json").read_text())["limits"]
@@ -31,11 +31,17 @@ TOY_LOKI = json.loads((FIXTURE / "configs" / "toy_loki.json").read_text())
 IQ_MIX = Traffic.from_dict(json.loads((FIXTURE / "traffic" / "toy_iq.json").read_text()))
 IQ_LIMITS = json.loads((FIXTURE / "limits" / "toy_loki.toy_iq.json").read_text())["limits"]
 SANS_IQ = reference.load_kind(FIXTURE, "sans_iq")
+TOY_ODIN = json.loads((FIXTURE / "configs" / "toy_odin.json").read_text())
+FRAMES = reference.load_kind(BENCH, "frames")
+CAMERA_KINDS = {"frames": FRAMES}
+CAMERA_MIX = Traffic.from_dict(json.loads((FIXTURE / "traffic" / "toy_camera.json").read_text()))
+CAMERA_LIMITS = json.loads((FIXTURE / "limits" / "toy_odin.toy_camera.json").read_text())["limits"]
 #: name -> (configuration, traffic mix, its reference kinds, the limits of its cell)
 CASES = {
     "dream_banks": (DREAM, MIX, {}, LIMITS),
     "nmx_panels": (NMX, MIX, {}, LIMITS),
     "toy_loki": (TOY_LOKI, IQ_MIX, {"sans_iq": SANS_IQ}, IQ_LIMITS),
+    "toy_odin": (TOY_ODIN, CAMERA_MIX, CAMERA_KINDS, CAMERA_LIMITS),
 }
 
 
@@ -48,56 +54,82 @@ def _sha(*arrays) -> str:
 
 
 PINNED = json.loads((FIXTURE / "pins_parent.json").read_text())
+MANIFEST = json.loads((REPO / "BENCHMARK.json").read_text())
+#: What a configuration's pins hold: its pools, the wire bytes of its first
+#: pulse and of its whole pool, its reference's spectra, arrays and image,
+#: and the roofline's least time; a configuration has those of its outputs.
+PINNED_PARTS = ("pools", "first_pulse", "wire", "spectra", "arrays", "image", "least_seconds")
 
 
 @pytest.fixture(scope="module")
 def pinned_tables():
-    """Pools and references of both accepted configurations at the pins' reduced rate."""
-    mix = Traffic.from_dict({**json.loads((BENCH / "traffic" / "paced14.json").read_text()),
-                             "events_per_pulse": PINNED["events_per_pulse"]})
+    """Pools, generators and references of every accepted configuration,
+    each under its cell's traffic mix at the pins' reduced rate."""
     out = {}
-    for name in PINNED["pins"]:
-        config = CASES[name][0]
+    for name, pins in PINNED["pins"].items():
+        entry = next(c for c in MANIFEST["configs"] if c["name"] == name)
+        config = json.loads((REPO / entry["file"]).read_text())
+        mix = Traffic.from_dict({**json.loads((BENCH / "traffic" / f"{pins['traffic']}.json").read_text()),
+                                 "events_per_pulse": PINNED["events_per_pulse"]})
+        kinds = {job["view"]["kind"]: reference.load_kind(BENCH, job["view"]["kind"])
+                 for job in config["jobs"] if job["view"]["kind"] not in reference.VIEW_KINDS}
         pools = reference.make_pools(config, mix, PINNED["seed"])
-        out[name] = (config, mix, pools, reference.build(config, mix, pools))
+        with tempfile.TemporaryDirectory() as tmp:
+            source = generator.Generator({
+                "seed": PINNED["seed"], "traffic": mix.__dict__,
+                "streams": bench.streams_with_topics(config), "broker_dir": tmp, "log_path": f"{tmp}/log",
+            })
+            source.producer.close()
+        out[name] = (config, source, kinds, reference.build(config, mix, pools, kinds), pools)
     return out
 
 
-@pytest.mark.parametrize("what", ["pools", "first_pulse", "spectra", "image", "least_seconds"])
-@pytest.mark.parametrize("name", sorted(PINNED["pins"]))
+@pytest.mark.parametrize(
+    "name, what",
+    [(name, what) for name in sorted(PINNED["pins"]) for what in PINNED_PARTS if what in PINNED["pins"][name]],
+    ids=lambda value: value,
+)
 def test_nothing_moved_in_the_accepted_configurations(pinned_tables, name, what):
-    """Pools, the generator's first pulse of messages, the reference's
-    tables and the roofline's least time, each equal to what commit
-    7e4ce74 (the parent of the PR that made kinds, streams and outputs
-    pluggable) gave: a stream with no new key yields the bytes it
-    yielded, a detector view the numbers it had."""
-    config, mix, pools, refs = pinned_tables[name]
+    """Pools, the generator's first pulse and whole pool of messages, the
+    reference's tables and the roofline's least time of every accepted
+    configuration, each equal to what the harness gave before camera
+    streams came (NMX and DREAM: since commit 7e4ce74, the parent of the
+    PR that made kinds, streams and outputs pluggable): a stream with no
+    new key yields the bytes it yielded, a view the numbers it had."""
+    config, source, kinds, refs, pools = pinned_tables[name]
     pins = PINNED["pins"][name]
+    outputs = {job["name"]: reference.compared_classes(config, job) for job in config["jobs"]}
     if what == "pools":
         assert {
             stream["name"]: _sha(*[a for pulse in pool for a in pulse], np.asarray(span))
             for stream, (pool, span) in zip(config["streams"], pools)
         } == pins["pools"]
     elif what == "first_pulse":
-        with tempfile.TemporaryDirectory() as tmp:
-            source = generator.Generator({
-                "seed": PINNED["seed"], "traffic": mix.__dict__,
-                "streams": [{"topic": config["detector_topic"], **s} for s in config["streams"]],
-                "broker_dir": tmp, "log_path": f"{tmp}/log",
-            })
-            source.producer.close()
-        assert {topic for topic, _ in source.templates[0]} == {config["detector_topic"]}
+        assert {topic for topic, _ in source.templates[0]} == {
+            s["topic"] for s in bench.streams_with_topics(config)}
         digest = hashlib.sha256()
         for index, (_topic, template) in enumerate(source.templates[0]):
             digest.update(bytes(template.stamp(index, pulse_time_ns(1000))))
         assert digest.hexdigest() == pins["first_pulse"]
-        assert source.messages_per_pulse == pins["messages_per_pulse"]
+        assert {len(messages) for messages in source.templates} == {pins["messages_per_pulse"]}
+    elif what == "wire":
+        digest = hashlib.sha256()
+        for entry, messages in enumerate(source.templates):
+            for index, (topic, template) in enumerate(messages):
+                digest.update(topic.encode() + bytes(template.stamp(index, pulse_time_ns(1000 + entry))))
+        assert digest.hexdigest() == pins["wire"]
     elif what == "spectra":
         assert {
             job: _sha(ref.expected("spectrum_cumulative", 0, 27),
                       ref.expected("spectrum_current", 14, 28), ref.per_pulse)
-            for job, ref in refs.items()
+            for job, ref in refs.items() if "spectrum_cumulative" in outputs[job]
         } == pins["spectra"]
+    elif what == "arrays":
+        assert {
+            job: _sha(*[ref.expected(o, *ref.span(o, 14, 28))
+                        for o, c in outputs[job].items() if c == "arrays"], ref.per_pulse)
+            for job, ref in refs.items() if "arrays" in outputs[job].values()
+        } == pins["arrays"]
     elif what == "image":
         assert {job: _sha(refs[job].expected("image_current", 3, 30))
                 for job in pins["image"]} == pins["image"]
@@ -106,13 +138,27 @@ def test_nothing_moved_in_the_accepted_configurations(pinned_tables, name, what)
         assert json.loads((FIXTURE / "trace_dream_ticks.json").read_text())["expected"]["batches"] == batches
         jobs = [j["name"] for j in config["jobs"]]
         least = roofline.least_seconds(config, dict.fromkeys(jobs, batches * 14 * 229376),
-                                       dict.fromkeys(jobs, batches), "TPU v5 lite")
+                                       dict.fromkeys(jobs, batches), "TPU v5 lite", kinds)
         assert repr(least) == pins["least_seconds"]
 
 
+def test_the_pins_are_of_accepted_configurations_under_their_cells_traffic():
+    """Six, taken on the parent of the PR that added camera streams; a
+    configuration added later brings no pins and is not asked for any."""
+    assert len(PINNED["pins"]) == 6
+    for name, pins in PINNED["pins"].items():
+        assert any(w["config"] == name and w["traffic"] == pins["traffic"] for w in MANIFEST["workloads"])
+
+
 def test_every_cell_has_the_same_exact_limits():
+    """Every cell's limits are its comparison's checks, each at NMX's
+    limit where NMX has that check and exact (0) where it is another
+    kind's count (a camera's ``frame_bins_wrong``), so that a cell that
+    adds a camera brings its limits as a file."""
     for path in (BENCH / "limits").glob("*.json"):
-        assert json.loads(path.read_text())["limits"] == LIMITS
+        cell = manifest.load_cell(REPO, path.stem)
+        checks = reference.check_names(cell.config, cell.kinds)
+        assert json.loads(path.read_text())["limits"] == {name: LIMITS.get(name, 0) for name in checks}
 
 
 def test_limits_are_exact_where_the_outputs_are():
@@ -162,7 +208,7 @@ def test_streams_and_topics_mirror_the_packages():
         assert config["state_bytes"] == reckoned
 
 
-def _refs(config, seed=3, mix=MIX, kinds=None):
+def _refs(config, seed=3, mix=MIX, kinds=CAMERA_KINDS):
     pools = reference.make_pools(config, mix, seed)
     return pools, reference.build(config, mix, pools, kinds)
 
@@ -271,7 +317,10 @@ def test_the_reference_in_the_programs_place_is_correct(case, seed):
     assert [p.prefix for p in publishes[config["jobs"][0]["name"]]] == PREFIXES
     assert list(numbers)[: len(limits)] == list(limits)  # the checks in the limits' order
     assert all(numbers[k]["value"] <= limits[k] for k in limits)
-    if kinds:
+    if config is TOY_ODIN:  # two frame sums a publish, each bin exact
+        assert numbers["compared"] == {"spectra": 0, "images": 0, "arrays": 2 * 12}
+        assert set(numbers["frame_bins_wrong"]) == {"value", "limit"}
+    elif kinds:
         assert numbers["compared"] == {"spectra": 0, "images": 0, "arrays": 4 * 12}
         assert set(numbers["iq_bins_off"]) == {"value", "limit", "tolerance", "worst_share", "reason"}
         assert 0 < numbers["iq_bins_off"]["worst_share"] <= 0.25  # one float32 rounding of the room of four
@@ -290,11 +339,11 @@ def _lower_precision():
     return bfloat16
 
 
-#: (case, fault): every case under the pools' faults; the toy LOKI under its kind's own and,
-#: since its I(Q) is a float32 quotient, computed in the precision below.
+#: (case, fault): every case under the faults ``control.py`` runs for it (the pools' where it
+#: has a detector stream, its kinds' own); the toy LOKI, since its I(Q) is a float32 quotient,
+#: also computed in the precision below.
 CONTROLS = [
-    *((case, fault) for case in sorted(CASES) for fault in reference.FAULTS),
-    *(("toy_loki", f"sans_iq.{fault}") for fault in SANS_IQ.faults()),
+    *((case, fault) for case in sorted(CASES) for fault in reference.controls(CASES[case][0], CASES[case][2])),
     ("toy_loki", "lower_precision"),
 ]
 
@@ -319,7 +368,9 @@ def test_the_control_comes_out_as_not_correct(case, fault, seed):
     numbers, wrong = results.compare(publishes, refs, limits, _outputs(config))
     over = [k for k in limits if numbers[k]["value"] > limits[k]]
     assert over and wrong > 0, numbers
-    if not kinds:
+    if config is TOY_ODIN:
+        assert numbers["frame_bins_wrong"]["value"] >= 1
+    elif not kinds:
         assert numbers["spectrum_bins_wrong"]["value"] >= 1
     elif fault == "lower_precision":
         assert over == ["iq_bins_off"] and numbers["iq_bins_off"]["worst_share"] > 2**9
@@ -436,3 +487,128 @@ def test_pulses_offered_and_never_published_count_with_their_age_at_the_drains_e
     assert sorted(round(fresh) for _, fresh in pairs) == sorted(
         [160 - 130, 900 - 270, 900 - 410, 900 - 440, 900 - 130, 900 - 270, 900 - 410, 900 - 440]
     )
+
+
+#: A camera stream beside the toy panel: what a configuration of an instrument that
+#: has both (ODIN: the Timepix3 and the Orca) gives the harness.
+TOY_PANEL = json.loads((FIXTURE / "configs" / "toy_panel.json").read_text())
+CAMERA = {"name": "orca", "kind": "camera", "wire_source": "odin_orca", "topic": "odin_camera",
+          "frame_shape": [6, 10], "dtype": "uint16"}
+FRAMES_JOB = {"name": "camera", "workflow": ["detector_view", "camera_view"], "job_source": "orca",
+              "stream": "orca", "params": {}, "outputs": {"arrays": ["current", "cumulative"]},
+              "prefix_total": "cumulative", "view": {"kind": "frames"}}
+BOTH = {**TOY_PANEL, "streams": [*TOY_PANEL["streams"], CAMERA], "jobs": [*TOY_PANEL["jobs"], FRAMES_JOB]}
+PANEL_MIX = Traffic.from_dict(json.loads((FIXTURE / "traffic" / "toy_blob.json").read_text()))
+
+
+@pytest.mark.parametrize("camera", [{}, {"camera_frames_per_pulse": 3}, {"camera_pulses_per_frame": 5}])
+def test_a_camera_stream_leaves_an_event_streams_expected_outputs_untouched(camera):
+    """The panel's pool, its reference's spectra and images and the pools'
+    faults are what they are without the camera; the frames job reads
+    the camera's frames alone, and the pools' faults leave them as they are."""
+    mix = Traffic.from_dict({**PANEL_MIX.__dict__, **camera})
+    alone_pools, alone = _refs(TOY_PANEL, 7, mix)
+    pools, refs = _refs(BOTH, 7, mix)
+    assert _sha(*[a for pulse in pools[0][0] for a in pulse]) == _sha(*[a for pulse in alone_pools[0][0] for a in pulse])
+    for lo, hi in ((0, 14), (3, 30), (14, 28)):
+        for output in ("spectrum_current", "image_current"):
+            assert np.array_equal(refs["panel_view"].expected(output, lo, hi),
+                                  alone["panel_view"].expected(output, lo, hi))
+    frames = pools[1][0]
+    assert isinstance(frames, FramePool) and pools[1][1] == (0, 0)
+    window = sum(f.astype(np.float64) for pulse in range(14, 28) for f in frames[pulse % len(frames)])
+    assert np.array_equal(refs["camera"].expected("current", 14, 28), window)
+    for fault in reference.FAULTS:
+        broken = reference.break_guarantee(pools, fault)
+        assert broken[1][0] is frames
+        assert not np.array_equal(
+            reference.build(BOTH, mix, broken, CAMERA_KINDS)["panel_view"].expected("spectrum_current", 0, 14),
+            refs["panel_view"].expected("spectrum_current", 0, 14))
+    assert reference.controls(BOTH, CAMERA_KINDS) == (
+        *reference.FAULTS, "frames.frame_dropped", "frames.frame_twice", "frames.frame_transposed",
+        "frames.frame_uint8")
+    assert reference.controls(TOY_ODIN, CAMERA_KINDS) == (
+        reference.controls(BOTH, CAMERA_KINDS)[len(reference.FAULTS):])
+
+
+@pytest.mark.parametrize("params", [{}, {"transpose": True}, {"flip_y": True, "flip_x": True},
+                                    {"transpose": True, "flip_y": True, "flip_x": True}])
+def test_the_frames_reference_equals_a_loop_over_frames(params):
+    """``current`` sums the frames since the previous publish and
+    ``cumulative`` all of them, frame by frame as the generator sends them,
+    as the camera view shows them: transposed, then flipped in y, then in x."""
+    mix = Traffic.from_dict({**CAMERA_MIX.__dict__, "camera_frames_per_pulse": 2})
+    config = {**BOTH, "jobs": [{**FRAMES_JOB, "params": params}]}
+    pools, refs = _refs(config, 9, mix)
+    ref, frames = refs["camera"], pools[1][0]
+    assert [len(frames[e]) for e in range(len(frames))] == [2] * mix.pool_pulses
+
+    def loop(lo, hi):
+        image = np.zeros((6, 10), np.int64)
+        for pulse in range(lo, hi):
+            for frame in frames[pulse % mix.pool_pulses]:
+                image += frame
+        if params.get("transpose"):
+            image = image.T
+        if params.get("flip_y"):
+            image = image[::-1]
+        if params.get("flip_x"):
+            image = image[:, ::-1]
+        return image
+
+    for previous, prefix in ((0, 14), (14, 28), (28, 37)):
+        assert ref.span("current", previous, prefix) == (previous, prefix)
+        assert ref.span("cumulative", previous, prefix) == (0, prefix)
+        assert np.array_equal(ref.expected("current", previous, prefix), loop(previous, prefix))
+        assert np.array_equal(ref.expected("cumulative", 0, prefix), loop(0, prefix))
+        assert ref.counts(0, prefix) == loop(0, prefix).sum()
+    with pytest.raises(KeyError):
+        ref.expected("image_current", 0, 14)
+
+
+@pytest.mark.parametrize("camera", [{}, {"camera_frames_per_pulse": 2}, {"camera_pulses_per_frame": 5}])
+def test_prefix_of_recovers_every_prefix_of_a_cycled_frame_pool(camera):
+    """Every frame's total differs from every other's, so the sum of a
+    publish's cumulative finds its pulse prefix over several turns of the
+    pool; where a camera skips pulses, the prefix found holds the same
+    frames as the one summed (it ends at the last frame's pulse)."""
+    mix = Traffic.from_dict({**CAMERA_MIX.__dict__, **camera})
+    pools, refs = _refs(TOY_ODIN, 11, mix)
+    ref, frames = refs["camera"], pools[0][0]
+    totals = [int(f.sum(dtype=np.int64)) for f in frames.frames]
+    assert len(set(totals)) == len(totals) == len(frames.frames)
+    for n in range(1, 4 * mix.pool_pulses + 3):
+        total = float(ref.expected("cumulative", 0, n).sum())
+        prefix, off = ref.prefix_of(total, 200)
+        assert off == 0.0 and ref.counts(0, prefix) == total
+        if mix.camera_pulses_per_frame == 1:
+            assert prefix == n
+        else:
+            assert prefix <= n and (prefix - 1) % mix.camera_pulses_per_frame == 0
+
+
+def test_the_frames_kinds_least_bytes_join_the_one_roofline():
+    """Per frame its bytes in (2 B a pixel of uint16) and 16 B a pixel for
+    the two states, per publish the fold's four passes and the two
+    images fetched: by hand for 64 x 64 pixels, 140 frames, 10 publishes."""
+    job, pixels = TOY_ODIN["jobs"][0], 64 * 64
+    by_hand = 140 * (2 * pixels + 16 * pixels) + 10 * (16 * pixels + 8 * pixels)
+    assert FRAMES.work_bytes(job, TOY_ODIN, 140, 10) == by_hand == 11_304_960
+    assert roofline.least_seconds(TOY_ODIN, {"camera": 140}, {"camera": 10}, "TPU v5 lite",
+                                  CAMERA_KINDS) == pytest.approx(by_hand / 819e9)
+    assert reference.check_names(TOY_ODIN, CAMERA_KINDS) == ["frame_bins_wrong", "prefix_off_pulses"]
+
+
+@pytest.mark.parametrize("fault", sorted(FRAMES.faults()))
+def test_each_frames_fault_breaks_one_frame_of_the_pools_first_pulse(fault):
+    mix = Traffic.from_dict({**CAMERA_MIX.__dict__, "camera_frames_per_pulse": 2})
+    pools, refs = _refs(TOY_ODIN, 13, mix)
+    broken = reference.build(TOY_ODIN, mix, pools, CAMERA_KINDS, f"frames.{fault}")["camera"]
+    first = pools[0][0][0][0].astype(np.float64)
+    moved = broken.expected("current", 0, 1) - refs["camera"].expected("current", 0, 1)
+    want = {"frame_dropped": -first, "frame_twice": first, "frame_transposed": first.T - first,
+            "frame_uint8": first % 256 - first}[fault]
+    assert np.array_equal(moved, FRAMES.shown(want, TOY_ODIN["jobs"][0]["params"]))
+    assert np.count_nonzero(moved) > 0.9 * moved.size or fault == "frame_uint8"
+    assert np.count_nonzero(moved) > 0.1 * moved.size  # the beam spot, an eighth of it, passes a byte
+    assert np.array_equal(broken.expected("current", 1, 5), refs["camera"].expected("current", 1, 5))

@@ -157,6 +157,37 @@ def test_a_kind_a_monitor_stream_and_float_outputs_run_as_files(toy_root):
     assert line["pulses"]["sent"] > 14 * SECONDS
 
 
+def test_a_camera_cell_runs_as_files_and_each_frames_fault_is_caught(toy_root):
+    """The toy ODIN cell, with no edit to any file under ``benchmark/``:
+    the package's ``camera_view`` on the detector service fed 64 x 64
+    uint16 ad00 frames, correct, every pair's freshness found from the
+    sum of the job's cumulative image; and ``control.py``'s four faults
+    of the kind ``frames`` (a frame dropped, counted twice, transposed,
+    truncated to uint8), each put in the program's place, not correct."""
+    cell = manifest.load_cell(toy_root, "toy_odin.toy_camera")
+    controls = _control().controls_of(cell)
+    assert controls == ("frames.frame_dropped", "frames.frame_twice", "frames.frame_transposed",
+                        "frames.frame_uint8")
+    line, report = run(toy_root, "toy_odin.toy_camera", 2**31 + 41, controls=controls)
+    shape_of_a_result(line, cell, trace=False)
+    assert line["correct"] is True and line["failed"] == 0
+    assert line["attempted"] == int(SECONDS * 14) // 14
+    assert 71.0 < line["metrics"]["freshness_p50_ms"]["value"] < 2000.0
+    assert len(line["pulses"]["pairs"]) in (int(SECONDS) - 1, int(SECONDS), int(SECONDS) + 1)
+    checks = line["checks"]
+    assert list(checks)[:2] == ["frame_bins_wrong", "prefix_off_pulses"]
+    assert checks["frame_bins_wrong"] == {"value": 0, "limit": 0}
+    assert checks["prefix_off_pulses"]["value"] == 0.0  # float64 sums of whole counts
+    assert checks["compared"] == {"spectra": 0, "images": 0, "arrays": 2 * line["pulses"]["publishes"]["camera"]}
+    # a pulse is one ad00 frame of 64 x 64 x 2 B and its framing
+    assert line["pulses"]["bytes"] > line["pulses"]["sent"] * 64 * 64 * 2
+    assert any(text.startswith("check frame_bins_wrong: ") for text in report)
+    assert list(line["controls"]) == list(controls)
+    for fault, reading in line["controls"].items():
+        assert reading["correct"] is False and reading["failed"] > 0, fault
+        assert reading["frame_bins_wrong"] > 0, fault
+
+
 def test_aux_source_names_go_on_the_wire_and_default_to_none():
     loki = json.loads((FIXTURE / "configs" / "toy_loki.json").read_text())["jobs"][0]
     sent = json.loads(service.start_command("loki", loki, "n-1"))
@@ -178,13 +209,14 @@ def test_aux_source_names_go_on_the_wire_and_default_to_none():
         ("state_unchanged", "toy_panel.toy_paced"),
         ("half_batch", "toy_panel.toy_blob"),
         ("half_batch", "toy_loki.toy_iq"),
+        ("altered_frame", "toy_odin.toy_camera"),
     ],
 )
 def test_a_fault_under_the_timed_path_makes_correct_false(toy_root, monkeypatch, fault, cell_name):
     """The rest of a run as it is, the service broken underneath: an
     answer altered where it is produced, half of every batch left out
     (of the monitor's too, where there is one), a step that returns its
-    state unchanged."""
+    state unchanged, a camera frame altered where it is decoded."""
     monkeypatch.setenv("BENCH_TEST_FAULT", fault)
     monkeypatch.setenv("BENCH_TEST_SERVICE", manifest.load_cell(toy_root, cell_name).config["service"])
     monkeypatch.setenv("PYTHONPATH", str(FIXTURE.parent))

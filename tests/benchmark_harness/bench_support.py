@@ -91,7 +91,23 @@ PLUGS = {
     "a job on a stream the configuration lacks": (
         _edit_toy_loki(lambda d: d["jobs"][0].update(stream="rear")), "no stream 'rear'"),
     "a stream of an unknown kind": (
-        _edit_toy_loki(lambda d: d["streams"][1].update(kind="camera")), "kind 'camera'"),
+        _edit_toy_loki(lambda d: d["streams"][1].update(kind="hologram")), "kind 'hologram'"),
+    "a camera stream that lacks a key": (
+        _edit_toy_loki(lambda d: d["streams"].append(
+            {"name": "orca", "kind": "camera", "wire_source": "loki_orca", "topic": "loki_camera",
+             "frame_shape": [8, 8]})),
+        "camera stream orca lacks 'dtype'",
+    ),
+    "a camera stream of a type ad00 has not": (
+        _edit_toy_loki(lambda d: d["streams"].append(
+            {"name": "orca", "kind": "camera", "wire_source": "loki_orca", "topic": "loki_camera",
+             "frame_shape": [8, 8], "dtype": "float16"})),
+        "dtype 'float16' is no ad00 type",
+    ),
+    "a camera view of an event stream": (
+        _edit_toy_loki(lambda d: d["jobs"][0]["view"].update(kind="frames")),
+        "a view of kind 'frames' on the detector stream",
+    ),
     "a view kind that is no name": (
         _edit_toy_loki(lambda d: d["jobs"][0]["view"].update(kind="../sans_iq")), "is not a name"),
 }

@@ -9,6 +9,7 @@ configurations state, where the program produces the answer:
 ``half_batch``       every ev44 message loses the second half of its events.
 ``state_unchanged``  the tick's step returns its state as it got it.
 ``altered_answer``   one bin of every fetched result is off by one.
+``altered_frame``    one pixel of every ad00 frame is off by one where it is decoded.
 """
 
 from __future__ import annotations
@@ -53,6 +54,18 @@ def plant(fault: str) -> None:
             return out
 
         jax.device_get = altered
+    elif fault == "altered_frame":
+        from esslivedata_tpu.kafka import wire
+
+        sound_ad00 = wire.decode_ad00
+
+        def altered_frame(buf):
+            image = sound_ad00(buf)
+            data = np.array(image.data)
+            data[0, 0] += 1
+            return dataclasses.replace(image, data=data)
+
+        wire.decode_ad00 = altered_frame
     else:
         raise SystemExit(f"faulty_service: unknown fault {fault!r}")
 
