@@ -8,7 +8,7 @@ files in a shared directory:
 
     <root>/<topic>.log     frames of [key_len u32][value_len u32][key][value]
 
-Appends happen under an exclusive ``flock`` and as a single ``write`` so
+Appends happen under an exclusive ``flock`` and as one ``writev`` so
 concurrent producers interleave only at frame boundaries; consumers track
 a *byte* offset per topic and only surface complete frames, so a reader
 racing a writer sees the prefix. Offsets double as Kafka watermarks
@@ -96,6 +96,19 @@ class FileMessage:
         return None
 
 
+def _write_all(fd: int, parts: list[bytes]) -> None:
+    """Write ``parts`` one after another with ``writev``, going on after
+    a short write until every byte is out."""
+    views = [memoryview(part) for part in parts if len(part)]
+    while views:
+        written = os.writev(fd, views)
+        while views and written >= len(views[0]):
+            written -= len(views[0])
+            views.pop(0)
+        if written:
+            views[0] = views[0][written:]
+
+
 class FileBrokerProducer:
     def __init__(self, root: str | Path) -> None:
         self._root = Path(root)
@@ -104,17 +117,16 @@ class FileBrokerProducer:
     def produce(self, topic: str, value: bytes, key=None) -> None:
         if isinstance(key, str):
             key = key.encode()
-        frame = (
-            _HEADER.pack(len(key or b""), len(value))
-            + (key or b"")
-            + value
-        )
+        key = key or b""
+        # The value is written from where it is, not concatenated behind
+        # the header first: for a camera image of 16 MiB that copy was a
+        # fresh allocation of its size, faulted in page by page.
+        parts = [_HEADER.pack(len(key), len(value)) + key, value]
         path = _topic_path(self._root, topic)
-        with open(path, "ab") as f:
+        with open(path, "ab", buffering=0) as f:
             fcntl.flock(f.fileno(), fcntl.LOCK_EX)
             try:
-                f.write(frame)
-                f.flush()
+                _write_all(f.fileno(), parts)
             finally:
                 fcntl.flock(f.fileno(), fcntl.LOCK_UN)
 

@@ -860,9 +860,7 @@ def _encode_da00_native(
     dims_count = np.empty(n, np.int32)
     axes_flat: list[int] = []
     shapes_flat: list[int] = []
-    data_parts: list[bytes] = []
-    data_offs = np.empty(n + 1, np.int64)
-    data_offs[0] = 0
+    data_parts: list[np.ndarray] = []
     for i, var in enumerate(variables):
         shape = np.asarray(var.data).shape
         data = np.ascontiguousarray(var.data)
@@ -878,12 +876,9 @@ def _encode_da00_native(
         dims_start[i] = len(shapes_flat)
         dims_count[i] = len(shape)
         shapes_flat.extend(int(s) for s in shape)
-        # Encode side, per VARIABLE not per message: the native builder
-        # needs one contiguous serialization of each payload to splice
-        # into the flatbuffer — there is no zero-copy alternative here.
-        raw = data.tobytes()  # graftlint: disable=JGL028
-        data_parts.append(raw)
-        data_offs[i + 1] = data_offs[i] + len(raw)
+        # The payload's bytes where they are: the native builder copies
+        # each into the message once.
+        data_parts.append(data.reshape(-1).view(np.uint8))
     return da00_encode_raw(
         b"".join(strings),
         np.asarray(offs, np.int64),
@@ -900,8 +895,7 @@ def _encode_da00_native(
         dims_start,
         dims_count,
         np.asarray(shapes_flat, np.int64),
-        data_offs,
-        b"".join(data_parts),
+        data_parts,
     )
 
 

@@ -187,7 +187,7 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
         i8p,                       # dtype codes
         i32p, i32p, i32p,          # axes start/count/flat idx
         i32p, i32p, i64p,          # dims start/count, shapes flat
-        i64p, u8p,                 # data offsets, data blob
+        ctypes.POINTER(ctypes.c_void_p), i64p,  # data pointers, lengths
         u8p, i64,                  # out, cap
     ]
     return lib
@@ -250,25 +250,38 @@ def da00_encode_raw(
     dims_start: np.ndarray,
     dims_count: np.ndarray,
     shapes_flat: np.ndarray,
-    data_offs: np.ndarray,
-    data_blob: bytes,
+    data_parts: list[np.ndarray],
 ) -> bytes | None:
     """Raw interface to the native da00 serializer (da00_encode.cpp);
     marshalling from Da00Variable lives in kafka/wire.py which owns the
-    dtype table. None = library unavailable; raises on invalid input."""
+    dtype table. ``data_parts`` holds each variable's bytes as a
+    C-contiguous 1-D uint8 array (what ``wire._encode_da00_native``
+    makes), read where it is. None = library unavailable; raises on
+    invalid input.
+
+    The message is built at the end of the output buffer and copied out
+    once from there: a camera image of 16 MiB costs two copies, where a
+    concatenation first and a move to the buffer's front cost two more."""
     lib = load_library()
     if lib is None:
         return None
     i32p = ctypes.POINTER(ctypes.c_int32)
     i64p = ctypes.POINTER(ctypes.c_int64)
     i8p = ctypes.POINTER(ctypes.c_int8)
+    u8p_t = ctypes.POINTER(ctypes.c_uint8)
 
     def p(arr, ptr_type):
         return arr.ctypes.data_as(ptr_type)
 
     n_vars = int(name_idx.size)
-    cap = len(data_blob) + len(strings_blob) + 4096 + 160 * max(n_vars, 1)
-    u8p_t = ctypes.POINTER(ctypes.c_uint8)
+    if len(data_parts) != n_vars:
+        raise ValueError(f"{len(data_parts)} data parts for {n_vars} variables")
+    data_ptrs = (ctypes.c_void_p * max(n_vars, 1))(
+        *[part.ctypes.data for part in data_parts]
+    )
+    lens = [part.nbytes for part in data_parts]
+    data_lens = np.array(lens, np.int64)
+    cap = sum(lens) + len(strings_blob) + 4096 + 160 * max(n_vars, 1)
     for _ in range(3):
         out = np.empty(cap, np.uint8)  # no zero fill (create_string_buffer's)
         rc = lib.ld_da00_encode(
@@ -289,13 +302,13 @@ def da00_encode_raw(
             p(dims_start, i32p),
             p(dims_count, i32p),
             p(shapes_flat, i64p),
-            p(data_offs, i64p),
-            _as_u8p(data_blob),
-            out.ctypes.data_as(u8p_t),
+            data_ptrs,
+            p(data_lens, i64p),
+            p(out, u8p_t),
             cap,
         )
         if rc >= 0:
-            return out[: int(rc)].tobytes()
+            return out[cap - int(rc) :].tobytes()
         if rc == -1:
             cap *= 4
             continue

@@ -183,7 +183,11 @@ extern "C" {
 // (caller retries with a bigger buffer), or -2 on invalid input.
 //
 // String table: strings_blob with n_strs+1 offsets; indices reference
-// it. label_idx/source_idx entries of -1 omit the slot.
+// it. label_idx/source_idx entries of -1 omit the slot. Variable i's
+// data is data_lens[i] bytes at data_ptrs[i], read where the caller
+// holds it: no concatenation first. The message is built from the END
+// of out: the return value n is its length, and it is out[out_cap - n,
+// out_cap) (no move to the front).
 int64_t ld_da00_encode(
     const uint8_t* strings_blob, const int64_t* str_offs, int32_t n_strs,
     int32_t source_name_idx, int64_t timestamp, int32_t n_vars,
@@ -194,7 +198,7 @@ int64_t ld_da00_encode(
     const int32_t* axes_idx_flat,
     const int32_t* dims_start, const int32_t* dims_count,
     const int64_t* shapes_flat,
-    const int64_t* data_offs, const uint8_t* data_blob,
+    const uint8_t* const* data_ptrs, const int64_t* data_lens,
     uint8_t* out, int64_t out_cap) {
     if (n_vars < 0 || source_name_idx < 0 || source_name_idx >= n_strs)
         return -2;
@@ -210,7 +214,7 @@ int64_t ld_da00_encode(
     std::vector<int64_t> var_offs(static_cast<size_t>(n_vars));
     for (int32_t i = 0; i < n_vars; ++i) {
         // Mirror _encode_da00_variable's write order exactly.
-        int64_t data_len = data_offs[i + 1] - data_offs[i];
+        int64_t data_len = data_lens[i];
         int64_t data_off;
         if (data_len == 0) {
             b.start_vector(1, 0, 1);
@@ -218,7 +222,7 @@ int64_t ld_da00_encode(
         } else {
             b.prep(4, data_len);
             b.prep(1, data_len);
-            b.place_bytes(data_blob + data_offs[i], data_len);
+            b.place_bytes(data_ptrs[i], data_len);
             data_off = b.end_vector(data_len);
         }
         int64_t shape_off = 0;
@@ -291,9 +295,7 @@ int64_t ld_da00_encode(
     b.prepend_uoffset(root_off);
     if (b.overflow) return -1;
 
-    int64_t n = b.cap - b.head;
-    std::memmove(out, out + b.head, static_cast<size_t>(n));
-    return n;
+    return b.cap - b.head;
 }
 
 }  // extern "C"

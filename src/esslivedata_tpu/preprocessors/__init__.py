@@ -7,13 +7,15 @@ pre-groups them by pixel (GroupByPixel), here events are only *staged* into
 fixed-shape padded device batches (``ToEventBatch``) — projection, grouping
 and binning all happen inside the jitted scatter kernel (ops/histogram.py).
 Dense accumulators (Cumulative, LatestValue, ToNXlog) remain host-side over
-labeled DataArrays.
+labeled DataArrays; a camera's frames are kept one by one (WindowFrames)
+for the view that sums them on the device.
 """
 
 from .accumulators import (
     Cumulative,
     LatestValueAccumulator,
     NullAccumulator,
+    WindowFrames,
 )
 from .event_data import DetectorEvents, MonitorEvents, StagedEvents, ToEventBatch
 from .to_nxlog import LogData, ToNXlog
@@ -28,4 +30,5 @@ __all__ = [
     "StagedEvents",
     "ToEventBatch",
     "ToNXlog",
+    "WindowFrames",
 ]

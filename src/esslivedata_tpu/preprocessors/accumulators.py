@@ -9,26 +9,24 @@ construction*: large histograms are device-resident kernel state with
 fold semantics (ops/histogram.py — window and cumulative share one
 scatter, reads are device views), and host-side accumulators only ever
 hold the small dense outputs. ``Cumulative`` therefore defaults to
-no-copy reads; ``WindowedCumulative`` provides the paired
-window/cumulative semantics for dense streams that never touch the
-accelerator, staying aliasing-safe by transferring window ownership on
-``take`` and copying the cumulative.
+no-copy reads. A camera's ad00 frames are not summed here at all:
+``WindowFrames`` keeps the window's frames as they were decoded, and
+the view that reads them sums them on the device in a wider type
+(``ops/frames.py``, ADR 0132).
 """
 
 from __future__ import annotations
 
 from typing import ClassVar
 
-import numpy as np
-
 from ..core.timestamp import Timestamp
-from ..utils.labeled import DataArray, Variable
+from ..utils.labeled import DataArray
 
 __all__ = [
     "Cumulative",
     "LatestValueAccumulator",
     "NullAccumulator",
-    "WindowedCumulative",
+    "WindowFrames",
 ]
 
 
@@ -146,63 +144,32 @@ class Cumulative:
         pass
 
 
-def _zero_like(da: DataArray) -> DataArray:
-    out = da.copy()
-    out.data = Variable(
-        np.zeros_like(np.asarray(da.values)), da.dims, da.unit
-    )
-    return out
+class WindowFrames:
+    """The window's ad00 frames in order of arrival, none summed.
 
-
-class WindowedCumulative:
-    """Paired window/cumulative views of one dense stream.
-
-    One ``add`` feeds both views; ``take`` returns ``(window,
-    cumulative)`` and resets the window while the cumulative persists —
-    the host-side analog of the device kernel's fold semantics
-    (docs/design/fold-semantics.md), for the non-event streams that
-    never touch the accelerator: da00 camera frames, rebinned monitor
-    histograms, dense log aggregates.
-
-    Composed from two :class:`Cumulative` instances so restart-on-
-    mismatch semantics live in exactly one place. Incoming samples are
-    unit-aligned to the cumulative before feeding the window: a window
-    restarting just after ``take`` must not adopt a new compatible unit
-    while the cumulative keeps converting into its original one — both
-    views of one stream always share a unit.
+    Summed here in the frame's own type, as :class:`Cumulative` would, a
+    16-bit camera wraps past 65 535 counts a pixel a window. The view
+    that reads the stream sums the frames itself, on the device and in
+    uint32 (``workflows/area_detector_view.py``, ADR 0132), so this
+    keeps each frame as the decoder gave it: a read-only view of its
+    message, which nothing overwrites, so nothing is copied. ``get``
+    hands the window's list over and starts the next.
     """
 
     is_context: ClassVar[bool] = False
 
     def __init__(self) -> None:
-        self._cumulative = Cumulative(copy_on_get=True)
-        self._window = Cumulative(clear_on_get=True)
+        self._frames: list[DataArray] = []
 
     def add(self, timestamp: Timestamp, data: DataArray) -> None:
-        self._cumulative.add(timestamp, data)
-        anchor = self._cumulative.current
-        if anchor is not None and anchor.unit != data.unit:
-            data = data.to_unit(anchor.unit)
-        self._window.add(timestamp, data)
+        self._frames.append(data)
 
-    @property
-    def is_empty(self) -> bool:
-        return self._cumulative.is_empty
-
-    def take(self) -> tuple[DataArray, DataArray]:
-        """(window, cumulative); the window transfers ownership and
-        resets, the cumulative is a defensive copy."""
-        cumulative = self._cumulative.get()
-        if self._window.is_empty:
-            window = _zero_like(cumulative)
-        else:
-            window = self._window.get()
-        return window, cumulative
+    def get(self) -> list[DataArray]:
+        frames, self._frames = self._frames, []
+        return frames
 
     def clear(self) -> None:
-        self._window.clear()
-        self._cumulative.clear()
+        self._frames = []
 
     def release_buffers(self) -> None:
         pass
-

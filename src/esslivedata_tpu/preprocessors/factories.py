@@ -9,7 +9,7 @@ preprocessor (the service consumes but ignores them).
 from __future__ import annotations
 
 from ..core.message import StreamId, StreamKind
-from .accumulators import Cumulative, LatestValueAccumulator
+from .accumulators import Cumulative, LatestValueAccumulator, WindowFrames
 from .event_data import ToEventBatch
 from .to_nxlog import ToNXlog
 
@@ -26,6 +26,8 @@ class _KindBasedFactory:
 
     event_kinds: frozenset[StreamKind] = frozenset()
     dense_kinds: frozenset[StreamKind] = frozenset()
+    #: Camera frames: kept one by one for the view to sum (ADR 0132).
+    frame_kinds: frozenset[StreamKind] = frozenset()
     log_kinds: frozenset[StreamKind] = frozenset()
     latest_kinds: frozenset[StreamKind] = frozenset(
         {StreamKind.LIVEDATA_ROI, StreamKind.DEVICE}
@@ -40,6 +42,8 @@ class _KindBasedFactory:
             return ToEventBatch(min_bucket=self._min_bucket)
         if kind in self.dense_kinds:
             return Cumulative(clear_on_get=True)
+        if kind in self.frame_kinds:
+            return WindowFrames()
         if kind in self.log_kinds:
             return ToNXlog(name=stream.name)
         if kind in self.latest_kinds:
@@ -51,7 +55,7 @@ class DetectorPreprocessorFactory(_KindBasedFactory):
     """Detector service: ev44 events, ad00 frames, ROI, logs as context."""
 
     event_kinds = frozenset({StreamKind.DETECTOR_EVENTS})
-    dense_kinds = frozenset({StreamKind.AREA_DETECTOR})
+    frame_kinds = frozenset({StreamKind.AREA_DETECTOR})
     log_kinds = frozenset({StreamKind.LOG})
 
 
@@ -69,7 +73,8 @@ class ReductionPreprocessorFactory(_KindBasedFactory):
     event_kinds = frozenset(
         {StreamKind.DETECTOR_EVENTS, StreamKind.MONITOR_EVENTS}
     )
-    dense_kinds = frozenset({StreamKind.MONITOR_COUNTS, StreamKind.AREA_DETECTOR})
+    dense_kinds = frozenset({StreamKind.MONITOR_COUNTS})
+    frame_kinds = frozenset({StreamKind.AREA_DETECTOR})
     log_kinds = frozenset({StreamKind.LOG})
 
 

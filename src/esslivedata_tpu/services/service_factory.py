@@ -511,9 +511,13 @@ class DataServiceRunner:
         from ..kafka.consumer import assign_all_partitions
         from ..utils.runtime import (
             enable_persistent_compilation_cache,
+            keep_freed_host_memory,
             log_device_identity,
         )
 
+        # Before the first large array: what a window frees is faulted
+        # in once, not again every window (ADR 0133).
+        keep_freed_host_memory()
         # Before anything compiles: the AOT warm-up path and the live
         # jits share one on-disk compilation cache, so restarts skip XLA.
         enable_persistent_compilation_cache()

@@ -18,6 +18,8 @@ __all__ = [
     "BATCHER_SCALE_CHANGES",
     "BATCH_HOLD_SECONDS",
     "CALIBRATION_SWAPS",
+    "CAMERA_FRAMES",
+    "CAMERA_FRAME_BYTES",
     "DECODE_BATCH_SIZE",
     "DECODE_BYTES",
     "DECODE_ERRORS",
@@ -341,4 +343,29 @@ Q_BINCOUNT_STEPS = REGISTRY.counter(
     "Q-histogram steps dispatched, by how the looked-up bins were "
     "counted (onehot, mxu or scatter)",
     labelnames=("method",),
+)
+
+#: Camera frames summed by the area-detector views
+#: (``workflows/area_detector_view.py`` over ``ops/frames.py``, ADR
+#: 0132), one count a frame, by where its sum is taken: ``device`` = the
+#: uint32 window state on the device (an unsigned frame of at most 16
+#: bits: every ad00 camera of the package), ``host`` = a frame of
+#: another type, summed in a wider type on the host. Both labels have a
+#: sample from import on. device / both is the benchmark's
+#: ``frames_device_share``.
+CAMERA_FRAMES = REGISTRY.counter(
+    "livedata_camera_frames_total",
+    "Camera frames summed by the area-detector views, by where the sum "
+    "is taken (device or host)",
+    labelnames=("path",),
+)
+for _path in ("device", "host"):
+    CAMERA_FRAMES.inc(0.0, path=_path)
+
+#: The bytes of those frames as they came off the wire (the frame's own
+#: type), summed over both paths.
+CAMERA_FRAME_BYTES = REGISTRY.counter(
+    "livedata_camera_frame_bytes_total",
+    "Bytes of the camera frames summed by the area-detector views, in "
+    "the frames' own type",
 )

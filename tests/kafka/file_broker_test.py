@@ -161,3 +161,39 @@ def test_bookmark_beyond_high_watermark_clamps_to_live(broker):
     assert cons.consume(10, 0.0) == []
     prod.produce("alpha", b"new")
     assert [m.value() for m in cons.consume(10, 0.0)] == [b"new"]
+
+
+def test_a_large_value_round_trips_byte_for_byte(broker):
+    """A value of many MiB (a camera image's message) is appended from
+    where it is, behind its header, and read back whole."""
+    value = bytes(range(256)) * (3 * 2**14 + 7)
+    prod = FileBrokerProducer(broker)
+    prod.produce("alpha", b"before")
+    prod.produce("alpha", value, key=b"orca")
+    prod.produce("alpha", b"after")
+    cons = FileBrokerConsumer(broker)
+    cons.assign([type("TP", (), {"topic": "alpha", "offset": 0})()])
+    got = [(m.value(), m.key()) for m in cons.consume(10, 0.0)]
+    assert got == [(b"before", None), (value, b"orca"), (b"after", None)]
+
+
+def test_a_short_write_is_carried_on_to_the_frame_end(broker, monkeypatch):
+    """``writev`` may write fewer bytes than asked: the producer goes on
+    from where it stopped, also inside a part, until the frame is out."""
+    import os
+
+    real_writev = os.writev
+    calls = []
+
+    def short_writev(fd, buffers):
+        calls.append(sum(len(b) for b in buffers))
+        return real_writev(fd, [bytes(memoryview(buffers[0])[:5])])
+
+    monkeypatch.setattr(os, "writev", short_writev)
+    FileBrokerProducer(broker).produce("beta", b"0123456789abcdef", key=b"key")
+    monkeypatch.setattr(os, "writev", real_writev)
+    cons = FileBrokerConsumer(broker)
+    cons.assign([type("TP", (), {"topic": "beta", "offset": 0})()])
+    got = [(m.value(), m.key()) for m in cons.consume(10, 0.0)]
+    assert got == [(b"0123456789abcdef", b"key")]
+    assert len(calls) > 2 and calls == sorted(calls, reverse=True)

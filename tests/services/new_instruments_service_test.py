@@ -175,3 +175,59 @@ class TestOdinCameraService:
         )
         assert cum.data.shape == (8, 10)
         assert cum.data.sum() == 3 * 2.0 * 8 * 10
+
+    def test_uint16_frames_past_65535_publish_exact_uint32_sums(self):
+        """The Orca's uint16 frames through the detector service: each
+        summed on the device into uint32, so a cumulative past 65 535
+        counts a pixel is published exactly, as uint32."""
+        from esslivedata_tpu.config.instruments.odin.specs import CAMERA_HANDLE
+        from esslivedata_tpu.telemetry.instruments import CAMERA_FRAMES
+
+        builder = make_detector_service_builder(
+            instrument="odin", batcher=NaiveMessageBatcher(), job_threads=1
+        )
+        raw = PulsedRawSource([])
+        producer = FakeProducer()
+        sink = KafkaSink(
+            producer,
+            make_default_serializer(builder.stream_mapping.livedata, "odin_u"),
+        )
+        service = builder.from_raw_source(raw, sink)
+        raw.inject(
+            start_command(
+                CAMERA_HANDLE.workflow_id,
+                "orca_camera",
+                "odin_livedata_commands",
+                params={"transpose": True},
+            )
+        )
+        service.step()
+        device_frames = CAMERA_FRAMES.value(path="device")
+        rng = np.random.default_rng(42)
+        frames = [
+            rng.integers(20_000, 40_000, (6, 10)).astype(np.uint16)
+            for _ in range(5)
+        ]
+        t0 = 1_700_000_000_000_000_000
+        for i, frame in enumerate(frames):
+            raw.inject(
+                FakeKafkaMessage(
+                    wire.encode_ad00("odin_orca", t0 + i * 10**9, frame),
+                    "odin_camera",
+                )
+            )
+            service.step()
+        service.step()
+        outputs = decoded(producer, "odin_livedata_data")
+        cum = next(
+            v for v in outputs["cumulative"].variables if v.name == "signal"
+        )
+        current = next(
+            v for v in outputs["current"].variables if v.name == "signal"
+        )
+        expected = np.sum(frames, axis=0, dtype=np.int64).T
+        assert expected.min() > 65_535
+        assert cum.data.dtype == np.uint32 and cum.data.shape == (10, 6)
+        np.testing.assert_array_equal(cum.data, expected)
+        np.testing.assert_array_equal(current.data, frames[-1].T)
+        assert CAMERA_FRAMES.value(path="device") == device_frames + 5

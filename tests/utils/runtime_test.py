@@ -1,6 +1,9 @@
 """Start-up helpers every device-using process calls (utils/runtime.py)."""
 
 import logging
+import os
+import subprocess
+import sys
 from pathlib import Path
 
 import jax
@@ -70,3 +73,104 @@ class TestDeviceIdentity:
         [record] = caplog.records
         assert record.levelno == logging.WARNING
         assert "without being asked" in record.getMessage()
+
+
+class _FakeLibc:
+    """Records ``mallopt`` calls instead of changing this process's
+    allocator; answers ``refuse`` for the parameter it names."""
+
+    def __init__(self, refuse=None):
+        self.calls = []
+        self._refuse = refuse
+
+    def mallopt(self, param, value):
+        self.calls.append((param, value))
+        return 0 if param == self._refuse else 1
+
+
+_MALLOC_ENV = ("GLIBC_TUNABLES", *runtime._MALLOC_ENV)
+
+# A thread makes and frees, window after window, what a camera's
+# publish does: two fetched 16 MiB images, two encoded messages of their
+# size, a flat wire. Printed: the page faults of the last windows.
+_PROBE = """
+import resource, sys, threading
+import numpy as np
+from esslivedata_tpu.utils import runtime
+if sys.argv[1] == "keep":
+    assert runtime.keep_freed_host_memory()
+kept = np.empty((1 << 24) + (1 << 20), np.uint8)
+faults = []
+def windows():
+    for window in range(12):
+        before = resource.getrusage(resource.RUSAGE_SELF).ru_minflt
+        images = [np.full(1 << 22, window, np.uint32) for _ in range(2)]
+        messages = []
+        for image in images:
+            kept[: image.nbytes] = image.view(np.uint8)
+            messages.append(kept.tobytes())
+        flat = np.full(3 << 20, window, np.int32).copy()
+        del images, messages, flat
+        faults.append(resource.getrusage(resource.RUSAGE_SELF).ru_minflt - before)
+thread = threading.Thread(target=windows)
+thread.start()
+thread.join()
+print(max(faults[4:]))
+"""
+
+
+class TestKeepFreedHostMemory:
+    @pytest.fixture(autouse=True)
+    def _untuned(self, monkeypatch):
+        for name in _MALLOC_ENV:
+            monkeypatch.delenv(name, raising=False)
+
+    def test_sets_the_three_thresholds_on_glibc(self, monkeypatch):
+        libc = _FakeLibc()
+        monkeypatch.setattr(runtime, "_glibc", lambda: libc)
+        assert runtime.keep_freed_host_memory() is True
+        assert libc.calls == [(-3, 32 << 20), (-1, 1 << 30), (-2, 64 << 20)]
+
+    @pytest.mark.parametrize(
+        ("name", "value"),
+        [
+            ("MALLOC_TRIM_THRESHOLD_", "131072"),
+            ("MALLOC_MMAP_THRESHOLD_", "131072"),
+            ("MALLOC_TOP_PAD_", "0"),
+            ("GLIBC_TUNABLES", "glibc.malloc.trim_threshold=131072"),
+        ],
+    )
+    def test_the_environments_tuning_stands(self, monkeypatch, name, value):
+        libc = _FakeLibc()
+        monkeypatch.setattr(runtime, "_glibc", lambda: libc)
+        monkeypatch.setenv(name, value)
+        assert runtime.keep_freed_host_memory() is False
+        assert libc.calls == []
+
+    def test_another_c_library_is_left_alone(self, monkeypatch):
+        monkeypatch.setattr(runtime, "_glibc", lambda: None)
+        assert runtime.keep_freed_host_memory() is False
+
+    def test_a_refused_setting_is_reported(self, monkeypatch, caplog):
+        libc = _FakeLibc(refuse=-1)
+        monkeypatch.setattr(runtime, "_glibc", lambda: libc)
+        with caplog.at_level(logging.WARNING, logger=runtime.__name__):
+            assert runtime.keep_freed_host_memory() is False
+        assert "refused" in caplog.text
+
+    @pytest.mark.skipif(runtime._glibc() is None, reason="glibc's malloc")
+    def test_a_windows_arrays_are_faulted_in_once(self):
+        """In a process of its own: glibc's defaults fault a window's
+        large arrays in again every window; kept, they are not."""
+
+        def faults(mode):
+            out = subprocess.run(
+                [sys.executable, "-c", _PROBE, mode],
+                capture_output=True, text=True, check=True,
+                env={**os.environ, "PYTHONPATH": str(REPO / "src")},
+            )
+            return int(out.stdout.split()[-1])
+
+        pages = (1 << 24) // 4096  # of one 16 MiB image
+        assert faults("keep") < pages // 10
+        assert faults("default") > pages
